@@ -31,9 +31,6 @@ import os
 # runnable from a checkout without installation
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from pilosa_tpu.platform import honor_platform_env
-
-honor_platform_env()  # respect JAX_PLATFORMS even under host backend pins
 import time
 import urllib.request
 

@@ -88,21 +88,21 @@ def _deep_update(dst: dict, src: dict) -> None:
             dst[k] = v
 
 
-def _ensure_backend() -> None:
-    """Fall back to the CPU backend when the accelerator can't initialize
-    (e.g. another process holds the chip grant) — a degraded node beats a
-    node whose every query 500s."""
+def _init_backend() -> str | None:
+    """Initialise the backend the environment asks for; the error text
+    when it cannot (no chip, or another process holds it).  There is no
+    stand-in: a node that would serve the device path from the CPU
+    without being asked to is worse than one that does not start."""
     import jax
 
-    from pilosa_tpu.platform import honor_platform_env
+    from pilosa_tpu import jaxcache
 
-    honor_platform_env()
+    jaxcache.configure()
     try:
         jax.devices()
-    except Exception as e:
-        print(f"warning: accelerator unavailable ({e}); using CPU backend")
-        jax.config.update("jax_platforms", "cpu")
-        jax.devices()
+    except RuntimeError as e:
+        return str(e)
+    return None
 
 
 def _parse_statsd_host(raw: str) -> tuple[str, int]:
@@ -123,7 +123,10 @@ def _parse_statsd_host(raw: str) -> tuple[str, int]:
 
 
 def cmd_server(args) -> int:
-    _ensure_backend()
+    err = _init_backend()
+    if err is not None:
+        print(f"error: JAX backend failed to initialise: {err}", file=sys.stderr)
+        return 1
     from pilosa_tpu.obs.stats import MemStatsClient, NOP
     from pilosa_tpu.server.node import NodeServer
 

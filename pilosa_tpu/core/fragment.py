@@ -766,6 +766,21 @@ class Fragment:
     def _device_nbytes(self) -> int:
         return (self.capacity + 1) * self.n_words * 4
 
+    def _to_device(self, padded: np.ndarray) -> jax.Array:
+        """Upload the compute copy to the device that serves this shard.
+        On a multi-device host fragments are dealt round-robin by shard
+        number over the serving mesh's devices — every field's copy of
+        one shard on the same device, so per-shard Row algebra never
+        mixes placements — instead of piling every copy (and the whole
+        ingest upload stream) onto device 0."""
+        from pilosa_tpu.parallel.mesh import serving_mesh
+
+        mesh = serving_mesh()
+        if mesh is None:
+            return jnp.asarray(padded)
+        devices = mesh.devices.flat
+        return jax.device_put(padded, devices[self.shard % len(devices)])
+
     def device_declined(self) -> bool:
         """True when this fragment's full device copy alone would exceed
         the HBM budget cap — callers page rows from the host mirror
@@ -845,7 +860,7 @@ class Fragment:
             if self._device is None or self._device.shape[0] != self.capacity + 1:
                 padded = np.zeros((self.capacity + 1, self.n_words), dtype=np.uint32)
                 padded[: self.capacity] = self._host
-                self._device = jnp.asarray(padded)
+                self._device = self._to_device(padded)
                 self._dirty.clear()
                 self._delta_reset()
                 rebuilt = True
@@ -910,7 +925,7 @@ class Fragment:
                         (self.capacity + 1, self.n_words), dtype=np.uint32
                     )
                     padded[: self.capacity] = self._host
-                    self._device = jnp.asarray(padded)
+                    self._device = self._to_device(padded)
                     h2d = padded.nbytes
                 self._dirty.clear()
                 self._delta_reset()

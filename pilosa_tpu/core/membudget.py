@@ -32,11 +32,14 @@ owner-lock -> budget-lock — the two orders never nest.
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import weakref
 from collections import OrderedDict
 from typing import Callable
+
+logger = logging.getLogger(__name__)
 
 # A pinned working set may not squat on the whole budget: the eviction
 # scan must always be able to find victims, so pin() declines once
@@ -279,23 +282,29 @@ DEFAULT_HBM_FRACTION = 0.8
 
 
 def _probe_device_cap() -> int | None:
-    """Derive a default cap from the local accelerator's memory stats
+    """Derive a default cap from the local accelerators' memory stats
     (reference ships working syswrap defaults — 60k maps,
-    syswrap/mmap.go — rather than unlimited).  None on CPU backends or
-    when the runtime exposes no stats."""
-    try:
-        import jax
+    syswrap/mmap.go — rather than unlimited).  The ledger counts bytes
+    across every local device (stacks are sharded over the serving mesh,
+    fragment copies are dealt round-robin by shard), so the cap is the
+    SUM of their limits.  None on CPU backends or when the runtime
+    exposes no stats."""
+    import jax
 
-        dev = jax.local_devices()[0]
-        if dev.platform == "cpu":
-            return None
-        stats = dev.memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        if not limit:
-            return None
-        return int(limit * DEFAULT_HBM_FRACTION)
-    except Exception:
+    devs = jax.local_devices()
+    if devs[0].platform == "cpu":
         return None
+    limit = sum(
+        int((d.memory_stats() or {}).get("bytes_limit") or 0) for d in devs
+    )
+    if not limit:
+        logger.warning(
+            "%s backend reports no bytes_limit; the HBM budget is"
+            " accounting-only",
+            devs[0].platform,
+        )
+        return None
+    return int(limit * DEFAULT_HBM_FRACTION)
 
 
 def default_budget() -> DeviceBudget:

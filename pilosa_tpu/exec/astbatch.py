@@ -290,9 +290,8 @@ def _compiled_spanning(sig, mesh, axis, chunk, n_stacks):
     uint32 carry-save (exact past int32 — the same machinery as
     ops/kernels.py's spanning pair/gram kinds).  Returns replicated
     (hi, lo) uint32[B] arrays."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from pilosa_tpu.compat import shard_map
 
     from pilosa_tpu.ops import kernels as _k
 

@@ -12,9 +12,16 @@ server can answer two questions the rest of the observability plane cannot:
   read from the ``X-Pilosa-Tenant`` request header and threaded
   http → api → batcher → executor via a contextvar (default tenant ``"-"``).
 
-Compile detection rides ``jax.monitoring``: a cache-hit jit call emits no
-events, while a real XLA compile emits ``backend_compile_duration`` exactly
-once (plus trace/lowering durations), synchronously in the calling thread.
+Compile detection rides ``jax.monitoring``: a jit call served from the
+in-process cache emits no events, while a backend compile request emits
+``backend_compile_duration`` exactly once (plus trace/lowering durations),
+synchronously in the calling thread.  That event wraps jax's
+``compile_or_get_cached``, so with a persistent compilation cache
+(pilosa_tpu/jaxcache.py) it also fires when the executable was only read
+back from disk; jax announces those with ``/jax/compilation_cache/cache_hits``
+just before, and the listener books them apart as ``persistentCacheHits``
+— a retrieval is not a compile, and must trip neither the storm detector
+nor a bench lane's ``forbid_compiles`` guard.
 The listener attributes each event to the innermost active *launch window*
 (``with site.launch(sig=...)``) on that thread; sites that report after the
 fact (the ops.kernels dispatch funnel) claim the thread's stashed events
@@ -55,11 +62,13 @@ _OVERFLOW_PRINCIPAL = ("~overflow", "-", "-")
 _MAX_TENANT_LEN = 64
 _MAX_TRACKED = 8192  # per-site identity set cap (mirrors kernels._seen_programs)
 
-# jax.monitoring event keys (jax 0.4.x).  backend_compile fires once per
-# real XLA compile and never on a cache hit — it is the new-compile signal;
-# the other two are folded into compile wall-time.
+# jax.monitoring event keys.  backend_compile fires once per backend
+# compile request — a new compile unless a persistent-cache hit was
+# announced on the same thread just before; the other durations under the
+# prefix are folded into compile wall-time.
 _EV_BACKEND = "/jax/core/compile/backend_compile_duration"
 _EV_COMPILE_PREFIX = "/jax/core/compile/"
+_EV_PERSISTENT_HIT = "/jax/compilation_cache/cache_hits"
 
 _tenant: ContextVar[str] = ContextVar("devledger_tenant", default=DEFAULT_TENANT)
 # (index, op_class) bound by the api layer once both are known.
@@ -215,6 +224,9 @@ class _TLS(threading.local):
         # cannot grow it forever.
         self.stash_compiles = 0
         self.stash_ms = 0.0
+        # persistent-cache hits announced on this thread whose
+        # backend_compile_duration has not fired yet
+        self.pending_retrievals = 0
 
 
 _tls = _TLS()
@@ -340,6 +352,8 @@ class Ledger:
         self._principals = {}
         self.totals = _Accum()
         self.unattributed = _Accum()
+        # executables read back from jax's persistent compilation cache
+        self.persistent_cache_hits = 0
         self.started = time.monotonic()
         # storm detector
         self.storm_threshold = 8
@@ -412,6 +426,7 @@ class Ledger:
             self._principals.clear()
             self.totals = _Accum()
             self.unattributed = _Accum()
+            self.persistent_cache_hits = 0
             self.started = time.monotonic()
             self._warm_mark = False
             self._storm_events.clear()
@@ -560,10 +575,18 @@ class Ledger:
             from jax import monitoring as _mon
 
             _mon.register_event_duration_secs_listener(self._on_event)
+            _mon.register_event_listener(self._on_plain_event)
         except Exception:
             # no jax / no monitoring API: sites still work via explicit
             # record_compile / track(); only automatic detection is lost
             self._listener_installed = True
+
+    def _on_plain_event(self, key, **kw):
+        """jax.monitoring plain-event listener: remembers a persistent
+        cache hit until the backend_compile_duration that closes the
+        same request arrives on this thread.  Must never raise."""
+        if key == _EV_PERSISTENT_HIT:
+            _tls.pending_retrievals += 1
 
     def _on_event(self, key, seconds, **kw):
         """jax.monitoring duration listener.  Fires synchronously in the
@@ -574,6 +597,11 @@ class Ledger:
                 return
             ms = seconds * 1e3
             is_compile = key == _EV_BACKEND
+            if is_compile and _tls.pending_retrievals:
+                _tls.pending_retrievals -= 1
+                is_compile = False
+                with self._lock:
+                    self.persistent_cache_hits += 1
             windows = _tls.windows
             if windows:
                 w = windows[-1]
@@ -653,6 +681,7 @@ class Ledger:
                 "deviceMs": round(self.totals.device_ms, 3),
                 "h2dBytes": self.totals.h2d_bytes,
                 "d2hBytes": self.totals.d2h_bytes,
+                "persistentCacheHits": self.persistent_cache_hits,
                 "storms": len(self.storms),
             }
             for name, s in self._sites.items():
@@ -713,6 +742,7 @@ class Ledger:
                 "uptimeSec": round(uptime, 3),
                 "warm": self.warm,
                 "totals": self.totals.to_dict(uptime),
+                "persistentCacheHits": self.persistent_cache_hits,
                 "unattributed": {
                     "compiles": self.unattributed.compiles,
                     "compileMs": round(self.unattributed.compile_ms, 3),

@@ -86,7 +86,7 @@ NOP = NopStatsClient()
 
 # Prometheus-style cumulative bucket bounds.  Log-spaced seconds: the
 # sub-ms bounds (50/100/250/500 µs) resolve the measured serving-cache
-# floor of 0.07-0.16 ms/op (BENCH_r05) — without them every read-path
+# floor of ~0.1 ms/op (host-served hits) — without them every read-path
 # latency collapses into the first bucket and p999 is meaningless — and
 # the top end still covers multi-second cluster queries.
 HISTOGRAM_BUCKETS = (

@@ -150,21 +150,32 @@ class SystemInfo:
         }
 
     def devices(self) -> list[dict]:
-        """Accelerator inventory (TPU-native extension)."""
+        """Accelerator inventory (TPU-native extension), with each
+        device's memory in use where the backend reports it — on a
+        multi-chip host the per-device figures show whether stacks and
+        fragment copies are spread or piled on device 0."""
         try:
             import jax
 
-            return [
-                {
-                    "id": d.id,
-                    "kind": d.device_kind,
-                    "platform": d.platform,
-                    "process": d.process_index,
-                }
-                for d in jax.devices()
-            ]
-        except Exception:
+            devs = jax.devices()
+        except (ImportError, RuntimeError):
             return []
+        out = []
+        for d in devs:
+            rec = {
+                "id": d.id,
+                "kind": d.device_kind,
+                "platform": d.platform,
+                "process": d.process_index,
+            }
+            local = d.process_index == jax.process_index()
+            stats = d.memory_stats() if local else None
+            if stats:
+                rec["bytesInUse"] = stats.get("bytes_in_use")
+                rec["peakBytesInUse"] = stats.get("peak_bytes_in_use")
+                rec["bytesLimit"] = stats.get("bytes_limit")
+            out.append(rec)
+        return out
 
     def to_dict(self) -> dict:
         m = self._meminfo()

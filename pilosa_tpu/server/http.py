@@ -500,6 +500,12 @@ class Handler(BaseHTTPRequestHandler):
         # process identity block: pid/version/uptime — distinct from the
         # host report in /info (sysinfo.py reports host uptime there)
         snap["process"] = sysinfo.SystemInfo().process_block(__version__)
+        # which native libraries this process loaded (or why not): the
+        # latency tier and the roaring codec fall back to numpy without
+        # them (nativelib.py)
+        from pilosa_tpu import nativelib
+
+        snap["native"] = nativelib.status()
         blackbox = getattr(self.api, "blackbox", None)
         if blackbox is not None:
             # black-box writer self-accounting: checkpoint counts/cost,

@@ -23,7 +23,7 @@ _SRC = os.path.join(
     "native",
     "roaring_codec.cpp",
 )
-_LIB_PATH = os.path.join(os.path.dirname(_SRC), "libpilosa_native.so")
+_LIB_STEM = "libpilosa_native"
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -41,7 +41,7 @@ def load() -> ctypes.CDLL | None:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        _lib = nativelib.load(_SRC, _LIB_PATH, _bind)
+        _lib = nativelib.load(_SRC, _LIB_STEM, _bind)
         return _lib
 
 

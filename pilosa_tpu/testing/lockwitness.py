@@ -77,7 +77,11 @@ class _State:
     """Process-global witness state (reset by tests)."""
 
     def __init__(self):
-        self.guard = _real_lock()
+        # Re-entrant: a weakref finalizer (membudget.release on a dead
+        # field) can run at any allocation, including one made under this
+        # guard by a thread that holds a witnessed lock; its own lock
+        # acquisition then comes back here on the same thread.
+        self.guard = _real_rlock()
         # (a, b) -> short witness string for the first observed a-then-b
         self.edges: dict[tuple[str, str], str] = {}
         self.inversions: list[dict] = []

@@ -322,8 +322,7 @@ _WORKER = r"""
 import json, os, sys, threading
 
 os.environ.setdefault("PILOSA_TPU_SHARD_WIDTH", "13")
-import jax
-jax.config.update("jax_platforms", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"  # several processes, one machine
 
 sys.path.insert(0, os.environ["REPO"])
 from pilosa_tpu.server.node import NodeServer

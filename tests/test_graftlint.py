@@ -146,10 +146,9 @@ class TestBadCorpusCoverage:
         assert "direct pmap" in msgs
         assert all("device-cost-ledger" in f.message for f in findings)
 
-    def test_launch_ledger_and_shim_exempt(self):
+    def test_launch_ledger_exempt(self):
         p = BY_ID["launch-discipline"]
         assert not p.applies("pilosa_tpu/obs/devledger.py")
-        assert not p.applies("pilosa_tpu/compat.py")
         assert p.applies("pilosa_tpu/ops/kernels.py")
         assert not p.applies("tools/bench.py")
 

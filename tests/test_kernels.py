@@ -69,18 +69,9 @@ def test_row_counts_matches_numpy(r):
     rng = np.random.default_rng(r)
     S, W = 3, 128
     bits = _rand_bits(rng, S, r, W)
-    got = np.asarray(kernels.row_counts_pallas(jnp.asarray(bits)))
+    got = np.asarray(kernels.row_counts_xla(jnp.asarray(bits)))
     want = np.bitwise_count(bits).sum(axis=(0, 2))
     assert got.tolist() == want.tolist()
-
-
-def test_row_counts_pallas_vs_xla():
-    rng = np.random.default_rng(1)
-    bits = jnp.asarray(_rand_bits(rng, 4, 10, 256))
-    assert (
-        np.asarray(kernels.row_counts_pallas(bits)).tolist()
-        == np.asarray(kernels.row_counts_xla(bits)).tolist()
-    )
 
 
 def test_dispatch_wrappers_run():
@@ -95,9 +86,7 @@ def test_dispatch_wrappers_run():
 def test_row_counts_per_shard_matches_numpy():
     rng = np.random.default_rng(21)
     bits = _rand_bits(rng, 3, 9, 256)
-    got = np.asarray(kernels.row_counts_per_shard_pallas(jnp.asarray(bits)))
     want = np.bitwise_count(bits).sum(axis=2)
-    assert got.tolist() == want.tolist()
     got_x = np.asarray(kernels.row_counts_per_shard_xla(jnp.asarray(bits)))
     assert got_x.tolist() == want.tolist()
 
@@ -296,23 +285,6 @@ def test_combo_counts_gram_declines_oversized_prefix():
     # work, so a zeros placeholder suffices
     prefix = jnp.zeros((big_c, S, W), jnp.uint32)
     assert kernels.combo_counts_gram(prefix, bits, jnp.arange(4)) is None
-
-
-def test_pallas_row_block_vmem_bounds():
-    """Tile sizing respects the VMEM budget; infeasible shapes return 0
-    and the wrappers delegate to XLA instead of a doomed compile."""
-    # typical serving shape fits
-    assert kernels._pallas_row_block(32768, 64) >= 128
-    # enormous row axis: no dividing block fits -> 0
-    assert kernels._pallas_row_block(32768, 100_000) == 0
-    # wrappers still answer (XLA delegate), matching ground truth
-    rng = np.random.default_rng(41)
-    bits = _rand_bits(rng, 2, 3, 64)
-    big_r = int(kernels._PALLAS_VMEM_BUDGET // (kernels._SHARD_BLOCK * 128 * 4)) + 1
-    assert kernels._pallas_row_block(64, big_r) == 0
-    got = np.asarray(kernels.row_counts_per_shard_pallas(jnp.asarray(bits)))
-    want = np.bitwise_count(bits).sum(axis=2)
-    assert got.tolist() == want.tolist()
 
 
 class TestFusedGramPallas:

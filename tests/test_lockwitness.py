@@ -203,6 +203,50 @@ class TestEdgeSemantics:
             assert lockwitness.findings() == []
 
 
+class TestFinalizerReentrancy:
+    def test_lock_taken_from_inside_the_guard_does_not_deadlock(self):
+        """``weakref.finalize(field, budget.release, ...)`` runs wherever
+        the collector fires — also at an allocation inside the witness's
+        own critical section, on a thread that holds a witnessed lock.
+        The finalizer's lock acquisition then re-enters the witness on
+        the same thread; with a plain guard that thread waited for itself
+        and every later witnessed acquisition in the process queued up
+        behind it (a tier-1 run that stops with no CPU use).  The
+        collector is stood in for by a held-site whose formatting, which
+        happens under the guard, takes a lock."""
+        with lockwitness.active(mode="raise"):
+            a, b = _two_locks()
+            c = threading.Lock()
+
+            class CollectorFiresHere:
+                fired = False
+
+                def __format__(self, spec):
+                    if not self.fired:
+                        self.fired = True
+                        with c:
+                            pass
+                    return "site"
+
+            def worker():
+                with a:
+                    held = lockwitness._state.held()
+                    held[-1] = (held[-1][0], CollectorFiresHere())
+                    with b:
+                        pass
+
+            t = threading.Thread(target=worker, daemon=True)
+            t.start()
+            t.join(timeout=10.0)
+            if t.is_alive():
+                # the stuck worker owns the guard for good: give the rest
+                # of the session a fresh one before failing
+                lockwitness._state.guard = lockwitness._real_rlock()
+                pytest.fail("re-entrant acquisition under the guard deadlocked")
+            assert lockwitness.findings() == []
+            assert lockwitness.stats()["edges"] >= 2  # a->b and a->c
+
+
 class TestInstallScoping:
     def test_out_of_scope_allocations_pass_through(self):
         with lockwitness.active(mode="raise"):

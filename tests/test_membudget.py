@@ -261,6 +261,22 @@ def test_default_cap_derived_from_tpu_memory_stats(monkeypatch):
     assert b.cap == int(10_000_000_000 * mb.DEFAULT_HBM_FRACTION)
 
 
+def test_default_cap_sums_every_local_device(monkeypatch):
+    """The ledger counts bytes on all local devices (sharded stacks,
+    round-robin fragment copies), so the probed cap is their sum."""
+    import pilosa_tpu.core.membudget as mb
+
+    monkeypatch.delenv("PILOSA_TPU_HBM_BUDGET_BYTES", raising=False)
+    monkeypatch.setattr(
+        "jax.local_devices",
+        lambda: [_FakeDev("tpu", {"bytes_limit": 10_000_000_000})] * 4,
+    )
+    monkeypatch.setattr(mb, "_default", None)
+    assert mb.default_budget().cap == int(
+        4 * 10_000_000_000 * mb.DEFAULT_HBM_FRACTION
+    )
+
+
 def test_default_cap_unlimited_on_cpu(monkeypatch):
     import pilosa_tpu.core.membudget as mb
 

@@ -21,8 +21,8 @@ _WORKER = r"""
 import os, sys
 import numpy as np
 
+os.environ["JAX_PLATFORMS"] = "cpu"  # two processes, one machine
 import jax
-jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 2)
 
 sys.path.insert(0, os.environ["REPO"])

@@ -399,3 +399,64 @@ def test_import_merge_absent_row_id_skipped():
         )
         assert got[0] == n_ref, (case, got[0], n_ref)
         np.testing.assert_array_equal(mir, ref, err_msg=f"case {case}")
+
+
+# -- nativelib: a library is named by what it was built from ------------------
+
+_TINY_SRC = 'extern "C" int tiny_answer() { return 42; }\n'
+
+
+def _bind_tiny(lib):
+    import ctypes
+
+    lib.tiny_answer.restype = ctypes.c_int
+    lib.tiny_answer.argtypes = []
+
+
+def test_nativelib_name_follows_source_flags_and_cpu(tmp_path, monkeypatch):
+    """A library built elsewhere (other source, other flags, other CPU)
+    lives under another name, so it is never found, only rebuilt."""
+    from pilosa_tpu import nativelib
+
+    src = tmp_path / "tiny.cpp"
+    src.write_text(_TINY_SRC)
+    here = nativelib.lib_path(str(src), "libtiny")
+    assert here == nativelib.lib_path(str(src), "libtiny")
+    assert here != nativelib.lib_path(str(src), "libtiny", extra=())
+    monkeypatch.setattr(nativelib, "_cpu_flags", lambda: "another cpu")
+    assert nativelib.lib_path(str(src), "libtiny") != here
+    monkeypatch.undo()
+    src.write_text(_TINY_SRC + "// edited\n")
+    assert nativelib.lib_path(str(src), "libtiny") != here
+
+
+def test_nativelib_ignores_a_library_it_did_not_build(tmp_path):
+    from pilosa_tpu import nativelib
+
+    src = tmp_path / "tiny.cpp"
+    src.write_text(_TINY_SRC)
+    # what the old loader would have picked up: a fixed name, newer than
+    # the source, from who knows which machine
+    (tmp_path / "libtiny.so").write_bytes(b"not a library")
+    lib = nativelib.load(str(src), "libtiny", _bind_tiny)
+    assert lib is not None and lib.tiny_answer() == 42
+    st = nativelib.status()["libtiny"]
+    assert st["built"] and st["path"] == nativelib.lib_path(str(src), "libtiny")
+    # a second load finds this machine's own build and does not rebuild
+    assert nativelib.load(str(src), "libtiny", _bind_tiny) is not None
+    assert nativelib.status()["libtiny"]["built"] is False
+
+
+def test_nativelib_build_failure_is_logged_with_the_compilers_words(
+    tmp_path, caplog
+):
+    from pilosa_tpu import nativelib
+
+    src = tmp_path / "broken.cpp"
+    src.write_text("this is not C++;\n")
+    with caplog.at_level("WARNING", logger="pilosa_tpu.nativelib"):
+        assert nativelib.load(str(src), "libbroken", _bind_tiny) is None
+    st = nativelib.status()["libbroken"]
+    assert st["path"] is None and "error" in st["error"]
+    assert any("broken.cpp" in r.getMessage() for r in caplog.records)
+    assert list(tmp_path.glob("*.so*")) == []  # no temp file left behind

@@ -244,4 +244,4 @@ def test_kernel_series_in_metrics_and_debug_vars():
         )
         k = dv["kernels"]
         assert sum(k["dispatch_lanes"].values()) >= 1
-        assert "pallas_ok" in k and "pallas_fallbacks" in k
+        assert "gram_gates" in k and "pallas_fallbacks" in k

@@ -18,8 +18,7 @@ funnel names above.  Jitted helpers that are only ever invoked beneath
 another site's window may carry a per-line suppression instead — the
 mandatory reason must say which site adopts their dispatches.
 
-Scope: ``pilosa_tpu/`` only, excluding ``compat.py`` (the shard_map
-shim definition itself) and the ledger module.
+Scope: ``pilosa_tpu/`` only, excluding the ledger module.
 """
 
 from __future__ import annotations
@@ -65,10 +64,7 @@ def applies(path: str) -> bool:
     p = path.replace("\\", "/")
     if "pilosa_tpu/" not in p:
         return False
-    return not (
-        p.endswith("pilosa_tpu/compat.py")
-        or p.endswith("pilosa_tpu/obs/devledger.py")
-    )
+    return not p.endswith("pilosa_tpu/obs/devledger.py")
 
 
 def _is_registered(tree: ast.AST) -> bool:

@@ -363,6 +363,9 @@ def main(argv: list[str] | None = None) -> int:
         nodes = max(nodes, 2)
         cluster_kwargs["mesh_dispatch"] = False
 
+    from pilosa_tpu import jaxcache
+
+    jaxcache.configure()
     report = run_harness(
         config,
         stages,

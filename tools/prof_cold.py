@@ -9,9 +9,6 @@ import time
 import numpy as np
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

@@ -9,7 +9,7 @@ end over actual HTTP:
   subrequests — the fan-out was one jit-sharded launch;
 * ``/metrics`` shows ``pilosa_dist_mesh_local_total`` advanced;
 * ``/debug/vars`` carries a ``dist`` block (placement map + partition
-  decisions);
+  decisions) with no mesh fallback booked;
 * the ``?profile=true`` span tree contains a ``meshDispatch`` span and
   NO ``dist.fanout``/``dist.httpFanout`` leg, and the request itself is
   tail-kept in ``/debug/traces``;
@@ -103,6 +103,11 @@ def main() -> int:
         assert dist["meshEnabled"] is True, dist
         assert dist["placement"], dist
         assert dist["meshDispatches"] >= 1, dist
+        assert dist["meshFallbacks"] == 0, dist
+        assert not any(
+            k.startswith("dist_mesh_fallback_total")
+            for k in vars_.get("counters", {})
+        ), vars_.get("counters")
         assert dist["recentPartitions"], dist
 
         # span attribution: the dispatch shows up as ONE meshDispatch
@@ -148,7 +153,13 @@ def main() -> int:
             ), metrics[:600]
         finally:
             del os.environ["PILOSA_MESH_DISPATCH"]
-    print("meshdist smoke OK")
+    import jax
+
+    d = jax.devices()
+    print(
+        f"meshdist smoke OK on {len(d)} x {d[0].platform} ({d[0].device_kind}): "
+        f"zero HTTP subrequests, meshFallbacks 0"
+    )
     return 0
 
 
