@@ -1,0 +1,158 @@
+"""The one general traffic generator.
+
+A traffic mix is a data file (``traffic/<mix>.json``).  This module turns
+a mix, a configuration and a seed into request streams: one stream per
+connection, each a function of ``(seed, phase, connection)`` alone, so the
+same seed sends the same requests whatever the timing.
+
+Mix file::
+
+    {"loop": "closed", "connections": 32, "processes": 4,
+     "zipf_theta": 0.99, "check_one_in": 40, "check_max": 128,
+     "classes": {
+       "<class>": {"weight": 40,
+                   "variants": ["Count(Intersect(Row(cab_type={c}), Row(passenger_count={p})))"],
+                   "slots": {"c": {"pick": "row", "field": "cab_type"}, ...}}}}
+
+Every class reads; each connection sends its next request when the last
+has answered (a closed loop).
+
+Classes are dealt from a shuffled deck that holds each class ``weight``
+times, so every seed sends the classes in the same shares, in another
+order.  Slot picks:
+
+``row``            a row of ``field``, zipfian by popularity -> ``{x}``
+``int``            uniform in the int ``field``'s range       -> ``{x}``
+``int_range``      two of those, ordered         -> ``{x[lo]}``, ``{x[hi]}``
+"""
+
+from __future__ import annotations
+
+import hashlib
+
+import numpy as np
+
+from datagen import fields_by_name, popularity_order
+
+PHASES = {"window": 0, "warm": 1}
+
+
+class Mix:
+    def __init__(self, cfg: dict, mix: dict):
+        self.cfg = cfg
+        self.fields = fields_by_name(cfg)
+        self.theta = float(mix.get("zipf_theta", 0.99))
+        self.classes = mix["classes"]
+        self.deck = [name for name, c in self.classes.items() for _ in range(int(c["weight"]))]
+        self._cdf: dict[int, np.ndarray] = {}
+        self._order = {n: popularity_order(f) for n, f in self.fields.items() if f["kind"] == "set"}
+
+    def _zipf(self, rng, n: int) -> int:
+        if n not in self._cdf:
+            cdf = np.cumsum(np.arange(1, n + 1, dtype=np.float64) ** -self.theta)
+            self._cdf[n] = cdf / cdf[-1]
+        return min(int(np.searchsorted(self._cdf[n], rng.random(), side="right")), n - 1)
+
+    def _row(self, rng, field: str, uniform: bool) -> int:
+        order = self._order[field]
+        return int(order[rng.integers(len(order)) if uniform else self._zipf(rng, len(order))])
+
+    def _pick(self, rng, spec: dict, uniform: bool):
+        kind = spec["pick"]
+        if kind == "row":
+            return self._row(rng, spec["field"], uniform)
+        if kind == "int":
+            f = self.fields[spec["field"]]
+            return int(rng.integers(f["min"], f["max"] + 1))
+        if kind == "int_range":
+            f = self.fields[spec["field"]]
+            lo, hi = sorted(int(x) for x in rng.integers(f["min"], f["max"] + 1, 2))
+            return {"lo": lo, "hi": hi + 1}
+        raise ValueError(f"unknown slot pick {kind!r}")
+
+    def _draw(self, rng, cls: str, variant: int | None, uniform: bool) -> tuple[str, set]:
+        """One request of the class and the (field, row) pairs it names."""
+        c = self.classes[cls]
+        specs = c.get("slots", {})
+        slots = {name: self._pick(rng, spec, uniform) for name, spec in specs.items()}
+        variants = c["variants"]
+        if variant is None:
+            variant = int(rng.integers(len(variants)))
+        text = variants[variant]
+        rows = {(spec["field"], slots[name]) for name, spec in specs.items()
+                if spec["pick"] == "row" and "{" + name + "}" in text}
+        return text.format(**slots), rows
+
+    def request(self, rng, cls: str, variant: int | None = None) -> str:
+        """One request of the class, of a random variant unless one is
+        named; rows zipfian by popularity."""
+        return self._draw(rng, cls, variant, uniform=False)[0]
+
+    def sweep(self, seed: int, largest: int):
+        """The warm-up's first pass, (class, calls of one request).  The
+        program compiles one program per query shape and per size of a
+        batch rounded up to a power of two; it batches the calls of one
+        request like the requests of one flight, and answers a call it has
+        seen from its result cache without a launch.  So each request here
+        holds 1, 2, 4, ... ``largest`` calls that were never sent before, as
+        far up as there are such calls to give: of all the variants of a
+        class in turn (lanes that batch across variants), then of each
+        variant alone (lanes that compile per pair of fields).  The planner
+        may evaluate a row that several calls share once and apart, which
+        changes the batch, so each variant goes twice: with calls as they
+        come, and with calls that share no row.  The same walk under every
+        seed."""
+        rng = np.random.default_rng([int(seed), PHASES["warm"], 0x5EE9])
+        sent: set[str] = set()
+        for cls, c in self.classes.items():
+            alone = [[v] for v in range(len(c["variants"]))]
+            passes = [(sum(alone, []), True)] if len(alone) > 1 else []
+            for group in alone:
+                passes.append((group, False))
+                if self._draw(rng, cls, group[0], uniform=True)[1]:  # it names rows
+                    passes.append((group, True))
+            for group, disjoint in passes:
+                k = 1
+                while k <= largest:
+                    calls: list[str] = []
+                    rows: set = set()
+                    for t in range(40 * k):
+                        pql, named = self._draw(rng, cls, group[t % len(group)], uniform=True)
+                        if pql not in sent and not (disjoint and named & rows):
+                            sent.add(pql)
+                            rows |= named
+                            calls.append(pql)
+                            if len(calls) == k:
+                                break
+                    if calls:
+                        yield cls, calls
+                    if len(calls) < k:
+                        break
+                    k *= 2
+
+    def stream(self, seed: int, phase: str, conn: int):
+        """Endless (class, pql) for one connection."""
+        rng = np.random.default_rng([int(seed), PHASES[phase], int(conn), 0x3C1])
+        while True:
+            for cls in (self.deck[i] for i in rng.permutation(len(self.deck))):
+                yield cls, self.request(rng, cls)
+
+
+def fingerprint(cfg: dict, mix: dict, seed: int, per_conn: int = 200) -> str:
+    """sha256 over the first ``per_conn`` window requests of every
+    connection: two builds from one seed must agree."""
+    m = Mix(cfg, mix)
+    h = hashlib.sha256()
+    for conn in range(int(mix["connections"])):
+        s = m.stream(seed, "window", conn)
+        for _ in range(per_conn):
+            cls, pql = next(s)
+            h.update(f"{conn}\x00{cls}\x00{pql}\n".encode())
+    return h.hexdigest()
+
+
+def sampled(seed: int, conn: int, n: int, one_in: int) -> bool:
+    """Whether request ``n`` of connection ``conn`` keeps its answer for
+    the comparison: a sample drawn from the seed, one in ``one_in``."""
+    h = hashlib.blake2b(f"{seed}/{conn}/{n}".encode(), digest_size=8).digest()
+    return int.from_bytes(h, "little") % one_in == 0

@@ -1,0 +1,232 @@
+"""The plain reference: the same PQL semantics in numpy.
+
+Independent of ``pilosa_tpu``: it parses the PQL text the generator sent
+with a small parser of its own and evaluates it over data it generated
+itself from the seed (``datagen``): one row id (set field) or one value
+(int field) per column.
+"""
+
+from __future__ import annotations
+
+import re
+
+import numpy as np
+
+from datagen import UNSET, fields_by_name, gen_slab, slabs_per_shard, width_of
+
+# ---------------------------------------------------------------------------
+# PQL: the calls the traffic mixes use
+# ---------------------------------------------------------------------------
+
+_TOKEN = re.compile(
+    r"\s*(?:(?P<ts>\d{4}-\d\d-\d\dT\d\d:\d\d)|(?P<num>-?\d+)|(?P<id>[A-Za-z_][A-Za-z0-9_-]*)"
+    r"|(?P<op>><|<=|>=|==|!=|[=<>(),\[\]]))"
+)
+
+
+class Call:
+    """``Name(positional..., key=value..., field <op> value)``."""
+
+    def __init__(self, name: str):
+        self.name = name
+        self.pos: list = []       # Calls, ints, field names, timestamps
+        self.kw: dict = {}        # key -> value
+        self.cond: tuple | None = None  # (field, [(op, value), ...])
+
+    def __repr__(self) -> str:
+        return f"Call({self.name}, {self.pos}, {self.kw}, {self.cond})"
+
+
+def tokenize(text: str) -> list[tuple[str, object]]:
+    out, i = [], 0
+    text = text.strip()
+    while i < len(text):
+        m = _TOKEN.match(text, i)
+        if m is None:
+            raise ValueError(f"cannot read PQL at {text[i:i + 20]!r}")
+        kind = m.lastgroup
+        val = m.group(kind)
+        out.append((kind, int(val) if kind == "num" else val))
+        i = m.end()
+    return out
+
+
+def parse(text: str) -> Call:
+    toks = tokenize(text)
+    call, i = _parse_call(toks, 0)
+    if i != len(toks):
+        raise ValueError(f"trailing PQL in {text!r}")
+    return call
+
+
+_CMP = {"<", ">", "<=", ">=", "==", "!=", "><"}
+_FLIP = {"<": ">", ">": "<", "<=": ">=", ">=": "<="}
+
+
+def _parse_value(toks, i):
+    kind, val = toks[i]
+    if kind == "op" and val == "[":
+        items = []
+        i += 1
+        while toks[i] != ("op", "]"):
+            if toks[i] != ("op", ","):
+                items.append(toks[i][1])
+            i += 1
+        return items, i + 1
+    if kind == "id" and i + 1 < len(toks) and toks[i + 1] == ("op", "("):
+        return _parse_call(toks, i)
+    return val, i + 1
+
+
+def _parse_call(toks, i):
+    kind, name = toks[i]
+    if kind != "id" or toks[i + 1] != ("op", "("):
+        raise ValueError(f"expected a call at token {i}")
+    call = Call(name)
+    i += 2
+    while toks[i] != ("op", ")"):
+        if toks[i] == ("op", ","):
+            i += 1
+            continue
+        first, j = _parse_value(toks, i)
+        nxt = toks[j]
+        if nxt == ("op", "=") and toks[i][0] == "id":
+            val, j = _parse_value(toks, j + 1)
+            call.kw[first] = val
+        elif nxt[0] == "op" and nxt[1] in _CMP:
+            if toks[i][0] == "id":  # field <op> value
+                val, j = _parse_value(toks, j + 1)
+                call.cond = (first, [(nxt[1], val)])
+            else:  # value <op> field <op> value
+                field = toks[j + 1][1]
+                op2 = toks[j + 2][1]
+                val2, j = _parse_value(toks, j + 3)
+                call.cond = (field, [(_FLIP[nxt[1]], first), (op2, val2)])
+        else:
+            call.pos.append(first)
+        i = j
+    return call, i + 1
+
+
+# ---------------------------------------------------------------------------
+# state
+# ---------------------------------------------------------------------------
+
+
+class Reference:
+    def __init__(self, cfg: dict, seed: int):
+        self.cfg, self.seed = cfg, int(seed)
+        self.width = width_of(cfg)
+        self.shards = int(cfg["shards"])
+        self.slab = int(cfg["slab_rides"])
+        self.hi = int(cfg["columns"])
+        self.fields = fields_by_name(cfg)
+        # [shards, columns]: the row id (set field) or the value (int field) of each column
+        self.one: dict[str, np.ndarray] = {
+            f["name"]: (np.full((self.shards, self.hi), -1, np.int32) if f["kind"] == "int"
+                        else np.full((self.shards, self.hi), UNSET, np.uint16))
+            for f in cfg["fields"]}
+        self._answers: dict = {}
+
+    def apply_slab(self, shard: int, slab: int, values: dict) -> None:
+        lo = slab * self.slab
+        for name, v in values.items():
+            self.one[name][shard, lo:lo + self.slab] = v
+
+    def load(self) -> None:
+        """The load stage's data: every slab of every shard."""
+        for shard in range(self.shards):
+            for slab in range(slabs_per_shard(self.cfg)):
+                self.apply_slab(shard, slab, gen_slab(self.cfg, self.seed, shard, slab))
+
+    # -- reads -------------------------------------------------------------
+
+    def bitmap(self, c: Call) -> np.ndarray:
+        """A bitmap call -> bool [shards, columns]."""
+        if c.name == "Row" or c.name == "Range":
+            if c.cond is not None:
+                name, conds = c.cond
+                v = self.one[name]
+                out = v >= 0
+                for op, x in conds:
+                    out &= _compare(v, op, x)
+                return out
+            (name, row), = c.kw.items()
+            return self.one[name] == row
+        if c.name not in ("Intersect", "Union", "Difference", "Xor"):
+            raise ValueError(f"no reference for {c.name}")
+        parts = [self.bitmap(p) for p in c.pos]
+        out = parts[0]
+        for p in parts[1:]:
+            if c.name == "Intersect":
+                out = out & p
+            elif c.name == "Union":
+                out = out | p
+            elif c.name == "Difference":
+                out = out & ~p
+            else:
+                out = out ^ p
+        return out
+
+    def row_counts(self, name: str, filt: np.ndarray | None) -> np.ndarray:
+        v = self.one[name] if filt is None else self.one[name][filt]
+        n = int(self.fields[name]["rows"])
+        return np.bincount(v.ravel(), minlength=UNSET + 1)[:n].astype(np.int64)
+
+    def answer(self, pql: str):
+        """``evaluate`` of the PQL text, remembered: a dashboard repeats
+        its unparametrised panels."""
+        if pql not in self._answers:
+            if len(self._answers) > 512:
+                self._answers.clear()
+            self._answers[pql] = self.evaluate(parse(pql))
+        return self._answers[pql]
+
+    def evaluate(self, c: Call):
+        """The call's answer in a plain form ``compare`` understands:
+        Count -> int; Sum -> (value, count); TopN -> (per-row counts, n);
+        GroupBy -> (field names, counts by combined row ids); a bitmap
+        call -> sorted column ids."""
+        if c.name == "Count":
+            return int(np.count_nonzero(self.bitmap(c.pos[0])))
+        if c.name == "Sum":
+            v = self.one[c.kw["field"]]
+            m = v >= 0
+            if c.pos:
+                m &= self.bitmap(c.pos[0])
+            return int(v[m].sum(dtype=np.int64)), int(np.count_nonzero(m))
+        if c.name == "TopN":
+            filt = self.bitmap(c.pos[1]) if len(c.pos) > 1 else None
+            return self.row_counts(c.pos[0], filt), c.kw.get("n", 0)
+        if c.name == "GroupBy":
+            names = [r.pos[0] for r in c.pos]
+            sizes = [int(self.fields[n]["rows"]) for n in names]
+            valid = self.bitmap(c.kw["filter"]) if "filter" in c.kw else None
+            code = np.zeros((self.shards, self.hi), np.int32)
+            for n, size in zip(names, sizes):
+                v = self.one[n]
+                valid = (v != UNSET) if valid is None else valid & (v != UNSET)
+                code = code * np.int32(size) + v
+            counts = np.bincount(code[valid], minlength=int(np.prod(sizes)))
+            return names, counts.reshape(sizes)
+        cols = np.flatnonzero(self.bitmap(c).ravel())
+        # [shards, columns] -> global column ids
+        return (cols // self.hi) * self.width + cols % self.hi
+
+
+def _compare(v: np.ndarray, op: str, x) -> np.ndarray:
+    if op == "<":
+        return v < x
+    if op == "<=":
+        return v <= x
+    if op == ">":
+        return v > x
+    if op == ">=":
+        return v >= x
+    if op == "==":
+        return v == x
+    if op == "!=":
+        return v != x
+    if op == "><":
+        return (v >= x[0]) & (v <= x[1])
+    raise ValueError(op)

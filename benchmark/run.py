@@ -1,0 +1,609 @@
+"""The benchmark's command.
+
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+One run of one cell of ``BENCHMARK.json``: start the server child, make the
+schema, load the configuration's data from the seed through the import
+route, warm up every shape of the cell's mix, drive the mix for the
+window, stop the server, judge the window's own answers against the numpy
+reference, and print one result line built from the manifest.
+
+This process never imports JAX: the server child holds the chip.  The
+cell's configuration, traffic mix and per-layer metrics are files found by
+the names in ``BENCHMARK.json`` (``README.md`` beside this file).
+
+``--rehearsal`` drives the same stages on the CPU at the tiny shape each
+configuration file gives under ``rehearsal``; it says so, exits 3 and can
+never print a passing result.  ``--control lossy`` judges the reference
+with a stated guarantee broken in the program's place, which has to come
+out as not correct.
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib.util
+import json
+import math
+import os
+import pickle
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import tempfile
+import time
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(HERE)
+sys.path.insert(0, HERE)
+
+import manifest as mf  # noqa: E402
+from loadgen import Conn  # noqa: E402  (numpy only)
+
+TRACE_CAP_S = 10.0      # a traced run's window: traces are large and tracing slows the host
+WARM_POLL_S = 1.0
+WARM_QUIET_S = 10.0     # warm-up ends after this long with nothing compiled or retrieved
+WARM_LIMIT_S = 900.0
+LOADERS = 4             # processes that import the load stage, each its share of the shards
+
+
+class RunFailure(Exception):
+    """The run cannot give a result; the message says why."""
+
+
+def log(msg: str) -> None:
+    print(f"[bench +{time.monotonic() - _T0:7.1f}s] {msg}", file=sys.stderr, flush=True)
+
+
+_T0 = time.monotonic()
+
+
+# ---------------------------------------------------------------------------
+# processes
+# ---------------------------------------------------------------------------
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+class Http(Conn):
+    """The parent's own connection to the server (schema, /debug/vars,
+    read-back): a transport error ends the run."""
+
+    def request(self, method: str, path: str, body: bytes | None = None,
+                ctype: str = "application/json") -> tuple[int, bytes]:
+        status, data = super().request(method, path, body, ctype)
+        if status == 0:
+            raise RunFailure(f"{method} {path}: the server did not answer")
+        return status, data
+
+    def json(self, method: str, path: str, obj=None):
+        body = None if obj is None else json.dumps(obj).encode()
+        status, data = self.request(method, path, body)
+        if status != 200:
+            raise RunFailure(f"{method} {path} -> {status}: {data[:300]!r}")
+        return json.loads(data) if data else None
+
+
+class ServerChild:
+    def __init__(self, work: str, env: dict, script: str):
+        self.port, self.control_port = free_port(), free_port()
+        self.log_path = os.path.join(work, "server.log")
+        cfg = os.path.join(work, "server_config.json")
+        with open(cfg, "w") as f:
+            # shipped options; the in-memory stats client, so /debug/vars carries the counters
+            json.dump({"metric": {"service": "expvar"}}, f)
+        self._log = open(self.log_path, "ab")
+        self.t_spawn = time.monotonic()
+        self.proc = subprocess.Popen(
+            [sys.executable, script, "--data-dir", os.path.join(work, "data"),
+             "--bind", f"127.0.0.1:{self.port}", "--config", cfg,
+             "--control-port", str(self.control_port)],
+            cwd=REPO, env=env, stdout=self._log, stderr=subprocess.STDOUT)
+        self._ctl = None
+
+    def wait_ready(self, timeout: float = 600.0) -> float:
+        c = Http(self.port, timeout=5.0)
+        while True:
+            rc = self.proc.poll()
+            if rc is not None:
+                raise RunFailure(f"server exited with code {rc} before serving:\n{self.log_tail()}")
+            try:
+                status, _ = c.request("GET", "/status")
+                if status == 200:
+                    c.close()
+                    return time.monotonic() - self.t_spawn
+            except RunFailure:
+                pass
+            if time.monotonic() - self.t_spawn > timeout:
+                raise RunFailure(f"server not ready after {timeout:.0f}s:\n{self.log_tail()}")
+            time.sleep(0.1)
+
+    def control(self, verb: str, timeout: float = 600.0) -> dict:
+        if self._ctl is None:
+            self._ctl = socket.create_connection(("127.0.0.1", self.control_port), timeout=timeout)
+            self._ctl_file = self._ctl.makefile("rwb")
+        self._ctl_file.write(verb.encode() + b"\n")
+        self._ctl_file.flush()
+        line = self._ctl_file.readline()
+        out = json.loads(line) if line else {"ok": False, "error": "control socket closed"}
+        if not out.pop("ok", False):
+            raise RunFailure(f"control {verb.split()[0]}: {out.get('error')}")
+        return out
+
+    def stop(self, timeout: float = 120.0) -> None:
+        if self._ctl is not None:
+            self._ctl.close()
+            self._ctl = None
+        if self.proc.poll() is None:
+            self.proc.send_signal(signal.SIGTERM)
+            try:
+                self.proc.wait(timeout=timeout)
+            except subprocess.TimeoutExpired:
+                self.proc.kill()
+                self.proc.wait(timeout=30)
+        self._log.close()
+
+    def log_tail(self, n: int = 3000) -> str:
+        try:
+            with open(self.log_path, "rb") as f:
+                f.seek(0, os.SEEK_END)
+                f.seek(max(0, f.tell() - n))
+                return f.read().decode("utf-8", "replace")
+        except OSError:
+            return ""
+
+
+class Worker:
+    """A ``loadgen.py`` process."""
+
+    def __init__(self, wid: int, work: str, cfg: dict, mix: dict):
+        self.wid, self.work = wid, work
+        self.proc = subprocess.Popen(
+            [sys.executable, os.path.join(HERE, "loadgen.py")], cwd=REPO,
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
+        self.send({"config": cfg, "mix": mix})
+        self.conns: list[int] = []
+
+    def send(self, obj: dict) -> None:
+        self.proc.stdin.write(json.dumps(obj) + "\n")
+        self.proc.stdin.flush()
+
+    def reply(self) -> dict:
+        line = self.proc.stdout.readline()
+        if not line:
+            raise RunFailure(f"load generator {self.wid} died (exit {self.proc.poll()})")
+        out = json.loads(line)
+        if not out.get("ok"):
+            raise RunFailure(f"load generator {self.wid}: {out.get('error')}")
+        return out
+
+    def stop(self) -> None:
+        if self.proc.poll() is None:
+            try:
+                self.send({"cmd": "quit"})
+                self.proc.wait(timeout=10)
+            except (OSError, subprocess.TimeoutExpired):
+                self.proc.kill()
+                self.proc.wait(timeout=10)
+
+
+# ---------------------------------------------------------------------------
+# stages
+# ---------------------------------------------------------------------------
+
+
+def load_cell(manifest: dict, workload: str, rehearsal: bool) -> tuple[dict, dict, dict]:
+    cell = mf.cell(manifest, workload)
+    cfg = mf.read_json(mf.config_entry(manifest, cell["config"])["file"])
+    if rehearsal:
+        cfg.update(cfg.get("rehearsal", {}))
+    mix = mf.read_json(os.path.join("benchmark", "traffic", cell["traffic"] + ".json"))
+    if rehearsal:
+        mix.update(mix.get("rehearsal", {}))
+    return cell, cfg, mix
+
+
+def make_schema(c: Http, cfg: dict) -> None:
+    c.json("POST", f"/index/{cfg['index']}", {})
+    for f in cfg["fields"]:
+        opts = {"type": "int", "min": f["min"], "max": f["max"]} if f["kind"] == "int" else {}
+        c.json("POST", f"/index/{cfg['index']}/field/{f['name']}", {"options": opts})
+
+
+def ledger(dbg: dict) -> tuple[int, int]:
+    """(programs compiled, programs retrieved from the persistent cache) so far."""
+    return int(dbg["devledger"]["totals"]["compiles"]), int(dbg["devledger"]["persistentCacheHits"])
+
+
+def new_programs(before: dict, after: dict) -> list[str]:
+    """Which ledger sites compiled between two /debug/vars readings."""
+    out = []
+    for site, s1 in after["devledger"]["sites"].items():
+        n = s1.get("compiles", 0) - before["devledger"]["sites"].get(site, {}).get("compiles", 0)
+        if n:
+            out.append(f"{site} compiled {n}; recent signatures {s1.get('recentCompileSigs')}")
+    return out
+
+
+class Driver:
+    """Sends ``run`` jobs to the workers and gathers their logs."""
+
+    def __init__(self, workers: list[Worker], work: str, base: dict):
+        self.workers, self.work, self.base = workers, work, base
+        self.n_jobs = 0
+
+    def start(self, phase: str, seconds: float, lead: float = 0.15) -> dict:
+        """Send the job to every worker; ``finish`` gathers the logs.  The
+        workers stop at ``seconds`` or when ``stop`` is called."""
+        start_at = time.monotonic() + lead
+        self.stop_file = os.path.join(self.work, f"stop_{self.n_jobs}")
+        active = []
+        for w in self.workers:
+            if not w.conns:
+                continue
+            self.n_jobs += 1
+            job = dict(self.base, cmd="run", phase=phase, start_at=start_at, seconds=seconds,
+                       conns=w.conns, stop_file=self.stop_file,
+                       log=os.path.join(self.work, f"log_{self.n_jobs}.pkl"))
+            w.send(job)
+            active.append((w, job))
+        return {"start_at": start_at, "end_at": start_at + seconds, "phase": phase, "active": active}
+
+    def stop(self) -> None:
+        open(self.stop_file, "w").close()
+
+    def finish(self, run: dict) -> dict:
+        out = dict(run, cpu_share=[], reads=[])
+        for w, job in out.pop("active"):
+            rep = w.reply()
+            if rep["stuck_threads"]:
+                raise RunFailure(f"{rep['stuck_threads']} connections never returned in {run['phase']}")
+            out["cpu_share"].append(round(rep["cpu_share"], 3))
+            with open(job["log"], "rb") as f:
+                out["reads"] += pickle.load(f)  # written by our own worker
+            os.unlink(job["log"])
+        return out
+
+
+def warm_up(drv: Driver, c: Http, cfg: dict, mix: dict, seed: int, seconds: float) -> dict:
+    """Two passes.  The sweep sends every variant of every class as one
+    request of 1, 2, 4, ... calls (``generator.Mix.sweep``): the shapes a
+    flight can take, in a fixed order.  Then the whole mix runs at the
+    window's concurrency until nothing has compiled or been retrieved from
+    the cache for a while: ``WARM_QUIET_S``, or a window's length in a
+    process that has compiled (a checkout's first runs), since a shape
+    that rare still has to be in the cache before a window meets it."""
+    from generator import Mix  # numpy only
+
+    t0 = time.monotonic()
+    largest = 1
+    while largest < int(mix["connections"]):
+        largest *= 2
+    path = f"/index/{cfg['index']}/query"
+    for cls, calls in Mix(cfg, mix).sweep(seed, largest):
+        status, body = c.request("POST", path, " ".join(calls).encode(), "text/plain")
+        if status != 200:
+            raise RunFailure(f"warm-up request of class {cls} -> {status}: {body[:300]!r}")
+    dbg = c.json("GET", "/debug/vars")
+    swept = ledger(dbg)
+    sweep_s = time.monotonic() - t0
+    log(f"warm-up sweep: {sweep_s:.1f}s, compiles {swept[0]}, retrievals {swept[1]}")
+    seen = swept
+    run = drv.start("warm", WARM_LIMIT_S)
+    t_new = run["start_at"]
+    try:
+        while True:
+            time.sleep(WARM_POLL_S)
+            dbg, before = c.json("GET", "/debug/vars"), dbg
+            now = ledger(dbg)
+            for line in new_programs(before, dbg):
+                log(f"warm-up +{time.monotonic() - t0:.0f}s, mixed flights: " + line)
+            if now != seen:
+                seen, t_new = now, time.monotonic()
+            if time.monotonic() - t_new >= (max(WARM_QUIET_S, seconds) if seen[0] else WARM_QUIET_S):
+                break
+            if time.monotonic() - t0 > WARM_LIMIT_S:
+                raise RunFailure(f"warm-up still compiling after {WARM_LIMIT_S:.0f}s "
+                                 f"(compiles {seen[0]}, retrievals {seen[1]})")
+    finally:
+        drv.stop()
+        drv.finish(run)
+    return {"seconds": time.monotonic() - t0, "sweep_s": sweep_s, "compiles": seen[0],
+            "persistent_cache_hits": seen[1], "after_sweep": [seen[0] - swept[0], seen[1] - swept[1]]}
+
+
+def percentile(sorted_vals: list[float], q: float) -> float:
+    return sorted_vals[max(0, math.ceil(q * len(sorted_vals)) - 1)]
+
+
+def delta(after, before):
+    """after - before over every number two /debug/vars trees share."""
+    if isinstance(after, dict) and isinstance(before, dict):
+        return {k: delta(v, before[k]) for k, v in after.items() if k in before}
+    if isinstance(after, (int, float)) and isinstance(before, (int, float)) \
+            and not isinstance(after, bool):
+        return after - before
+    return None
+
+
+def window_numbers(win: dict, setup_s: float):
+    """The window's requests reduced: (reads sent, reads answered inside the
+    window, counts for the per-layer readers, end-to-end metrics, failed).
+    Every read sent in the window counts in the percentiles, the ones
+    answered after its end too; a failed read misses every limit."""
+    t0, t1 = win["start_at"], win["end_at"]
+    reads = win["reads"]
+    failed = sum(r["status"] != 200 for r in reads)
+    done = [r for r in reads if r["status"] == 200 and r["t_recv"] <= t1]
+    if not done:
+        raise RunFailure("no read completed inside the window")
+    lat = sorted((r["t_recv"] - r["t_send"]) * 1e3 if r["status"] == 200 else 1e3 * (t1 - t0 + 600)
+                 for r in reads)
+    window = {"seconds": t1 - t0, "reads": len(done)}
+    log("read latency ms: " + ", ".join(
+        f"p{int(q * 100)} {percentile(lat, q):.1f}" for q in (0.1, 0.25, 0.5, 0.75, 0.9, 0.95, 0.99)))
+    by_class: dict[str, list[float]] = {}
+    for r in reads:
+        by_class.setdefault(r["cls"], []).append((r["t_recv"] - r["t_send"]) * 1e3)
+    log("per class n/p50/p95 ms: " + ", ".join(
+        f"{k} {len(v)}/{percentile(sorted(v), 0.5):.0f}/{percentile(sorted(v), 0.95):.0f}"
+        for k, v in sorted(by_class.items())))
+    e2e = {"read_qps": len(done) / (t1 - t0), "read_p50_ms": percentile(lat, 0.50),
+           "read_p95_ms": percentile(lat, 0.95), "setup_s": setup_s}
+    return reads, done, window, e2e, failed
+
+
+def check_sample(reads: list[dict], seed: int, check_max: int) -> list[dict]:
+    """The answers to judge: those the connections kept (a sample drawn
+    from the seed), cut to ``check_max`` so that the reference stays shorter
+    than the window; the seed picks which, every class still among them."""
+    sample = sorted((r for r in reads if r["status"] == 200 and r["body"] is not None),
+                    key=lambda r: (r["conn"], r["n"]))
+    if len(sample) <= check_max:
+        return sample
+    first: dict[str, int] = {}
+    for i, r in enumerate(sample):
+        first.setdefault(r["cls"], i)
+    rng = np.random.default_rng([seed, 0xC4EC])
+    rest = [i for i in rng.permutation(len(sample)) if i not in first.values()]
+    return [sample[i] for i in sorted(list(first.values()) + rest[:check_max - len(first)])]
+
+
+# ---------------------------------------------------------------------------
+# per-layer readers
+# ---------------------------------------------------------------------------
+
+
+def lookup(ctx: dict, path: str):
+    node = ctx
+    for part in path.split("."):
+        node = node[part]
+    if isinstance(node, bool) or not isinstance(node, (int, float)):
+        raise KeyError(f"{path} is {node!r}, not a number")
+    return node
+
+
+def read_layer_metric(name: str, ctx: dict) -> float:
+    """``layer_metrics/<name>.json`` is pure data: ``{"value": path}`` or
+    ``{"num": [paths], "den": [paths], "scale": k}`` over the run's
+    context (sums of paths; an empty denominator reads 0: nothing
+    happened).  ``layer_metrics/<name>.py`` is code: ``read(ctx)``."""
+    base = os.path.join(HERE, "layer_metrics", name)
+    if os.path.exists(base + ".json"):
+        with open(base + ".json") as f:
+            spec = json.load(f)
+        if "value" in spec:
+            return lookup(ctx, spec["value"])
+        num = sum(lookup(ctx, p) for p in spec["num"])
+        den = sum(lookup(ctx, p) for p in spec["den"])
+        return float(spec.get("scale", 1)) * num / den if den else 0.0
+    if os.path.exists(base + ".py"):
+        modspec = importlib.util.spec_from_file_location("layer_metric_" + name.replace(".", "_"),
+                                                         base + ".py")
+        mod = importlib.util.module_from_spec(modspec)
+        modspec.loader.exec_module(mod)
+        return mod.read(ctx)
+    raise mf.ManifestError(f"per-layer metric {name} has no reader under benchmark/layer_metrics/")
+
+
+# ---------------------------------------------------------------------------
+# main
+# ---------------------------------------------------------------------------
+
+
+def main(argv=None, child_script: str | None = None) -> int:
+    """``child_script`` is for the tests: a server child with the timed path broken."""
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--seconds", type=float, default=None)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="CPU, tiny shape, labelled, never a pass")
+    ap.add_argument("--control", choices=("lossy",),
+                    help="judge the reference with a guarantee broken in the program's place")
+    args = ap.parse_args(argv)
+
+    if not os.path.exists(os.path.join(REPO, "pilosa_tpu", "cli.py")):
+        print("benchmark: the program is not here (no pilosa_tpu/cli.py); nothing to run",
+              file=sys.stderr)
+        return 2
+    manifest = mf.load()
+    cell, cfg, mix = load_cell(manifest, args.workload, args.rehearsal)
+    traced = bool(args.trace)
+    seconds = float(args.seconds if args.seconds is not None else manifest["run_seconds"])
+    if traced:
+        seconds = min(seconds, TRACE_CAP_S)
+
+    env = dict(os.environ)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    if args.rehearsal:
+        env["JAX_PLATFORMS"] = "cpu"
+        env["PILOSA_TPU_SHARD_WIDTH"] = str(cfg["shard_width_exp"])
+    else:
+        env.pop("PILOSA_TPU_SHARD_WIDTH", None)
+
+    work = tempfile.mkdtemp(prefix="pilosa_bench_")
+    srv = ServerChild(work, env, child_script or os.path.join(HERE, "serve_child.py"))
+    workers: list[Worker] = []
+    c = Http(srv.port)
+    try:
+        try:
+            ready_s = srv.wait_ready()
+            device = srv.control("device")
+        except RunFailure as e:
+            print(f"benchmark: {e}", file=sys.stderr)
+            return 2
+        on_chip = device["platform"] == "tpu"
+        if on_chip == args.rehearsal or device["count"] < int(cell["chips"]):
+            print(f"benchmark: found {device['count']} x {device['platform']}; the cell asks for "
+                  f"{cell['chips']} chip(s)" + (" and --rehearsal is for machines without one"
+                                               if args.rehearsal else "; there is no stand-in"),
+                  file=sys.stderr)
+            return 2
+        info = c.json("GET", "/info")
+        if int(info["shardWidth"]) != 1 << int(cfg["shard_width_exp"]):
+            raise RunFailure(f"shard width {info['shardWidth']}, not 2^{cfg['shard_width_exp']}")
+        log(f"server ready in {ready_s:.1f}s on {device['count']} x {device['kind']}"
+            + ("  ** REHEARSAL: CPU, tiny shape, not a result **" if args.rehearsal else ""))
+
+        # ---- set-up: schema, load, warm-up ---------------------------------
+        make_schema(c, cfg)
+        n_read = int(mix["processes"])
+        n_load = min(LOADERS, int(cfg["shards"]))
+        workers = [Worker(i, work, cfg, mix) for i in range(max(n_read, n_load))]
+        for cid in range(int(mix["connections"])):
+            workers[cid % n_read].conns.append(cid)
+        for i, w in enumerate(workers[:n_load]):
+            w.send({"cmd": "load", "seed": args.seed, "port": srv.port,
+                    "shards": list(range(int(cfg["shards"])))[i::n_load]})
+        loads = [w.reply() for w in workers[:n_load]]
+        load_s = max(r["t_last"] for r in loads) - min(r["t_first"] for r in loads)
+        load_bits = sum(r["bits"] for r in loads)
+        log(f"loaded {load_bits} bits in {load_s:.1f}s ({load_bits / load_s:.0f} bits/s) "
+            f"through {sum(r['requests'] for r in loads)} import requests")
+
+        drv = Driver(workers, work, {"seed": args.seed, "port": srv.port, "index": cfg["index"],
+                                     "check_one_in": int(mix.get("check_one_in", 40))})
+        warm = warm_up(drv, c, cfg, mix, args.seed, seconds)
+        log(f"warm-up: {warm['seconds']:.1f}s, compiles {warm['compiles']}, retrievals "
+            f"{warm['persistent_cache_hits']}; after the sweep {warm['after_sweep']}")
+
+        # ---- the window: taken once; a program that compiles in it fails the run
+        trace_dir = os.path.join(work, "trace")
+        if traced:
+            srv.control(f"trace_start {trace_dir}")
+            t_trace0 = time.monotonic()
+        vars0 = c.json("GET", "/debug/vars")
+        win = drv.finish(drv.start("window", seconds, lead=0.3))
+        vars1 = c.json("GET", "/debug/vars")
+        if traced:
+            window_s = time.monotonic() - t_trace0
+            srv.control("trace_stop")
+        compiles0, hits0 = ledger(vars0)
+        compiles1, hits1 = ledger(vars1)
+        for line in new_programs(vars0, vars1):  # named for whoever has to warm them up
+            log("compiled inside the window: " + line)
+        setup_s = win["start_at"] - srv.t_spawn
+        log(f"window of {seconds:.1f}s done; generator CPU share per process {win['cpu_share']}")
+        device = srv.control("device")
+    except RunFailure as e:
+        print(f"benchmark: {e}\n--- server log tail ---\n{srv.log_tail()}", file=sys.stderr)
+        return 1
+    finally:
+        c.close()
+        for w in workers:
+            w.stop()
+        srv.stop()
+
+    try:
+        # ---- reduce: the window's requests ---------------------------------
+        reads, done, window, e2e, failed = window_numbers(win, setup_s)
+        trace = None
+        if traced:
+            red = subprocess.run(
+                [sys.executable, os.path.join(HERE, "trace_reduce.py"), trace_dir],
+                env=dict(env, JAX_PLATFORMS="cpu"), capture_output=True, text=True, timeout=300)
+            if red.returncode != 0:
+                raise RunFailure(f"trace reduction failed: {red.stderr[-600:]}")
+            trace = json.loads(red.stdout.strip().splitlines()[-1])
+            trace["window_s"] = window_s
+            log(f"trace: {trace['op_count']} device ops, busy {trace['busy_s']:.3f}s of "
+                f"{window_s:.3f}s; lines {trace.get('lines')}")
+        ctx = {"setup": {"ready_s": ready_s, "load_s": load_s, "warm_s": warm["seconds"],
+                         "sweep_s": warm["sweep_s"], "compiles": compiles0,
+                         "persistent_cache_hits": hits0, "load_bits_per_s": load_bits / load_s},
+               "window": window, "vars": delta(vars1, vars0), "vars_start": vars0,
+               "trace": trace, "e2e": e2e}
+
+        # ---- judge: the window's own answers against the reference ---------
+        from compare import CONTROLS, judge_reads
+        from reference import Reference
+
+        t_ref = time.monotonic()
+        sample = check_sample(reads, args.seed, int(mix.get("check_max", 128)))
+        ref = Reference(cfg, args.seed)
+        ref.load()
+        verdict = judge_reads(ref, sample)
+        compared = {
+            "read_mismatches": [verdict["mismatches"], 0],
+            "window_compiles": [compiles1 - compiles0, 0],
+            "failed_requests": [failed, 0],
+            # read classes that answered in the window and had no answer judged
+            "classes_unjudged": [len({r["cls"] for r in done} - set(verdict["per_class"])), 0],
+        }
+        examples = verdict["examples"]
+        if args.control:
+            control = CONTROLS[args.control](cfg, args.seed)
+            control.load()
+            cv = judge_reads(ref, sample, control=control)
+            compared["control_mismatches"] = [cv["mismatches"], 0]
+            examples += ["control " + args.control + ": " + x for x in cv["examples"][:3]]
+        if args.rehearsal:
+            compared["rehearsal"] = [1, 0]
+        correct = all(v <= limit for v, limit in compared.values())
+        log(f"judged {verdict['compared']} window reads (per class {verdict['per_class']}) in "
+            f"{time.monotonic() - t_ref:.1f}s; window retrievals {hits1 - hits0}")
+
+        dev = {"platform": device["platform"], "kind": device["kind"], "count": device["count"],
+               "memory_peak_bytes": device["memory_peak_bytes"]}
+        breakdown = None
+        if traced:
+            dev["busy_s"], dev["window_s"] = trace["busy_s"], window_s
+            breakdown = {"device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"]}
+
+        def read_metric(m: dict) -> float:
+            return read_layer_metric(m["name"], ctx) if traced else e2e[m["name"]]
+
+        line = mf.result_line(manifest, args.workload, traced, read_metric, correct=correct,
+                              attempted=len(reads), failed=failed, device=dev, compared=compared,
+                              breakdown=breakdown)
+    except (RunFailure, mf.ManifestError, KeyError) as e:
+        print(f"benchmark: {type(e).__name__}: {e}", file=sys.stderr)
+        return 1
+    finally:
+        shutil.rmtree(work, ignore_errors=True)
+
+    log("setup " + json.dumps({k: round(v, 3) for k, v in ctx["setup"].items()})
+        + " window " + json.dumps(window) + " e2e " + json.dumps({k: round(v, 4) for k, v in e2e.items()}))
+    for x in examples:
+        print("mismatch: " + x, file=sys.stderr)
+    if args.rehearsal:
+        print("REHEARSAL: CPU, tiny shape; never a result", file=sys.stderr)
+    for name, (value, limit) in compared.items():
+        print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
+    print(line, flush=True)
+    return 3 if args.rehearsal else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
