@@ -37,7 +37,9 @@ from pilosa_tpu.cluster.cluster import Cluster
 from pilosa_tpu.cluster.meshexec import MeshHolderView
 from pilosa_tpu.cluster.topology import NODE_STATE_DOWN
 from pilosa_tpu.cluster.wire import decode_results
-from pilosa_tpu.exec.executor import ExecuteError, Executor, IndexNotFoundError
+from pilosa_tpu.exec.executor import (
+    ExecuteError, Executor, IndexNotFoundError, execute_span,
+)
 from pilosa_tpu.exec.result import GroupCount, Pair, Row, RowIdentifiers, ValCount
 from pilosa_tpu.obs import devledger, qprofile, tracing
 from pilosa_tpu.parallel import meshplace
@@ -196,7 +198,7 @@ class DistributedExecutor:
                 # per-call span, matching the single-node executor's loop
                 # (executor.go:298 executeCall) — profiles and traces of
                 # clustered queries then show the same per-call shape
-                with tracing.start_span(f"executor.execute{tcall.name}"):
+                with tracing.start_span(execute_span(tcall.name)):
                     results.append(
                         self._execute_call(index_name, idx, tcall, shards)
                     )
@@ -248,7 +250,7 @@ class DistributedExecutor:
         q = pql.parse(query) if isinstance(query, str) else query
         out = []
         for c in q.calls:
-            with tracing.start_span(f"executor.execute{c.name}"):
+            with tracing.start_span(execute_span(c.name)):
                 out.append(self.local._execute_call(idx, c, shards))
         return out
 

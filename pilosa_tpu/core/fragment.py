@@ -834,7 +834,8 @@ class Fragment:
             self._budget_key = membudget.register_owner(self, budget)
         if rebuilt:
             budget.admit(
-                self._budget_key, self._device_nbytes(), self._budget_evict_cb()
+                self._budget_key, self._device_nbytes(),
+                self._budget_evict_cb(), owner=membudget.OWNER_FRAGMENT,
             )
         else:
             budget.touch(self._budget_key)

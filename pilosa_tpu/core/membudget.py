@@ -47,14 +47,24 @@ logger = logging.getLogger(__name__)
 PIN_MAX_FRACTION = 0.5
 
 
+# What kind of thing holds an entry's bytes, tagged at admit:
+# ``stack_<field type>`` for an executor's serving stack, ``fragment`` for
+# a fragment's own device copy.  The snapshot sums bytes by it.
+OWNER_OTHER = "other"
+OWNER_FRAGMENT = "fragment"
+
+
 class _Entry:
     """One admitted allocation: bytes, evict callback, clock state."""
 
-    __slots__ = ("nbytes", "evict", "pinned", "ref")
+    __slots__ = ("nbytes", "evict", "pinned", "ref", "owner")
 
-    def __init__(self, nbytes: int, evict: Callable[[], None]):
+    def __init__(
+        self, nbytes: int, evict: Callable[[], None], owner: str = OWNER_OTHER
+    ):
         self.nbytes = nbytes
         self.evict = evict
+        self.owner = owner
         self.pinned = False
         self.ref = False
 
@@ -113,7 +123,15 @@ class DeviceBudget:
                     1 for e in self._entries.values() if e.pinned
                 ),
                 "pinnedBytes": self._pinned_bytes,
+                "byOwner": self._by_owner(),
             }
+
+    def _by_owner(self) -> dict[str, int]:
+        """Bytes held per owner kind (caller holds the lock)."""
+        out: dict[str, int] = {}
+        for e in self._entries.values():
+            out[e.owner] = out.get(e.owner, 0) + e.nbytes
+        return out
 
     def would_decline(self, nbytes: int) -> bool:
         """True when a single allocation of ``nbytes`` exceeds the whole
@@ -147,13 +165,16 @@ class DeviceBudget:
             victims.append(entry.evict)
         return victims
 
-    def admit(self, key, nbytes: int, evict: Callable[[], None]) -> None:
+    def admit(
+        self, key, nbytes: int, evict: Callable[[], None],
+        owner: str = OWNER_OTHER,
+    ) -> None:
         """Account ``nbytes`` of device memory for ``key`` (replacing any
-        previous entry), evicting cold OTHER entries until the cap is
-        met.  An entry larger than the entire cap is still admitted
-        after evicting everything evictable — the caller already holds
-        the array; callers that can page should check ``would_decline``
-        first."""
+        previous entry), held by an ``owner`` kind, evicting cold OTHER
+        entries until the cap is met.  An entry larger than the entire
+        cap is still admitted after evicting everything evictable — the
+        caller already holds the array; callers that can page should
+        check ``would_decline`` first."""
         victims: list[Callable[[], None]] = []
         with self._lock:
             old = self._entries.pop(key, None)
@@ -165,7 +186,7 @@ class DeviceBudget:
                 self.misses += 1
             if self.cap is not None:
                 victims = self._collect_victims(nbytes)
-            entry = _Entry(nbytes, evict)
+            entry = _Entry(nbytes, evict, owner)
             # arrive with the reference bit set: a freshly staged entry
             # (often a predictive prefetch whose consumer hasn't run yet)
             # survives one scan cycle instead of being the next admit's
@@ -305,6 +326,27 @@ def _probe_device_cap() -> int | None:
         )
         return None
     return int(limit * DEFAULT_HBM_FRACTION)
+
+
+def backend_memory() -> dict:
+    """What the backend itself reports for the fullest local device:
+    ``platform``, ``devices``, ``bytesInUse``, ``peakBytesInUse`` (both
+    None where the backend keeps no memory statistics, as the CPU's does
+    not).  The budget counts what the program admitted; the difference
+    is what nothing of the program's accounts for.  Read when
+    ``/debug/vars`` is served, never on a request's path."""
+    import jax
+
+    devs = jax.local_devices()
+    stats = [d.memory_stats() or {} for d in devs]
+    in_use = [s["bytes_in_use"] for s in stats if "bytes_in_use" in s]
+    peak = [s["peak_bytes_in_use"] for s in stats if "peak_bytes_in_use" in s]
+    return {
+        "platform": devs[0].platform,
+        "devices": len(devs),
+        "bytesInUse": int(max(in_use)) if in_use else None,
+        "peakBytesInUse": int(max(peak)) if peak else None,
+    }
 
 
 def default_budget() -> DeviceBudget:

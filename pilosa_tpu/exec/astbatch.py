@@ -358,10 +358,11 @@ def run_count_batch(sig, stacks: tuple, slots_np: np.ndarray) -> np.ndarray:
     launches += 1
     label = f"count B{slots_np.shape[0]} S{stacks[0].shape[0]}"
     _DL.track(fn, (slots_np.shape, tuple(s.shape for s in stacks)))
+    slots = _k.h2d(slots_np)
     with _DL.launch(sig=label) as w:
-        partials = np.asarray(
-            fn(stacks, jnp.asarray(slots_np))
-        ).astype(np.int64)
+        with _k.enqueue("ast_count"):
+            out = fn(stacks, slots)
+        partials = _k.pull(out, "ast_count").astype(np.int64)
     if w.compiles:
         devledger.ledger().analyze_cost(
             _DL, fn, stacks, jnp.asarray(slots_np), sig=label
@@ -376,8 +377,13 @@ def run_bitmap(sig, stacks: tuple, slots_np: np.ndarray):
     assert slots_np.shape[0] == n_leaves
     launches += 1
     _DL.track(fn, tuple(s.shape for s in stacks))
-    with _DL.launch(sig=f"bitmap S{stacks[0].shape[0]}"):
-        return fn(stacks, jnp.asarray(slots_np))
+    from pilosa_tpu.ops import kernels as _k
+
+    slots = _k.h2d(slots_np)
+    with _DL.launch(sig=f"bitmap S{stacks[0].shape[0]}"), _k.enqueue(
+        "ast_bitmap"
+    ):
+        return fn(stacks, slots)
 
 
 # ------------------------------------------------------------- BSI signing

@@ -91,6 +91,19 @@ _WRITE_CALLS = {
 }
 
 
+# The per-call fall-through's span, one name per call the parser's list
+# has; a name outside it (the executor then raises) shares ``Other``.
+tracing.register_family(
+    "executor.execute", pql.CALL_NAMES + ("Other",),
+    "executor lanes", "executor.host_ms_per_flight",
+)
+_EXECUTE_SPAN = {n: f"executor.execute{n}" for n in pql.CALL_NAMES}
+
+
+def execute_span(call_name: str) -> str:
+    return _EXECUTE_SPAN.get(call_name, "executor.executeOther")
+
+
 def _pow2(n: int) -> int:
     """Batch sizes pad to powers of two so jit programs are reused
     across drifting batch sizes (shared impl: ops/bitops)."""
@@ -229,7 +242,7 @@ class Executor:
             self._batch_bsi(idx, calls[:first_write], shards, results)
             for i, call in enumerate(calls):
                 if results[i] is _UNSET:
-                    with tracing.start_span(f"executor.execute{call.name}"):
+                    with tracing.start_span(execute_span(call.name)):
                         results[i] = self._execute_call(idx, call, shards)
             for i, call in enumerate(calls[:first_write]):
                 if tokens[i] is not None:
@@ -307,9 +320,12 @@ class Executor:
                 # are captured; grafts/reorders cannot shift identity)
                 # and BEFORE the batch passes (grafted trees must fall
                 # to host segment algebra, which is the sharing win)
-                self.planner.plan_group(
-                    idx, flat_calls, shards, flat_results, _UNSET
-                )
+                with tracing.start_span("planner.plan").set_tag(
+                    "n", len(flat_calls)
+                ):
+                    self.planner.plan_group(
+                        idx, flat_calls, shards, flat_results, _UNSET
+                    )
                 self._batch_pair_counts(idx, flat_calls, shards, flat_results)
                 self._batch_general(idx, flat_calls, shards, flat_results)
                 self._batch_bsi(idx, flat_calls, shards, flat_results)
@@ -323,24 +339,27 @@ class Executor:
                         for ci, call in enumerate(calls):
                             if res[ci] is _UNSET:
                                 with tracing.start_span(
-                                    f"executor.execute{call.name}"
+                                    execute_span(call.name)
                                 ):
                                     res[ci] = self._execute_call(
                                         idx, call, shards
                                     )
-                        for ci, call in enumerate(calls):
-                            if toks[ci] is not None:
-                                self.rescache.store(
-                                    toks[ci],
-                                    res[ci],
-                                    recompute=self._maintained_recompute(
-                                        idx, call, shards
-                                    ),
-                                )
-                        out[qi] = [
-                            self._translate_result(idx, c, r)
-                            for c, r in zip(parsed[qi].calls, res)
-                        ]
+                        with tracing.start_span("executor.demux").set_tag(
+                            "n", len(calls)
+                        ):
+                            for ci, call in enumerate(calls):
+                                if toks[ci] is not None:
+                                    self.rescache.store(
+                                        toks[ci],
+                                        res[ci],
+                                        recompute=self._maintained_recompute(
+                                            idx, call, shards
+                                        ),
+                                    )
+                            out[qi] = [
+                                self._translate_result(idx, c, r)
+                                for c, r in zip(parsed[qi].calls, res)
+                            ]
                     except Exception as e:
                         out[qi] = e
         return out
@@ -545,7 +564,6 @@ class Executor:
         re-uploading the whole field — the write-batch analogue of the
         reference applying ops to an mmap'd fragment in place
         (fragment.go:2284-2293). None when over budget or empty."""
-        from jax.sharding import NamedSharding, PartitionSpec
         from pilosa_tpu.parallel.mesh import serving_mesh
 
         v = field.view(view_name)
@@ -624,110 +642,127 @@ class Executor:
                 caches.pop(cache_key, None)
                 budget.release(entry["bkey"])
 
-            if fixed_rows is not None:
-                row_ids = list(fixed_rows)
-            else:
-                row_ids = sorted(
-                    {r for f in frags.values() for r in f.row_ids()}
+            with tracing.start_span("executor.stackBuild").set_tag(
+                "field", field.name
+            ) as sp:
+                return self._stack_build(
+                    field, frags, shards, fixed_rows, mesh, cache_key,
+                    versions, budget, caches, sp,
                 )
-            if not row_ids:
-                return None
-            S, R, W = len(shards), len(row_ids), field.n_words
-            if mesh is not None:
-                n_dev = mesh.devices.size
-                S = -(-S // n_dev) * n_dev  # pad so the mesh divides the axis
-            nbytes = S * R * W * 4
-            if nbytes > _STACK_BUDGET_BYTES or budget.would_decline(nbytes):
-                # over HBM budget: callers fall back to per-fragment paths,
-                # which page rows under the same budget (membudget)
-                return None
-            slot_of = {r: i for i, r in enumerate(row_ids)}
-            bits = np.zeros((S, R, W), dtype=np.uint32)
-            for si, s in enumerate(shards):
-                f = frags.get(s)
-                if f is None:
-                    continue
-                # bulk matrix copy, not one Python call per row
-                ids, matrix = f.rows_matrix_host()
-                src = [
-                    k for k, r in enumerate(ids) if r in slot_of
-                ]  # fixed_rows: ignore strays
-                if src:
-                    dst = [slot_of[ids[k]] for k in src]
-                    bits[si, dst] = matrix[src]
-            if mesh is not None:
-                dev = jax.device_put(
-                    bits,
-                    NamedSharding(mesh, PartitionSpec("shards", None, None)),
+
+    def _stack_build(
+        self, field: Field, frags, shards: list[int], fixed_rows, mesh,
+        cache_key, versions, budget, caches, span,
+    ):
+        """The miss path of :meth:`_field_stack`, under the field's stack
+        lock and the ``executor.stackBuild`` span: gather the rows on the
+        host, upload, retire what the new stack replaces, admit."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        from pilosa_tpu.ops import kernels
+
+        if fixed_rows is not None:
+            row_ids = list(fixed_rows)
+        else:
+            row_ids = sorted(
+                {r for f in frags.values() for r in f.row_ids()}
+            )
+        if not row_ids:
+            return None
+        S, R, W = len(shards), len(row_ids), field.n_words
+        if mesh is not None:
+            n_dev = mesh.devices.size
+            S = -(-S // n_dev) * n_dev  # pad so the mesh divides the axis
+        nbytes = S * R * W * 4
+        if nbytes > _STACK_BUDGET_BYTES or budget.would_decline(nbytes):
+            # over HBM budget: callers fall back to per-fragment paths,
+            # which page rows under the same budget (membudget)
+            return None
+        slot_of = {r: i for i, r in enumerate(row_ids)}
+        bits = np.zeros((S, R, W), dtype=np.uint32)
+        for si, s in enumerate(shards):
+            f = frags.get(s)
+            if f is None:
+                continue
+            # bulk matrix copy, not one Python call per row
+            ids, matrix = f.rows_matrix_host()
+            src = [
+                k for k, r in enumerate(ids) if r in slot_of
+            ]  # fixed_rows: ignore strays
+            if src:
+                dst = [slot_of[ids[k]] for k in src]
+                bits[si, dst] = matrix[src]
+        span.set_tag("bytes", nbytes)
+        dev = kernels.h2d(
+            bits,
+            NamedSharding(mesh, PartitionSpec("shards", None, None))
+            if mesh is not None else None,
+        )
+        self.stack_rebuilds += 1
+        kernels.note_transfer(nbytes, "h2d", dl_site=_DL_STACK)
+        qprofile.incr("stack_rebuilds")
+        # a BSI depth autogrow (or a standard view's row-set change)
+        # retires same-(mesh, shards, view) entries with a different
+        # row-axis length — they can never be hit again and would
+        # otherwise strand a full device stack under a dead key
+        for stale in [
+            k for k in caches
+            if k[:3] == cache_key[:3] and k[3] != cache_key[3]
+        ]:
+            old = caches.pop(stale, None)
+            if old is not None:
+                budget.release(old["bkey"])
+        while len(caches) >= self._STACK_CACHE_ENTRIES:
+            # the budget's _evict pops lock-free, so snapshot-scan and
+            # pop with defaults; retry when a concurrent pop races us
+            try:
+                lru_key = min(
+                    caches, key=lambda k: caches.get(k, {}).get("lru", -1)
                 )
-            else:
-                dev = jnp.asarray(bits)
-            self.stack_rebuilds += 1
-            from pilosa_tpu.ops import kernels
+            except (RuntimeError, ValueError):
+                continue  # dict mutated mid-scan; re-check the bound
+            old = caches.pop(lru_key, None)  # least recently used
+            if old is not None:
+                budget.release(old["bkey"])
+        # Each cache entry carries its OWN budget key (two stacks per
+        # field may be live; one shared key would undercount) and is
+        # released whenever the entry is dropped.
+        bkey = object()
+        weakref.finalize(field, budget.release, bkey)
+        tracker = residency.default_tracker()
+        prefetched = tracker.in_prefetch()
+        if prefetched:
+            # built off the dispatch path by the residency
+            # prefetcher: the first query hit counts it useful
+            tracker.note_prefetch_upload(nbytes)
+        else:
+            tracker.note_miss()
+        entry = {
+            "versions": versions,
+            "slot_of": slot_of,
+            "dev": dev,
+            "bkey": bkey,
+            "lru": next(self._stack_lru_clock),
+            # use-stamp hit count feeds the pin policy: a stack this
+            # hot is exempted from budget eviction (residency.py)
+            "hits": 0,
+            "pinned": False,
+            "prefetched": prefetched,
+        }
+        caches[cache_key] = entry
 
-            kernels.note_transfer(nbytes, "h2d", dl_site=_DL_STACK)
-            qprofile.incr("stack_rebuilds")
-            # a BSI depth autogrow (or a standard view's row-set change)
-            # retires same-(mesh, shards, view) entries with a different
-            # row-axis length — they can never be hit again and would
-            # otherwise strand a full device stack under a dead key
-            for stale in [
-                k for k in caches
-                if k[:3] == cache_key[:3] and k[3] != cache_key[3]
-            ]:
-                old = caches.pop(stale, None)
-                if old is not None:
-                    budget.release(old["bkey"])
-            while len(caches) >= self._STACK_CACHE_ENTRIES:
-                # the budget's _evict pops lock-free, so snapshot-scan and
-                # pop with defaults; retry when a concurrent pop races us
-                try:
-                    lru_key = min(
-                        caches, key=lambda k: caches.get(k, {}).get("lru", -1)
-                    )
-                except (RuntimeError, ValueError):
-                    continue  # dict mutated mid-scan; re-check the bound
-                old = caches.pop(lru_key, None)  # least recently used
-                if old is not None:
-                    budget.release(old["bkey"])
-            # Each cache entry carries its OWN budget key (two stacks per
-            # field may be live; one shared key would undercount) and is
-            # released whenever the entry is dropped.
-            bkey = object()
-            weakref.finalize(field, budget.release, bkey)
-            tracker = residency.default_tracker()
-            prefetched = tracker.in_prefetch()
-            if prefetched:
-                # built off the dispatch path by the residency
-                # prefetcher: the first query hit counts it useful
-                tracker.note_prefetch_upload(nbytes)
-            else:
-                tracker.note_miss()
-            entry = {
-                "versions": versions,
-                "slot_of": slot_of,
-                "dev": dev,
-                "bkey": bkey,
-                "lru": next(self._stack_lru_clock),
-                # use-stamp hit count feeds the pin policy: a stack this
-                # hot is exempted from budget eviction (residency.py)
-                "hits": 0,
-                "pinned": False,
-                "prefetched": prefetched,
-            }
-            caches[cache_key] = entry
+        def _evict(fref=weakref.ref(field), ck=cache_key):
+            f = fref()
+            if f is not None:
+                # lock-free atomic pop: the evicting thread may hold a
+                # different field's stack lock (AB-BA risk); a reader
+                # holding a reference to the popped entry just keeps
+                # using its (still-valid) device array
+                getattr(f, "_stack_caches", {}).pop(ck, None)
 
-            def _evict(fref=weakref.ref(field), ck=cache_key):
-                f = fref()
-                if f is not None:
-                    # lock-free atomic pop: the evicting thread may hold a
-                    # different field's stack lock (AB-BA risk); a reader
-                    # holding a reference to the popped entry just keeps
-                    # using its (still-valid) device array
-                    getattr(f, "_stack_caches", {}).pop(ck, None)
-
-            budget.admit(bkey, nbytes, _evict)
-            return slot_of, dev
+        budget.admit(
+            bkey, nbytes, _evict, owner=f"stack_{field.field_type}"
+        )
+        return slot_of, dev
 
     # incremental refresh only pays when few shards changed; past this
     # fraction a single bulk re-upload wins
@@ -764,8 +799,10 @@ class Executor:
                 return None  # new row: shape change, full rebuild
             if ids:
                 blocks[k, dst] = matrix
-        dev = entry["dev"].at[jnp.asarray(changed, jnp.int32)].set(
-            jnp.asarray(blocks)
+        from pilosa_tpu.ops import kernels
+
+        dev = entry["dev"].at[kernels.h2d(changed, dtype=np.int32)].set(
+            kernels.h2d(blocks)
         )
         entry.pop("gram", None)  # cached gram matched the old snapshot
         entry.pop("gram_misses", None)  # reuse restarts per snapshot
@@ -931,13 +968,17 @@ class Executor:
             if gram is not None and gram[0] is bits:
                 rc = np.diag(gram[1]).astype(np.int64)
             else:
-                rc = np.asarray(kernels.row_counts(bits)).astype(np.int64)
+                rc = kernels.pull(
+                    kernels.row_counts(bits), "row_counts"
+                ).astype(np.int64)
             lock = vars(field).setdefault("_stack_lock", threading.RLock())
             with lock:
                 if entry.get("dev") is bits:  # snapshot still current
                     entry["rowcounts"] = (bits, rc)
             return rc
-        return np.asarray(kernels.row_counts(bits)).astype(np.int64)
+        return kernels.pull(
+            kernels.row_counts(bits), "row_counts"
+        ).astype(np.int64)
 
     # live cross-gram slots kept per stack entry (one per partner field);
     # each full gram is <= 8 MiB host memory at _GRAM_CACHE_MAX_ROWS
@@ -1115,16 +1156,22 @@ class Executor:
             ):
                 gram, pos = self._field_gram(field, bits, uniq)
                 if gram is not None:
-                    pa = np.array([pos[sa] for _, _, sa, _ in launch])
-                    pb = np.array([pos[sb] for _, _, _, sb in launch])
-                    for op in {op for _, op, _, _ in launch}:
-                        sel = [j for j, it in enumerate(launch) if it[1] == op]
-                        counts = kernels.pair_counts_from_gram(
-                            gram, pa[sel], pb[sel], op
-                        )
-                        for c, j in zip(counts, sel):
-                            results[launch[j][0]] = int(c)
-                            _count_stat()
+                    with tracing.start_span("executor.demux").set_tag(
+                        "n", len(launch)
+                    ):
+                        pa = np.array([pos[sa] for _, _, sa, _ in launch])
+                        pb = np.array([pos[sb] for _, _, _, sb in launch])
+                        for op in {op for _, op, _, _ in launch}:
+                            sel = [
+                                j for j, it in enumerate(launch)
+                                if it[1] == op
+                            ]
+                            counts = kernels.pair_counts_from_gram(
+                                gram, pa[sel], pb[sel], op
+                            )
+                            for c, j in zip(counts, sel):
+                                results[launch[j][0]] = int(c)
+                                _count_stat()
                     continue
                 # gram declined (too many distinct rows): scan kernels,
                 # one launch per op, padded to powers of two for program
@@ -1152,18 +1199,22 @@ class Executor:
                     rbs = np.zeros(B, dtype=np.int32)
                     for j, (_, sa, sb) in enumerate(olaunch):
                         ras[j], rbs[j] = sa, sb
-                    partials = np.asarray(
+                    partials = kernels.pull(
                         kernels.pair_count_batched(
-                            bits, jnp.asarray(ras), jnp.asarray(rbs), op=op
-                        )
+                            bits, kernels.h2d(ras), kernels.h2d(rbs), op=op
+                        ),
+                        "pair_count",
                     ).astype(np.int64)
-                    counts = (
-                        partials if partials.ndim == 1
-                        else partials.sum(axis=1)
-                    )
-                    for j, (i, _, _) in enumerate(olaunch):
-                        results[i] = int(counts[j])
-                        _count_stat()
+                    with tracing.start_span("executor.demux").set_tag(
+                        "n", len(olaunch)
+                    ):
+                        counts = (
+                            partials if partials.ndim == 1
+                            else partials.sum(axis=1)
+                        )
+                        for j, (i, _, _) in enumerate(olaunch):
+                            results[i] = int(counts[j])
+                            _count_stat()
 
     # ------------------------------------------ general AST one-launch path
 
@@ -1386,9 +1437,12 @@ class Executor:
                 "n", len(items)
             ):
                 totals = astbatch.run_count_batch(sig, stacks, slots)
-            for j, (i, _) in enumerate(items):
-                results[i] = int(totals[j])
-                self._count_stat(idx)
+                with tracing.start_span("executor.demux").set_tag(
+                    "n", len(items)
+                ):
+                    for j, (i, _) in enumerate(items):
+                        results[i] = int(totals[j])
+                        self._count_stat(idx)
 
         for i, sig, pairs, leaves in bitmap_items:
             st = _stacks_for(pairs, allow_spanning=False)
@@ -1399,18 +1453,22 @@ class Executor:
                 dev = astbatch.run_bitmap(
                     sig, stacks, _slots_of(leaves, slot_maps)
                 )
-            if getattr(dev, "sharding", None) is not None and len(
-                getattr(dev.sharding, "device_set", ())
-            ) > 1:
-                # mesh-sharded result: one host pull, numpy segments
-                # (device slices would pin segments to different chips
-                # and later segment algebra would mix placements)
-                dev = np.asarray(dev)
-            segments = {
-                s: dev[si] for si, s in enumerate(shard_list)
-            }
-            results[i] = Row(segments, n_words=idx.n_words)
-            self._count_stat(idx, calls[i].name)
+                if getattr(dev, "sharding", None) is not None and len(
+                    getattr(dev.sharding, "device_set", ())
+                ) > 1:
+                    # mesh-sharded result: one host pull, numpy segments
+                    # (device slices would pin segments to different
+                    # chips and later segment algebra would mix
+                    # placements)
+                    from pilosa_tpu.ops import kernels
+
+                    dev = kernels.pull(dev, "ast_bitmap")
+                with tracing.start_span("executor.demux").set_tag("n", 1):
+                    segments = {
+                        s: dev[si] for si, s in enumerate(shard_list)
+                    }
+                    results[i] = Row(segments, n_words=idx.n_words)
+                    self._count_stat(idx, calls[i].name)
 
     # ------------------------------------------------------- key translation
 
@@ -1756,15 +1814,22 @@ class Executor:
                     masks = bsi.range_batch(
                         planes, exists, sign, queries, depth=depth
                     )
-                if getattr(masks, "sharding", None) is not None and len(
-                    getattr(masks.sharding, "device_set", ())
-                ) > 1:
-                    masks = np.asarray(masks)  # one pull for the flight
-                for qi, (i, _) in enumerate(mask_items):
-                    row = Row(n_words=self.holder.n_words)
-                    m = masks[qi]
-                    for si, s in enumerate(shard_list):
-                        row.segments[s] = m[si]
+                    if getattr(masks, "sharding", None) is not None and len(
+                        getattr(masks.sharding, "device_set", ())
+                    ) > 1:
+                        # one pull for the flight
+                        masks = kernels.pull(masks, "bsi_range_batch")
+                rows = []
+                with tracing.start_span("executor.demux").set_tag(
+                    "n", len(mask_items)
+                ):
+                    for qi in range(len(mask_items)):
+                        row = Row(n_words=self.holder.n_words)
+                        m = masks[qi]
+                        for si, s in enumerate(shard_list):
+                            row.segments[s] = m[si]
+                        rows.append(row)
+                for (i, _), row in zip(mask_items, rows):
                     if calls[i].name == "GroupBy":
                         try:
                             results[i] = self._execute_groupby(
@@ -1812,10 +1877,13 @@ class Executor:
                         counts = bsi.range_count_batch(
                             planes, exists, sign, queries, depth=depth
                         )
-                    for (i, _), put, n in zip(pending, puts, counts):
-                        put(n)
-                        results[i] = n
-                        self._count_stat(idx)
+                    with tracing.start_span("executor.demux").set_tag(
+                        "n", len(pending)
+                    ):
+                        for (i, _), put, n in zip(pending, puts, counts):
+                            put(n)
+                            results[i] = n
+                            self._count_stat(idx)
 
         # -- Sum: unfiltered repeats collapse onto the cached stacked
         # aggregate; filtered Sums share one fused popcount matmul when
@@ -1902,14 +1970,15 @@ class Executor:
                 "bsi_sum_batch", S_stack * P * W * 4, S_stack * Q * W * 4
             )
         exists, sign, planes = self._bsi_split(bits)
-        filters = jnp.asarray(fw)
         self.bsi_stack_launches += 1
         with tracing.start_span("executor.bsiSumBatch").set_tag("n", Q):
+            filters = kernels.h2d(fw)
             pairs = bsi.sum_batch_host(
                 planes, exists, sign, filters, depth=depth
             )
-        for (i, _), tc in zip(filtered, pairs):
-            results[i] = self._sum_valcount(field, tc)
+            with tracing.start_span("executor.demux").set_tag("n", Q):
+                for (i, _), tc in zip(filtered, pairs):
+                    results[i] = self._sum_valcount(field, tc)
 
     def _bitmap_call(self, idx: Index, call: Call, shards: list[int]) -> Row:
         name = call.name
@@ -2139,7 +2208,8 @@ class Executor:
         """(exists, sign, planes) slices of a raw BSI stack.  Each slice
         is a device dispatch, so callers split only when they actually
         compute — a cache-served aggregate never pays it."""
-        return bits[:, 0], bits[:, 1], bits[:, 2:]
+        with tracing.start_span("executor.bsiSplit"):
+            return bits[:, 0], bits[:, 1], bits[:, 2:]
 
     @staticmethod
     def _host_cpu_device():
@@ -2234,12 +2304,17 @@ class Executor:
         if st is not None:
             exists, sign, planes = self._bsi_split(st)
             self.bsi_stack_launches += 1
-            with _DL_STACK.launch(sig=f"bsi_rows/stack d{field.bit_depth}"):
+            from pilosa_tpu.ops import kernels
+
+            with _DL_STACK.launch(
+                sig=f"bsi_rows/stack d{field.bit_depth}"
+            ), kernels.enqueue("bsi_rows"):
                 mask = kernel(planes, exists, sign)  # [S, W], one launch
             if getattr(mask, "sharding", None) is not None and len(
                 getattr(mask.sharding, "device_set", ())
             ) > 1:
-                mask = np.asarray(mask)  # one pull; avoid mixed placements
+                # one pull; avoid mixed placements
+                mask = kernels.pull(mask, "bsi_rows")
             for si, s in enumerate(shards):
                 out.segments[s] = mask[si]
             return out
@@ -2578,17 +2653,18 @@ class Executor:
             # the stack's shard axis is padded to the mesh size;
             # padded slices have exists == 0, so any filter value
             # there is inert
+            from pilosa_tpu.ops import kernels
+
             S_stack = exists.shape[0]
             fw_np = np.zeros((S_stack, field.n_words), np.uint32)
             for si, s in enumerate(shards):
                 seg = filt.segments.get(s)
                 if seg is not None:
-                    fw_np[si] = np.asarray(seg)
+                    fw_np[si] = kernels.pull(seg, "row_segment")
             sh = getattr(exists, "sharding", None)
-            if sh is not None and len(getattr(sh, "device_set", ())) > 1:
-                fw = jax.device_put(fw_np, sh)  # co-locate with stack
-            else:
-                fw = jnp.asarray(fw_np)
+            multi = sh is not None and len(getattr(sh, "device_set", ())) > 1
+            # co-locate with a sharded stack
+            fw = kernels.h2d(fw_np, sh if multi else None)
             _DL_STACK.record_transfer(fw_np.nbytes, "h2d")
         return planes, exists, sign, fw
 
@@ -2889,13 +2965,17 @@ class Executor:
                 S, _, W = bits.shape
                 filt = self._row_to_shard_matrix(src, shards, S, W)
                 mc = kernels.masked_row_counts(bits, filt)
-                for rid, slot in slot_of.items():
-                    if mc[slot]:
-                        counts[rid] = int(mc[slot])
-                if has_tanimoto:
-                    rc = self._stack_row_counts(field, bits)
+                rc = (
+                    self._stack_row_counts(field, bits)
+                    if has_tanimoto else None
+                )
+                with tracing.start_span("executor.demux").set_tag(
+                    "n", len(slot_of)
+                ):
                     for rid, slot in slot_of.items():
-                        if rc[slot]:
+                        if mc[slot]:
+                            counts[rid] = int(mc[slot])
+                        if rc is not None and rc[slot]:
                             row_totals[rid] = int(rc[slot])
                 view = None  # stack covered every shard; skip the loop
         if view is not None and src is None:
@@ -2952,10 +3032,11 @@ class Executor:
                     ).sum(axis=1, dtype=np.int64)
                     ids = mids
                 else:
-                    inter = np.asarray(
+                    inter = kernels.pull(
                         bitops.count_rows(
                             frag.rows_device(ids) & seg[None, :]
-                        )
+                        ),
+                        "count_rows",
                     )
                 for rid, c in zip(ids, inter.tolist()):
                     if c:
@@ -3253,34 +3334,36 @@ class Executor:
                 rbs = np.zeros(B, dtype=np.int32)
                 for j, (sa, sb) in enumerate(combos_s):
                     ras[j], rbs[j] = sa, sb
+                ras_d, rbs_d = kernels.h2d(ras), kernels.h2d(rbs)
                 if f2 is f1:
-                    partials = kernels.pair_count_batched(
-                        bits1, jnp.asarray(ras), jnp.asarray(rbs)
-                    )
+                    partials = kernels.pair_count_batched(bits1, ras_d, rbs_d)
                 else:
                     partials = kernels.pair_count_two_batched(
-                        bits1, bits2, jnp.asarray(ras), jnp.asarray(rbs)
+                        bits1, bits2, ras_d, rbs_d
                     )
-                partials = np.asarray(partials).astype(np.int64)
+                partials = kernels.pull(partials, "pair_count").astype(
+                    np.int64
+                )
                 counts = (
                     partials if partials.ndim == 1
                     else partials.sum(axis=1)
                 )
         out = []
-        for j, (r1, r2) in enumerate(
-            (r1, r2) for r1 in present1 for r2 in present2
-        ):
-            c = int(counts[j])
-            if c > 0:
-                out.append(
-                    GroupCount(
-                        group=[
-                            FieldRow(field=f1name, row_id=r1),
-                            FieldRow(field=f2name, row_id=r2),
-                        ],
-                        count=c,
+        with tracing.start_span("executor.demux").set_tag("n", len(counts)):
+            for j, (r1, r2) in enumerate(
+                (r1, r2) for r1 in present1 for r2 in present2
+            ):
+                c = int(counts[j])
+                if c > 0:
+                    out.append(
+                        GroupCount(
+                            group=[
+                                FieldRow(field=f1name, row_id=r1),
+                                FieldRow(field=f2name, row_id=r2),
+                            ],
+                            count=c,
+                        )
                     )
-                )
         return out
 
     @staticmethod
@@ -3288,11 +3371,14 @@ class Executor:
         """A Row's per-shard segments as a dense ``uint32[S, W]`` matrix
         aligned to a stack's (padded) shard axis; absent shards are
         zero."""
+        from pilosa_tpu.ops import kernels
+
         filt = np.zeros((S, W), dtype=np.uint32)
         for si, s in enumerate(shards):
             seg = row.segments.get(s)
             if seg is not None:
-                filt[si] = np.asarray(seg)
+                # a segment a device lane produced is a wait for the device
+                filt[si] = kernels.pull(seg, "row_segment")
         return filt
 
     # prefix-mask memory ceiling for the k-level GroupBy batch
@@ -3330,11 +3416,11 @@ class Executor:
         if len(rows1) > cmax or len(rows1) > self._GROUPBY_BATCH_MAX:
             return None
         prefix = kernels.gather_prefix(
-            bits0, jnp.asarray([slot0[r] for r in rows1], jnp.int32)
+            bits0, kernels.h2d([slot0[r] for r in rows1], dtype=np.int32)
         )
         if filt_row is not None:
             filt = self._row_to_shard_matrix(filt_row, shards, S, W)
-            prefix = prefix & jnp.asarray(filt)[None]
+            prefix = prefix & kernels.h2d(filt)[None]
         combos: list[tuple[int, ...]] = [(r,) for r in rows1]
 
         with tracing.start_span("executor.groupByKLevel").set_tag(
@@ -3345,19 +3431,21 @@ class Executor:
                 rows = [r for r in levels[li][2] if r in slotL]
                 if not rows:
                     return []
-                idxL = jnp.asarray([slotL[r] for r in rows], jnp.int32)
+                idxL = kernels.h2d([slotL[r] for r in rows], dtype=np.int32)
                 # MXU cross gram when safe (one prefix read per level);
                 # per-shard scan partials otherwise
                 counts = kernels.combo_counts_gram(prefix, bitsL, idxL)
                 if counts is None:
-                    counts = np.asarray(
-                        kernels.combo_counts(prefix, bitsL, idxL)
+                    counts = kernels.pull(
+                        kernels.combo_counts(prefix, bitsL, idxL),
+                        "combo_counts",
                     ).astype(np.int64).sum(axis=2)  # [C, Rl]
                 live = np.argwhere(counts > 0)  # row-major: DFS order
                 if li == len(levels) - 1:
-                    out = []
-                    for ci, ri in live:
-                        out.append(
+                    with tracing.start_span("executor.demux").set_tag(
+                        "n", len(live)
+                    ):
+                        return [
                             GroupCount(
                                 group=[
                                     FieldRow(
@@ -3369,8 +3457,8 @@ class Executor:
                                 ],
                                 count=int(counts[ci, ri]),
                             )
-                        )
-                    return out
+                            for ci, ri in live
+                        ]
                 if len(live) == 0:
                     return []
                 if len(live) > cmax or len(live) > self._GROUPBY_BATCH_MAX:
@@ -3378,9 +3466,9 @@ class Executor:
                 prefix = kernels.refine_prefix(
                     prefix,
                     bitsL,
-                    jnp.asarray(live[:, 0], jnp.int32),
-                    jnp.asarray(
-                        [slotL[rows[ri]] for ri in live[:, 1]], jnp.int32
+                    kernels.h2d(live[:, 0], dtype=np.int32),
+                    kernels.h2d(
+                        [slotL[rows[ri]] for ri in live[:, 1]], dtype=np.int32
                     ),
                 )
                 combos = [

@@ -95,7 +95,22 @@ class Row:
     def count(self) -> int:
         """Python-int exact total (per-shard int32 partials summed host
         side, so >2^31 totals are safe)."""
-        return sum(self._seg_count(seg) for seg in self.segments.values())
+        total = 0
+        device = []
+        for seg in self.segments.values():
+            if isinstance(seg, np.ndarray):
+                total += bitops.popcount_host(seg)
+            else:
+                device.append(seg)
+        if device:
+            # a device lane's segments: launch every count, then wait for
+            # them under the kernels' spans like every other device result
+            from pilosa_tpu.ops import kernels
+
+            with kernels.enqueue("count_bits"):
+                parts = [bitops.count_bits(seg) for seg in device]
+            total += sum(int(kernels.pull(p, "count_bits")) for p in parts)
+        return total
 
     def intersection_count(self, other: "Row") -> int:
         total = 0

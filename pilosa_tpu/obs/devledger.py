@@ -637,13 +637,15 @@ class Ledger:
 
             sp = tracing.active_span()
             if sp is not None:
-                sp.log_kv(
-                    event="xla_compile",
-                    site=site_name or UNATTRIBUTED,
-                    sig=str(sig) if sig is not None else "-",
-                    compileMs=round(ms, 3),
-                )
                 sp.set_tag("xlaCompiles", int(sp.tags.get("xlaCompiles", 0)) + 1)
+                sp.set_tag(
+                    "xlaCompileMs",
+                    round(float(sp.tags.get("xlaCompileMs", 0.0)) + ms, 3),
+                )
+                sp.tags.setdefault("xlaCompileSites", []).append(
+                    f"{site_name or UNATTRIBUTED} "
+                    f"{sig if sig is not None else '-'}"
+                )
         except Exception:  # graftlint: disable=exception-hygiene -- span annotation is advisory; tracing must never fail a compile
             pass
 

@@ -24,6 +24,16 @@ Retention is two-tier:
 The baseline decision hashes the trace id, so every node that touches a
 trace makes the SAME keep/drop call — a baseline-kept trace is kept
 whole across the cluster (Dapper's coherent-sampling property).
+
+A batched read is two traces: the request's own (``http.query`` and what
+its handler thread did) and the flight's (``batcher.collect``,
+``batcher.flight`` and everything the dispatcher thread ran for all the
+flight's members).  The request's ``batcher.dispatch`` span names the
+flight's trace in its tag ``flight``.  A flight's trace is never judged
+by the tail policy; it waits in ``_recent``, a request that is kept pins
+the flights it names, and ``detail`` / ``spans_for`` render a flight's
+spans under the ``batcher.dispatch`` span that names it.  A flight is
+not copied into its members' traces.
 """
 
 from __future__ import annotations
@@ -55,6 +65,12 @@ def activate(store: "TraceStore | None"):
         _active_store.reset(token)
 
 
+def current() -> "TraceStore | None":
+    """The store this context's spans go to (the batcher snapshots it at
+    submit, for the dispatcher thread to route a flight's spans by)."""
+    return _active_store.get()
+
+
 def feed(span) -> None:
     """tracing span sink: deliver one finished span to the active store."""
     store = _active_store.get()
@@ -83,6 +99,24 @@ def _span_dict(span, node_id: str) -> dict:
             k: v for k, v in span.tags.items() if k != "logs"
         },
     }
+
+
+# keys of a kept record that hold Span objects, rendered at read time
+_SPAN_KEYS = ("spans", "linked")
+
+# the root of a flight's trace (server/batcher.py)
+FLIGHT_ROOT = "batcher.flight"
+
+
+def _flight_of(span) -> int | None:
+    """The flight's trace id that a ``batcher.dispatch`` span names."""
+    ft = span.tags.get("flight") if span.tags else None
+    if ft is None:
+        return None
+    try:
+        return int(ft, 16)
+    except (TypeError, ValueError):
+        return None
 
 
 def baseline_kept(trace_id: int, baseline_n: int) -> bool:
@@ -122,7 +156,7 @@ class TraceStore:
         self._recent: OrderedDict[int, list[dict]] = OrderedDict()
         self._stats = {"completed": 0, "kept": 0, "dropped": 0,
                        "kept_error": 0, "kept_slow": 0, "kept_baseline": 0,
-                       "pending_evicted": 0}
+                       "pending_evicted": 0, "flights": 0}
 
     # -- ingest --------------------------------------------------------------
 
@@ -135,28 +169,36 @@ class TraceStore:
 
     def _observe(self, span) -> None:
         tid = span.context.trace_id
-        with self._lock:
+        if not getattr(span, "local_root", False):
+            # a child: two atomic calls under the GIL, no lock.  The
+            # dispatcher thread finishes dozens of these a flight beside
+            # every handler thread's; only a root pays for the lock.
             self._pending.setdefault(tid, []).append(span)
+            return
+        with self._lock:
+            spans = self._pending.pop(tid, [])
+            spans.append(span)
             # bound the in-flight set: a span whose root never finishes
             # (crashed handler, dropped client) must not leak forever
             while len(self._pending) > self.pending_limit:
                 self._pending.popitem(last=False)
                 self._stats["pending_evicted"] += 1
-            if not getattr(span, "local_root", False):
-                return
-            spans = self._pending.pop(tid, [span])
         self._complete(tid, span, spans)
 
     def _complete(self, tid: int, root, spans: list) -> None:
+        if root.name == FLIGHT_ROOT:
+            # a flight is kept by the requests that rode it, not judged
+            with self._lock:
+                self._stats["flights"] += 1
+                self._remember(tid, spans)
+            return
         duration = root.duration or 0.0
         op_class = root.tags.get("op_class")
         error = bool(root.tags.get("error"))
         reason = self._tail_reason(tid, op_class, duration, error)
         with self._lock:
             self._stats["completed"] += 1
-            self._recent[tid] = spans
-            while len(self._recent) > self.recent_capacity:
-                self._recent.popitem(last=False)
+            self._remember(tid, spans)
             if reason is None:
                 self._stats["dropped"] += 1
                 return
@@ -171,6 +213,7 @@ class TraceStore:
                 "reason": reason,
                 "at": time.time(),
                 "spans": spans,
+                "linked": self._linked(spans),
             }
             while len(self._kept) > self.capacity:
                 self._kept.popitem(last=False)
@@ -180,6 +223,23 @@ class TraceStore:
                 hook(op_class, duration, f"{tid & (2**128 - 1):032x}")
             except Exception:  # graftlint: disable=exception-hygiene -- exemplar wiring must not fail the request
                 pass
+
+    def _linked(self, spans: list) -> dict[int, list]:
+        """The flights a request's spans name that are still in
+        ``_recent``, by reference: every member of a flight shares the
+        one list.  Caller holds the lock."""
+        out = {}
+        for s in spans:
+            ft = _flight_of(s)
+            if ft is not None and ft in self._recent:
+                out[ft] = self._recent[ft]
+        return out
+
+    def _remember(self, tid: int, spans: list) -> None:
+        """Caller holds the lock."""
+        self._recent[tid] = spans
+        while len(self._recent) > self.recent_capacity:
+            self._recent.popitem(last=False)
 
     def _tail_reason(self, tid, op_class, duration, error) -> str | None:
         if error:
@@ -215,7 +275,7 @@ class TraceStore:
         with self._lock:
             recs = list(self._kept.values())[-limit:]
         return [
-            {k: v for k, v in rec.items() if k != "spans"}
+            {k: v for k, v in rec.items() if k not in _SPAN_KEYS}
             for rec in reversed(recs)
         ]
 
@@ -228,9 +288,10 @@ class TraceStore:
             rec = self._kept.get(tid)
             if rec is None:
                 return None
-            out = {k: v for k, v in rec.items() if k != "spans"}
+            out = {k: v for k, v in rec.items() if k not in _SPAN_KEYS}
             spans = list(rec["spans"])
-        out["spans"] = [_span_dict(s, self.node_id) for s in spans]
+            linked = dict(rec["linked"])
+        out["spans"] = self._render(spans, linked)
         return out
 
     def spans_for(self, trace_id_hex: str) -> list[dict]:
@@ -244,9 +305,27 @@ class TraceStore:
             rec = self._kept.get(tid)
             if rec is not None:
                 spans = list(rec["spans"])
+                linked = dict(rec["linked"])
             else:
                 spans = list(self._recent.get(tid, ()))
-        return [_span_dict(s, self.node_id) for s in spans]
+                linked = self._linked(spans)
+        return self._render(spans, linked)
+
+    def _render(self, spans: list, linked: dict) -> list[dict]:
+        """Span dicts of one request; a flight's spans follow the
+        ``batcher.dispatch`` span that names it, with the flight's
+        parentless spans (``batcher.collect``, ``batcher.flight``)
+        re-pointed at that span.  They keep their own ``traceId``."""
+        out = []
+        for s in spans:
+            member = _span_dict(s, self.node_id)
+            out.append(member)
+            for fs in linked.get(_flight_of(s), ()):
+                d = _span_dict(fs, self.node_id)
+                if d["parentId"] is None:
+                    d["parentId"] = member["spanId"]
+                out.append(d)
+        return out
 
     def blackbox_snapshot(self, limit: int = 32) -> dict:
         """Black-box checkpoint block: kept-trace summaries (no span

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import contextvars
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -34,31 +35,36 @@ TRACEPARENT_HEADER = "traceparent"
 # Id minting (W3C trace-context widths: 128-bit trace ids, 64-bit span
 # ids).  A per-process RNG — NOT a counter — so two nodes never mint the
 # same trace id; ``seed_ids`` re-seeds it for deterministic tests.
-_id_lock = threading.Lock()
+# ``getrandbits`` is one C call under the GIL, so the span path takes no
+# lock to mint.
 _id_rng = random.Random()
 
 
 def seed_ids(seed: int | None) -> None:
     """Re-seed the id generator (tests); ``None`` restores entropy."""
-    with _id_lock:
-        _id_rng.seed(seed)
+    _id_rng.seed(seed)
 
 
-def _new_trace_id() -> int:
-    with _id_lock:
-        while True:
-            tid = _id_rng.getrandbits(128)
-            if tid:  # the zero id is invalid on the wire (W3C §3.2.2.3)
-                return tid
+def new_trace_id() -> int:
+    while True:
+        tid = _id_rng.getrandbits(128)
+        if tid:  # the zero id is invalid on the wire (W3C §3.2.2.3)
+            return tid
 
 
 def _new_span_id() -> int:
-    with _id_lock:
-        while True:
-            sid = _id_rng.getrandbits(64)
-            if sid:
-                return sid
+    while True:
+        sid = _id_rng.getrandbits(64)
+        if sid:
+            return sid
 
+
+_new_trace_id = new_trace_id  # the name tests/test_tracestore.py seeds by
+
+# A span reads one clock, the monotonic one, at each end.  Its wall-clock
+# start is that reading plus this anchor, taken once per process, so an
+# exporter never derives it from the time of export.
+_UNIX_ANCHOR_NS = time.time_ns() - time.monotonic_ns()
 
 _active_span: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
     "pilosa_active_span", default=None
@@ -76,6 +82,150 @@ def set_span_sink(sink) -> None:
     _span_sink = sink
 
 
+# ---------------------------------------------------------------------------
+# The span table: every span name the program may open, with its layer
+# (PERF.md section 3) and the per-layer metric of BENCHMARK.json it is
+# for.  ``Span.finish`` adds to the name's row; ``/debug/vars`` serves
+# the rows as the block ``spans`` (every name there from process start,
+# at zero), docs/observability.md prints them, and a span opened under a
+# name that is not here is an error.  Names are ``<layer>.<what>``.
+# ---------------------------------------------------------------------------
+
+TRACE_ONLY = "trace only"
+
+_LISTENER = "listener"
+_BATCHER = "QoS / batcher"
+_PLANNER = "planner / rescache"
+_LANES = "executor lanes"
+_KERNELS = "kernels"
+_CLUSTER = "cluster"
+_INGEST = "ingest"
+
+_HOST_MS = "executor.host_ms_per_flight"
+
+SPAN_TABLE = (
+    # name, layer, metric
+    ("http.query", _LISTENER, "listener.ms_per_read"),
+    ("http.decode", _LISTENER, "listener.ms_per_read"),
+    ("api.parse", _LISTENER, "listener.ms_per_read"),
+    ("http.encode", _LISTENER, "listener.ms_per_read"),
+    ("qos.admit", _BATCHER, TRACE_ONLY),
+    ("rescache.probe", _PLANNER, TRACE_ONLY),
+    ("batcher.queueWait", _BATCHER, "batcher.queue_wait_ms"),
+    ("batcher.dispatch", _BATCHER, TRACE_ONLY),
+    ("batcher.collect", _BATCHER, TRACE_ONLY),
+    ("batcher.flight", _BATCHER, "batcher.flight_busy_pct"),
+    ("planner.plan", _PLANNER, _HOST_MS),
+    ("executor.Execute", _LANES, _HOST_MS),
+    ("executor.ExecuteBatch", _LANES, _HOST_MS),
+    ("executor.batchPairCount", _LANES, _HOST_MS),
+    ("executor.batchCountTree", _LANES, _HOST_MS),
+    ("executor.batchBitmapTree", _LANES, _HOST_MS),
+    ("executor.batchBSI", _LANES, "executor.bsi_pct_of_flight"),
+    ("executor.bsiRangeBatch", _LANES, _HOST_MS),
+    ("executor.bsiRangeCountBatch", _LANES, _HOST_MS),
+    ("executor.bsiSumBatch", _LANES, _HOST_MS),
+    ("executor.groupByBatch", _LANES, _HOST_MS),
+    ("executor.groupByKLevel", _LANES, _HOST_MS),
+    ("executor.stackBuild", _LANES, _HOST_MS),
+    ("executor.bsiSplit", _LANES, _HOST_MS),
+    ("executor.demux", _LANES, _HOST_MS),
+    ("executor.mapReduce", _CLUSTER, _HOST_MS),
+    ("kernels.h2d", _KERNELS, TRACE_ONLY),
+    ("kernels.enqueue", _KERNELS, TRACE_ONLY),
+    ("kernels.pull", _KERNELS, "kernels.pull_ms_per_launch"),
+    ("dist.fanout", _CLUSTER, TRACE_ONLY),
+    ("dist.httpFanout", _CLUSTER, TRACE_ONLY),
+    ("dist.meshDispatch", _CLUSTER, TRACE_ONLY),
+    ("holderSyncer.SyncHolder", _CLUSTER, TRACE_ONLY),
+    ("field.Import", _INGEST, TRACE_ONLY),
+)
+
+
+class _Row:
+    """One name's totals.  Plain adds: a span finishes on the thread that
+    ran it, and between reading and storing an int attribute CPython
+    switches no thread, so the path takes no lock."""
+
+    __slots__ = ("name", "layer", "metric", "count", "ns", "self_ns", "items")
+
+    def __init__(self, name: str, layer: str, metric: str):
+        self.name = name
+        self.layer = layer
+        self.metric = metric
+        self.count = 0
+        self.ns = 0
+        self.self_ns = 0
+        self.items = 0
+
+
+_rows: dict[str, _Row] = {}
+
+
+def register(name: str, layer: str, metric: str = TRACE_ONLY) -> None:
+    """Add one name to the table (idempotent).  A name has exactly two
+    segments; the first is the block it is served under."""
+    parts = name.split(".")
+    if len(parts) != 2 or not all(parts):
+        raise ValueError(f"span name {name!r} is not <layer>.<what>")
+    if name not in _rows:
+        _rows[name] = _Row(name, layer, metric)
+
+
+def register_family(prefix: str, whats, layer: str, metric: str = TRACE_ONLY) -> None:
+    """Names an owner derives from a list of its own (``http.<route>``,
+    ``executor.execute<Call>``), registered where that list lives."""
+    for what in whats:
+        register(f"{prefix}{what}", layer, metric)
+
+
+for _name, _layer, _metric in SPAN_TABLE:
+    register(_name, _layer, _metric)
+
+
+def registered() -> list[tuple[str, str, str]]:
+    """(name, layer, metric) of every registered name, in table order."""
+    return [(r.name, r.layer, r.metric) for r in _rows.values()]
+
+
+def spans_snapshot() -> dict:
+    """The table for ``/debug/vars``: ``{first segment: {second segment:
+    {count, seconds, self_seconds, items}}}``."""
+    out: dict = {}
+    for r in list(_rows.values()):
+        block, what = r.name.split(".")
+        out.setdefault(block, {})[what] = {
+            "count": r.count,
+            "seconds": r.ns * 1e-9,
+            "self_seconds": r.self_ns * 1e-9,
+            "items": r.items,
+        }
+    return out
+
+
+def table_markdown(rows=None) -> str:
+    """The table as docs/observability.md prints it (a test holds the
+    document to this)."""
+    lines = ["| span | layer | metric |", "|---|---|---|"]
+    lines += [
+        f"| `{n}` | {layer} | {m} |" for n, layer, m in rows or registered()
+    ]
+    return "\n".join(lines)
+
+
+# ``jax.profiler.TraceAnnotation``, found once JAX is loaded and never
+# imported from here: a process without JAX opens spans without it.
+_annotation = None
+
+
+def _find_annotation():
+    global _annotation
+    prof = sys.modules.get("jax.profiler")
+    if prof is not None:
+        _annotation = prof.TraceAnnotation
+    return _annotation
+
+
 class SpanContext:
     """Wire-propagatable identity of a span.  ``remote`` marks a context
     extracted from incoming headers: a span whose parent is remote is a
@@ -90,53 +240,115 @@ class SpanContext:
         self.remote = remote
 
 
-class Span:
-    """One timed operation (reference tracing.Span :44-50)."""
+def trace_root(trace_id: int, local_root: bool = True) -> SpanContext:
+    """The context under which a span opens parentless in a trace whose
+    id is already minted (the batcher's flight: ``collect`` and ``flight``
+    are siblings in one trace, and the flight, which ends last, is its
+    local root)."""
+    return SpanContext(trace_id, 0, remote=local_root)
 
-    def __init__(self, tracer: "Tracer", name: str, parent: SpanContext | None):
+
+class Span:
+    """One timed operation (reference tracing.Span :44-50).
+
+    At ``finish`` a span adds its duration to its parent's ``child_ns``
+    and to its name's row of the span table, self time (duration minus
+    what its children covered) included, so neither needs the tree."""
+
+    __slots__ = (
+        "tracer", "name", "parent_id", "local_root", "context", "start_ns",
+        "duration", "tags", "child_ns", "_parent", "_row", "_token",
+        "_phandle", "_ann",
+    )
+
+    def __init__(
+        self,
+        tracer: "Tracer",
+        name: str,
+        parent: SpanContext | None,
+        parent_span: "Span | None" = None,
+    ):
+        row = _rows.get(name)
+        if row is None:
+            raise ValueError(
+                f"span name {name!r} is not in the span table"
+                " (pilosa_tpu/obs/tracing.py)"
+            )
+        self._row = row
         self.tracer = tracer
         self.name = name
         self.parent_id = parent.span_id if parent else 0
         # local root = no parent at all, or a parent extracted from the
         # wire (the first span of the trace on THIS node)
         self.local_root = parent is None or parent.remote
-        trace_id = parent.trace_id if parent else _new_trace_id()
+        trace_id = parent.trace_id if parent else new_trace_id()
         self.context = SpanContext(trace_id, _new_span_id())
-        self.start = time.monotonic()
-        # wall-clock anchor, taken once at span start: exporters must not
-        # re-derive it at export time (batched exports would skew it)
-        self.start_unix_ns = time.time_ns()
+        self._parent = parent_span
+        self.child_ns = 0
+        self.start_ns = time.monotonic_ns()
         self.duration = None
         self.tags: dict = {}
         self._token = None
         self._phandle = None
+        self._ann = None
+
+    @property
+    def start(self) -> float:
+        return self.start_ns * 1e-9
+
+    @property
+    def start_unix_ns(self) -> int:
+        return _UNIX_ANCHOR_NS + self.start_ns
 
     def set_tag(self, key: str, value) -> "Span":
         self.tags[key] = value
         return self
 
-    def log_kv(self, **fields) -> None:
-        self.tags.setdefault("logs", []).append((time.monotonic(), fields))
-
-    def finish(self) -> None:
+    def finish(self, end_ns: int | None = None) -> None:
         if self.duration is None:
-            self.duration = time.monotonic() - self.start
+            ns = (end_ns if end_ns is not None else time.monotonic_ns()) - self.start_ns
+            self.duration = ns * 1e-9
+            row = self._row
+            row.count += 1
+            row.ns += ns
+            # children that ran side by side (fan-out legs) can cover
+            # more than the parent's own time
+            row.self_ns += max(ns - self.child_ns, 0)
+            n = self.tags.get("n")
+            if n:
+                row.items += n
+            parent = self._parent
+            if parent is not None:
+                parent.child_ns += ns
+                self._parent = None  # a kept span keeps no tree alive
             self.tracer._record(self)
             if _span_sink is not None:
                 _span_sink(self)
 
-    # context-manager + ambient-activation protocol.  Every span is
-    # also mirrored into the active QueryProfile (if any) — this runs
-    # for the NopTracer too, which is how ``?profile=true`` sees the
-    # call tree without a tracing backend configured.
+    # context-manager + ambient-activation protocol.  Entering a span
+    # also enters a profiler annotation of the same name, so a profiler
+    # session running in the process (the program starts none) holds the
+    # program's spans on its own clock; with no session that is the
+    # native no-op.  Under ``?profile=true`` the span is mirrored into
+    # the active QueryProfile as well, whatever the tracer.
     def __enter__(self) -> "Span":
         self._token = _active_span.set(self)
-        self._phandle = qprofile.span_enter(self.name)
+        if qprofile.profiling():
+            self._phandle = qprofile.span_enter(self.name)
+        cls = _annotation or _find_annotation()
+        if cls is not None:
+            ann = self._ann = cls(self.name)
+            ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
-        qprofile.span_exit(self._phandle, self.tags)
-        self._phandle = None
+        ann = self._ann
+        if ann is not None:
+            ann.__exit__(None, None, None)
+            self._ann = None
+        if self._phandle is not None:
+            qprofile.span_exit(self._phandle, self.tags)
+            self._phandle = None
         if self._token is not None:
             _active_span.reset(self._token)
             self._token = None
@@ -151,7 +363,8 @@ class Tracer:
     ) -> Span:
         if child_of is None:
             parent = _active_span.get()
-            child_of = parent.context if parent is not None else None
+            if parent is not None:
+                return Span(self, name, parent.context, parent)
         return Span(self, name, child_of)
 
     def inject_headers(self, ctx: SpanContext, headers: dict) -> None:
@@ -281,6 +494,24 @@ def start_span(name: str, child_of: SpanContext | None = None) -> Span:
     """reference tracing.StartSpanFromContext — ambient parenting via the
     context variable when ``child_of`` is not given."""
     return _global.start_span(name, child_of)
+
+
+def record_span(
+    name: str, start_ns: int, end_ns: int, tags: dict | None = None
+) -> Span:
+    """A span built after the fact, under the active span, from two
+    ``time.monotonic_ns`` readings taken elsewhere (the batcher's
+    dispatcher times a member's queue wait and dispatch; the member
+    records them on wake-up).  It reaches the table, the store and an
+    active profile like any span; it was never entered, so the
+    profiler's trace does not hold it."""
+    span = start_span(name)
+    span.start_ns = start_ns
+    if tags:
+        span.tags.update(tags)
+    qprofile.annotate(name, (end_ns - start_ns) * 1e-6, **span.tags)
+    span.finish(end_ns)
+    return span
 
 
 def active_span() -> Span | None:

@@ -177,17 +177,22 @@ def sum_host(planes, exists, sign, filter_words, *, depth: int) -> tuple[int, in
     """Host wrapper: exact arbitrary-precision (sum, count) from the
     per-plane device popcounts."""
 
-    pos_c, neg_c, count = sum_count(planes, exists, sign, filter_words, depth=depth)
+    from pilosa_tpu.ops import kernels
+
+    with kernels.enqueue("bsi_sum"):
+        pos_c, neg_c, count = sum_count(
+            planes, exists, sign, filter_words, depth=depth
+        )
     # ONE pull per tensor (a per-plane loop of np.asarray would pay a
     # host round trip per plane)
-    pos_np = np.asarray(pos_c).astype(np.int64)
-    neg_np = np.asarray(neg_c).astype(np.int64)
+    pos_np = kernels.pull(pos_c, "bsi_sum").astype(np.int64)
+    neg_np = kernels.pull(neg_c, "bsi_sum").astype(np.int64)
     pos_sums = pos_np.reshape(depth, -1).sum(axis=1) if depth else []
     neg_sums = neg_np.reshape(depth, -1).sum(axis=1) if depth else []
     total = sum(int(c) << k for k, c in enumerate(pos_sums)) - sum(
         int(c) << k for k, c in enumerate(neg_sums)
     )
-    return total, int(np.asarray(count).astype(np.int64).sum())
+    return total, int(kernels.pull(count, "bsi_sum").astype(np.int64).sum())
 
 
 @partial(jax.jit, static_argnames=("depth", "maximal"))
@@ -242,16 +247,20 @@ def min_max_host(planes, exists, sign, filter_words, *, depth: int, maximal: boo
     (0, 0) when no column matches.  One launch, one host pull (the
     survivor masks are pulled only for the depth >= 31 exact-magnitude
     recompute)."""
-    scalars, c_a, c_b = _min_max_fused(
-        jnp.asarray(planes),
-        jnp.asarray(exists),
-        jnp.asarray(sign),
-        jnp.asarray(filter_words),
-        depth=depth,
-        maximal=maximal,
-    )
+    from pilosa_tpu.ops import kernels
+
+    with kernels.enqueue("bsi_min_max"):
+        scalars, c_a, c_b = _min_max_fused(
+            jnp.asarray(planes),
+            jnp.asarray(exists),
+            jnp.asarray(sign),
+            jnp.asarray(filter_words),
+            depth=depth,
+            maximal=maximal,
+        )
     has_a, has_b, mag_a, cnt_a, mag_b, cnt_b = (
-        np.asarray(scalars).tolist()  # ONE host pull for every decision
+        # ONE host pull for every decision
+        kernels.pull(scalars, "bsi_min_max").tolist()
     )
     if not has_a and not has_b:
         return 0, 0
@@ -554,11 +563,11 @@ _COUNT_BATCH_VMAP_LIMIT = 256 << 20
 def _batch_args(queries, depth: int):
     from pilosa_tpu.ops.bitops import pow2_pad_len
 
+    from pilosa_tpu.ops import kernels
+
     P = pow2_pad_len(len(queries))
     qmask, qinv, qmeta, need = encode_query_bounds(queries, depth, q_pad=P)
-    return (
-        jnp.asarray(qmask), jnp.asarray(qinv), jnp.asarray(qmeta),
-    ), need
+    return (kernels.h2d(qmask), kernels.h2d(qinv), kernels.h2d(qmeta)), need
 
 
 def range_batch(planes, exists, sign, queries, *, depth: int):
@@ -567,14 +576,15 @@ def range_batch(planes, exists, sign, queries, *, depth: int):
     ``len(queries)`` slices are the per-query results, the pow2-padding
     tail is garbage the caller must ignore."""
     from pilosa_tpu.ops import kernels
-    import time
 
     args = _batch_args(queries, depth)
-    t0 = time.perf_counter()
-    out = _range_batch_kernel(planes, exists, sign, *args[0], depth=depth, need=args[1])
+    with kernels.enqueue("bsi_range_batch") as sp:
+        out = _range_batch_kernel(
+            planes, exists, sign, *args[0], depth=depth, need=args[1]
+        )
     kernels.note_bsi_dispatch(
         "bsi_range_batch",
-        wall=time.perf_counter() - t0,
+        wall=sp.duration,
         args=(planes, args[0][0]),
         depth=depth,
         q_bucket=int(args[0][0].shape[0]),
@@ -587,7 +597,6 @@ def range_count_batch(planes, exists, sign, queries, *, depth: int):
     """Batched Count(Range): per-query int64 match counts (host-side
     exact sum of the per-shard int32 partials)."""
     from pilosa_tpu.ops import kernels
-    import time
 
     args, need = _batch_args(queries, depth)
     P = int(args[0].shape[0])
@@ -597,17 +606,17 @@ def range_count_batch(planes, exists, sign, queries, *, depth: int):
         if mask_bytes <= _COUNT_BATCH_VMAP_LIMIT
         else _range_count_scan_kernel
     )
-    t0 = time.perf_counter()
-    counts = kern(planes, exists, sign, *args, depth=depth, need=need)
+    with kernels.enqueue("bsi_range_count_batch") as sp:
+        counts = kern(planes, exists, sign, *args, depth=depth, need=need)
     kernels.note_bsi_dispatch(
         "bsi_range_count_batch",
-        wall=time.perf_counter() - t0,
+        wall=sp.duration,
         args=(planes, args[0]),
         depth=depth,
         q_bucket=P,
         q_useful=len(queries),
     )
-    arr = np.asarray(counts).astype(np.int64)
+    arr = kernels.pull(counts, "bsi_range_count_batch").astype(np.int64)
     arr = arr.reshape(arr.shape[0], -1)
     return [int(c) for c in arr.sum(axis=1)[: len(queries)]]
 
@@ -661,20 +670,19 @@ def sum_batch_host(planes, exists, sign, filters, *, depth: int):
     unfiltered queries); place-value combine in python ints so totals
     past 2^63 stay exact."""
     from pilosa_tpu.ops import kernels
-    import time
 
     Q = int(filters.shape[1])
-    t0 = time.perf_counter()
-    acc = _sum_batch_kernel(planes, exists, sign, filters)
+    with kernels.enqueue("bsi_sum_batch") as sp:
+        acc = _sum_batch_kernel(planes, exists, sign, filters)
     kernels.note_bsi_dispatch(
         "bsi_sum_batch",
-        wall=time.perf_counter() - t0,
+        wall=sp.duration,
         args=(planes, filters),
         depth=depth,
         q_bucket=Q,
         q_useful=Q,
     )
-    acc = np.asarray(acc).astype(np.int64)  # [depth+1, 2Q]
+    acc = kernels.pull(acc, "bsi_sum_batch").astype(np.int64)  # [depth+1, 2Q]
     out = []
     for q in range(Q):
         pos, neg = acc[:, q], acc[:, Q + q]

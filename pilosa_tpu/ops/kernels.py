@@ -48,7 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from pilosa_tpu.obs import devledger, qprofile
+from pilosa_tpu.obs import devledger, qprofile, tracing
 from pilosa_tpu.obs.stats import MemStatsClient
 from pilosa_tpu.ops.bitops import pow2_pad_len
 
@@ -284,11 +284,47 @@ def note_pad(kernel: str, padded_bytes: int, useful_bytes: int) -> None:
     tagged.count("kernel_useful_bytes", int(useful_bytes))
 
 
-def _pull(out) -> np.ndarray:
-    """Materialize a device result on the host, counting the d2h bytes."""
-    arr = np.asarray(out)
+def enqueue(kernel: str) -> tracing.Span:
+    """The span over one jitted call until it returns, ``with
+    enqueue(k) as sp: out = fn(...)``: the host's side of a launch
+    (argument handling, a compile or a cache fetch, the enqueue), while
+    the device may still be running.  ``sp.duration`` is the ``wall``
+    the dispatch notes book, so the two time one interval."""
+    return tracing.start_span("kernels.enqueue").set_tag("kernel", kernel)
+
+
+def pull(out, kernel: str = "") -> np.ndarray:
+    """The one place the served path waits for a device result and
+    copies it to the host: under a ``kernels.pull`` span, counting the
+    d2h bytes.  A numpy array passes through untimed."""
+    if isinstance(out, np.ndarray):
+        return out
+    with tracing.start_span("kernels.pull") as sp:
+        arr = np.asarray(out)
+        sp.set_tag("kernel", kernel).set_tag("bytes", arr.nbytes)
     note_transfer(arr.nbytes, "d2h")
     return arr
+
+
+def wait(out, kernel: str = ""):
+    """``block_until_ready`` under the same ``kernels.pull`` span: a wait
+    for the device that copies nothing."""
+    with tracing.start_span("kernels.pull").set_tag("kernel", kernel):
+        return jax.block_until_ready(out)
+
+
+def h2d(host, sharding=None, dtype=None):
+    """Host arguments to the device under a ``kernels.h2d`` span
+    (``device_put`` onto ``sharding`` where given).  What is already a
+    device array passes through."""
+    if isinstance(host, jax.Array):
+        return host
+    with tracing.start_span("kernels.h2d") as sp:
+        arr = np.asarray(host, dtype)
+        sp.set_tag("bytes", arr.nbytes)
+        if sharding is not None:
+            return jax.device_put(arr, sharding)
+        return jnp.asarray(arr)
 
 
 def record_host_op(kernel: str) -> None:
@@ -424,9 +460,9 @@ def _row_counts_mesh_fn(mesh, axis, in_program_reduce):
 
 def _timed_xla(kernel: str, fn, *args) -> jax.Array:
     """Launch an XLA-only kernel and book the dispatch."""
-    t0 = time.perf_counter()
-    out = fn(*args)
-    _note_dispatch(kernel, "xla", wall=time.perf_counter() - t0, args=args)
+    with enqueue(kernel) as sp:
+        out = fn(*args)
+    _note_dispatch(kernel, "xla", wall=sp.duration, args=args)
     return out
 
 
@@ -456,17 +492,13 @@ def pair_count_batched(
             out = _hi_lo_total(hi, lo)
             _note_dispatch("pair_count", "xla", args=(bits, ras))
             return out
-        t0 = time.perf_counter()
-        out = _pair_count_sharded_fn(mesh, axis, op, False)(bits, ras, rbs)
-        _note_dispatch(
-            "pair_count", "xla", wall=time.perf_counter() - t0, args=(bits, ras)
-        )
+        with enqueue("pair_count") as sp:
+            out = _pair_count_sharded_fn(mesh, axis, op, False)(bits, ras, rbs)
+        _note_dispatch("pair_count", "xla", wall=sp.duration, args=(bits, ras))
         return out
-    t0 = time.perf_counter()
-    out = pair_count_batched_xla(bits, ras, rbs, op=op)
-    _note_dispatch(
-        "pair_count", "xla", wall=time.perf_counter() - t0, args=(bits, ras)
-    )
+    with enqueue("pair_count") as sp:
+        out = pair_count_batched_xla(bits, ras, rbs, op=op)
+    _note_dispatch("pair_count", "xla", wall=sp.duration, args=(bits, ras))
     return out
 
 
@@ -673,7 +705,9 @@ def _with_gram_fallback(pallas_fn, fallback_fn, gate=None, kernel="gram"):
         # np.asarray instead of being re-answered by the fallback — and
         # every call site pulls the result immediately anyway
         t0 = time.perf_counter()
-        out = jax.block_until_ready(pallas_fn())
+        with enqueue(kernel):
+            out = pallas_fn()
+        out = wait(out, kernel)
         if gate.ok is None:
             gate.ok = True
         _note_dispatch(kernel, "pallas", wall=time.perf_counter() - t0)
@@ -690,11 +724,9 @@ def _with_gram_fallback(pallas_fn, fallback_fn, gate=None, kernel="gram"):
                 "pallas %s family disabled after %d failures",
                 kernel, gate.fails,
             )
-        t0 = time.perf_counter()
-        out = fallback_fn()
-        _note_dispatch(
-            kernel, "xla", wall=time.perf_counter() - t0, demoted=True
-        )
+        with enqueue(kernel) as sp:
+            out = fallback_fn()
+        _note_dispatch(kernel, "xla", wall=sp.duration, demoted=True)
         return out
 
 
@@ -988,7 +1020,7 @@ def _psum_chunk_size(mesh, w: int) -> int:
 
 
 def _hi_lo_total(hi, lo) -> np.ndarray:
-    return _pull(hi).astype(np.int64) * 2**32 + _pull(lo).astype(np.int64)
+    return pull(hi).astype(np.int64) * 2**32 + pull(lo).astype(np.int64)
 
 
 def pair_gram(bits: jax.Array, row_idx) -> np.ndarray | None:
@@ -1029,7 +1061,7 @@ def pair_gram(bits: jax.Array, row_idx) -> np.ndarray | None:
             if _gram_int32_safe(S, W):
                 fn = _gram_mesh_fn(mesh, axis, not full, True)
                 out = fn(bits) if full else fn(bits, jnp.asarray(idx))
-                return _pull(out).astype(np.int64)[:U, :U]
+                return pull(out).astype(np.int64)[:U, :U]
             chunk = _psum_chunk_size(mesh, W)
             if chunk < 1:
                 return None
@@ -1048,27 +1080,27 @@ def pair_gram(bits: jax.Array, row_idx) -> np.ndarray | None:
         # must not own the Pallas gate's failure semantics
         use_p = _gram_pallas_eligible(R if full else len(idx), W)
 
+        idx_d = None if full else h2d(idx)
+
         def _run(with_pallas: bool):
             fn = _gram_mesh_fn(mesh, axis, not full, False, with_pallas)
-            return fn(bits) if full else fn(bits, jnp.asarray(idx))
+            return fn(bits) if full else fn(bits, idx_d)
 
         if use_p:
             out = _with_gram_fallback(
                 lambda: _run(True), lambda: _run(False), kernel="pair_gram"
             )
         else:
-            t0 = time.perf_counter()
-            out = _run(False)
-            _note_dispatch(
-                "pair_gram", "xla", wall=time.perf_counter() - t0, args=(bits,)
-            )
-        return _pull(out).astype(np.int64).sum(axis=0)[:U, :U]
+            with enqueue("pair_gram") as sp:
+                out = _run(False)
+            _note_dispatch("pair_gram", "xla", wall=sp.duration, args=(bits,))
+        return pull(out, "pair_gram").astype(np.int64).sum(axis=0)[:U, :U]
     if _gram_int32_safe(S, W):
         if full:
             out = gram_matrix(bits)
         else:
-            out = gram_gather(bits, jnp.asarray(idx))
-        return _pull(out).astype(np.int64)[:U, :U]
+            out = gram_gather(bits, h2d(idx))
+        return pull(out, "pair_gram").astype(np.int64)[:U, :U]
     # Giant single-device index: chunk the shard axis so each chunk's
     # partial gram is int32-exact, and sum the chunks in host int64
     # (int64 on device is unavailable without jax_enable_x64).
@@ -1079,7 +1111,7 @@ def pair_gram(bits: jax.Array, row_idx) -> np.ndarray | None:
         out = gram_matrix(blk) if full else gram_gather(
             blk, jnp.asarray(idx)
         )
-        total += _pull(out).astype(np.int64)
+        total += pull(out).astype(np.int64)
     return total[:U, :U]
 
 
@@ -1230,13 +1262,10 @@ def cross_gram_gather(
         or _multi_device(bits_b)
         or not _cross_pallas_engages(Ua, Ub, W)
     ):
-        t0 = time.perf_counter()
-        out = cross_gram_gather_xla(bits_a, bits_b, ia, ib)
+        with enqueue("cross_gram_gather") as sp:
+            out = cross_gram_gather_xla(bits_a, bits_b, ia, ib)
         _note_dispatch(
-            "cross_gram_gather",
-            "xla",
-            wall=time.perf_counter() - t0,
-            args=(bits_a, ia, ib),
+            "cross_gram_gather", "xla", wall=sp.duration, args=(bits_a, ia, ib)
         )
         return out
     return _with_gram_fallback(
@@ -1309,7 +1338,7 @@ def cross_pair_gram(bits_a: jax.Array, bits_b: jax.Array, idx_a, idx_b):
                 out = _cross_gram_psum_fn(mesh, axis)(
                     bits_a, bits_b, jnp.asarray(ia), jnp.asarray(ib)
                 )
-                return _pull(out).astype(np.int64)[:Ua, :Ub]
+                return pull(out).astype(np.int64)[:Ua, :Ub]
             chunk = _psum_chunk_size(mesh, W)
             if chunk < 1:
                 return None
@@ -1319,23 +1348,25 @@ def cross_pair_gram(bits_a: jax.Array, bits_b: jax.Array, idx_a, idx_b):
             return _hi_lo_total(hi, lo)[:Ua, :Ub]
         if not _gram_int32_safe(-(-S // mesh.devices.size), W):
             return None
-        out = _cross_gram_sharded_fn(mesh, axis)(
-            bits_a, bits_b, jnp.asarray(ia), jnp.asarray(ib)
-        )
-        return _pull(out).astype(np.int64).sum(axis=0)[:Ua, :Ub]
+        ia_d, ib_d = h2d(ia), h2d(ib)
+        with enqueue("cross_pair_gram"):
+            out = _cross_gram_sharded_fn(mesh, axis)(bits_a, bits_b, ia_d, ib_d)
+        return pull(out, "cross_pair_gram").astype(np.int64).sum(axis=0)[
+            :Ua, :Ub
+        ]
     if m is not None or shards_axis_of(bits_b) is not None:
         return None  # mismatched shardings; scan kernels handle it
-    ia_d, ib_d = jnp.asarray(ia), jnp.asarray(ib)
+    ia_d, ib_d = h2d(ia), h2d(ib)
     if _gram_int32_safe(S, W):
         out = cross_gram_gather(bits_a, bits_b, ia_d, ib_d)
-        return _pull(out).astype(np.int64)[:Ua, :Ub]
+        return pull(out, "cross_gram_gather").astype(np.int64)[:Ua, :Ub]
     chunk = max(1, _GRAM_ACC_LIMIT // (W * 32))
     total = np.zeros((len(ia), len(ib)), np.int64)
     for c0 in range(0, S, chunk):
         out = cross_gram_gather(
             bits_a[c0 : c0 + chunk], bits_b[c0 : c0 + chunk], ia_d, ib_d
         )
-        total += _pull(out).astype(np.int64)
+        total += pull(out).astype(np.int64)
     return total[:Ua, :Ub]
 
 
@@ -1385,24 +1416,18 @@ def pair_count_two_batched(
             out = _hi_lo_total(hi, lo)
             _note_dispatch("pair_count_two", "xla", args=(bits_a, ras))
             return out
-        t0 = time.perf_counter()
-        out = _pair_count_sharded_fn(mesh, axis, op, True)(
-            bits_a, bits_b, ras, rbs
-        )
+        with enqueue("pair_count_two") as sp:
+            out = _pair_count_sharded_fn(mesh, axis, op, True)(
+                bits_a, bits_b, ras, rbs
+            )
         _note_dispatch(
-            "pair_count_two",
-            "xla",
-            wall=time.perf_counter() - t0,
-            args=(bits_a, ras),
+            "pair_count_two", "xla", wall=sp.duration, args=(bits_a, ras)
         )
         return out
-    t0 = time.perf_counter()
-    out = pair_count_two_batched_xla(bits_a, bits_b, ras, rbs, op=op)
+    with enqueue("pair_count_two") as sp:
+        out = pair_count_two_batched_xla(bits_a, bits_b, ras, rbs, op=op)
     _note_dispatch(
-        "pair_count_two",
-        "xla",
-        wall=time.perf_counter() - t0,
-        args=(bits_a, ras),
+        "pair_count_two", "xla", wall=sp.duration, args=(bits_a, ras)
     )
     return out
 
@@ -1479,7 +1504,7 @@ def combo_counts_gram(prefix: jax.Array, bits: jax.Array, idx) -> np.ndarray | N
         # replicate prefix + stack onto every device; the scan kernels
         # iterate rows and partition cleanly, so decline
         return None
-    idx_dev = jnp.asarray(idx, jnp.int32)
+    idx_dev = h2d(idx, dtype=np.int32)
     # the shared predicate keeps this gate in lockstep with
     # cross_gram_traced (a desync would falsely prove the Pallas gate
     # from a quietly-XLA trace); a replicated multi-device stack (no
@@ -1494,7 +1519,7 @@ def combo_counts_gram(prefix: jax.Array, bits: jax.Array, idx) -> np.ndarray | N
         )
     else:
         out = _timed_xla("combo_gram", _combo_gram_xla, prefix, bits, idx_dev)
-    return _pull(out).astype(np.int64)
+    return pull(out, "combo_gram").astype(np.int64)
 
 
 @jax.jit
@@ -1557,14 +1582,14 @@ def masked_row_counts(bits: jax.Array, filt: jax.Array):
                 )
             fspec = NamedSharding(mesh, P(axis, None))
             if getattr(filt, "sharding", None) != fspec:
-                filt = jax.device_put(np.asarray(filt), fspec)
+                filt = h2d(np.asarray(filt), fspec)
             hi, lo = _psum_chunked_fn(mesh, axis, "masked_rows", chunk)(
                 bits, filt
             )
             return _hi_lo_total(hi, lo)
         fspec = NamedSharding(mesh, P(axis, None))
         if getattr(filt, "sharding", None) != fspec:
-            filt = jax.device_put(np.asarray(filt), fspec)
+            filt = h2d(np.asarray(filt), fspec)
         partials = _timed_xla(
             "masked_row_counts",
             _masked_row_counts_sharded_fn(mesh, axis),
@@ -1572,10 +1597,12 @@ def masked_row_counts(bits: jax.Array, filt: jax.Array):
             filt,
         )
     else:
+        # a host filter goes into the call as it is: the jitted call's own
+        # argument handling uploads it, inside kernels.enqueue
         partials = _timed_xla(
             "masked_row_counts", masked_row_counts_xla, bits, filt
         )
-    return np.asarray(partials).astype(np.int64).sum(axis=0)
+    return pull(partials, "masked_row_counts").astype(np.int64).sum(axis=0)
 
 
 def _int32_safe(bits) -> bool:
@@ -1598,7 +1625,7 @@ def row_counts(bits: jax.Array):
             S, _, W = bits.shape
             if _gram_int32_safe(S, W):
                 out = _row_counts_mesh_fn(mesh, axis, True)(bits)
-                return np.asarray(out).astype(np.int64)
+                return pull(out, "row_counts").astype(np.int64)
             chunk = _psum_chunk_size(mesh, W)
             if chunk < 1:
                 raise ValueError(
@@ -1610,13 +1637,13 @@ def row_counts(bits: jax.Array):
         partials = _timed_xla(
             "row_counts", _row_counts_mesh_fn(mesh, axis, False), bits
         )
-        return np.asarray(partials).astype(np.int64).sum(axis=0)
+        return pull(partials, "row_counts").astype(np.int64).sum(axis=0)
     if _int32_safe(bits):
         return _timed_xla("row_counts", row_counts_xla, bits)
     partials = _timed_xla(
         "row_counts_per_shard", row_counts_per_shard_xla, bits
     )
-    return np.asarray(partials).astype(np.int64).sum(axis=0)
+    return pull(partials, "row_counts").astype(np.int64).sum(axis=0)
 
 
 @partial(jax.jit, static_argnames=("n",))

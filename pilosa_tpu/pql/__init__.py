@@ -6,6 +6,9 @@ by the executor which lowers ASTs to jitted XLA computations.
 """
 
 from pilosa_tpu.pql.ast import Call, Condition, Query
-from pilosa_tpu.pql.parser import ParseError, parse
+from pilosa_tpu.pql.parser import CALL_NAMES, ParseError, parse, parse_noting_hit
 
-__all__ = ["Call", "Condition", "Query", "ParseError", "parse"]
+__all__ = [
+    "CALL_NAMES", "Call", "Condition", "Query", "ParseError", "parse",
+    "parse_noting_hit",
+]

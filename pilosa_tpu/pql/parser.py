@@ -459,16 +459,34 @@ _parse_cache_lock = threading.Lock()
 
 def parse(src: str) -> Query:
     """Parse a PQL string into a Query (reference pql/parser.go Parse)."""
+    return parse_noting_hit(src)[0]
+
+
+def parse_noting_hit(src: str) -> tuple[Query, bool]:
+    """:func:`parse`, and whether the parsed-AST cache answered (the
+    ``api.parse`` span's tag)."""
     if len(src) > _PARSE_CACHE_MAX_LEN:
-        return _Parser(src).parse()
+        return _Parser(src).parse(), False
     with _parse_cache_lock:
         q = _parse_cache.get(src)
         if q is not None:
             _parse_cache.move_to_end(src)
-            return Query([c.clone() for c in q.calls])
+            return Query([c.clone() for c in q.calls]), True
     q = _Parser(src).parse()
     with _parse_cache_lock:
         _parse_cache[src] = Query([c.clone() for c in q.calls])
         while len(_parse_cache) > _PARSE_CACHE_ENTRIES:
             _parse_cache.popitem(last=False)
-    return q
+    return q, False
+
+
+# Every call name the executor answers.  The generic rule parses any
+# identifier as a call; one that is not here fails in the executor as an
+# unknown call.  The executor's per-call span family
+# (``executor.execute<Call>``) is registered from this list.
+CALL_NAMES = (
+    "Set", "Clear", "ClearRow", "Store", "SetRowAttrs", "SetColumnAttrs",
+    "Row", "Range", "Intersect", "Union", "Difference", "Xor", "Not",
+    "Shift", "Count", "Sum", "Min", "Max", "MinRow", "MaxRow", "TopN",
+    "Rows", "GroupBy", "Options",
+)

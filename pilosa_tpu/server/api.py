@@ -21,7 +21,7 @@ from pilosa_tpu import __version__, deadline
 from pilosa_tpu.cluster.client import ClientError
 from pilosa_tpu.obs import devledger
 from pilosa_tpu.obs import events as ev
-from pilosa_tpu.obs import qprofile, slo
+from pilosa_tpu.obs import qprofile, slo, tracing
 from pilosa_tpu.server import qos as qos_mod
 from pilosa_tpu.testing import faults
 from pilosa_tpu.core.field import FieldOptions
@@ -361,7 +361,12 @@ class API:
         distributed executor batches per-hop itself (ROADMAP item 4)."""
         from pilosa_tpu import pql
 
-        q = pql.parse(pql_text) if isinstance(pql_text, str) else pql_text
+        if isinstance(pql_text, str):
+            with tracing.start_span("api.parse") as sp:
+                q, hit = pql.parse_noting_hit(pql_text)
+                sp.set_tag("cacheHit", hit)
+        else:
+            q = pql_text
         # SLO op class rides a contextvar to the HTTP layer's recording
         # point (this thread handles the whole request).
         op_class = slo.classify_query(q)

@@ -40,10 +40,17 @@ truncates a neighbor's work, and an expired one still 504s).
 
 Observability: ``pilosa_batcher_*`` metrics (depth gauge, window closes
 by reason, batch-size distribution, queue-wait histogram, deadline
-bypasses/expiries) and per-request ``?profile=true`` attribution — a
-``batcher.queueWait`` span tagged with batch size and close reason, a
-``batcher.dispatch`` span, and the flight's shared execution profile
-grafted as a sub-profile (kernel records for the batched launch).
+bypasses/expiries) and one span tree per read.  The dispatcher opens
+``batcher.collect`` and ``batcher.flight`` in a trace of the flight's
+own, routed to the trace store its members snapshot at submit, so the
+``executor.*`` and ``kernels.*`` spans of a flight are kept once for all
+its members.  On wake-up each member records ``batcher.queueWait``
+(tagged with batch size and close reason) and ``batcher.dispatch``
+(tagged ``flight``, the flight's trace id) under its own ``http.query``,
+from the timestamps the dispatcher took; ``/debug/traces?id=`` follows
+the tag.  ``?profile=true`` renders the same two spans, and the flight's
+shared execution profile grafted as a sub-profile (kernel records for
+the batched launch).
 
 Write-bearing queries never enter the plane (strict in-order semantics
 stay on the per-request path).  On a clustered node the plane fronts the
@@ -74,7 +81,7 @@ import time
 
 from pilosa_tpu import deadline
 from pilosa_tpu.deadline import DeadlineExceeded
-from pilosa_tpu.obs import devledger, qprofile
+from pilosa_tpu.obs import devledger, qprofile, tracestore, tracing
 from pilosa_tpu.server import qos as qos_mod
 
 logger = logging.getLogger(__name__)
@@ -87,8 +94,9 @@ class _Flight:
 
     __slots__ = (
         "index", "query", "shards", "event", "result", "error", "enqueued",
-        "deadline_at", "profiling", "principal", "batch_size", "reason",
-        "queue_wait", "dispatch_ms", "batch_profile",
+        "deadline_at", "profiling", "principal", "store", "batch_size",
+        "reason", "flight_start", "flight_end", "flight_trace",
+        "batch_profile",
     )
 
     def __init__(self, index: str, query, shards):
@@ -98,19 +106,23 @@ class _Flight:
         self.event = threading.Event()
         self.result: list | None = None
         self.error: BaseException | None = None
-        self.enqueued = time.monotonic()
+        self.enqueued = time.monotonic_ns()
         # Snapshots of the request's ambient context: the dispatcher
-        # thread has neither the deadline nor the profile contextvar.
+        # thread has neither the deadline nor the profile contextvar,
+        # nor the trace store the request's spans go to.
         self.deadline_at = deadline.at()
         self.profiling = qprofile.profiling()
+        self.store = tracestore.current()
         # (tenant, index, op_class) for the device cost ledger: the
         # dispatcher attributes the shared batched launch fractionally
         # across every principal whose queries rode the flight.
         self.principal = devledger.current_principal()
         self.batch_size = 0
         self.reason = ""
-        self.queue_wait = 0.0
-        self.dispatch_ms = 0.0
+        # monotonic_ns readings of the dispatcher, and the flight's trace
+        self.flight_start = 0
+        self.flight_end = 0
+        self.flight_trace = 0
         self.batch_profile: dict | None = None
 
 
@@ -156,7 +168,8 @@ class QueryBatcher:
         self.dispatched = 0  # flights dispatched (observability)
         self.coalesced = 0  # requests that shared a flight with >=1 other
         self.rescache_demux = 0  # members served from the semantic cache
-        self._thread = threading.Thread(  # graftlint: disable=thread-boundary -- dispatcher is context-free by design: each _Flight snapshots deadline_at/profiling/principal at submit and _dispatch rebuilds the scopes per flight
+        self._flight_seq = 0
+        self._thread = threading.Thread(  # graftlint: disable=thread-boundary -- the dispatcher serves many requests at once, so it inherits no one's context: each _Flight snapshots deadline_at/profiling/principal/store at submit and _run/_dispatch rebuild the scopes per flight
             target=self._run, name="query-batcher", daemon=True
         )
         self._thread.start()
@@ -204,9 +217,11 @@ class QueryBatcher:
         # Retry-After upstream) before it can reach the deadline-bypass
         # or cache-probe fast paths — backpressure must not be dodged
         # by tightening the request budget.
-        decision = self.qos.admit(
-            tenant, can_degrade=self._degradable(query)
-        )
+        with tracing.start_span("qos.admit") as sp:
+            decision = self.qos.admit(
+                tenant, can_degrade=self._degradable(query)
+            )
+            sp.set_tag("decision", decision)
         if decision == qos_mod.DEGRADE:
             stale = getattr(self.executor, "rescache_degraded", None)
             served = stale(index, query, shards) if stale is not None else None
@@ -231,7 +246,9 @@ class QueryBatcher:
         # rescache.lookup span.
         probe = getattr(self.executor, "rescache_probe", None)
         if probe is not None:
-            cached = probe(index, query, shards)
+            with tracing.start_span("rescache.probe") as sp:
+                cached = probe(index, query, shards)
+                sp.set_tag("hit", cached is not None)
             if cached is not None:
                 self.rescache_demux += 1
                 if self.stats is not None:
@@ -266,13 +283,16 @@ class QueryBatcher:
             # dispatcher will still demux into the abandoned slot
             self._count_expired(tenant, "dispatch-wait")
             raise DeadlineExceeded("deadline exceeded (batched dispatch)")
-        qprofile.annotate(
-            "batcher.queueWait",
-            duration_ms=item.queue_wait * 1e3,
-            batchSize=item.batch_size,
-            closeReason=item.reason,
+        # the dispatcher's timestamps become this request's own spans (and,
+        # under ?profile=true, the same two nodes of its profile)
+        tracing.record_span(
+            "batcher.queueWait", item.enqueued, item.flight_start,
+            {"batchSize": item.batch_size, "closeReason": item.reason},
         )
-        qprofile.annotate("batcher.dispatch", duration_ms=item.dispatch_ms)
+        tracing.record_span(
+            "batcher.dispatch", item.flight_start, item.flight_end,
+            {"flight": f"{item.flight_trace:032x}"},
+        )
         if item.batch_profile is not None:
             qprofile.add_subprofile("batcher", item.batch_profile)
         deadline.check("batched response")
@@ -288,19 +308,29 @@ class QueryBatcher:
             first = self._q.get()
             if first is _STOP:
                 break
-            batch, reason = self._collect(first)
-            stopping = reason == "drain"
-            if self.prefetcher is not None:
-                try:
-                    # window close: the flight's full shard set is known;
-                    # re-stage anything whose submit-time prefetch was
-                    # dropped while the uploader serviced ingest
-                    self.prefetcher.prefetch_flight(
-                        [(f.index, f.query, f.shards) for f in batch]
-                    )
-                except Exception:
-                    logger.debug("flight prefetch failed", exc_info=True)
-            self._dispatch(batch, reason)
+            # one trace per flight, in the store its members' spans go to
+            # (the members of one batcher share their node's)
+            trace = tracing.new_trace_id()
+            with tracestore.activate(first.store):
+                with tracing.start_span(
+                    "batcher.collect",
+                    child_of=tracing.trace_root(trace, local_root=False),
+                ) as sp:
+                    batch, reason = self._collect(first)
+                    sp.set_tag("reason", reason).set_tag("n", len(batch))
+                stopping = reason == "drain"
+                if self.prefetcher is not None:
+                    try:
+                        # window close: the flight's full shard set is
+                        # known; re-stage anything whose submit-time
+                        # prefetch was dropped while the uploader
+                        # serviced ingest
+                        self.prefetcher.prefetch_flight(
+                            [(f.index, f.query, f.shards) for f in batch]
+                        )
+                    except Exception:
+                        logger.debug("flight prefetch failed", exc_info=True)
+                self._dispatch(batch, reason, trace)
             # governor control loop rides the dispatcher cadence (it
             # has no thread of its own); admission paths tick it too,
             # so a quiet dispatcher still relaxes the ladder
@@ -341,10 +371,12 @@ class QueryBatcher:
             batch.append(nxt)
             urgent = urgent or self._urgent(nxt)
 
-    def _dispatch(self, batch: list[_Flight], reason: str) -> None:
-        now = time.monotonic()
+    def _dispatch(self, batch: list[_Flight], reason: str, trace: int) -> None:
+        now_ns = time.monotonic_ns()
+        now = now_ns * 1e-9
         n = len(batch)
         self.dispatched += 1
+        self._flight_seq += 1
         if n > 1:
             self.coalesced += n
         stats = self.stats
@@ -357,9 +389,11 @@ class QueryBatcher:
         for item in batch:
             item.reason = reason
             item.batch_size = n
-            item.queue_wait = now - item.enqueued
+            item.flight_start = now_ns
             if stats is not None:
-                stats.timing("batcher_queue_wait", item.queue_wait)
+                stats.timing(
+                    "batcher_queue_wait", (now_ns - item.enqueued) * 1e-9
+                )
             if item.deadline_at is not None and item.deadline_at <= now:
                 # expired while queued: 504 without paying device work
                 item.error = DeadlineExceeded(
@@ -371,29 +405,45 @@ class QueryBatcher:
             else:
                 ready.append(item)
         t0 = time.monotonic()
+        # the flight's span ends before its members wake, so that its
+        # trace is in the store when a member's own completes
+        span = tracing.start_span(
+            "batcher.flight",
+            child_of=tracing.trace_root(trace),
+        ).set_tag("n", n).set_tag("reason", reason).set_tag(
+            "seq", self._flight_seq
+        )
         try:
-            if ready:
-                budgets = [
-                    f.deadline_at for f in ready if f.deadline_at is not None
-                ]
-                # Dispatch under the most GENEROUS budget in the flight
-                # (each member re-checks its own on wake-up); one
-                # budget-less member means an uncapped dispatch.
-                budget = (
-                    max(budgets) - t0 if len(budgets) == len(ready) else None
-                )
-                with deadline.scope(budget):
-                    self._execute(ready)
-        except BaseException as e:
-            # a dispatch bug must never strand parked handler threads
-            logger.exception("batch dispatch failed")
-            for item in ready:
-                if item.error is None and item.result is None:
-                    item.error = e
+            with span:
+                try:
+                    if ready:
+                        budgets = [
+                            f.deadline_at for f in ready
+                            if f.deadline_at is not None
+                        ]
+                        # Dispatch under the most GENEROUS budget in the
+                        # flight (each member re-checks its own on
+                        # wake-up); one budget-less member means an
+                        # uncapped dispatch.
+                        budget = (
+                            max(budgets) - t0
+                            if len(budgets) == len(ready) else None
+                        )
+                        with deadline.scope(budget):
+                            self._execute(ready)
+                except BaseException as e:
+                    # a dispatch bug must never strand parked handler
+                    # threads
+                    logger.exception("batch dispatch failed")
+                    span.set_tag("error", True)
+                    for item in ready:
+                        if item.error is None and item.result is None:
+                            item.error = e
         finally:
-            dispatch_ms = (time.monotonic() - t0) * 1e3
+            end_ns = time.monotonic_ns()
             for item in batch:
-                item.dispatch_ms = dispatch_ms
+                item.flight_end = end_ns
+                item.flight_trace = span.context.trace_id
                 item.event.set()
             with self._lock:
                 self._depth -= n

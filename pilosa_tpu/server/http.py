@@ -149,6 +149,12 @@ _ROUTES: list[tuple[str, re.Pattern, str]] = [
     ("GET", re.compile(r"^/internal/nodes$"), "nodes"),
 ]
 
+# one span name per route (``http.<route>``), registered from the route
+# table itself; ``http.query`` and its children are in tracing's own table
+tracing.register_family(
+    "http.", dict.fromkeys(name for _, _, name in _ROUTES), "listener"
+)
+
 
 class Handler(BaseHTTPRequestHandler):
     api: API = None  # set by make_server
@@ -464,7 +470,15 @@ class Handler(BaseHTTPRequestHandler):
         from pilosa_tpu.ops import kernels
 
         snap["kernels"] = kernels.telemetry_snapshot()
-        snap["device"] = membudget.default_budget().snapshot()
+        # the budget's ledger, its bytes by owner, and beside them what
+        # the backend itself says is in use (read here, never on a
+        # request's path)
+        snap["device"] = dict(
+            membudget.default_budget().snapshot(), **membudget.backend_memory()
+        )
+        # the span table (obs/tracing.py): count, seconds, self seconds
+        # and items per span name, every name present from the start
+        snap["spans"] = tracing.spans_snapshot()
         # residency-tier counters: hit/miss rates, prefetch yield, pin
         # policy outcomes (core/residency.py)
         snap["residency"] = residency.default_tracker().snapshot()
@@ -771,21 +785,25 @@ class Handler(BaseHTTPRequestHandler):
         ``{"query": ..., "shards": [...], "remote": bool}`` — the latter
         is the node↔node fan-out form (reference QueryRequest,
         internal/public.proto)."""
-        body = self._body()
-        remote = False
-        profile = False
-        shards = None
-        pql = body.decode()
-        if self.headers.get("Content-Type", "").startswith("application/json"):
-            try:
-                obj = json.loads(pql or "{}")
-            except json.JSONDecodeError:
-                obj = None  # raw PQL sent with a JSON content type
-            if isinstance(obj, dict):
-                pql = obj.get("query", "")
-                shards = obj.get("shards")
-                remote = bool(obj.get("remote"))
-                profile = bool(obj.get("profile"))
+        with tracing.start_span("http.decode") as sp:
+            body = self._body()
+            sp.set_tag("bytes", len(body))
+            remote = False
+            profile = False
+            shards = None
+            pql = body.decode()
+            if self.headers.get("Content-Type", "").startswith(
+                "application/json"
+            ):
+                try:
+                    obj = json.loads(pql or "{}")
+                except json.JSONDecodeError:
+                    obj = None  # raw PQL sent with a JSON content type
+                if isinstance(obj, dict):
+                    pql = obj.get("query", "")
+                    shards = obj.get("shards")
+                    remote = bool(obj.get("remote"))
+                    profile = bool(obj.get("profile"))
         if "shards" in self.query_params:
             shards = [
                 int(s)
@@ -795,12 +813,11 @@ class Handler(BaseHTTPRequestHandler):
             ]
         if self.query_params.get("profile", [""])[0].lower() in ("1", "true"):
             profile = True
-        self._send_json(
-            200,
-            self.api.query(
-                index, pql, shards=shards, remote=remote, profile=profile
-            ),
+        resp = self.api.query(
+            index, pql, shards=shards, remote=remote, profile=profile
         )
+        with tracing.start_span("http.encode"):
+            self._send_json(200, resp)
 
     def r_create_index(self, index: str):
         body = self._json_body()

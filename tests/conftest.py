@@ -31,6 +31,14 @@ from pilosa_tpu.testing import lockwitness  # noqa: E402
 
 lockwitness.install()
 
+# Spans open only under names of the span table (pilosa_tpu/obs/tracing.py);
+# these are the names tests open spans of their own under.
+from pilosa_tpu.obs import tracing  # noqa: E402
+
+tracing.register_family(
+    "test.", ("op", "parent", "child", "other", "root", "query"), "tests"
+)
+
 
 def pytest_terminal_summary(terminalreporter):
     bad = lockwitness.findings()

@@ -146,14 +146,14 @@ class TestCompileVsCacheHit:
             fn = jax.jit(lambda x: x * 31)
             x = jnp.arange(19, dtype=jnp.int32)
             _drain_stash()
-            with tracing.start_span("query") as sp:
+            with tracing.start_span("test.query") as sp:
                 with s.launch(sig="i32[19]"):
                     fn(x).block_until_ready()
             assert int(sp.tags.get("xlaCompiles", 0)) >= 1
+            assert sp.tags.get("xlaCompileMs", 0) > 0
             assert any(
-                fields.get("event") == "xla_compile"
-                and fields.get("site") == "test.span"
-                for _, fields in sp.tags.get("logs", [])
+                site.startswith("test.span ")
+                for site in sp.tags.get("xlaCompileSites", [])
             )
         finally:
             tracing.set_tracer(old)

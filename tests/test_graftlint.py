@@ -99,6 +99,15 @@ class TestBadCorpusCoverage:
         msgs = " | ".join(self._msgs("span_discipline"))
         assert "no tracing span" in msgs
         assert "bypasses the span-injecting" in msgs
+        assert "'executor.groupByNotInTheTable' is not in the span table" in msgs
+
+    def test_span_table_is_read_from_the_source(self):
+        from pilosa_tpu.obs import tracing
+
+        p = BY_ID["span-discipline"]
+        assert p.span_table() == {row[0] for row in tracing.SPAN_TABLE}
+        assert p.applies("pilosa_tpu/server/batcher.py")
+        assert not p.applies("tests/test_tracing.py")
 
     def test_log_classes(self):
         msgs = " | ".join(self._msgs("log_discipline"))

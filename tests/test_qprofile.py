@@ -81,7 +81,7 @@ def test_slow_query_log_threshold_and_bound():
 
 
 def test_otlp_span_anchored_at_start_not_export():
-    with tracing.start_span("op") as s:
+    with tracing.start_span("test.op") as s:
         s.set_tag("index", "i").set_tag("logs", ["hidden"])
     anchor = s.start_unix_ns
     # the span may sit in the export queue arbitrarily long; the payload

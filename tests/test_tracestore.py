@@ -46,7 +46,7 @@ def _post(uri, path, body):
         return e.code, json.loads(e.read() or b"{}")
 
 
-def _span(store, name="root", op_class="read.count", error=False,
+def _span(store, name="test.root", op_class="read.count", error=False,
           sleep=0.0):
     """Finish one root span routed into ``store``."""
     with tracestore.activate(store):
@@ -152,14 +152,14 @@ def test_fast_root_is_dropped_and_baseline_keeps_everything_at_1():
 def test_dropped_trace_spans_stay_in_recent_for_assembly():
     store = TraceStore(baseline_n=0)
     with tracestore.activate(store):
-        with tracing.start_span("root") as root:
-            with tracing.start_span("child"):
+        with tracing.start_span("test.root") as root:
+            with tracing.start_span("test.child"):
                 pass
             root.set_tag("op_class", "read.count")
     tid = f"{root.context.trace_id:032x}"
     assert store.detail(tid) is None  # fast: not kept
     spans = store.spans_for(tid)     # ...but assemblable
-    assert {s["name"] for s in spans} == {"root", "child"}
+    assert {s["name"] for s in spans} == {"test.root", "test.child"}
     assert all(s["traceId"] == tid for s in spans)
 
 
@@ -168,7 +168,7 @@ def test_kept_detail_carries_spans_and_capacity_bounds():
     tids = []
     for _ in range(8):
         with tracestore.activate(store):
-            with tracing.start_span("r") as s:
+            with tracing.start_span("test.root") as s:
                 s.set_tag("op_class", "read.count")
         tids.append(f"{s.context.trace_id:032x}")
     assert len(store.kept_ids()) == 4
@@ -211,7 +211,12 @@ def test_debug_traces_and_exemplars_over_http():
         _seed(c)
         status, _ = _post(uri, "/index/ti/query", "Count(Row(f=1))")
         assert status == 200
-        out = _get(uri, "/debug/traces")
+        # the root span finishes after the response's last byte: settle
+        for _ in range(100):
+            out = _get(uri, "/debug/traces")
+            if out["store"]["stats"]["kept_slow"] >= 1:
+                break
+            time.sleep(0.02)
         assert out["store"]["stats"]["kept_slow"] >= 1
         top = out["traces"][0]
         assert top["reason"] == "slow" and top["opClass"] == "read.count"

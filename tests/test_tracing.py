@@ -24,21 +24,21 @@ def recorder():
 
 
 def test_span_records_on_finish(recorder):
-    with tracing.start_span("op") as s:
+    with tracing.start_span("test.op") as s:
         s.set_tag("k", "v")
-    spans = recorder.finished("op")
+    spans = recorder.finished("test.op")
     assert len(spans) == 1
     assert spans[0].tags["k"] == "v"
     assert spans[0].duration >= 0
 
 
 def test_ambient_parenting(recorder):
-    with tracing.start_span("parent") as p:
-        with tracing.start_span("child") as c:
+    with tracing.start_span("test.parent") as p:
+        with tracing.start_span("test.child") as c:
             assert c.parent_id == p.context.span_id
             assert c.context.trace_id == p.context.trace_id
     # after both exit, a new span roots a fresh trace
-    with tracing.start_span("other") as o:
+    with tracing.start_span("test.other") as o:
         assert o.parent_id == 0
         assert o.context.trace_id != p.context.trace_id
 

@@ -16,6 +16,12 @@ def _batch_pair_counts(ops, stacks):
     return out
 
 
+def _group_by(levels):
+    # BAD: a span under a name the span table does not have
+    with tracing.start_span("executor.groupByNotInTheTable"):
+        return len(levels)
+
+
 class Executor:
     def execute(self, index, query, shards):
         # BAD: the top-level execute entry point opens no span

@@ -15,13 +15,21 @@ every profile and every exported trace:
   ``self._pool.request``): a direct call skips header injection and
   deadline propagation, so the remote leg falls out of the trace tree.
 
-Scope is the three hot-path files only; helpers elsewhere may be
-span-free by design.
+Those two are scoped to the three hot-path files; helpers elsewhere may
+be span-free by design.  A third holds everywhere under ``pilosa_tpu/``:
+
+* every string literal passed to ``start_span`` / ``record_span`` is a
+  name of the span table in ``pilosa_tpu/obs/tracing.py`` (``SPAN_TABLE``,
+  read from the source, not imported).  A span under any other name fails
+  at run time, ``/debug/vars`` has no row for it and no per-layer metric
+  can read it.  Names computed from a registered family
+  (``http.<route>``, ``executor.execute<Call>``) are not literals.
 """
 
 from __future__ import annotations
 
 import ast
+import os
 
 from tools.graftlint._astutil import dotted, walk_no_nested_functions
 from tools.graftlint.engine import Finding
@@ -34,8 +42,39 @@ _SCOPE_SUFFIXES = ("exec/executor.py", "cluster/dist.py", "cluster/client.py")
 _TRANSPORT_SUFFIXES = ("urlopen", "HTTPConnection", "HTTPSConnection")
 
 
+_TRACING = os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", "pilosa_tpu", "obs", "tracing.py"
+)
+_table: frozenset[str] | None = None
+
+
+def span_table() -> frozenset[str]:
+    """The names of ``SPAN_TABLE``, parsed out of tracing.py."""
+    global _table
+    if _table is None:
+        with open(_TRACING) as f:
+            tree = ast.parse(f.read())
+        rows = next(
+            n.value for n in tree.body
+            if isinstance(n, ast.Assign) and dotted(n.targets[0]) == "SPAN_TABLE"
+        )
+        _table = frozenset(row.elts[0].value for row in rows.elts)
+    return _table
+
+
+def _in_program(path: str) -> bool:
+    return "pilosa_tpu/" in path.replace("\\", "/")
+
+
 def applies(path: str) -> bool:
-    return path.replace("\\", "/").endswith(_SCOPE_SUFFIXES)
+    return _in_program(path)
+
+
+def _on_hot_path(path: str) -> bool:
+    """The entry-point and transport rules: the three files, and anything
+    checked from outside the program (the corpus)."""
+    p = path.replace("\\", "/")
+    return p.endswith(_SCOPE_SUFFIXES) or not _in_program(p)
 
 
 def _is_span_entry(fn: ast.FunctionDef) -> bool:
@@ -62,8 +101,32 @@ def _is_transport_call(node: ast.Call) -> bool:
     return d.endswith("._pool.request")
 
 
+def _unregistered(path: str, tree: ast.AST) -> list[Finding]:
+    out = []
+    for node in ast.walk(tree):
+        if not (isinstance(node, ast.Call) and node.args):
+            continue
+        d = dotted(node.func)
+        if d is None or d.rsplit(".", 1)[-1] not in ("start_span", "record_span"):
+            continue
+        arg = node.args[0]
+        if isinstance(arg, ast.Constant) and isinstance(arg.value, str) \
+                and arg.value not in span_table():
+            out.append(
+                Finding(
+                    path, node.lineno, node.col_offset, PASS_ID,
+                    f"span name {arg.value!r} is not in the span table "
+                    "(pilosa_tpu/obs/tracing.py SPAN_TABLE): it fails at "
+                    "run time and no metric can read it",
+                )
+            )
+    return out
+
+
 def check(path: str, tree: ast.AST, lines: list[str]) -> list[Finding]:
-    findings: list[Finding] = []
+    findings = _unregistered(path, tree)
+    if not _on_hot_path(path):
+        return findings
 
     for node in ast.walk(tree):
         if isinstance(node, ast.FunctionDef) and _is_span_entry(node):
