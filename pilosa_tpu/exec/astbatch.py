@@ -397,13 +397,15 @@ def run_bitmap(sig, stacks: tuple, slots_np: np.ndarray):
 
 BSI_RANGE = "bsi.range"
 BSI_RANGE_COUNT = "bsi.range_count"
+BSI_RANGE_COUNT_FILTERED = "bsi.range_count_filtered"
 BSI_SUM = "bsi.sum"
 BSI_MIN = "bsi.min"
 BSI_MAX = "bsi.max"
 BSI_GROUPBY = "bsi.groupby"
 
 BSI_OP_CLASSES = (
-    BSI_RANGE, BSI_RANGE_COUNT, BSI_SUM, BSI_MIN, BSI_MAX, BSI_GROUPBY,
+    BSI_RANGE, BSI_RANGE_COUNT, BSI_RANGE_COUNT_FILTERED, BSI_SUM, BSI_MIN,
+    BSI_MAX, BSI_GROUPBY,
 )
 
 
@@ -428,19 +430,51 @@ def _bsi_condition(idx, call: Call):
     return field, cond
 
 
+def _filtered_condition(idx, call: Call):
+    """(field, Condition, leaves) when ``call`` is an ``Intersect`` of
+    exactly one pure BSI condition and one or more plain set rows, its
+    children in any order (the planner reorders commutative children
+    before the lanes run); None otherwise.  ``leaves`` are the rows'
+    ``(field, view, row)`` as :func:`match_tree` accepts a ``Row`` leaf,
+    sorted, so both child orders sign into one group."""
+    if call.name != "Intersect" or call.args or len(call.children) < 2:
+        return None
+    found = None
+    leaves: list[tuple[str, str, int]] = []
+    for c in call.children:
+        m = _bsi_condition(idx, c)
+        if m is not None:
+            if found is not None:
+                return None
+            found = m
+            continue
+        # a time-range leaf signs as a union and a graft as nothing
+        sig = match_tree(idx, c, leaves, []) if c.name == "Row" else None
+        if sig is None or sig[0] != "row":
+            return None
+    if found is None or not leaves:
+        return None
+    return found[0], found[1], tuple(sorted(leaves))
+
+
 def match_bsi(idx, call: Call):
-    """Sign one call as BSI-batchable: ``(op_class, field, condition)``
-    (condition None for the aggregate classes, which carry their filter
-    as a child/arg instead) or None.  Conservative by construction —
-    anything unsigned keeps the exact per-call semantics."""
+    """Sign one call as BSI-batchable: ``(op_class, field, condition,
+    leaves)`` (condition None for the aggregate classes, which carry
+    their filter as a child/arg instead; ``leaves`` the set rows a
+    filtered range count intersects with, empty for every other class)
+    or None.  Conservative by construction — anything unsigned keeps the
+    exact per-call semantics."""
     name = call.name
     m = _bsi_condition(idx, call)
     if m is not None:
-        return BSI_RANGE, m[0], m[1]
+        return BSI_RANGE, m[0], m[1], ()
     if name == "Count" and len(call.children) == 1 and not call.args:
         m = _bsi_condition(idx, call.children[0])
         if m is not None:
-            return BSI_RANGE_COUNT, m[0], m[1]
+            return BSI_RANGE_COUNT, m[0], m[1], ()
+        m = _filtered_condition(idx, call.children[0])
+        if m is not None:
+            return BSI_RANGE_COUNT_FILTERED, *m
         return None
     if name in ("Sum", "Min", "Max"):
         fname, ok = call.string_arg("field")
@@ -450,11 +484,11 @@ def match_bsi(idx, call: Call):
         if field is None or not field.is_bsi():
             return None
         cls = {"Sum": BSI_SUM, "Min": BSI_MIN, "Max": BSI_MAX}[name]
-        return cls, field, None
+        return cls, field, None, ()
     if name == "GroupBy":
         filt, has = call.call_arg("filter")
         if has and filt is not None:
             m = _bsi_condition(idx, filt)
             if m is not None:
-                return BSI_GROUPBY, m[0], m[1]
+                return BSI_GROUPBY, m[0], m[1], ()
     return None

@@ -1303,6 +1303,21 @@ class Executor:
                     entry["gram"] = (bits, g)
                     field._gram_host = ((entry.get("versions"), R), g)
 
+    def _stack_on_demand(
+        self, field: Field, shard_list: list[int], view_name: str,
+        demand: int,
+    ):
+        """The view's serving stack entry for a batch lane's leaf, or
+        None when it declines.  A live stack serves for free; a cold one
+        is built only when >= 2 calls of the flight read it (stack builds
+        are full-field uploads), a heuristic that stands until the ledger
+        prices the batch-vs-solo lanes."""
+        if self._stack_cached(
+            field, shard_list, view_name
+        ) or self.planner.choose_lane("tree_count", demand >= 2):
+            return self._field_stack(field, shard_list, view_name=view_name)
+        return None
+
     def _batch_general(
         self, idx: Index, calls: list[Call], shards: list[int] | None,
         results: list[Any],
@@ -1375,19 +1390,10 @@ class Executor:
                         stacks_by_view[pair] = None
                     elif field.view(vname) is None:
                         stacks_by_view[pair] = _ABSENT
-                    elif self._stack_cached(
-                        field, shard_list, vname
-                    ) or self.planner.choose_lane(
-                        # live stack: serving from it is free.  Cold:
-                        # the >= 2 demand heuristic stands until the
-                        # ledger prices the batch-vs-solo lanes.
-                        "tree_count", demand.get(pair, 0) >= 2
-                    ):
-                        stacks_by_view[pair] = self._field_stack(
-                            field, shard_list, view_name=vname
-                        )
                     else:
-                        stacks_by_view[pair] = None
+                        stacks_by_view[pair] = self._stack_on_demand(
+                            field, shard_list, vname, demand.get(pair, 0)
+                        )
                 entry = stacks_by_view[pair]
                 if entry is None:
                     return None
@@ -1726,7 +1732,9 @@ class Executor:
         slice-plane launches: flight-mates group by (field, depth,
         op-class), so Q concurrent range predicates cost ONE
         range_batch/range_count_batch dispatch and Q filtered Sums ONE
-        fused popcount matmul (ops/bsi.py batched kernels).  Per-item
+        fused popcount matmul (ops/bsi.py batched kernels); range counts
+        intersected with set rows group by their filter stacks too, ONE
+        launch a group (_batch_bsi_filtered_counts).  Per-item
         trouble leaves the slot _UNSET for the per-call path, which
         re-raises inside the owning query's demux scope — one bad query
         never fails its flight-mates.
@@ -1736,17 +1744,24 @@ class Executor:
         predicate keeps the per-call host latency tier."""
         from pilosa_tpu.exec import astbatch
 
-        by_field: dict[str, list[tuple[int, str, Any]]] = {}
+        by_field: dict[str, list[tuple[int, str, Any, tuple]]] = {}
         fields: dict[str, Field] = {}
+        # flight demand per filter (field, view): what builds a cold
+        # filter stack, as _batch_general counts it for its leaves
+        demand: dict[tuple[str, str], int] = {}
         for i, call in enumerate(calls):
             if results[i] is not _UNSET:
                 continue
             m = astbatch.match_bsi(idx, call)
             if m is None:
                 continue
-            op_class, field, cond = m
-            by_field.setdefault(field.name, []).append((i, op_class, cond))
+            op_class, field, cond, leaves = m
+            by_field.setdefault(field.name, []).append(
+                (i, op_class, cond, leaves)
+            )
             fields[field.name] = field
+            for pair in {leaf[:2] for leaf in leaves}:
+                demand[pair] = demand.get(pair, 0) + 1
         if not by_field:
             return
 
@@ -1763,14 +1778,25 @@ class Executor:
             if bits is None:
                 continue  # over budget: per-fragment path answers
             groups: dict[str, list[tuple[int, Any]]] = {}
-            for i, op_class, cond in items:
-                groups.setdefault(op_class, []).append((i, cond))
+            # filtered range counts group by their filter stacks too
+            filtered: dict[tuple, list[tuple[int, Any, tuple]]] = {}
+            for i, op_class, cond, leaves in items:
+                if op_class == astbatch.BSI_RANGE_COUNT_FILTERED:
+                    pairs = tuple(leaf[:2] for leaf in leaves)
+                    filtered.setdefault(pairs, []).append((i, cond, leaves))
+                else:
+                    groups.setdefault(op_class, []).append((i, cond))
             with tracing.start_span("executor.batchBSI").set_tag(
                 "field", fname
             ).set_tag("n", len(items)):
                 self._batch_bsi_field(
                     idx, field, bits, groups, shard_list, calls, results
                 )
+                for pairs, fitems in filtered.items():
+                    self._batch_bsi_filtered_counts(
+                        idx, field, bits, pairs, fitems, shard_list,
+                        demand, results,
+                    )
 
     def _batch_bsi_field(
         self, idx: Index, field: Field, bits, groups, shard_list,
@@ -1908,6 +1934,63 @@ class Executor:
                 except Exception:
                     # per-call path re-raises per query
                     self.bsi_batch_item_errors += 1
+
+    def _batch_bsi_filtered_counts(
+        self, idx: Index, field: Field, bits, pairs, items, shard_list,
+        demand, results: list[Any],
+    ) -> None:
+        """``Count(Intersect(set rows, range predicate))`` items of one
+        (int field, filter stacks) group as ONE launch: the filter rows
+        are gathered from their field stacks on the device, so only the
+        encoded bounds and the row slots leave the host and the BSI
+        stack is sliced inside the program.  A group whose stacks decline
+        leaves its slots _UNSET for the per-call path."""
+        from pilosa_tpu.ops import kernels
+
+        entries = []
+        for fname, vname in pairs:
+            entry = self._stack_on_demand(
+                idx.field(fname), shard_list, vname,
+                demand.get((fname, vname), 0),
+            )
+            if entry is None:
+                return  # cold and under-demanded, or over budget
+            entries.append(entry)
+        if any(
+            kernels.shards_axis_of(a) is not None
+            for a in (bits, *(e[1] for e in entries))
+        ):
+            # per-shard partials over a mesh (let alone one that spans
+            # processes): the per-call path keeps its own story
+            return
+        try:
+            queries = [
+                self._bsi_stored_bounds(field, cond) for _, cond, _ in items
+            ]
+        except (ValueError, TypeError):
+            return  # the per-call path raises per query
+        # absent rows -> slot -1 (masked to zero words in the kernel)
+        slots = np.array(
+            [
+                [e[0].get(leaf[2], -1) for e, leaf in zip(entries, leaves)]
+                for _, _, leaves in items
+            ],
+            np.int32,
+        )
+        self.bsi_stack_launches += 1
+        with tracing.start_span(
+            "executor.bsiFilteredCountBatch"
+        ).set_tag("n", len(items)):
+            counts = bsi.range_count_filtered_batch(
+                bits, queries, [e[1] for e in entries], slots,
+                depth=field.bit_depth,
+            )
+            with tracing.start_span("executor.demux").set_tag(
+                "n", len(items)
+            ):
+                for (i, _, _), n in zip(items, counts):
+                    results[i] = n
+                    self._count_stat(idx)
 
     def _batch_bsi_sums(
         self, idx: Index, field: Field, bits, sum_items, shard_list,

@@ -124,6 +124,7 @@ SPAN_TABLE = (
     ("executor.batchBSI", _LANES, "executor.bsi_pct_of_flight"),
     ("executor.bsiRangeBatch", _LANES, _HOST_MS),
     ("executor.bsiRangeCountBatch", _LANES, _HOST_MS),
+    ("executor.bsiFilteredCountBatch", _LANES, _HOST_MS),
     ("executor.bsiSumBatch", _LANES, _HOST_MS),
     ("executor.groupByBatch", _LANES, _HOST_MS),
     ("executor.groupByKLevel", _LANES, _HOST_MS),

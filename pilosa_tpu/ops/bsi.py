@@ -555,6 +555,34 @@ def _range_count_scan_kernel(planes, exists, sign, qmask, qinv, qmeta, *, depth:
     return counts
 
 
+@partial(jax.jit, static_argnames=("depth", "need"))
+def _range_count_filtered_kernel(bits, qmask, qinv, qmeta, stacks, slots, *, depth: int, need):
+    """Per-query per-shard counts ``int32[Q, S]`` of a range predicate
+    intersected with set rows.  ``bits`` is the raw ``[S, depth+2, W]``
+    BSI stack (exists, sign, planes), sliced in here so the caller pays
+    no dispatch for the views; ``stacks`` holds one ``[S, R, W]`` field
+    stack per filter leaf and ``slots`` the ``int32[Q, leaves]`` rows to
+    gather from them (-1: an absent row, zero words, as the compiled-AST
+    lane's leaves).  The filter narrows the sign classes before the
+    bounds are applied, so a filled class counts only its filtered
+    columns.  A scan over the queries: the working set stays one mask
+    wide."""
+    exists, sign, planes = bits[:, 0], bits[:, 1], bits[:, 2:]
+
+    def step(carry, q):
+        mB, iB, tB, sl = q
+        f = exists
+        for li, st in enumerate(stacks):
+            s = sl[li]
+            row = st[:, jnp.maximum(s, 0)]
+            f = f & row & jnp.where(s >= 0, _ONES32, jnp.uint32(0))
+        r = _query_eval(planes, f & sign, f & ~sign, mB, iB, tB, depth, need)
+        return carry, jnp.sum(lax.population_count(r).astype(jnp.int32), axis=-1)
+
+    _, counts = lax.scan(step, 0, (qmask, qinv, qmeta, slots))
+    return counts
+
+
 # above this many bytes of [Q-bucket, S, W] flight masks, batched counts
 # take the scan kernel (planes re-read per query, but no Q-wide state)
 _COUNT_BATCH_VMAP_LIMIT = 256 << 20
@@ -568,6 +596,16 @@ def _batch_args(queries, depth: int):
     P = pow2_pad_len(len(queries))
     qmask, qinv, qmeta, need = encode_query_bounds(queries, depth, q_pad=P)
     return (kernels.h2d(qmask), kernels.h2d(qinv), kernels.h2d(qmeta)), need
+
+
+def _host_totals(counts, kernel: str, n: int) -> list[int]:
+    """One pull of a count kernel's per-shard int32 partials ``[P, ...]``,
+    summed per query in int64 on the host; the pow2-padding tail past
+    ``n`` is dropped."""
+    from pilosa_tpu.ops import kernels
+
+    arr = kernels.pull(counts, kernel).astype(np.int64)
+    return [int(c) for c in arr.reshape(arr.shape[0], -1).sum(axis=1)[:n]]
 
 
 def range_batch(planes, exists, sign, queries, *, depth: int):
@@ -616,9 +654,35 @@ def range_count_batch(planes, exists, sign, queries, *, depth: int):
         q_bucket=P,
         q_useful=len(queries),
     )
-    arr = kernels.pull(counts, "bsi_range_count_batch").astype(np.int64)
-    arr = arr.reshape(arr.shape[0], -1)
-    return [int(c) for c in arr.sum(axis=1)[: len(queries)]]
+    return _host_totals(counts, "bsi_range_count_batch", len(queries))
+
+
+def range_count_filtered_batch(bits, queries, stacks, slots, *, depth: int):
+    """Batched ``Count(Intersect(set rows, range predicate))``: per-query
+    int64 counts from ONE launch over the raw BSI stack ``bits`` and the
+    filter leaves' field ``stacks`` (one per column of ``slots``,
+    ``int32[len(queries), leaves]``).  Only the encoded bounds and the
+    slots leave the host; the filter rows are gathered on the device."""
+    from pilosa_tpu.ops import kernels
+
+    args, need = _batch_args(queries, depth)
+    P = int(args[0].shape[0])
+    padded = np.full((P, slots.shape[1]), -1, np.int32)
+    padded[: len(queries)] = slots
+    slots_dev = kernels.h2d(padded)
+    with kernels.enqueue("bsi_range_count_filtered") as sp:
+        counts = _range_count_filtered_kernel(
+            bits, *args, tuple(stacks), slots_dev, depth=depth, need=need
+        )
+    kernels.note_bsi_dispatch(
+        "bsi_range_count_filtered",
+        wall=sp.duration,
+        args=(bits, args[0], *stacks),
+        depth=depth,
+        q_bucket=P,
+        q_useful=len(queries),
+    )
+    return _host_totals(counts, "bsi_range_count_filtered", len(queries))
 
 
 # int32 ceiling for the fused Sum matmul accumulator: per-plane popcounts

@@ -299,3 +299,96 @@ class TestKeyedBatch:
         want = _fresh_executor(h, like=ex).execute("ki", q)
         assert sorted(got[0].keys) == sorted(want[0].keys)
         assert len(got[0].keys) > 0
+
+
+class TestFilteredRangeCountSigning:
+    """``astbatch.match_bsi`` signs ``Count(Intersect(set rows, one int
+    condition))`` for the BSI lane's filtered-count class, children in
+    any order, and leaves everything else to the per-call path."""
+
+    @pytest.fixture()
+    def idx(self, setup):
+        from pilosa_tpu.core.field import FieldOptions
+
+        h, _ = setup
+        idx = h.index("i")
+        idx.create_field(
+            "v", FieldOptions(field_type="int", min_=-100, max_=100)
+        )
+        idx.create_field(
+            "w", FieldOptions(field_type="int", min_=0, max_=100)
+        )
+        idx.create_field(
+            "t", FieldOptions(field_type="time", time_quantum="YMD")
+        )
+        idx.create_field("k", FieldOptions(keys=True))
+        return idx
+
+    @staticmethod
+    def _call(q):
+        import pilosa_tpu.pql as pql
+
+        return pql.parse(q).calls[0]
+
+    @pytest.mark.parametrize(
+        "q,leaves",
+        [
+            ("Count(Intersect(Row(f=1), Row(v < 3)))", [("f", 1)]),
+            ("Count(Intersect(Row(v < 3), Row(f=1)))", [("f", 1)]),
+            ("Count(Intersect(Row(f=1), Range(v >< [1, 3])))", [("f", 1)]),
+            ("Count(Intersect(Row(g=2), Row(v != null), Row(f=1)))",
+             [("f", 1), ("g", 2)]),
+            ("Count(Intersect(Row(f=4), Row(f=1), Row(-3 < v <= 3)))",
+             [("f", 1), ("f", 4)]),
+            ("Count(Intersect(Row(f=999), Row(v == 3)))", [("f", 999)]),
+        ],
+    )
+    def test_signs(self, idx, q, leaves):
+        m = astbatch.match_bsi(idx, self._call(q))
+        assert m is not None, q
+        op_class, field, cond, got = m
+        assert op_class == astbatch.BSI_RANGE_COUNT_FILTERED
+        assert op_class in astbatch.BSI_OP_CLASSES
+        assert field.name == "v" and cond is not None
+        assert [(f, r) for f, _, r in got] == leaves
+        # the compiled-AST lane does not claim what this lane signs
+        assert astbatch.match_count(idx, self._call(q), [], []) is None
+
+    @pytest.mark.parametrize(
+        "q",
+        [
+            "Count(Intersect(Row(f=1), Row(v < 3), Row(w > 1)))",
+            "Count(Intersect(Row(f=1), Row(v < 3), Row(v > 1)))",
+            "Count(Intersect(Union(Row(f=1), Row(f=2)), Row(v < 3)))",
+            "Count(Intersect(Row(f=1), Not(Row(f=2)), Row(v < 3)))",
+            "Count(Intersect(Row(t=1, from=2017-01-01T00:00,"
+            " to=2017-02-01T00:00), Row(v < 3)))",
+            "Count(Intersect(Row(f=1), Row(v == null)))",
+            "Count(Intersect(Row(v < 3)))",
+            "Count(Intersect(Row(f=1), Row(f=2)))",
+            "Count(Union(Row(f=1), Row(v < 3)))",
+            'Count(Intersect(Row(k="a"), Row(v < 3)))',
+            "Count(Intersect(Row(nosuch=1), Row(v < 3)))",
+            "Intersect(Row(f=1), Row(v < 3))",
+        ],
+    )
+    def test_declines(self, idx, q):
+        m = astbatch.match_bsi(idx, self._call(q))
+        assert m is None or m[0] != astbatch.BSI_RANGE_COUNT_FILTERED, q
+
+    def test_declines_a_planner_graft(self, idx):
+        from pilosa_tpu.exec import planner
+        from pilosa_tpu.exec.result import Row
+
+        call = self._call("Count(Intersect(Row(f=1), Row(v < 3)))")
+        call.children[0].children[0] = planner.make_shared(Row())
+        assert astbatch.match_bsi(idx, call) is None
+
+    def test_unfiltered_classes_carry_no_leaves(self, idx):
+        for q, cls in [
+            ("Row(v < 3)", astbatch.BSI_RANGE),
+            ("Count(Row(v < 3))", astbatch.BSI_RANGE_COUNT),
+            ("Sum(Row(f=1), field=v)", astbatch.BSI_SUM),
+        ]:
+            m = astbatch.match_bsi(idx, self._call(q))
+            assert m[0] == cls and m[3] == (), q

@@ -158,6 +158,65 @@ def test_range_count_batch(stacked):
         assert counts[qi] == want, (op, v)
 
 
+def _filter_stacks(rng, exists, n_rows):
+    """A ``[S, R, W]`` set-field stack of random rows and the per-shard
+    column sets of each row."""
+    words = rng.integers(
+        0, 1 << 32, size=(S, n_rows, exists.shape[-1]), dtype=np.uint64
+    ).astype(np.uint32)
+    cols = [[_cols(words[si, r]) for r in range(n_rows)] for si in range(S)]
+    return words, cols
+
+
+@pytest.mark.parametrize(
+    "name,queries",
+    [
+        # single-bound flights, the filled sign class among them: the
+        # shards hold negative values, so "< positive" fills every
+        # negative column and may count only the filtered ones
+        ("single", [q for q in _QUERIES if isinstance(q[1], int)]),
+        ("filled", [("<", 37), ("<=", 1023), (">", -37), (">=", -1023)]),
+        ("notnull", [("!=", None)]),
+        # two-bound flights (and a mixed one: the second bound of a
+        # single-bound query is the neutral "any")
+        ("two", [q for q in _QUERIES if isinstance(q[1], tuple)]),
+        ("mixed", _QUERIES),
+        ("pad", _QUERIES[:3]),  # 3 pads to 4
+    ],
+)
+def test_range_count_filtered_batch(stacked, name, queries):
+    """The filtered count kernel against numpy: every query's range
+    predicate intersected with one or two gathered set rows, an absent
+    row (slot -1) counting nothing, padding rows ignored."""
+    shard_values, planes, exists, sign = stacked
+    rng = np.random.default_rng(17)
+    bits = np.concatenate(
+        [exists[:, None], sign[:, None], planes], axis=1
+    )  # the raw stack layout: exists, sign, planes
+    a, a_cols = _filter_stacks(rng, exists, 5)
+    b, b_cols = _filter_stacks(rng, exists, 3)
+    slots = np.array(
+        [[qi % 5, (qi % 4) - 1] for qi in range(len(queries))], np.int32
+    )
+    counts = bsi.range_count_filtered_batch(
+        bits, _encode(queries), [a, b], slots, depth=DEPTH
+    )
+    assert len(counts) == len(queries)
+    one = bsi.range_count_filtered_batch(
+        bits, _encode(queries), [a], slots[:, :1], depth=DEPTH
+    )
+    for qi, (op, v) in enumerate(queries):
+        sa, sb = slots[qi]
+        want2 = want1 = 0
+        for si, values in enumerate(shard_values):
+            m = _np_match(values, op, v) & a_cols[si][sa]
+            want1 += len(m)
+            if sb >= 0:
+                want2 += len(m & b_cols[si][sb])
+        assert counts[qi] == want2, (op, v, "two leaves")
+        assert one[qi] == want1, (op, v, "one leaf")
+
+
 def test_depth_edge_one_bit(stacked):
     """depth=1 exercises the scan with a single plane."""
     rng = np.random.default_rng(3)
