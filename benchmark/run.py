@@ -12,6 +12,9 @@ This process never imports JAX: the server child holds the chip.  The
 cell's configuration, traffic mix and per-layer metrics are files found by
 the names in ``BENCHMARK.json`` (``README.md`` beside this file).
 
+No process this one starts outlives it, however it ends, and it ends by
+itself inside ``RUN_LIMIT_S`` (``README.md``, "How a run ends").
+
 ``--rehearsal`` drives the same stages on the CPU at the tiny shape each
 configuration file gives under ``rehearsal``; it says so, exits 3 and can
 never print a passing result.  ``--control lossy`` judges the reference
@@ -22,6 +25,7 @@ out as not correct.
 from __future__ import annotations
 
 import argparse
+import ctypes
 import importlib.util
 import json
 import math
@@ -33,6 +37,7 @@ import socket
 import subprocess
 import sys
 import tempfile
+import threading
 import time
 
 import numpy as np
@@ -49,10 +54,28 @@ WARM_POLL_S = 1.0
 WARM_QUIET_S = 10.0     # warm-up ends after this long with nothing compiled or retrieved
 WARM_LIMIT_S = 900.0
 LOADERS = 4             # processes that import the load stage, each its share of the shards
+# Spawn to result line.  Three times the slowest whole run the ledger holds (cold
+# cache: set-up 205.58 s, window 50, reference 11-13, trace reduction); a cell whose
+# cold run does not fit is too large for the grid (README.md, "How a run ends").
+RUN_LIMIT_S = 900.0
+CALLER_SIGNALS = (signal.SIGTERM, signal.SIGINT, signal.SIGHUP)
+SERVER_LOG = "server.log"  # in the work directory
+PR_SET_PDEATHSIG = 1    # <linux/prctl.h>
+KILL_WAIT_S = 60.0      # how long a killed child may take to be gone before the run ends without it
 
 
 class RunFailure(Exception):
     """The run cannot give a result; the message says why."""
+
+
+class RunCut(BaseException):
+    """The run is ended from outside the stage it is in: by a signal, or by
+    the alarm of its limit.  Not an ``Exception``, so that no stage takes it
+    for a failure of its own and goes on."""
+
+    def __init__(self, signum: int):
+        super().__init__(signum)
+        self.signum = signum
 
 
 def log(msg: str) -> None:
@@ -71,6 +94,103 @@ def free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
+
+
+class Procs:
+    """Every process the run starts, so that none outlives it.  Each asks
+    the kernel, between ``fork`` and ``exec``, for SIGKILL when the thread
+    that spawned it dies (the setting survives ``execve``), which holds
+    even where this process is killed and runs no ``finally``.  They stay
+    in this process's group: a caller that signals the group reaches them."""
+
+    def __init__(self):
+        self.all: list[subprocess.Popen] = []
+        self._preexec = None
+        if sys.platform.startswith("linux"):
+            # bound before any fork: the child may only make the two calls
+            prctl = ctypes.CDLL(None, use_errno=True).prctl
+            prctl.restype = ctypes.c_int
+            prctl.argtypes = [ctypes.c_int] + [ctypes.c_ulong] * 4
+            parent = os.getpid()
+
+            def die_with_parent() -> None:
+                prctl(PR_SET_PDEATHSIG, signal.SIGKILL, 0, 0, 0)
+                if os.getppid() != parent:  # it died before the call: no signal will come
+                    os.kill(os.getpid(), signal.SIGKILL)
+
+            self._preexec = die_with_parent
+        else:
+            log(f"no parent-death signal on {sys.platform}: a killed run can leave its children")
+
+    def spawn(self, argv: list[str], **kw) -> subprocess.Popen:
+        proc = subprocess.Popen(argv, cwd=REPO, preexec_fn=self._preexec, **kw)
+        self.all.append(proc)
+        return proc
+
+    def kill(self) -> None:
+        """SIGKILL whatever still runs, and wait until it is gone: an
+        abandoned run has nothing to keep, and whoever looks once this
+        process has ended must find none of them.  A server child that holds
+        four chips and 28 GB of rows took 20-24 s to go (my chip runs, PR 28)."""
+        alive = [p for p in self.all if p.poll() is None]
+        for p in alive:
+            p.kill()
+        end = time.monotonic() + KILL_WAIT_S
+        for p in alive:
+            try:
+                p.wait(timeout=max(0.0, end - time.monotonic()))
+            except subprocess.TimeoutExpired:
+                log(f"process {p.pid} ({p.args[1]}) is still there {KILL_WAIT_S:g} s after SIGKILL")
+
+
+class Watch:
+    """The run's one clock: the stages it has been through, its limit, and
+    the signals that end it.  SIGTERM, SIGINT, SIGHUP and the limit's alarm
+    all raise ``RunCut`` into the main thread, wherever it blocks; a second
+    one is ignored while the first is cleaned up after.  Outside the main
+    thread no handler can be installed and the run has no limit."""
+
+    def __init__(self, limit_s: float):
+        self.limit_s = limit_s
+        self.stages: list[tuple[str, float]] = []
+        self._old: dict = {}
+        self._t_cut = 0.0
+
+    def __enter__(self) -> "Watch":
+        if threading.current_thread() is threading.main_thread():
+            self._old[signal.SIGALRM] = signal.signal(signal.SIGALRM, self._cut)
+            for s in CALLER_SIGNALS:
+                # one the caller had ignored (nohup, a shell's background job) stays ignored
+                if signal.getsignal(s) != signal.SIG_IGN:
+                    self._old[s] = signal.signal(s, self._cut)
+            signal.setitimer(signal.ITIMER_REAL, self.limit_s)
+        else:
+            log("not in the main thread: no limit on this run, and a signal ends it as it would any process")
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._old:
+            signal.setitimer(signal.ITIMER_REAL, 0)
+            for s, handler in self._old.items():
+                signal.signal(s, signal.SIG_DFL if handler is None else handler)
+
+    def _cut(self, signum: int, frame) -> None:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        for s in self._old:
+            signal.signal(s, signal.SIG_IGN)
+        self._t_cut = time.monotonic()
+        raise RunCut(signum)
+
+    def stage(self, name: str) -> None:
+        self.stages.append((name, time.monotonic()))
+        log(f"stage {name}")
+
+    def cut_message(self, cut: RunCut) -> str:
+        ends = [t for _, t in self.stages[1:]] + [self._t_cut]
+        times = ", ".join(f"{name} {end - t:.1f}s" for (name, t), end in zip(self.stages, ends))
+        why = (f"the run passed its limit of {self.limit_s:g} s" if cut.signum == signal.SIGALRM
+               else f"the run was ended by {signal.Signals(cut.signum).name}")
+        return f"{why} in stage {self.stages[-1][0] if self.stages else 'start'}; stage times: {times}"
 
 
 class Http(Conn):
@@ -93,20 +213,20 @@ class Http(Conn):
 
 
 class ServerChild:
-    def __init__(self, work: str, env: dict, script: str):
+    def __init__(self, procs: Procs, work: str, env: dict, script: str):
         self.port, self.control_port = free_port(), free_port()
-        self.log_path = os.path.join(work, "server.log")
+        self.log_path = os.path.join(work, SERVER_LOG)
         cfg = os.path.join(work, "server_config.json")
         with open(cfg, "w") as f:
             # shipped options; the in-memory stats client, so /debug/vars carries the counters
             json.dump({"metric": {"service": "expvar"}}, f)
         self._log = open(self.log_path, "ab")
         self.t_spawn = time.monotonic()
-        self.proc = subprocess.Popen(
+        self.proc = procs.spawn(
             [sys.executable, script, "--data-dir", os.path.join(work, "data"),
              "--bind", f"127.0.0.1:{self.port}", "--config", cfg,
              "--control-port", str(self.control_port)],
-            cwd=REPO, env=env, stdout=self._log, stderr=subprocess.STDOUT)
+            env=env, stdout=self._log, stderr=subprocess.STDOUT)
         self._ctl = None
 
     def wait_ready(self, timeout: float = 600.0) -> float:
@@ -114,7 +234,7 @@ class ServerChild:
         while True:
             rc = self.proc.poll()
             if rc is not None:
-                raise RunFailure(f"server exited with code {rc} before serving:\n{self.log_tail()}")
+                raise RunFailure(f"server exited with code {rc} before serving:\n{file_tail(self.log_path)}")
             try:
                 status, _ = c.request("GET", "/status")
                 if status == 200:
@@ -123,7 +243,7 @@ class ServerChild:
             except RunFailure:
                 pass
             if time.monotonic() - self.t_spawn > timeout:
-                raise RunFailure(f"server not ready after {timeout:.0f}s:\n{self.log_tail()}")
+                raise RunFailure(f"server not ready after {timeout:.0f}s:\n{file_tail(self.log_path)}")
             time.sleep(0.1)
 
     def control(self, verb: str, timeout: float = 600.0) -> dict:
@@ -139,6 +259,7 @@ class ServerChild:
         return out
 
     def stop(self, timeout: float = 120.0) -> None:
+        """The orderly end of a run that went well; any other is ``Procs.kill``."""
         if self._ctl is not None:
             self._ctl.close()
             self._ctl = None
@@ -151,24 +272,24 @@ class ServerChild:
                 self.proc.wait(timeout=30)
         self._log.close()
 
-    def log_tail(self, n: int = 3000) -> str:
-        try:
-            with open(self.log_path, "rb") as f:
-                f.seek(0, os.SEEK_END)
-                f.seek(max(0, f.tell() - n))
-                return f.read().decode("utf-8", "replace")
-        except OSError:
-            return ""
+
+def file_tail(path: str, n: int = 3000) -> str:
+    try:
+        with open(path, "rb") as f:
+            f.seek(0, os.SEEK_END)
+            f.seek(max(0, f.tell() - n))
+            return f.read().decode("utf-8", "replace")
+    except OSError:
+        return ""
 
 
 class Worker:
     """A ``loadgen.py`` process."""
 
-    def __init__(self, wid: int, work: str, cfg: dict, mix: dict):
+    def __init__(self, procs: Procs, wid: int, work: str, cfg: dict, mix: dict):
         self.wid, self.work = wid, work
-        self.proc = subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "loadgen.py")], cwd=REPO,
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
+        self.proc = procs.spawn([sys.executable, os.path.join(HERE, "loadgen.py")],
+                                stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
         self.send({"config": cfg, "mix": mix})
         self.conns: list[int] = []
 
@@ -299,23 +420,21 @@ def warm_up(drv: Driver, c: Http, cfg: dict, mix: dict, seed: int, seconds: floa
     seen = swept
     run = drv.start("warm", WARM_LIMIT_S)
     t_new = run["start_at"]
-    try:
-        while True:
-            time.sleep(WARM_POLL_S)
-            dbg, before = c.json("GET", "/debug/vars"), dbg
-            now = ledger(dbg)
-            for line in new_programs(before, dbg):
-                log(f"warm-up +{time.monotonic() - t0:.0f}s, mixed flights: " + line)
-            if now != seen:
-                seen, t_new = now, time.monotonic()
-            if time.monotonic() - t_new >= (max(WARM_QUIET_S, seconds) if seen[0] else WARM_QUIET_S):
-                break
-            if time.monotonic() - t0 > WARM_LIMIT_S:
-                raise RunFailure(f"warm-up still compiling after {WARM_LIMIT_S:.0f}s "
-                                 f"(compiles {seen[0]}, retrievals {seen[1]})")
-    finally:
-        drv.stop()
-        drv.finish(run)
+    while True:
+        time.sleep(WARM_POLL_S)
+        dbg, before = c.json("GET", "/debug/vars"), dbg
+        now = ledger(dbg)
+        for line in new_programs(before, dbg):
+            log(f"warm-up +{time.monotonic() - t0:.0f}s, mixed flights: " + line)
+        if now != seen:
+            seen, t_new = now, time.monotonic()
+        if time.monotonic() - t_new >= (max(WARM_QUIET_S, seconds) if seen[0] else WARM_QUIET_S):
+            break
+        if time.monotonic() - t0 > WARM_LIMIT_S:  # the run ends on it, and its workers with it
+            raise RunFailure(f"warm-up still compiling after {WARM_LIMIT_S:.0f}s "
+                             f"(compiles {seen[0]}, retrievals {seen[1]})")
+    drv.stop()
+    drv.finish(run)
     return {"seconds": time.monotonic() - t0, "sweep_s": sweep_s, "compiles": seen[0],
             "persistent_cache_hits": seen[1], "after_sweep": [seen[0] - swept[0], seen[1] - swept[1]]}
 
@@ -420,7 +539,8 @@ def read_layer_metric(name: str, ctx: dict) -> float:
 
 
 def main(argv=None, child_script: str | None = None) -> int:
-    """``child_script`` is for the tests: a server child with the timed path broken."""
+    """``child_script`` is for the tests: a server child with the timed path
+    broken, or one that never serves."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=1)
@@ -430,6 +550,9 @@ def main(argv=None, child_script: str | None = None) -> int:
                     help="CPU, tiny shape, labelled, never a pass")
     ap.add_argument("--control", choices=("lossy",),
                     help="judge the reference with a guarantee broken in the program's place")
+    ap.add_argument("--limit", type=float, default=RUN_LIMIT_S,
+                    help="seconds from spawn to result line; for tests and for rehearsing a "
+                         "new cell (the driver's command does not pass it)")
     args = ap.parse_args(argv)
 
     if not os.path.exists(os.path.join(REPO, "pilosa_tpu", "cli.py")):
@@ -437,6 +560,31 @@ def main(argv=None, child_script: str | None = None) -> int:
               file=sys.stderr)
         return 2
     manifest = mf.load()
+    procs, work, cut = Procs(), None, None
+    with Watch(args.limit) as watch:
+        try:
+            work = tempfile.mkdtemp(prefix="pilosa_bench_")
+            rc, line = one_run(args, manifest, procs, watch, work,
+                               child_script or os.path.join(HERE, "serve_child.py"))
+        except RunCut as e:
+            cut, rc, line = e, 1, None
+        finally:
+            procs.kill()  # before any message: stderr may be a pipe that nobody reads any more
+            if cut is not None:
+                print(f"benchmark: {watch.cut_message(cut)}\n--- server log tail ---\n"
+                      f"{file_tail(os.path.join(work or '', SERVER_LOG))}", file=sys.stderr)
+            if work is not None:
+                shutil.rmtree(work, ignore_errors=True)
+    if line is not None:  # only past the watch: a run that was cut prints no result
+        print(line, flush=True)
+    return rc
+
+
+def one_run(args, manifest: dict, procs: Procs, watch: Watch, work: str,
+            child_script: str) -> tuple[int, str | None]:
+    """The stages of a run; (exit code, result line).  Only a run that went
+    well stops its children itself, in order; ``main`` kills what any other
+    leaves."""
     cell, cfg, mix = load_cell(manifest, args.workload, args.rehearsal)
     traced = bool(args.trace)
     seconds = float(args.seconds if args.seconds is not None else manifest["run_seconds"])
@@ -451,9 +599,8 @@ def main(argv=None, child_script: str | None = None) -> int:
     else:
         env.pop("PILOSA_TPU_SHARD_WIDTH", None)
 
-    work = tempfile.mkdtemp(prefix="pilosa_bench_")
-    srv = ServerChild(work, env, child_script or os.path.join(HERE, "serve_child.py"))
-    workers: list[Worker] = []
+    watch.stage("ready")
+    srv = ServerChild(procs, work, env, child_script)
     c = Http(srv.port)
     try:
         try:
@@ -461,14 +608,14 @@ def main(argv=None, child_script: str | None = None) -> int:
             device = srv.control("device")
         except RunFailure as e:
             print(f"benchmark: {e}", file=sys.stderr)
-            return 2
+            return 2, None
         on_chip = device["platform"] == "tpu"
         if on_chip == args.rehearsal or device["count"] < int(cell["chips"]):
             print(f"benchmark: found {device['count']} x {device['platform']}; the cell asks for "
                   f"{cell['chips']} chip(s)" + (" and --rehearsal is for machines without one"
                                                if args.rehearsal else "; there is no stand-in"),
                   file=sys.stderr)
-            return 2
+            return 2, None
         info = c.json("GET", "/info")
         if int(info["shardWidth"]) != 1 << int(cfg["shard_width_exp"]):
             raise RunFailure(f"shard width {info['shardWidth']}, not 2^{cfg['shard_width_exp']}")
@@ -476,10 +623,12 @@ def main(argv=None, child_script: str | None = None) -> int:
             + ("  ** REHEARSAL: CPU, tiny shape, not a result **" if args.rehearsal else ""))
 
         # ---- set-up: schema, load, warm-up ---------------------------------
+        watch.stage("schema")
         make_schema(c, cfg)
+        watch.stage("load")
         n_read = int(mix["processes"])
         n_load = min(LOADERS, int(cfg["shards"]))
-        workers = [Worker(i, work, cfg, mix) for i in range(max(n_read, n_load))]
+        workers = [Worker(procs, i, work, cfg, mix) for i in range(max(n_read, n_load))]
         for cid in range(int(mix["connections"])):
             workers[cid % n_read].conns.append(cid)
         for i, w in enumerate(workers[:n_load]):
@@ -491,6 +640,7 @@ def main(argv=None, child_script: str | None = None) -> int:
         log(f"loaded {load_bits} bits in {load_s:.1f}s ({load_bits / load_s:.0f} bits/s) "
             f"through {sum(r['requests'] for r in loads)} import requests")
 
+        watch.stage("warm-up")
         drv = Driver(workers, work, {"seed": args.seed, "port": srv.port, "index": cfg["index"],
                                      "check_one_in": int(mix.get("check_one_in", 40))})
         warm = warm_up(drv, c, cfg, mix, args.seed, seconds)
@@ -498,6 +648,7 @@ def main(argv=None, child_script: str | None = None) -> int:
             f"{warm['persistent_cache_hits']}; after the sweep {warm['after_sweep']}")
 
         # ---- the window: taken once; a program that compiles in it fails the run
+        watch.stage("window")
         trace_dir = os.path.join(work, "trace")
         if traced:
             srv.control(f"trace_start {trace_dir}")
@@ -515,26 +666,32 @@ def main(argv=None, child_script: str | None = None) -> int:
         setup_s = win["start_at"] - srv.t_spawn
         log(f"window of {seconds:.1f}s done; generator CPU share per process {win['cpu_share']}")
         device = srv.control("device")
-    except RunFailure as e:
-        print(f"benchmark: {e}\n--- server log tail ---\n{srv.log_tail()}", file=sys.stderr)
-        return 1
-    finally:
+
+        watch.stage("stop")
         c.close()
         for w in workers:
             w.stop()
         srv.stop()
+    except RunFailure as e:
+        print(f"benchmark: {e}\n--- server log tail ---\n{file_tail(srv.log_path)}", file=sys.stderr)
+        return 1, None
 
     try:
         # ---- reduce: the window's requests ---------------------------------
+        watch.stage("reduce")
         reads, done, window, e2e, failed = window_numbers(win, setup_s)
         trace = None
         if traced:
-            red = subprocess.run(
-                [sys.executable, os.path.join(HERE, "trace_reduce.py"), trace_dir],
-                env=dict(env, JAX_PLATFORMS="cpu"), capture_output=True, text=True, timeout=300)
+            red = procs.spawn([sys.executable, os.path.join(HERE, "trace_reduce.py"), trace_dir],
+                              env=dict(env, JAX_PLATFORMS="cpu"), stdout=subprocess.PIPE,
+                              stderr=subprocess.PIPE, text=True)
+            try:
+                red_out, red_err = red.communicate(timeout=300)
+            except subprocess.TimeoutExpired:
+                raise RunFailure("trace reduction took over 300 s") from None
             if red.returncode != 0:
-                raise RunFailure(f"trace reduction failed: {red.stderr[-600:]}")
-            trace = json.loads(red.stdout.strip().splitlines()[-1])
+                raise RunFailure(f"trace reduction failed: {red_err[-600:]}")
+            trace = json.loads(red_out.strip().splitlines()[-1])
             trace["window_s"] = window_s
             log(f"trace: {trace['op_count']} device ops, busy {trace['busy_s']:.3f}s of "
                 f"{window_s:.3f}s; lines {trace.get('lines')}")
@@ -545,6 +702,7 @@ def main(argv=None, child_script: str | None = None) -> int:
                "trace": trace, "e2e": e2e}
 
         # ---- judge: the window's own answers against the reference ---------
+        watch.stage("judge")
         from compare import CONTROLS, judge_reads
         from reference import Reference
 
@@ -588,9 +746,7 @@ def main(argv=None, child_script: str | None = None) -> int:
                               breakdown=breakdown)
     except (RunFailure, mf.ManifestError, KeyError) as e:
         print(f"benchmark: {type(e).__name__}: {e}", file=sys.stderr)
-        return 1
-    finally:
-        shutil.rmtree(work, ignore_errors=True)
+        return 1, None
 
     log("setup " + json.dumps({k: round(v, 3) for k, v in ctx["setup"].items()})
         + " window " + json.dumps(window) + " e2e " + json.dumps({k: round(v, 4) for k, v in e2e.items()}))
@@ -601,8 +757,7 @@ def main(argv=None, child_script: str | None = None) -> int:
     for name, (value, limit) in compared.items():
         print(f"compared {name}: {value} (limit {limit})", file=sys.stderr)
     sys.stderr.flush()
-    print(line, flush=True)
-    return 3 if args.rehearsal else 0
+    return (3 if args.rehearsal else 0), line
 
 
 if __name__ == "__main__":

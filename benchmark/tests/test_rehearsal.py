@@ -7,7 +7,8 @@ for the cell and the kind of run with value and unit, ``busy_s`` within
 false, the exit code is 3, and the one number over its limit is
 ``rehearsal``.  Every class of the mix has an answer judged against the
 reference (``classes_unjudged`` 0), none disagrees, and nothing compiled
-inside the window (``window_compiles`` 0).
+inside the window (``window_compiles`` 0).  And a run that ends well leaves
+no process behind (``test_processes.py`` has the runs that end otherwise).
 """
 
 import json
@@ -20,6 +21,7 @@ import pytest
 
 import manifest as mf
 import run
+from proctools import children_of
 
 MANIFEST = mf.load()
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
@@ -28,6 +30,7 @@ CELLS = [w["name"] for w in MANIFEST["workloads"]]
 def rehearse(capfd, *extra, child_script=None):
     rc = run.main(["--seed", "11", "--seconds", "3", "--rehearsal", *extra], child_script=child_script)
     out, err = capfd.readouterr()
+    assert children_of(os.getpid()) == [], "the run left a process"
     lines = out.strip().splitlines()
     assert lines, f"no result line (exit {rc}):\n{err[-3000:]}"
     return rc, json.loads(lines[-1]), err
