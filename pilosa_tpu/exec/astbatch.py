@@ -350,7 +350,8 @@ def run_count_batch(sig, stacks: tuple, slots_np: np.ndarray) -> np.ndarray:
         launches += 1
         label = f"count_span B{slots_np.shape[0]} S{stacks[0].shape[0]}"
         _DL.track(fn, (slots_np.shape, stacks[0].shape))
-        with _DL.launch(sig=label):
+        with _DL.launch(sig=label) as w:
+            w.mesh = True
             hi, lo = fn(*stacks, jnp.asarray(slots_np))
         return _k._hi_lo_total(hi, lo)
     fn, n_leaves = compiled(sig, True)
@@ -360,6 +361,7 @@ def run_count_batch(sig, stacks: tuple, slots_np: np.ndarray) -> np.ndarray:
     _DL.track(fn, (slots_np.shape, tuple(s.shape for s in stacks)))
     slots = _k.h2d(slots_np)
     with _DL.launch(sig=label) as w:
+        w.mesh = _k._multi_device(stacks[0])
         with _k.enqueue("ast_count"):
             out = fn(stacks, slots)
         partials = _k.pull(out, "ast_count").astype(np.int64)
@@ -380,9 +382,10 @@ def run_bitmap(sig, stacks: tuple, slots_np: np.ndarray):
     from pilosa_tpu.ops import kernels as _k
 
     slots = _k.h2d(slots_np)
-    with _DL.launch(sig=f"bitmap S{stacks[0].shape[0]}"), _k.enqueue(
+    with _DL.launch(sig=f"bitmap S{stacks[0].shape[0]}") as w, _k.enqueue(
         "ast_bitmap"
     ):
+        w.mesh = _k._multi_device(stacks[0])
         return fn(stacks, slots)
 
 

@@ -70,7 +70,15 @@ _DL_STACK = devledger.site("executor.stack_launch")
 _DL_PAIR = devledger.site("executor.pair_counts")
 
 # Largest stacked [S, R, W] tensor the batch fast path will materialize.
-_STACK_BUDGET_BYTES = 4 << 30  # device serving stacks; tuned for v5e HBM
+# one device's share of a serving stack (the whole of it without a mesh);
+# tuned for v5e HBM
+_STACK_BUDGET_BYTES = 4 << 30
+# the batch lanes, and why one hands an item back to the per-call path:
+# an operand's mesh layout, a stack that was not to be had (stack_refusals
+# says why), a lone item of a cold field (the latency tier's by design),
+# a shape the lane's kernel does not take, trouble with the item itself
+_LANES = ("pair_counts", "general", "bsi", "bsi_filtered_counts", "bsi_sums")
+_DECLINE_REASONS = ("mesh", "budget", "demand", "shape", "error")
 
 _PAIR_OPS = {
     "Intersect": "intersect",
@@ -188,11 +196,20 @@ class Executor:
         self.crossgram_cache_hits = 0
         # unfiltered BSI Sum/Min/Max scalars served per snapshot
         self.bsi_agg_cache_hits = 0
-        # flight items the batch lane handed back to the per-call path
-        # (malformed predicate, per-item compute trouble): the slot is
-        # re-executed — and its error re-raised — in the owning query's
-        # demux scope, so this counts fallbacks, not lost queries
-        self.bsi_batch_item_errors = 0
+        # flight items a batch lane handed back to the per-call path, by
+        # lane and reason: the slot is re-executed — and an error
+        # re-raised — in the owning query's demux scope, so this counts
+        # fallbacks, not lost queries.  Every key is there from the start
+        # (/debug/vars readers take deltas)
+        self.lane_declines = {
+            lane: dict.fromkeys(_DECLINE_REASONS, 0) for lane in _LANES
+        }
+        # serving stacks not built: one device's share past
+        # _STACK_BUDGET_BYTES, the HBM budget's decline, or a cold field
+        # that fewer than two calls of the flight read
+        self.stack_refusals = dict.fromkeys(
+            ("array_budget", "hbm_budget", "demand"), 0
+        )
 
     # ------------------------------------------------------------------ API
 
@@ -657,7 +674,9 @@ class Executor:
         """The miss path of :meth:`_field_stack`, under the field's stack
         lock and the ``executor.stackBuild`` span: gather the rows on the
         host, upload, retire what the new stack replaces, admit."""
-        from jax.sharding import NamedSharding, PartitionSpec
+        from jax.sharding import (
+            NamedSharding, PartitionSpec, SingleDeviceSharding,
+        )
         from pilosa_tpu.ops import kernels
 
         if fixed_rows is not None:
@@ -669,34 +688,66 @@ class Executor:
         if not row_ids:
             return None
         S, R, W = len(shards), len(row_ids), field.n_words
+        n_dev = 1
         if mesh is not None:
             n_dev = mesh.devices.size
             S = -(-S // n_dev) * n_dev  # pad so the mesh divides the axis
         nbytes = S * R * W * 4
-        if nbytes > _STACK_BUDGET_BYTES or budget.would_decline(nbytes):
+        # the array limit holds against ONE device's share (the shard
+        # axis is split over the mesh); the budget's cap is the sum of
+        # the chips, so it judges the whole
+        refused = (
+            "array_budget" if nbytes // n_dev > _STACK_BUDGET_BYTES
+            else "hbm_budget" if budget.would_decline(nbytes)
+            else None
+        )
+        if refused is not None:
             # over HBM budget: callers fall back to per-fragment paths,
             # which page rows under the same budget (membudget)
+            self.stack_refusals[refused] += 1
+            span.set_tag("refused", refused)
             return None
         slot_of = {r: i for i, r in enumerate(row_ids)}
-        bits = np.zeros((S, R, W), dtype=np.uint32)
-        for si, s in enumerate(shards):
-            f = frags.get(s)
-            if f is None:
-                continue
-            # bulk matrix copy, not one Python call per row
-            ids, matrix = f.rows_matrix_host()
-            src = [
-                k for k, r in enumerate(ids) if r in slot_of
-            ]  # fixed_rows: ignore strays
-            if src:
-                dst = [slot_of[ids[k]] for k in src]
-                bits[si, dst] = matrix[src]
-        span.set_tag("bytes", nbytes)
-        dev = kernels.h2d(
-            bits,
-            NamedSharding(mesh, PartitionSpec("shards", None, None))
-            if mesh is not None else None,
+
+        def host_rows(lo: int, hi: int) -> np.ndarray:
+            """Stack positions ``lo..hi`` of the shard axis, gathered on
+            the host (positions past ``shards`` are the mesh's padding)."""
+            block = np.zeros((hi - lo, R, W), dtype=np.uint32)
+            for si in range(lo, min(hi, len(shards))):
+                f = frags.get(shards[si])
+                if f is None:
+                    continue
+                # bulk matrix copy, not one Python call per row
+                ids, matrix = f.rows_matrix_host()
+                src = [
+                    k for k, r in enumerate(ids) if r in slot_of
+                ]  # fixed_rows: ignore strays
+                if src:
+                    dst = [slot_of[ids[k]] for k in src]
+                    block[si - lo, dst] = matrix[src]
+            return block
+
+        span.set_tag("bytes", nbytes).set_tag("devices", n_dev).set_tag(
+            "bytes_per_device", nbytes // n_dev
         )
+        if mesh is None:
+            dev = kernels.h2d(host_rows(0, S))
+        else:
+            # a device's share at a time: the host never holds the whole
+            # array (8.4 GB for 4,000 rows over 16 full-width shards)
+            sharding = NamedSharding(mesh, PartitionSpec("shards", None, None))
+            slabs = [
+                kernels.h2d(
+                    host_rows(*index[0].indices(S)[:2]),
+                    SingleDeviceSharding(d),
+                )
+                for d, index in sharding.addressable_devices_indices_map(
+                    (S, R, W)
+                ).items()
+            ]
+            dev = jax.make_array_from_single_device_arrays(
+                (S, R, W), sharding, slabs
+            )
         self.stack_rebuilds += 1
         kernels.note_transfer(nbytes, "h2d", dl_site=_DL_STACK)
         qprofile.incr("stack_rebuilds")
@@ -815,6 +866,10 @@ class Executor:
         self.stack_incremental += 1
         qprofile.incr("stack_incremental")
         return slot_of, dev
+
+    def _lane_decline(self, lane: str, reason: str, n: int = 1) -> None:
+        """``n`` items of a flight go back to the per-call path."""
+        self.lane_declines[lane][reason] += n
 
     def _count_stat(self, idx: Index, call_name: str = "Count") -> None:
         """query_total stat for a batch-answered call (the per-call path
@@ -1115,9 +1170,11 @@ class Executor:
             if len(items) < 2 and not self._pair_single_ready(
                 field, shard_list
             ):
+                self._lane_decline("pair_counts", "demand")
                 continue
             stack = self._field_stack(field, shard_list)
             if stack is None:
+                self._lane_decline("pair_counts", "budget", len(items))
                 if len(items) < 2:
                     # over-budget field: restart the warm-up so singles
                     # don't pay a declined build attempt on every query
@@ -1140,6 +1197,8 @@ class Executor:
                     if op == "intersect":
                         results[i] = 0
                         _count_stat()
+                    else:
+                        self._lane_decline("pair_counts", "shape")
                     continue
                 launch.append((i, op, sa, sb))
             if not launch:
@@ -1183,6 +1242,7 @@ class Executor:
                     # spanning mesh too large even for the chunked psum
                     # — leave these results unset so the per-call
                     # per-fragment path answers them
+                    self._lane_decline("pair_counts", "mesh", len(launch))
                     continue
                 by_op: dict[str, list[tuple[int, int, int]]] = {}
                 for i, op, sa, sb in launch:
@@ -1316,6 +1376,7 @@ class Executor:
             field, shard_list, view_name
         ) or self.planner.choose_lane("tree_count", demand >= 2):
             return self._field_stack(field, shard_list, view_name=view_name)
+        self.stack_refusals["demand"] += 1
         return None
 
     def _batch_general(
@@ -1373,8 +1434,9 @@ class Executor:
         stacks_by_view: dict[tuple[str, str], Any] = {}
 
         def _stacks_for(pairs, allow_spanning):
-            """(stacks tuple, slot_of per (field, view)) or None when any
-            leaf declines (cold + under-demanded, or over budget).
+            """(stacks tuple, slot_of per (field, view)), or the reason
+            (of _DECLINE_REASONS) when any leaf declines (cold +
+            under-demanded, or over budget).
             ``allow_spanning``: count programs reduce in-program on a
             process-spanning mesh (astbatch._compiled_spanning), but
             bitmap programs materialize [S, W] result words for
@@ -1387,16 +1449,16 @@ class Executor:
                 if pair not in stacks_by_view:
                     field = idx.field(fname)  # includes _exists
                     if field is None:
-                        stacks_by_view[pair] = None
+                        stacks_by_view[pair] = "shape"
                     elif field.view(vname) is None:
                         stacks_by_view[pair] = _ABSENT
                     else:
                         stacks_by_view[pair] = self._stack_on_demand(
                             field, shard_list, vname, demand.get(pair, 0)
-                        )
+                        ) or "budget"
                 entry = stacks_by_view[pair]
-                if entry is None:
-                    return None
+                if isinstance(entry, str):
+                    return entry
                 if entry is _ABSENT:
                     slot_maps[pair] = {}
                     out.append(None)  # placeholder filled below
@@ -1408,11 +1470,11 @@ class Executor:
             # leaf's slot is -1, which masks the gather to zero words
             real = next((a for a in out if a is not None), None)
             if real is None:
-                return None  # every leaf view absent
+                return "shape"  # every leaf view absent
             from pilosa_tpu.ops import kernels
 
             if not allow_spanning and kernels.stack_spans_processes(real):
-                return None
+                return "mesh"
             return tuple(a if a is not None else real for a in out), slot_maps
 
         def _slots_of(leaves, slot_maps) -> np.ndarray:
@@ -1424,7 +1486,8 @@ class Executor:
 
         for (sig, pairs), items in count_groups.items():
             st = _stacks_for(pairs, allow_spanning=True)
-            if st is None:
+            if isinstance(st, str):
+                self._lane_decline("general", st, len(items))
                 continue
             stacks, slot_maps = st
             # same availability contract as every spanning lane: when
@@ -1434,6 +1497,7 @@ class Executor:
             from pilosa_tpu.ops import kernels as _kk
 
             if not _kk.row_counts_supported(stacks[0]):
+                self._lane_decline("general", "mesh", len(items))
                 continue
             B = _pow2(len(items))
             slots = np.full((B, len(items[0][1])), -1, np.int32)
@@ -1452,7 +1516,8 @@ class Executor:
 
         for i, sig, pairs, leaves in bitmap_items:
             st = _stacks_for(pairs, allow_spanning=False)
-            if st is None:
+            if isinstance(st, str):
+                self._lane_decline("general", st)
                 continue
             stacks, slot_maps = st
             with tracing.start_span("executor.batchBitmapTree"):
@@ -1773,10 +1838,13 @@ class Executor:
             if len(items) < 2 and not self._bsi_stack_live(
                 field, shard_list
             ):
+                self._lane_decline("bsi", "demand")
                 continue
             bits = self._bsi_stack(field, shard_list)
             if bits is None:
-                continue  # over budget: per-fragment path answers
+                # over budget: per-fragment path answers
+                self._lane_decline("bsi", "budget", len(items))
+                continue
             groups: dict[str, list[tuple[int, Any]]] = {}
             # filtered range counts group by their filter stacks too
             filtered: dict[tuple, list[tuple[int, Any, tuple]]] = {}
@@ -1809,6 +1877,9 @@ class Executor:
         if kernels.stack_spans_processes(bits):
             # per-shard result words/partials are not host-addressable
             # across processes; the per-call paths keep their own story
+            self._lane_decline(
+                "bsi", "mesh", sum(len(g) for g in groups.values())
+            )
             return
         depth = field.bit_depth
         split: list = []
@@ -1831,6 +1902,7 @@ class Executor:
                 ]
             except (ValueError, TypeError):
                 queries = None
+                self._lane_decline("bsi", "error", len(mask_items))
             if queries is not None:
                 exists, sign, planes = tensors()
                 self.bsi_stack_launches += 1
@@ -1863,7 +1935,7 @@ class Executor:
                             )
                         except Exception:
                             # per-call path re-raises per query
-                            self.bsi_batch_item_errors += 1
+                            self._lane_decline("bsi", "error")
                     else:
                         results[i] = row
 
@@ -1894,6 +1966,7 @@ class Executor:
                     ]
                 except (ValueError, TypeError):
                     queries = None
+                    self._lane_decline("bsi", "error", len(pending))
                 if queries is not None:
                     exists, sign, planes = tensors()
                     self.bsi_stack_launches += 1
@@ -1933,7 +2006,7 @@ class Executor:
                     )
                 except Exception:
                     # per-call path re-raises per query
-                    self.bsi_batch_item_errors += 1
+                    self._lane_decline("bsi", "error")
 
     def _batch_bsi_filtered_counts(
         self, idx: Index, field: Field, bits, pairs, items, shard_list,
@@ -1943,10 +2016,14 @@ class Executor:
         (int field, filter stacks) group as ONE launch: the filter rows
         are gathered from their field stacks on the device, so only the
         encoded bounds and the row slots leave the host and the BSI
-        stack is sliced inside the program.  A group whose stacks decline
-        leaves its slots _UNSET for the per-call path."""
+        stack is sliced inside the program.  Stacks sharded over the
+        serving mesh run as one SPMD launch, each device on its own
+        shards (ops/bsi.py chooses from the operands' layout).  A group
+        whose stacks decline leaves its slots _UNSET for the per-call
+        path."""
         from pilosa_tpu.ops import kernels
 
+        lane = "bsi_filtered_counts"
         entries = []
         for fname, vname in pairs:
             entry = self._stack_on_demand(
@@ -1954,21 +2031,27 @@ class Executor:
                 demand.get((fname, vname), 0),
             )
             if entry is None:
-                return  # cold and under-demanded, or over budget
+                # cold and under-demanded, or over budget
+                self._lane_decline(lane, "budget", len(items))
+                return
             entries.append(entry)
-        if any(
-            kernels.shards_axis_of(a) is not None
-            for a in (bits, *(e[1] for e in entries))
+        layout = kernels.shards_axis_of(bits)
+        if kernels.stack_spans_processes(bits) or any(
+            kernels.shards_axis_of(e[1]) != layout for e in entries
         ):
-            # per-shard partials over a mesh (let alone one that spans
-            # processes): the per-call path keeps its own story
+            # per-shard partials are not host-addressable across
+            # processes, and stacks built under two serving meshes share
+            # no program: the per-call path keeps its own story
+            self._lane_decline(lane, "mesh", len(items))
             return
         try:
             queries = [
                 self._bsi_stored_bounds(field, cond) for _, cond, _ in items
             ]
         except (ValueError, TypeError):
-            return  # the per-call path raises per query
+            # the per-call path raises per query
+            self._lane_decline(lane, "error", len(items))
+            return
         # absent rows -> slot -1 (masked to zero words in the kernel)
         slots = np.array(
             [
@@ -2007,7 +2090,7 @@ class Executor:
                 filt = self._sum_filter(idx, calls[i], shard_list)
             except Exception:
                 # malformed: per-call path raises per query
-                self.bsi_batch_item_errors += 1
+                self._lane_decline("bsi_sums", "error")
                 continue
             if filt is None:
                 unfiltered.append(i)
@@ -2027,21 +2110,25 @@ class Executor:
                     results[i] = self._sum_valcount(field, tc)
             except Exception:
                 # per-call path re-raises per query
-                self.bsi_batch_item_errors += 1
+                self._lane_decline("bsi_sums", "error", len(unfiltered))
         if not filtered:
             return
         Q = len(filtered)
         P = _pow2(Q)
-        if (
-            Q < 2
-            or not bsi.sum_batch_supported(S_stack, W)
-            or S_stack * P * W * 4 > self._BSI_SUM_FILTER_BUDGET_BYTES
-        ):
-            return  # per-query host lane (existing sum path) answers
-        sh = getattr(bits, "sharding", None)
-        if sh is not None and len(getattr(sh, "device_set", ())) > 1:
-            # the [S, Q, W] filter tensor has no mesh layout matching the
-            # stack's; keep the fused path single-device for now
+        # a mesh-sharded stack takes the filter tensor in its own layout:
+        # each device accumulates, and holds, its own shards' share
+        sh = bits.sharding if kernels.shards_axis_of(bits) else None
+        S_dev = S_stack // len(sh.device_set) if sh is not None else S_stack
+        declined = (
+            "demand" if Q < 2
+            else "shape" if not bsi.sum_batch_supported(S_dev, W)
+            else "budget"
+            if S_dev * P * W * 4 > self._BSI_SUM_FILTER_BUDGET_BYTES
+            else None
+        )
+        if declined is not None:
+            # per-query host lane (existing sum path) answers
+            self._lane_decline("bsi_sums", declined, Q)
             return
         fw = np.zeros((S_stack, P, W), np.uint32)
         for qi, (_, filt) in enumerate(filtered):
@@ -2055,7 +2142,7 @@ class Executor:
         exists, sign, planes = self._bsi_split(bits)
         self.bsi_stack_launches += 1
         with tracing.start_span("executor.bsiSumBatch").set_tag("n", Q):
-            filters = kernels.h2d(fw)
+            filters = kernels.h2d(fw, sh)
             pairs = bsi.sum_batch_host(
                 planes, exists, sign, filters, depth=depth
             )
@@ -2756,6 +2843,8 @@ class Executor:
         unfiltered queries, else materialize the tensors, run
         ``compute(planes, exists, sign, fw)``, and install (filtered
         queries always compute — their result depends on the filter)."""
+        from pilosa_tpu.ops import kernels
+
         bits, filt, _ = stacked
         cached, put = (
             self._bsi_agg_cache(field, bits, key)
@@ -2765,7 +2854,8 @@ class Executor:
         if cached is None:
             planes, exists, sign, fw = self._bsi_tensors(field, stacked)
             self.bsi_stack_launches += 1
-            with _DL_STACK.launch(sig=f"bsi_agg/{key.split(':', 1)[0]}"):
+            with _DL_STACK.launch(sig=f"bsi_agg/{key.split(':', 1)[0]}") as w:
+                w.mesh = kernels._multi_device(planes)
                 cached = compute(planes, exists, sign, fw)
             put(cached)
         return cached

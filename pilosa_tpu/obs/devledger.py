@@ -157,6 +157,7 @@ class _Accum:
         "compiles",
         "compile_ms",
         "launches",
+        "mesh_launches",
         "launch_ms",
         "device_ms",
         "h2d_bytes",
@@ -170,6 +171,7 @@ class _Accum:
         self.compiles = 0
         self.compile_ms = 0.0
         self.launches = 0
+        self.mesh_launches = 0  # of launches: operands on more than one device
         self.launch_ms = 0.0
         self.device_ms = 0.0
         self.h2d_bytes = 0
@@ -184,6 +186,7 @@ class _Accum:
             "compileMs": round(self.compile_ms, 3),
             "cacheHits": self.cache_hits,
             "launches": self.launches,
+            "meshLaunches": self.mesh_launches,
             "launchMs": round(self.launch_ms, 3),
             "deviceMs": round(self.device_ms, 3),
             "h2dBytes": self.h2d_bytes,
@@ -205,12 +208,17 @@ class _Window:
     listener folds compile events into the innermost window; the window's
     exit books them against its site and the ambient principals."""
 
-    __slots__ = ("site", "sig", "muted", "compiles", "compile_ms")
+    __slots__ = ("site", "sig", "muted", "mesh", "compiles", "compile_ms")
 
     def __init__(self, site, sig, muted=False):
         self.site = site
         self.sig = sig
         self.muted = muted
+        # set by whoever dispatches inside the window (a kernels-funnel
+        # dispatch, or the window's owner) once an operand is seen to lie
+        # on more than one device; the window's own launch then books as
+        # a mesh launch too
+        self.mesh = False
         self.compiles = 0
         self.compile_ms = 0.0
 
@@ -279,6 +287,14 @@ class Site:
     def record_launch(self, wall_s=0.0, n=1, device_s=None):
         self.ledger._book_launch(self, n, wall_s * 1e3, (device_s or wall_s) * 1e3)
 
+    def record_mesh_launch(self, n=1):
+        """``n`` of the launches just booked ran over more than one
+        device (one SPMD program); the windows open on this thread
+        wrapped such a dispatch and will book theirs the same."""
+        for w in _tls.windows:
+            w.mesh = True
+        self.ledger._book_mesh_launch(self, n)
+
     def record_transfer(self, nbytes, direction="h2d"):
         self.ledger._book_transfer(self, int(nbytes), direction)
 
@@ -312,6 +328,8 @@ class Site:
                     self, n, wall_ms, wall_ms,
                     sig=None if w.compiles else sig,
                 )
+                if w.mesh:
+                    self.ledger._book_mesh_launch(self, n)
 
     def claim(self, sig=None):
         """Adopt compile events this thread saw since the last claim —
@@ -493,6 +511,11 @@ class Ledger:
                 row.launches += max(1, round(n * w)) if n else 0
                 row.launch_ms += wall_ms * w
                 row.device_ms += device_ms * w
+
+    def _book_mesh_launch(self, site, n):
+        with self._lock:
+            site.acc.mesh_launches += n
+            self.totals.mesh_launches += n
 
     def _book_transfer(self, site, nbytes, direction):
         weights = ambient_weights()

@@ -24,7 +24,7 @@ stored = value - base; sign row holds stored < 0; planes hold abs(stored).
 
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -583,6 +583,35 @@ def _range_count_filtered_kernel(bits, qmask, qinv, qmeta, stacks, slots, *, dep
     return counts
 
 
+@lru_cache(maxsize=64)
+def _range_count_filtered_mesh_fn(mesh, axis, n_stacks: int, depth: int, need):
+    """jit(shard_map) of the filtered count over shards-sharded stacks:
+    the scan is elementwise over the shard axis up to its count, so each
+    device runs it on its own block of the BSI stack and of every filter
+    stack, and the per-shard partials ``int32[Q, S]`` come back along
+    the mesh axis for the host's int64 sum, as the one-device form's do.
+    Bounds and slots are replicated."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def local(bits, qmask, qinv, qmeta, slots, *stacks):
+        return _range_count_filtered_kernel(
+            bits, qmask, qinv, qmeta, stacks, slots, depth=depth, need=need
+        )
+
+    stack_spec = P(axis, None, None)
+    return jax.jit(
+        shard_map(
+            local,
+            mesh=mesh,
+            in_specs=(stack_spec, P(None), P(None), P(None), P(None))
+            + (stack_spec,) * n_stacks,
+            out_specs=P(None, axis),
+            check_vma=False,
+        )
+    )
+
+
 # above this many bytes of [Q-bucket, S, W] flight masks, batched counts
 # take the scan kernel (planes re-read per query, but no Q-wide state)
 _COUNT_BATCH_VMAP_LIMIT = 256 << 20
@@ -662,7 +691,9 @@ def range_count_filtered_batch(bits, queries, stacks, slots, *, depth: int):
     int64 counts from ONE launch over the raw BSI stack ``bits`` and the
     filter leaves' field ``stacks`` (one per column of ``slots``,
     ``int32[len(queries), leaves]``).  Only the encoded bounds and the
-    slots leave the host; the filter rows are gathered on the device."""
+    slots leave the host; the filter rows are gathered on the device.
+    Stacks sharded over a serving mesh (all of them, the executor's
+    layout) run as one SPMD launch, each device on its own shards."""
     from pilosa_tpu.ops import kernels
 
     args, need = _batch_args(queries, depth)
@@ -670,10 +701,16 @@ def range_count_filtered_batch(bits, queries, stacks, slots, *, depth: int):
     padded = np.full((P, slots.shape[1]), -1, np.int32)
     padded[: len(queries)] = slots
     slots_dev = kernels.h2d(padded)
+    m = kernels.shards_axis_of(bits)
     with kernels.enqueue("bsi_range_count_filtered") as sp:
-        counts = _range_count_filtered_kernel(
-            bits, *args, tuple(stacks), slots_dev, depth=depth, need=need
-        )
+        if m is not None:
+            counts = _range_count_filtered_mesh_fn(
+                *m, len(stacks), depth, need
+            )(bits, *args, slots_dev, *stacks)
+        else:
+            counts = _range_count_filtered_kernel(
+                bits, *args, tuple(stacks), slots_dev, depth=depth, need=need
+            )
     kernels.note_bsi_dispatch(
         "bsi_range_count_filtered",
         wall=sp.duration,
@@ -692,9 +729,10 @@ _SUM_BATCH_ACC_LIMIT = 2**31 - 1
 
 
 def sum_batch_supported(S: int, W: int) -> bool:
-    """Whether the fused batched Sum may accumulate across the whole
-    stack in int32 — the `row_counts_supported`-style decline gate;
-    callers fall back to the per-query host lane."""
+    """Whether the fused batched Sum may accumulate across ``S`` shards
+    in int32 (a device's share of a mesh-sharded stack: each device
+    accumulates its own) — the `row_counts_supported`-style decline
+    gate; callers fall back to the per-query host lane."""
     return S * W * 32 <= _SUM_BATCH_ACC_LIMIT
 
 
@@ -728,16 +766,47 @@ def _sum_batch_kernel(planes, exists, sign, filters):
     return acc
 
 
+@lru_cache(maxsize=8)
+def _sum_batch_mesh_fn(mesh, axis):
+    """jit(shard_map) of the fused Sum over a shards-sharded BSI stack
+    and filter tensor: each device scans its own shards and the
+    per-device accumulators ``int32[devices, depth+1, 2Q]`` come back
+    along the mesh axis for the host's int64 sum."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def local(planes, exists, sign, filters):
+        return _sum_batch_kernel(planes, exists, sign, filters)[None]
+
+    return jax.jit(
+        shard_map(
+            local,
+            mesh=mesh,
+            in_specs=(
+                P(axis, None, None), P(axis, None), P(axis, None),
+                P(axis, None, None),
+            ),
+            out_specs=P(axis, None, None),
+            check_vma=False,
+        )
+    )
+
+
 def sum_batch_host(planes, exists, sign, filters, *, depth: int):
     """Batched Sum host wrapper: ``[(sum, count), ...]`` per filter row.
     ``filters`` is ``uint32[S, Q, W]`` (pass ``exists`` slices for
     unfiltered queries); place-value combine in python ints so totals
-    past 2^63 stay exact."""
+    past 2^63 stay exact.  Filters laid out over a serving mesh (the
+    stack's own sharding) choose the SPMD form."""
     from pilosa_tpu.ops import kernels
 
     Q = int(filters.shape[1])
+    m = kernels.shards_axis_of(filters)
     with kernels.enqueue("bsi_sum_batch") as sp:
-        acc = _sum_batch_kernel(planes, exists, sign, filters)
+        if m is not None:
+            acc = _sum_batch_mesh_fn(*m)(planes, exists, sign, filters)
+        else:
+            acc = _sum_batch_kernel(planes, exists, sign, filters)
     kernels.note_bsi_dispatch(
         "bsi_sum_batch",
         wall=sp.duration,
@@ -747,6 +816,8 @@ def sum_batch_host(planes, exists, sign, filters, *, depth: int):
         q_useful=Q,
     )
     acc = kernels.pull(acc, "bsi_sum_batch").astype(np.int64)  # [depth+1, 2Q]
+    if m is not None:
+        acc = acc.sum(axis=0)  # one accumulator a device
     out = []
     for q in range(Q):
         pos, neg = acc[:, q], acc[:, Q + q]

@@ -196,6 +196,8 @@ def _note_dispatch(
         site.track_key(key)
         site.claim(sig=f"{kernel}/{lane}:{key[2]}")
         site.record_launch(wall or 0.0)
+        if any(_multi_device(a) for a in args):
+            site.record_mesh_launch()
     tagged = kernel_stats.with_tags(
         f"kernel:{kernel}", f"lane:{lane}", *extra_tags
     )

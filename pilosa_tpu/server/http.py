@@ -457,6 +457,14 @@ class Handler(BaseHTTPRequestHandler):
                 "stack_rebuilds": ex.stack_rebuilds,
                 "stack_incremental": ex.stack_incremental,
                 "bsi_stack_launches": ex.bsi_stack_launches,
+                # stacks not built, by reason, and flight items a batch
+                # lane handed back to the per-call path, by lane and
+                # reason (exec/executor.py)
+                "stack_refusals": dict(ex.stack_refusals),
+                "lane_declines": {
+                    lane: dict(by_reason)
+                    for lane, by_reason in ex.lane_declines.items()
+                },
             }
             # semantic result cache: hit/miss/invalidation counters plus
             # promotion state of the maintained TopN/GroupBy views

@@ -1,0 +1,333 @@
+"""The executor's batch lanes over a serving mesh of four devices, held to a
+numpy reference written here and to the one-device executor's answers: an
+index of the ``taxi`` shape (benchmark/configs/taxi.json: a handful of its set
+fields, the amount as an int field) at the suite's shard width, eight shards,
+every column filled, and one case per class of the ``dashboard-c32`` mix in
+flights of 1, 5 and 32 calls.  Then the stack budget, which holds against one
+device's share of a stack, and the host side of a build, which never holds
+more than that share."""
+
+import numpy as np
+import pytest
+
+from pilosa_tpu.core.field import FieldOptions
+from pilosa_tpu.core.holder import Holder
+from pilosa_tpu.exec import executor as executor_mod
+from pilosa_tpu.exec.executor import Executor
+from pilosa_tpu.obs import devledger, tracing
+from pilosa_tpu.parallel import mesh
+
+SHARDS, DEVICES = 8, 4
+# set field -> rows, as the taxi record names them
+FIELDS = {
+    "cab_type": 3, "passenger_count": 10, "pickup_year": 8, "pickup_month": 12,
+    "dist_miles": 50, "pickup_grid_id": 40, "drop_grid_id": 60,
+}
+AMOUNT_MAX = 20049
+
+
+@pytest.fixture(scope="module")
+def rides():
+    """(holder, numpy columns by field): one row a ride in every set field,
+    ``total_amount`` on nine rides in ten."""
+    h = Holder()
+    idx = h.create_index("taxi")
+    n = SHARDS * idx.n_words * 32
+    rng = np.random.default_rng(29)
+    cols = np.arange(n, dtype=np.uint64)
+    data = {}
+    for name, rows in FIELDS.items():
+        # a skewed draw: some rows rare, every row present
+        data[name] = np.minimum(
+            rng.geometric(3.0 / rows, size=n) - 1, rows - 1
+        ).astype(np.int64)
+        data[name][:rows] = np.arange(rows)
+        idx.create_field(name).import_bits(data[name].astype(np.uint64), cols)
+    amount = rng.integers(0, AMOUNT_MAX + 1, size=n)
+    has = rng.random(n) < 0.9
+    data["total_amount"] = np.where(has, amount, -1)
+    idx.create_field(
+        "total_amount", FieldOptions(field_type="int", min_=0, max_=AMOUNT_MAX)
+    ).import_values(cols[has], amount[has])
+    return h, data
+
+
+def _queries(cls: str, n: int, rng) -> list[str]:
+    """``n`` calls of one class of the mix, rows and thresholds drawn."""
+    def row(f):
+        return int(rng.integers(0, FIELDS[f]))
+
+    def amount():
+        return int(rng.integers(0, AMOUNT_MAX))
+
+    out = []
+    for k in range(n):
+        if cls == "range_count":
+            lo, hi = sorted((amount(), amount()))
+            out.append(
+                f"Count(Row(total_amount > {lo}))" if k % 2
+                else f"Count(Row({lo} < total_amount < {hi + 1}))"
+            )
+        elif cls == "range_count_filtered":
+            out.append(
+                f"Count(Intersect(Row(cab_type={row('cab_type')}), Row(total_amount < {amount()})))"
+                if k % 2 else
+                f"Count(Intersect(Row(pickup_year={row('pickup_year')}), Row(total_amount > {amount()})))"
+            )
+        elif cls == "sum_filtered":
+            f = ("passenger_count", "pickup_year", "cab_type")[k % 3]
+            out.append(f"Sum(Row({f}={row(f)}), field=total_amount)")
+        elif cls == "pair_count":
+            a, b = (("pickup_year", "passenger_count"), ("cab_type", "passenger_count"),
+                    ("pickup_year", "pickup_month"), ("passenger_count", "dist_miles"))[k % 4]
+            out.append(f"Count(Intersect(Row({a}={row(a)}), Row({b}={row(b)})))")
+        elif cls == "topn_filtered":
+            out.append(
+                f"TopN(drop_grid_id, Row(pickup_grid_id={row('pickup_grid_id')}), n=10)"
+                if k % 2 else
+                f"TopN(dist_miles, Row(pickup_year={row('pickup_year')}), n=10)"
+            )
+        elif cls == "groupby2":
+            out.append(
+                "GroupBy(Rows(passenger_count), Rows(pickup_year), "
+                f"filter=Row(pickup_month={row('pickup_month')}))"
+                if k % 2 else "GroupBy(Rows(passenger_count), Rows(pickup_year))"
+            )
+        elif cls == "groupby3":
+            out.append("GroupBy(Rows(passenger_count), Rows(pickup_year), Rows(cab_type))")
+        elif cls == "q1":
+            out.append("TopN(cab_type)" if k % 2 else "TopN(pickup_grid_id)")
+        else:
+            raise AssertionError(cls)
+    return out
+
+
+# ----------------------------------------------------------------- reference
+
+def _mask(data, text: str):
+    """The rides a ``Row(...)`` of the mix selects."""
+    inner = text[len("Row("):-1]
+    if "total_amount" not in inner:
+        f, r = inner.split("=")
+        return data[f] == int(r)
+    v = data["total_amount"]
+    words = inner.split()
+    if words[0] == "total_amount":
+        x = int(words[2])
+        return (v >= 0) & (v > x if words[1] == ">" else v < x)
+    return (v > int(words[0])) & (v < int(words[4]))
+
+
+def _args(q: str) -> list[str]:
+    """Top-level arguments of the outermost call."""
+    body, depth, out, cur = q[q.index("(") + 1:-1], 0, [], ""
+    for ch in body:
+        depth += ch == "("
+        depth -= ch == ")"
+        if ch == "," and depth == 0:
+            out.append(cur.strip())
+            cur = ""
+        else:
+            cur += ch
+    return out + [cur.strip()]
+
+
+def _reference(data, q: str):
+    """What the call answers, from the numpy columns: an int, (sum, count),
+    {row: count} of a TopN before its cut, or {rows: count} of a GroupBy."""
+    args = _args(q)
+    if q.startswith("Count(Intersect("):
+        a, b = _args(args[0])
+        return int((_mask(data, a) & _mask(data, b)).sum())
+    if q.startswith("Count("):
+        return int(_mask(data, args[0]).sum())
+    if q.startswith("Sum("):
+        m = _mask(data, args[0]) & (data["total_amount"] >= 0)
+        return int(data["total_amount"][m].sum()), int(m.sum())
+    if q.startswith("TopN("):
+        m = _mask(data, args[1]) if len(args) > 1 and args[1].startswith("Row(") else slice(None)
+        ids, counts = np.unique(data[args[0]][m], return_counts=True)
+        return dict(zip(ids.tolist(), counts.tolist()))
+    assert q.startswith("GroupBy(")
+    fields = [a[len("Rows("):-1] for a in args if a.startswith("Rows(")]
+    filt = [a for a in args if a.startswith("filter=")]
+    m = _mask(data, filt[0][len("filter="):]) if filt else np.ones(len(data[fields[0]]), bool)
+    keys, counts = np.unique(np.stack([data[f][m] for f in fields]), axis=1, return_counts=True)
+    return {tuple(k): int(c) for k, c in zip(keys.T.tolist(), counts.tolist())}
+
+
+def _check(q: str, got, want) -> None:
+    if q.startswith("Count("):
+        assert got == want, q
+    elif q.startswith("Sum("):
+        assert (got.value, got.count) == want, q
+    elif q.startswith("TopN("):
+        n = 10 if "n=10" in q else len(want)
+        best = sorted(want.values(), reverse=True)[:n]
+        assert [p.count for p in got] == best, q
+        assert all(want[p.id] == p.count for p in got), q
+    else:
+        assert {tuple(fr.row_id for fr in g.group): g.count for g in got} == want, q
+
+
+def _plain(r):
+    """An answer as plain data, to set two executors' side by side."""
+    if isinstance(r, list):
+        return [_plain(x) for x in r]
+    return r.to_dict() if hasattr(r, "to_dict") else r
+
+
+# --------------------------------------------------------------------- lanes
+
+# class -> (the span its flight enters, flight sizes from which it must)
+LANE_SPAN = {
+    "range_count": ("executor.bsiRangeCountBatch", 1),
+    "range_count_filtered": ("executor.bsiFilteredCountBatch", 1),
+    "sum_filtered": ("executor.bsiSumBatch", 5),  # two filtered sums make a launch
+    "pair_count": ("executor.batchCountTree", 1),
+    "topn_filtered": ("executor.executeTopN", 1),
+    "groupby2": ("executor.executeGroupBy", 1),
+    "groupby3": ("executor.executeGroupBy", 1),
+    "q1": ("executor.executeTopN", 1),
+}
+
+
+def _span_count(name: str) -> int:
+    block, leaf = name.split(".")
+    return tracing.spans_snapshot()[block][leaf]["count"]
+
+
+@pytest.fixture(scope="module")
+def served(rides):
+    """(mesh executor, numpy columns, one-device answers by query): every
+    flight's queries answered first under a one-device serving mesh, then
+    the four-device mesh with each class's stacks warm."""
+    h, data = rides
+    flights = {
+        (cls, n): _queries(cls, n, np.random.default_rng([29, k, n]))
+        for k, cls in enumerate(LANE_SPAN) for n in (1, 5, 32)
+    }
+    try:
+        mesh.configure_serving(1)
+        one = Executor(h, rescache_entries=0)
+        solo = {
+            q: _plain(one.execute("taxi", q)[0])
+            for qs in flights.values() for q in qs
+        }
+        mesh.configure_serving(DEVICES)
+        ex = Executor(h, rescache_entries=0)
+        for cls in LANE_SPAN:  # a lone call of a cold field stays off the lanes
+            ex.execute("taxi", " ".join(_queries(cls, 4, np.random.default_rng(7))))
+        yield ex, data, flights, solo
+    finally:
+        mesh.configure_serving(None)
+
+
+@pytest.mark.parametrize("n", [1, 5, 32])
+@pytest.mark.parametrize("cls", sorted(LANE_SPAN))
+def test_class_on_the_mesh_matches_reference_and_one_device(served, cls, n):
+    ex, data, flights, solo = served
+    qs = flights[cls, n]
+    span, from_n = LANE_SPAN[cls]
+    spans0 = _span_count(span)
+    mesh_declines0 = sum(by["mesh"] for by in ex.lane_declines.values())
+    launches0 = devledger.snapshot()["totals"]
+    got = ex.execute("taxi", " ".join(qs))
+    assert len(got) == n
+    for q, r in zip(qs, got):
+        _check(q, r, _reference(data, q))
+        assert _plain(r) == solo[q], q
+    if n >= from_n:
+        assert _span_count(span) > spans0, span
+    assert sum(by["mesh"] for by in ex.lane_declines.values()) == mesh_declines0
+    totals = devledger.snapshot()["totals"]
+    # whatever the flight launched ran over the mesh
+    assert totals["meshLaunches"] - launches0["meshLaunches"] == \
+        totals["launches"] - launches0["launches"]
+
+
+def test_every_stack_lies_on_the_four_devices(served):
+    ex, _, _, _ = served
+    idx = ex.holder.index("taxi")
+    shards = list(range(SHARDS))
+    stacks = [ex._field_stack(idx.field(name), shards)[1] for name in FIELDS]
+    stacks.append(ex._bsi_stack(idx.field("total_amount"), shards))
+    for bits in stacks:
+        assert bits.shape[0] == SHARDS and len(bits.sharding.device_set) == DEVICES
+        assert {s.data.shape[0] for s in bits.addressable_shards} == {SHARDS // DEVICES}
+    assert ex.stack_refusals["array_budget"] == ex.stack_refusals["hbm_budget"] == 0
+
+
+# -------------------------------------------------------------- stack budget
+
+@pytest.fixture()
+def small_budget(rides, monkeypatch):
+    """``_STACK_BUDGET_BYTES`` between a quarter of ``drop_grid_id``'s stack
+    and the whole of it."""
+    h, _ = rides
+    field = h.index("taxi").field("drop_grid_id")
+    whole = SHARDS * FIELDS["drop_grid_id"] * field.n_words * 4
+    monkeypatch.setattr(executor_mod, "_STACK_BUDGET_BYTES", whole // 2)
+    vars(field).pop("_stack_caches", None)
+    try:
+        yield h, field, whole
+    finally:
+        vars(field).pop("_stack_caches", None)
+        mesh.configure_serving(None)
+
+
+def test_budget_holds_against_a_devices_share(small_budget):
+    h, field, whole = small_budget
+    shards = list(range(SHARDS))
+    mesh.configure_serving(1)
+    one = Executor(h, rescache_entries=0)
+    assert one._field_stack(field, shards) is None
+    assert one.stack_refusals == {"array_budget": 1, "hbm_budget": 0, "demand": 0}
+    mesh.configure_serving(DEVICES)
+    ex = Executor(h, rescache_entries=0)
+    before = tracing.spans_snapshot()["executor"]["stackBuild"]["count"]
+    slot_of, bits = ex._field_stack(field, shards)
+    assert len(slot_of) == FIELDS["drop_grid_id"] and bits.nbytes == whole
+    assert len(bits.sharding.device_set) == DEVICES
+    assert ex.stack_refusals["array_budget"] == 0
+    assert tracing.spans_snapshot()["executor"]["stackBuild"]["count"] == before + 1
+
+
+def test_lane_hands_back_under_budget_when_its_stack_is_refused(small_budget):
+    h, field, _ = small_budget
+    mesh.configure_serving(1)
+    ex = Executor(h, rescache_entries=0)
+    q = "Count(Intersect(Row(drop_grid_id=1), Row(cab_type=0)))"
+    got = ex.execute("taxi", f"{q} {q}")
+    assert got[0] == got[1] > 0
+    assert ex.lane_declines["general"]["budget"] == 2
+    assert ex.stack_refusals["array_budget"] >= 1
+
+
+def test_host_side_of_a_build_holds_one_devices_share(rides, monkeypatch):
+    h, data = rides
+    field = h.index("taxi").field("pickup_grid_id")
+    vars(field).pop("_stack_caches", None)
+    whole = SHARDS * FIELDS["pickup_grid_id"] * field.n_words * 4
+    sizes = []
+    zeros = np.zeros
+
+    def watched(shape, *a, **kw):
+        out = zeros(shape, *a, **kw)
+        sizes.append(out.nbytes)
+        return out
+
+    monkeypatch.setattr(executor_mod.np, "zeros", watched)
+    try:
+        mesh.configure_serving(DEVICES)
+        ex = Executor(h, rescache_entries=0)
+        _, bits = ex._field_stack(field, list(range(SHARDS)))
+        monkeypatch.undo()
+        assert sizes and max(sizes) == whole // DEVICES and sum(sizes) == whole
+        # and what was put is the field: row 3's rides, shard by shard
+        got = np.asarray(bits[:, 3]).view(np.uint8)
+        want = np.packbits(data["pickup_grid_id"] == 3, bitorder="little")
+        assert np.array_equal(got.reshape(-1), want)
+    finally:
+        vars(field).pop("_stack_caches", None)
+        mesh.configure_serving(None)
