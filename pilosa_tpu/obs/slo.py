@@ -43,6 +43,8 @@ import math
 import threading
 import time
 
+import numpy as np
+
 from pilosa_tpu.obs import devledger
 
 # -- op classes ---------------------------------------------------------
@@ -214,82 +216,93 @@ def _bucket_of(v: float) -> int:
 
 
 class _Ring:
-    """Fixed ring of time slots covering ``window`` seconds; each slot
-    is [abs_slot_idx, total, errors, bucket_counts].  A slot is lazily
-    reset the first time an observation lands in a new time slice, so
-    idle periods cost nothing."""
+    """Fixed ring of time slots covering ``window`` seconds.  A slot is
+    its absolute index, its total and its errors, kept as three int64
+    arrays so that a window sum is array work and not a walk (the
+    governor's tick sums the 3 d window of every live class four times
+    a second: server/qos.py), plus its latency bucket counts, a plain
+    list made on the slot's first latency (only the short latency
+    window ever reads them).  A slot is lazily reset the first time an
+    observation lands in a new time slice, so idle periods cost nothing.
 
-    __slots__ = ("slot_seconds", "slots")
+    A read looks no further back than the ring's own first slot.  That
+    keeps the arrays it touches short while the class is young, and
+    under 500 elements numpy keeps the GIL: past that every operation
+    hands the interpreter to the other 32 threads and the reader waits
+    its turn to get it back, some 80 times a tick on a full ring.
+    """
+
+    __slots__ = ("slot_seconds", "first", "idx", "total", "errors", "buckets")
 
     def __init__(self, window: float, slot_seconds: float):
         n = max(2, int(math.ceil(window / slot_seconds)) + 1)
         self.slot_seconds = slot_seconds
-        self.slots: list[list] = [
-            [-1, 0, 0, None] for _ in range(n)
-        ]
+        self.first = None  # the oldest slot index this ring ever opened
+        self.idx = np.full(n, -1, dtype=np.int64)
+        self.total = np.zeros(n, dtype=np.int64)
+        self.errors = np.zeros(n, dtype=np.int64)
+        self.buckets: list[list[int] | None] = [None] * n
 
     def observe(self, now: float, error: bool, bucket: int | None) -> None:
         idx = int(now / self.slot_seconds)
-        slot = self.slots[idx % len(self.slots)]
-        if slot[0] != idx:
-            slot[0] = idx
-            slot[1] = 0
-            slot[2] = 0
-            slot[3] = None
-        slot[1] += 1
+        k = idx % len(self.buckets)
+        if self.idx[k] != idx:
+            if self.first is None or idx < self.first:
+                self.first = idx
+            self.idx[k] = idx
+            self.total[k] = 0
+            self.errors[k] = 0
+            self.buckets[k] = None
+        self.total[k] += 1
         if error:
-            slot[2] += 1
+            self.errors[k] += 1
         if bucket is not None:
-            counts = slot[3]
+            counts = self.buckets[k]
             if counts is None:
-                counts = slot[3] = [0] * _N_BUCKETS
+                counts = self.buckets[k] = [0] * _N_BUCKETS
             counts[bucket] += 1
+
+    def _live(self, now: float, window: float) -> np.ndarray:
+        """Ring positions of the slots of the trailing ``window``
+        seconds that hold an observation.  Slot index ``i`` lives at
+        position ``i % n``, so a window covers one run of positions, or
+        two where it wraps (all of the ring, for a window as long as it
+        or longer), and fewer than ``n`` indices share no position: a
+        stored index inside the window's bounds is the one the window
+        wants there."""
+        # never before the ring's own first slot: nothing is there, and
+        # a class seen for an hour has an hour of its 3 d ring to read
+        lo = max(int((now - window) / self.slot_seconds) + 1, self.first or 0)
+        hi = int(now / self.slot_seconds)
+        n = len(self.buckets)
+        start = lo % n
+        end = start + min(max(hi - lo + 1, 0), n)
+        runs = [(start, min(end, n))]
+        if end > n:
+            runs.append((0, end - n))
+        found = []
+        for a, b in runs:
+            idx = self.idx[a:b]
+            inside = idx >= lo
+            inside &= idx <= hi
+            found.append(np.flatnonzero(inside) + a)
+        return found[0] if len(found) == 1 else np.concatenate(found)
 
     def sum_window(self, now: float, window: float) -> tuple[int, int]:
         """(total, errors) over the trailing ``window`` seconds."""
-        lo = int((now - window) / self.slot_seconds) + 1
-        hi = int(now / self.slot_seconds)
-        total = errors = 0
-        slots = self.slots
-        n = len(slots)
-        if hi - lo + 1 < n:
-            # walk only the slot indices the window can cover — a
-            # short window over a long-lived ring (e.g. the 5m burn
-            # window over the 3d ring) is a tiny fraction of it
-            for idx in range(lo, hi + 1):
-                slot = slots[idx % n]
-                if slot[0] == idx:
-                    total += slot[1]
-                    errors += slot[2]
-        else:
-            for slot in slots:
-                if lo <= slot[0] <= hi:
-                    total += slot[1]
-                    errors += slot[2]
-        return total, errors
+        live = self._live(now, window)
+        return int(self.total[live].sum()), int(self.errors[live].sum())
 
     def merged_buckets(self, now: float, window: float) -> list[int]:
-        lo = int((now - window) / self.slot_seconds) + 1
-        hi = int(now / self.slot_seconds)
-        out = [0] * _N_BUCKETS
-        slots = self.slots
-        n = len(slots)
-        if hi - lo + 1 < n:
-            candidates = [
-                slot
-                for idx in range(lo, hi + 1)
-                for slot in (slots[idx % n],)
-                if slot[0] == idx and slot[3] is not None
-            ]
-        else:
-            candidates = [
-                s for s in slots if lo <= s[0] <= hi and s[3] is not None
-            ]
-        for slot in candidates:
-            counts = slot[3]
-            for i in range(_N_BUCKETS):
-                out[i] += counts[i]
-        return out
+        buckets = self.buckets
+        live = [
+            buckets[k]
+            for k in self._live(now, window)
+            if buckets[k] is not None
+        ]
+        if not live:
+            return [0] * _N_BUCKETS
+        return [sum(col) for col in zip(*live)]
 
 
 def _quantile(buckets: list[int], q: float) -> float | None:
@@ -341,9 +354,10 @@ class SLOTracker:
     the event journal / job tracker).
 
     ``slot_seconds`` trades ring memory for window edge accuracy; the
-    default 5 s keeps the 3 d ring at ~52k slots of four small fields
-    per active class.  Tests shrink windows via ``burn_rules`` and
-    ``latency_window`` so burn behavior is observable in milliseconds.
+    default 5 s keeps the 3 d ring at ~52k slots per active class
+    (three int64 arrays, 1.2 MB, and a list of bucket counts).  Tests
+    shrink windows via ``burn_rules`` and ``latency_window`` so burn
+    behavior is observable in milliseconds.
     """
 
     def __init__(
@@ -444,11 +458,12 @@ class SLOTracker:
         (obs/history.py): active classes only, the latency window
         only.
 
-        ``snapshot()`` walks every objective class across every burn
-        window — exposition-grade work, wrong for a ~1 s sampler
-        cadence.  This touches only classes that have observed traffic
-        and only short-window slots, so its cost tracks live
-        cardinality, not objective/burn-rule configuration."""
+        ``snapshot()`` sums every objective class over every burn
+        window and builds the whole payload — exposition-grade work,
+        wrong for a ~1 s sampler cadence.  This touches only classes
+        that have observed traffic and only short-window slots, so its
+        cost tracks live cardinality, not objective/burn-rule
+        configuration."""
         now = time.monotonic()
         out: dict[str, dict] = {}
         with self._lock:
@@ -475,6 +490,39 @@ class SLOTracker:
                 out[name] = d
         return out
 
+    def _judge(self, st: _ClassState | None, obj: Objective | None, now: float):
+        """One class's verdicts, for ``snapshot`` and ``pressure`` alike:
+        ``(sums, alerts, merged, p99, latency_ok)``.  ``sums`` maps each
+        distinct burn window to its (total, errors), summed once;
+        ``alerts`` maps each rule to whether it fires (both of its
+        windows burn at the rule's factor or above); ``merged`` is the
+        latency window's bucket counts and ``latency_ok`` its p99
+        against the objective's, None where either is missing.  Caller
+        holds the lock."""
+        if st is not None:
+            sums = {w: st.ring.sum_window(now, w) for w in self._windows}
+            merged = st.ring.merged_buckets(now, self.latency_window)
+        else:
+            sums = dict.fromkeys(self._windows, (0, 0))
+            merged = [0] * _N_BUCKETS
+        budget = 1.0 - obj.availability if obj is not None else None
+        alerts = {}
+        for rule in self.burn_rules:
+            lt, le = sums[rule.long]
+            sht, she = sums[rule.short]
+            firing = False
+            if budget and lt and sht:
+                firing = (
+                    (le / lt) / budget >= rule.factor
+                    and (she / sht) / budget >= rule.factor
+                )
+            alerts[rule.name] = firing
+        p99 = _quantile(merged, 0.99)
+        latency_ok = None
+        if obj is not None and obj.latency_p99 is not None and p99 is not None:
+            latency_ok = p99 <= obj.latency_p99
+        return sums, alerts, merged, p99, latency_ok
+
     def snapshot(self) -> dict:
         """Full live objective state — the /debug/slo payload."""
         now = time.monotonic()
@@ -485,11 +533,12 @@ class SLOTracker:
                 st = self._classes.get(name)
                 obj = self.objectives.get(name)
                 budget = 1.0 - obj.availability if obj is not None else None
+                sums, alerts, merged, p99, latency_ok = self._judge(
+                    st, obj, now
+                )
                 win_out: dict[str, dict] = {}
                 for w in self._windows:
-                    total, errors = (
-                        st.ring.sum_window(now, w) if st is not None else (0, 0)
-                    )
+                    total, errors = sums[w]
                     ratio = errors / total if total else 0.0
                     d = {
                         "total": total,
@@ -505,37 +554,9 @@ class SLOTracker:
                         # for the window (SRE Workbook's accounting)
                         d["budgetConsumed"] = burn * (w / self.budget_period)
                     win_out[_window_name(w)] = d
-                alerts = {}
-                for rule in self.burn_rules:
-                    lt, le = (
-                        st.ring.sum_window(now, rule.long)
-                        if st is not None
-                        else (0, 0)
-                    )
-                    sht, she = (
-                        st.ring.sum_window(now, rule.short)
-                        if st is not None
-                        else (0, 0)
-                    )
-                    firing = False
-                    if budget and lt and sht:
-                        firing = (
-                            (le / lt) / budget >= rule.factor
-                            and (she / sht) / budget >= rule.factor
-                        )
-                    alerts[rule.name] = firing
-                merged = (
-                    st.ring.merged_buckets(now, self.latency_window)
-                    if st is not None
-                    else [0] * _N_BUCKETS
-                )
                 lat_count = sum(merged)
                 p50 = _quantile(merged, 0.50)
-                p99 = _quantile(merged, 0.99)
                 p999 = _quantile(merged, 0.999)
-                latency_ok = None
-                if obj is not None and obj.latency_p99 is not None and p99 is not None:
-                    latency_ok = p99 <= obj.latency_p99
                 ok = None
                 if obj is not None:
                     ok = not any(alerts.values()) and latency_ok is not False
@@ -565,23 +586,31 @@ class SLOTracker:
         }
 
     def pressure(self) -> dict:
-        """Control-loop tap for the QoS governor (server/qos.py):
-        which objective-bearing classes are burning (any rule firing)
-        or violating their latency objective right now.  Derived from
-        the live snapshot — tenant-scoped classes (``op@tenant``)
-        appear here like any other, which is what lets the ladder see
-        a single victim's budget burning."""
-        snap = self.snapshot()
+        """Control-loop tap for the QoS governor (server/qos.py), which
+        calls it four times a second on a request's thread: which
+        objective-bearing classes are burning (any rule firing) or
+        violating their latency objective right now.  It reads what
+        ``snapshot`` would say of them, through the same ``_judge``,
+        and builds nothing else: only a class with traffic can burn or
+        be slow.  Tenant-scoped classes (``op@tenant``) appear here
+        like any other, which is what lets the ladder see a single
+        victim's budget burning."""
+        now = time.monotonic()
         alerts: list[tuple[str, str]] = []
         latency: list[str] = []
-        for name, c in snap["classes"].items():
-            if c["objective"] is None:
-                continue
-            for rule, firing in c["alerts"].items():
-                if firing:
-                    alerts.append((name, rule))
-            if c["latencyOk"] is False:
-                latency.append(name)
+        with self._lock:
+            for name in sorted(self._classes):
+                obj = self.objectives.get(name)
+                if obj is None:
+                    continue
+                _, firing, _, _, latency_ok = self._judge(
+                    self._classes[name], obj, now
+                )
+                alerts.extend(
+                    (name, rule) for rule, fires in firing.items() if fires
+                )
+                if latency_ok is False:
+                    latency.append(name)
         return {"alerts": alerts, "latency": latency}
 
     def summary(self) -> dict:

@@ -109,7 +109,8 @@ SPAN_TABLE = (
     ("http.decode", _LISTENER, "listener.ms_per_read"),
     ("api.parse", _LISTENER, "listener.ms_per_read"),
     ("http.encode", _LISTENER, "listener.ms_per_read"),
-    ("qos.admit", _BATCHER, TRACE_ONLY),
+    ("qos.admit", _BATCHER, "qos.admit_ms_per_read"),
+    ("qos.tick", _BATCHER, TRACE_ONLY),
     ("rescache.probe", _PLANNER, TRACE_ONLY),
     ("batcher.queueWait", _BATCHER, "batcher.queue_wait_ms"),
     ("batcher.dispatch", _BATCHER, TRACE_ONLY),

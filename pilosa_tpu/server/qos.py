@@ -63,7 +63,7 @@ import threading
 import time
 from collections import deque
 
-from pilosa_tpu.obs import devledger
+from pilosa_tpu.obs import devledger, tracing
 
 ADMIT = "admit"
 DEGRADE = "degrade"
@@ -421,6 +421,12 @@ class QosGovernor:
         Returns the transitions it made (for tests)."""
         if now is None:
             now = time.monotonic()
+        # what a tick costs, on whichever thread it landed: a request's
+        # (inside its qos.admit) or the dispatcher's between two flights
+        with tracing.start_span("qos.tick"):
+            return self._tick(now)
+
+    def _tick(self, now: float) -> list:
         self.observe_ledger(self._ledger_deltas())
         pressure = self.enabled and self._under_pressure()
         transitions = []  # (tenant, old_stage, new_stage, reason)

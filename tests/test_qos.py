@@ -379,6 +379,55 @@ def test_pressure_sees_tenant_scoped_latency_violation():
     assert "read.count@v" in p["latency"]
 
 
+def _count_sum_window(monkeypatch, tracker):
+    """Every ``_Ring.sum_window`` call from here on, as (class, window)."""
+    calls = []
+    names = {id(st.ring): name for name, st in tracker._classes.items()}
+    real = slo._Ring.sum_window
+
+    def counted(ring, now, window):
+        calls.append((names[id(ring)], window))
+        return real(ring, now, window)
+
+    monkeypatch.setattr(slo._Ring, "sum_window", counted)
+    return calls
+
+
+def test_a_tick_sums_each_burn_window_once_and_takes_no_snapshot(monkeypatch):
+    """What one governor tick reads of the SLO plane, as counts (no
+    clock: ROADMAP D9): of every class with an objective and traffic,
+    each distinct burn window once; of the others, nothing; and never
+    the /debug/slo payload."""
+    from pilosa_tpu.obs import tracing
+
+    tr = slo.SLOTracker()  # the default rules: 5 m, 1 h, 6 h, 3 d
+    for name in ("read.count", "read.topn", "internal"):
+        tr.observe(name, 0.4, error=True)
+    tr.observe("read.count", 0.4, tenant="v")  # "read.count@v": no objective
+    calls = _count_sum_window(monkeypatch, tr)
+
+    def no_snapshot():
+        raise AssertionError("a tick built the /debug/slo payload")
+
+    monkeypatch.setattr(tr, "snapshot", no_snapshot)
+    gov, _tracker, _journal, _incidents = _ladder_rig(slo_fn=lambda: tr)
+    ticks = tracing.spans_snapshot()["qos"]["tick"]["count"]
+    gov.admit("a")
+    gov.admit("b")
+    assert gov._under_pressure() is True
+    del calls[:]
+    gov.tick()
+    windows = sorted({w for r in slo.DEFAULT_BURN_RULES for w in (r.long, r.short)})
+    assert len(windows) == 4
+    assert sorted(calls) == [(n, w) for n in ("read.count", "read.topn") for w in windows]
+    assert tracing.spans_snapshot()["qos"]["tick"]["count"] == ticks + 1
+    # and it is the tracker's own answer the ladder acted on
+    assert tr.pressure() == {
+        "alerts": [(n, r) for n in ("read.count", "read.topn") for r in ("fast", "slow")],
+        "latency": ["read.count", "read.topn"],
+    }
+
+
 # -- batcher expiry accounting (per tenant, per reason) -----------------------
 
 

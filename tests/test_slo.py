@@ -6,6 +6,10 @@ the error-attribution contract (deadline 504s burn budget, 4xx client
 mistakes do not) and the translate-path telemetry riding along."""
 
 import json
+import math
+import random
+import sys
+import threading
 import urllib.error
 import urllib.request
 
@@ -141,12 +145,119 @@ def test_ring_expires_observations_outside_window():
 def test_ring_slot_reuse_resets_stale_counts():
     r = _Ring(window=10.0, slot_seconds=1.0)
     r.observe(0.5, error=True, bucket=0)
-    n = len(r.slots)
+    n = len(r.buckets)
     # land in the SAME physical slot one full ring revolution later:
     # stale totals must not leak into the new slice
     r.observe(0.5 + n, error=False, bucket=0)
     total, errors = r.sum_window(0.5 + n, 1.0)
     assert (total, errors) == (1, 0)
+
+
+class _WalkRing:
+    """The ring as it was before the arrays: a list of ``[idx, total,
+    errors, counts]`` walked slot by slot.  Kept here as the plain
+    twin that the array ring is held to."""
+
+    def __init__(self, window, slot_seconds):
+        n = max(2, int(math.ceil(window / slot_seconds)) + 1)
+        self.slot_seconds = slot_seconds
+        self.slots = [[-1, 0, 0, None] for _ in range(n)]
+
+    def observe(self, now, error, bucket):
+        idx = int(now / self.slot_seconds)
+        slot = self.slots[idx % len(self.slots)]
+        if slot[0] != idx:
+            slot[:] = [idx, 0, 0, None]
+        slot[1] += 1
+        if error:
+            slot[2] += 1
+        if bucket is not None:
+            if slot[3] is None:
+                slot[3] = [0] * _N_BUCKETS
+            slot[3][bucket] += 1
+
+    def _walk(self, now, window):
+        lo = int((now - window) / self.slot_seconds) + 1
+        hi = int(now / self.slot_seconds)
+        n = len(self.slots)
+        if hi - lo + 1 < n:
+            for idx in range(lo, hi + 1):
+                slot = self.slots[idx % n]
+                if slot[0] == idx:
+                    yield slot
+        else:
+            for slot in self.slots:
+                if lo <= slot[0] <= hi:
+                    yield slot
+
+    def sum_window(self, now, window):
+        total = errors = 0
+        for slot in self._walk(now, window):
+            total += slot[1]
+            errors += slot[2]
+        return total, errors
+
+    def merged_buckets(self, now, window):
+        out = [0] * _N_BUCKETS
+        for slot in self._walk(now, window):
+            if slot[3] is not None:
+                for i, c in enumerate(slot[3]):
+                    out[i] += c
+        return out
+
+
+# (ring window, slot seconds, windows asked): the default ring with the
+# default rules' four windows and one longer than the ring, and the
+# smallest rings, of 2 and 3 slots
+_DEFAULT_WINDOWS = sorted(
+    {w for r in slo.DEFAULT_BURN_RULES for w in (r.long, r.short)}
+)
+_TWIN_RINGS = {
+    "default-3d-5s": (259200.0, 5.0, _DEFAULT_WINDOWS + [400000.0]),
+    "hour-1s": (3600.0, 1.0, [1.0, 60.0, 300.0, 3599.0, 3600.0, 3601.0, 7200.0]),
+    "2-slots": (0.004, 0.005, [0.001, 0.004, 0.005, 0.01, 1.0]),
+    "3-slots": (0.010, 0.005, [0.004, 0.005, 0.010, 0.015, 1.0]),
+}
+
+
+@pytest.mark.parametrize("start", [0.0, 1234.5, 5e6], ids=["t0", "t1234", "t5e6"])
+@pytest.mark.parametrize("shape", sorted(_TWIN_RINGS))
+def test_ring_windows_match_a_plain_walk(shape, start):
+    """The same random stream into both rings; every window equal at
+    every step, through bursts inside one slot, steps of a few slots,
+    wrap-around, and idle stretches longer than the ring (``start``
+    puts the clock before, inside and far past the first revolution)."""
+    window, slot_seconds, asked = _TWIN_RINGS[shape]
+    ring, twin = _Ring(window, slot_seconds), _WalkRing(window, slot_seconds)
+    n = len(twin.slots)
+    assert len(ring.idx) == len(ring.total) == len(ring.errors) == len(ring.buckets) == n
+    assert n == {"2-slots": 2, "3-slots": 3}.get(shape, n)
+    rng = random.Random(f"{shape}/{start}")
+    now = start
+    for step in range(400 if n < 10000 else 120):
+        now += rng.choice([
+            0.0, 0.0, rng.random() * slot_seconds, rng.random() * slot_seconds,
+            slot_seconds * rng.randint(1, 4),
+            slot_seconds * rng.randint(n // 3, n - 1),  # most of a revolution
+            slot_seconds * n,                           # the very same position
+            slot_seconds * (n + rng.randint(1, 2 * n)),  # idle past the ring
+        ])
+        for _ in range(rng.randint(1, 4)):
+            bucket = rng.choice([None, rng.randrange(_N_BUCKETS)])
+            error = rng.random() < 0.3
+            ring.observe(now, error, bucket)
+            twin.observe(now, error, bucket)
+        at = now + rng.choice([0.0, rng.random() * slot_seconds, slot_seconds * n / 2,
+                               -slot_seconds * rng.randint(1, 3)])  # a reader's older clock
+        at = max(at, 0.0)
+        for w in asked:
+            got = ring.sum_window(at, w)
+            assert got == twin.sum_window(at, w), (step, at, w)
+            assert all(type(v) is int for v in got)
+            merged = ring.merged_buckets(at, w)
+            assert merged == twin.merged_buckets(at, w), (step, at, w)
+            assert all(type(v) is int for v in merged) and len(merged) == _N_BUCKETS
+    assert ring.sum_window(now, asked[-1])[0] > 0
 
 
 # -- tracker ------------------------------------------------------------------
@@ -206,6 +317,128 @@ def test_tracker_objectiveless_class_never_verdicts():
     assert c["ok"] is None
     assert "burnRate" not in c["windows"]["10s"]
     assert not any(c["alerts"].values())
+
+
+def _pressure_from_snapshot(snap):
+    """The two lists as ``pressure()`` derived them from ``snapshot()``
+    before it had a path of its own."""
+    alerts, latency = [], []
+    for name, c in snap["classes"].items():
+        if c["objective"] is None:
+            continue
+        for rule, firing in c["alerts"].items():
+            if firing:
+                alerts.append((name, rule))
+        if c["latencyOk"] is False:
+            latency.append(name)
+    return {"alerts": alerts, "latency": latency}
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_pressure_equals_snapshot_derivation(seed, monkeypatch):
+    """Random traffic with errors and slow requests over base, tenant
+    and objectiveless classes, under a clock the test steps: at every
+    step ``pressure()`` is what the snapshot says, alerts and latency
+    violations both occurring along the way."""
+    rng = random.Random(seed)
+    clock = [1000.0 * seed]
+    monkeypatch.setattr(slo.time, "monotonic", lambda: clock[0])
+    objs = objectives_from_dict({
+        "read.topn": {"availability": 0.9, "latencyP99Ms": 40.0},
+        "tenants": {"v": {"read.count": {"availability": 0.999, "latencyP99Ms": 5.0}}},
+    })
+    rules = (BurnRule("fast", long=8.0, short=1.0, factor=14.4),
+             BurnRule("slow", long=40.0, short=8.0, factor=1.0))
+    t = SLOTracker(objectives=objs, burn_rules=rules, slot_seconds=0.5, latency_window=4.0)
+    classes = [slo.OP_READ_COUNT, slo.OP_READ_TOPN, slo.OP_WRITE, slo.OP_INTERNAL, slo.OP_IMPORT]
+    seen_alert = seen_latency = seen_quiet = False
+    for _ in range(120):
+        clock[0] += rng.choice([0.0, 0.1, 0.6, 2.0, 9.0, 50.0])
+        bad = rng.random() < 0.5  # a stretch of errors and slow answers, or a clean one
+        for _ in range(rng.randint(0, 12)):
+            t.observe(
+                rng.choice(classes),
+                rng.choice([0.001, 0.02, 0.3]) if bad else 0.001,
+                error=bad and rng.random() < 0.4,
+                tenant=rng.choice([None, "v", "w"]),
+            )
+        snap = t.snapshot()
+        got = t.pressure()
+        assert got == _pressure_from_snapshot(snap)
+        assert json.loads(json.dumps(got)) == {
+            "alerts": [list(a) for a in got["alerts"]], "latency": got["latency"]}
+        seen_alert |= bool(got["alerts"])
+        seen_latency |= bool(got["latency"])
+        seen_quiet |= not (got["alerts"] or got["latency"])
+    assert seen_alert and seen_latency and seen_quiet
+    assert any("@v" in name for name in t.snapshot()["classes"])
+
+
+def _count_sum_window(monkeypatch, tracker):
+    """Every ``_Ring.sum_window`` call from here on, as (class, window)."""
+    calls = []
+    names = {id(st.ring): name for name, st in tracker._classes.items()}
+    real = slo._Ring.sum_window
+
+    def counted(ring, now, window):
+        calls.append((names[id(ring)], window))
+        return real(ring, now, window)
+
+    monkeypatch.setattr(slo._Ring, "sum_window", counted)
+    return calls
+
+
+def test_snapshot_sums_each_window_once_a_class(monkeypatch):
+    t = SLOTracker(burn_rules=FAST_RULES)
+    for name in (slo.OP_READ_COUNT, slo.OP_WRITE, slo.OP_INTERNAL):
+        t.observe(name, 0.001)
+    calls = _count_sum_window(monkeypatch, t)
+    t.snapshot()
+    # FAST_RULES name three distinct windows (60 s is long and short)
+    assert sorted(calls) == sorted(
+        (name, w) for name in (slo.OP_READ_COUNT, slo.OP_WRITE, slo.OP_INTERNAL)
+        for w in (10.0, 60.0, 300.0))
+
+
+def test_observers_and_readers_on_many_threads_lose_nothing():
+    """Handlers observe while the governor's tick and /debug/slo read:
+    every observation is in the lifetime totals and in the longest
+    window at the end, whoever interleaved with whom."""
+    t = SLOTracker(burn_rules=FAST_RULES, slot_seconds=0.01)
+    writers, each, stop = 12, 1500, threading.Event()
+
+    def write(k):
+        for i in range(each):
+            t.observe(slo.OP_READ_COUNT, 0.001, error=(i % 10 == 0), tenant=f"t{k % 3}")
+
+    def read():
+        while not stop.is_set():
+            t.pressure()
+            t.snapshot()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        readers = [threading.Thread(target=read) for _ in range(2)]
+        threads = [threading.Thread(target=write, args=(k,)) for k in range(writers)]
+        for th in readers + threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+        stop.set()
+        for th in readers:
+            th.join(timeout=60)
+        assert not any(th.is_alive() for th in threads + readers)
+    finally:
+        stop.set()
+        sys.setswitchinterval(old)
+    c = t.snapshot()["classes"]
+    n = writers * each
+    assert (c[slo.OP_READ_COUNT]["total"], c[slo.OP_READ_COUNT]["errors"]) == (n, n // 10)
+    assert c[slo.OP_READ_COUNT]["windows"]["5m"]["total"] == n
+    assert c[slo.OP_READ_COUNT]["windows"]["5m"]["errors"] == n // 10
+    assert sum(c[f"read.count@t{k}"]["windows"]["5m"]["total"] for k in range(3)) == n
+    assert c[slo.OP_READ_COUNT]["latency"]["count"] == n
 
 
 def test_tracker_prometheus_text_series():
