@@ -18,14 +18,14 @@ The facade is strictly read-only: writes never reach it
 paths before mesh planning happens), so none of the mutating holder /
 index / field methods are proxied.
 
-Identity matters for performance: the executor's field-stack caches live
-in ``vars(field)`` keyed per field object, so ``MeshIndex`` memoizes its
-``MeshField`` facades (and ``dist`` memoizes whole facade executors per
-shard assignment) to keep warm stacks across queries.  Delegation of
-public attributes falls through to the coordinator's own objects;
-underscore-prefixed attributes are deliberately NOT delegated so the
-executor's per-field cache slots (``_stack_caches`` et al.) stay private
-to the facade and can never alias the base field's caches.
+Identity matters for performance: a field's serving stacks hang on the
+field object (``exec/stacks.py``, one key of ``vars(field)``), so
+``MeshIndex`` memoizes its ``MeshField`` facades (and ``dist`` memoizes
+whole facade executors per shard assignment) to keep warm stacks across
+queries.  Delegation of public attributes falls through to the
+coordinator's own objects; underscore-prefixed attributes are
+deliberately NOT delegated so that state (``stacks._FIELD_KEY``) stays
+private to the facade and can never alias the base field's.
 """
 
 from __future__ import annotations
@@ -102,9 +102,10 @@ class MeshField:
         return out
 
     def __getattr__(self, name: str):
-        # Never delegate private attributes: the executor parks its
-        # stack caches/locks in vars(field), and falling through to the
-        # base field here would silently share (and corrupt) them.
+        # Never delegate private attributes: exec/stacks.py keeps a
+        # field's serving state under one key (``stacks._FIELD_KEY``) of
+        # the facade's own instance dict, and falling through to the base field here
+        # would silently share (and corrupt) it.
         if name.startswith("_"):
             raise AttributeError(name)
         return getattr(self._base, name)

@@ -29,7 +29,7 @@ Prefetch accounting: ``prefetch_issued`` counts fragments actually
 queued on the uploader; an upload that still found work to ship marks
 the fragment, and the first *query* hit on that copy counts
 ``prefetch_useful`` — the ratio is the lane-level proof that predictive
-staging pays (BENCH residency lane bar: useful/issued >= 0.5).
+staging pays.
 """
 
 from __future__ import annotations

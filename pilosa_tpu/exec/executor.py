@@ -14,11 +14,9 @@ Union/Xor/Not/Shift (executor.go:653-680)."""
 
 from __future__ import annotations
 
-import itertools
 import os
 import threading
 import time
-import weakref
 from datetime import datetime
 from typing import Any
 
@@ -28,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from pilosa_tpu import deadline, pql
-from pilosa_tpu.core import membudget, residency, timequantum
+from pilosa_tpu.core import timequantum
 from pilosa_tpu.obs import devledger, qprofile, tracing
 from pilosa_tpu.core.field import (
     FIELD_TYPE_BOOL,
@@ -43,6 +41,7 @@ from pilosa_tpu.core.translate import TranslateStore
 from pilosa_tpu.core.view import VIEW_STANDARD
 from pilosa_tpu.exec import planner as planner_mod
 from pilosa_tpu.exec import rescache
+from pilosa_tpu.exec import stacks as stacks_mod
 from pilosa_tpu.exec.result import (
     FieldRow,
     GroupCount,
@@ -60,21 +59,15 @@ DEFAULT_MIN_THRESHOLD = 1
 # Sentinel for "not yet computed" result slots in the batch fast path.
 _UNSET = object()
 
-# Device cost ledger site for executor-owned launches: stack uploads and
-# the BSI predicate/aggregate dispatches that don't funnel through the
-# kernels dispatch notes (those book under ops.kernels / ops.bsi).
-_DL_STACK = devledger.site("executor.stack_launch")
+# the executor's own launches book beside the stack uploads
+_DL_STACK = stacks_mod.DL_STACK
 # pair-count gram/scan answers: the per-item measured price the flight
 # planner's lane chooser weighs against the host latency tier
 # (exec/planner.py)
 _DL_PAIR = devledger.site("executor.pair_counts")
 
-# Largest stacked [S, R, W] tensor the batch fast path will materialize.
-# one device's share of a serving stack (the whole of it without a mesh);
-# tuned for v5e HBM
-_STACK_BUDGET_BYTES = 4 << 30
 # the batch lanes, and why one hands an item back to the per-call path:
-# an operand's mesh layout, a stack that was not to be had (stack_refusals
+# an operand's mesh layout, a stack that was not to be had (stacks.refusals
 # says why), a lone item of a cold field (the latency tier's by design),
 # a shape the lane's kernel does not take, trouble with the item itself
 _LANES = ("pair_counts", "general", "bsi", "bsi_filtered_counts", "bsi_sums")
@@ -181,21 +174,11 @@ class Executor:
             if max_writes_per_request is None
             else max_writes_per_request
         )
-        # stack maintenance accounting (tested: incremental refresh must
-        # replace full re-uploads on write-interleaved workloads)
-        self.stack_rebuilds = 0
-        self.stack_incremental = 0
+        # the device form of the holder's fields, what is cached against
+        # it, and this executor's count of both (exec/stacks.py)
+        self.stacks = stacks_mod.Stacks()
         # stacked-BSI launches (tests assert O(1) dispatch per BSI query)
         self.bsi_stack_launches = 0
-        # pair counts answered from the cached host gram (zero device
-        # work — the serving mode for repeat sequential queries)
-        self.gram_cache_hits = 0
-        # TopN row-count vectors served from the per-snapshot host cache
-        self.rowcount_cache_hits = 0
-        # GroupBy combination matrices served from the cached cross gram
-        self.crossgram_cache_hits = 0
-        # unfiltered BSI Sum/Min/Max scalars served per snapshot
-        self.bsi_agg_cache_hits = 0
         # flight items a batch lane handed back to the per-call path, by
         # lane and reason: the slot is re-executed — and an error
         # re-raised — in the owning query's demux scope, so this counts
@@ -204,12 +187,6 @@ class Executor:
         self.lane_declines = {
             lane: dict.fromkeys(_DECLINE_REASONS, 0) for lane in _LANES
         }
-        # serving stacks not built: one device's share past
-        # _STACK_BUDGET_BYTES, the HBM budget's decline, or a cold field
-        # that fewer than two calls of the flight read
-        self.stack_refusals = dict.fromkeys(
-            ("array_budget", "hbm_budget", "demand"), 0
-        )
 
     # ------------------------------------------------------------------ API
 
@@ -547,326 +524,6 @@ class Executor:
             return None
         return fname, op, rows[0], rows[1]
 
-    # stacks kept per (mesh, shard set); two entries so alternating shard
-    # arguments don't evict each other every call
-    _STACK_CACHE_ENTRIES = 2
-    # monotonic use stamps for LRU eviction (shared across executors —
-    # stamps only compare within one field's cache dict)
-    _stack_lru_clock = itertools.count()
-
-    def _field_stack(
-        self,
-        field: Field,
-        shards: list[int],
-        view_name: str = VIEW_STANDARD,
-        fixed_rows: range | None = None,
-    ):
-        """(slot_of, bits[S, R, W] device tensor) for one of the field's
-        views, DENSE over ``shards`` (all-zero slices where a shard has no
-        fragment, so stacks of different fields share the shard axis —
-        the GroupBy cross-field kernel needs that alignment). With more
-        than one device visible the stack is laid out over the serving
-        mesh — NamedSharding(mesh, P("shards")) with the shard axis
-        padded to the mesh size — so every batched kernel runs on all
-        chips (the reference's shard→node mapReduce, executor.go:2454,
-        as a static placement).
-
-        ``fixed_rows`` pins the row axis to position-aligned slots (the
-        BSI layout: exists/sign/planes at rows 0..depth+1, reference
-        fragment.go:90-96) instead of the union of observed row ids.
-
-        Maintenance is INCREMENTAL: when cached fragment versions drift
-        but the row set is unchanged, only the changed shards' row blocks
-        are scattered into the device stack (one launch) instead of
-        re-uploading the whole field — the write-batch analogue of the
-        reference applying ops to an mmap'd fragment in place
-        (fragment.go:2284-2293). None when over budget or empty."""
-        from pilosa_tpu.parallel.mesh import serving_mesh
-
-        v = field.view(view_name)
-        if v is None:
-            return None
-        frags = {s: v.fragments[s] for s in shards if s in v.fragments}
-        if not frags:
-            return None
-        mesh = serving_mesh()
-        # key and layout must use the SAME resolved mesh: resolving twice
-        # would let a concurrent configure_serving cache an old-mesh
-        # layout under the new mesh's key
-        cache_key = self._stack_key(
-            shards, view_name,
-            len(fixed_rows) if fixed_rows is not None else None,
-            mesh=mesh,
-        )
-        versions = tuple(
-            # (epoch, version): a re-created fragment (resize drop +
-            # re-own) restarts version at 0, so the number alone could
-            # alias a cached stack; the epoch pins the object identity
-            (frags[s].epoch, frags[s].version) if s in frags else (-1, -1)
-            for s in shards
-        )
-        budget = membudget.default_budget()
-        # Per-FIELD lock (fields are shared between executors wrapping the
-        # same holder); setdefault on the instance dict is atomic.
-        lock = vars(field).setdefault("_stack_lock", threading.RLock())
-        with lock:
-            caches = vars(field).setdefault("_stack_caches", {})
-            entry = caches.get(cache_key)
-            if entry is not None:
-                # LRU: stamp the entry on every hit; eviction below drops
-                # the min-stamp entry.  A stamp (vs dict pop/reinsert)
-                # leaves the budget's lock-free _evict pop as the only
-                # writer that removes keys, so no KeyError/resurrection
-                # race between a hit and a concurrent eviction.  The
-                # budget touch doubles as the clock reference bit — use
-                # stamps, not insertion order, drive its eviction scan —
-                # and a hot enough entry graduates to a budget pin so an
-                # oversubscribed tail can't evict the zipfian head.
-                entry["lru"] = next(self._stack_lru_clock)
-                entry["hits"] = entry.get("hits", 0) + 1
-                tracker = residency.default_tracker()
-                prefetching = tracker.in_prefetch()
-                if entry["versions"] == versions:
-                    budget.touch(entry["bkey"])
-                    if prefetching:
-                        # the prefetch thread found it already resident:
-                        # the query (or an earlier prefetch) beat it here
-                        tracker.note_prefetch_wasted()
-                    else:
-                        tracker.note_stack_hit()
-                        tracker.note_hit(entry.get("prefetched", False))
-                        entry["prefetched"] = False
-                        if not entry.get("pinned") and tracker.maybe_pin_stack(
-                            budget, entry["bkey"], entry["hits"]
-                        ):
-                            entry["pinned"] = True
-                    return entry["slot_of"], entry["dev"]
-                updated = self._stack_incremental_update(
-                    field, entry, frags, shards, versions
-                )
-                if updated is not None:
-                    budget.touch(entry["bkey"])
-                    if prefetching:
-                        # a refresh shipped only the drifted shards; the
-                        # NEXT query's hit still credits the prefetch
-                        entry["prefetched"] = True
-                        tracker.note_prefetch_upload(0)
-                    else:
-                        tracker.note_stack_hit()
-                        tracker.note_hit(entry.get("prefetched", False))
-                        entry["prefetched"] = False
-                    return updated
-                caches.pop(cache_key, None)
-                budget.release(entry["bkey"])
-
-            with tracing.start_span("executor.stackBuild").set_tag(
-                "field", field.name
-            ) as sp:
-                return self._stack_build(
-                    field, frags, shards, fixed_rows, mesh, cache_key,
-                    versions, budget, caches, sp,
-                )
-
-    def _stack_build(
-        self, field: Field, frags, shards: list[int], fixed_rows, mesh,
-        cache_key, versions, budget, caches, span,
-    ):
-        """The miss path of :meth:`_field_stack`, under the field's stack
-        lock and the ``executor.stackBuild`` span: gather the rows on the
-        host, upload, retire what the new stack replaces, admit."""
-        from jax.sharding import (
-            NamedSharding, PartitionSpec, SingleDeviceSharding,
-        )
-        from pilosa_tpu.ops import kernels
-
-        if fixed_rows is not None:
-            row_ids = list(fixed_rows)
-        else:
-            row_ids = sorted(
-                {r for f in frags.values() for r in f.row_ids()}
-            )
-        if not row_ids:
-            return None
-        S, R, W = len(shards), len(row_ids), field.n_words
-        n_dev = 1
-        if mesh is not None:
-            n_dev = mesh.devices.size
-            S = -(-S // n_dev) * n_dev  # pad so the mesh divides the axis
-        nbytes = S * R * W * 4
-        # the array limit holds against ONE device's share (the shard
-        # axis is split over the mesh); the budget's cap is the sum of
-        # the chips, so it judges the whole
-        refused = (
-            "array_budget" if nbytes // n_dev > _STACK_BUDGET_BYTES
-            else "hbm_budget" if budget.would_decline(nbytes)
-            else None
-        )
-        if refused is not None:
-            # over HBM budget: callers fall back to per-fragment paths,
-            # which page rows under the same budget (membudget)
-            self.stack_refusals[refused] += 1
-            span.set_tag("refused", refused)
-            return None
-        slot_of = {r: i for i, r in enumerate(row_ids)}
-
-        def host_rows(lo: int, hi: int) -> np.ndarray:
-            """Stack positions ``lo..hi`` of the shard axis, gathered on
-            the host (positions past ``shards`` are the mesh's padding)."""
-            block = np.zeros((hi - lo, R, W), dtype=np.uint32)
-            for si in range(lo, min(hi, len(shards))):
-                f = frags.get(shards[si])
-                if f is None:
-                    continue
-                # bulk matrix copy, not one Python call per row
-                ids, matrix = f.rows_matrix_host()
-                src = [
-                    k for k, r in enumerate(ids) if r in slot_of
-                ]  # fixed_rows: ignore strays
-                if src:
-                    dst = [slot_of[ids[k]] for k in src]
-                    block[si - lo, dst] = matrix[src]
-            return block
-
-        span.set_tag("bytes", nbytes).set_tag("devices", n_dev).set_tag(
-            "bytes_per_device", nbytes // n_dev
-        )
-        if mesh is None:
-            dev = kernels.h2d(host_rows(0, S))
-        else:
-            # a device's share at a time: the host never holds the whole
-            # array (8.4 GB for 4,000 rows over 16 full-width shards)
-            sharding = NamedSharding(mesh, PartitionSpec("shards", None, None))
-            slabs = [
-                kernels.h2d(
-                    host_rows(*index[0].indices(S)[:2]),
-                    SingleDeviceSharding(d),
-                )
-                for d, index in sharding.addressable_devices_indices_map(
-                    (S, R, W)
-                ).items()
-            ]
-            dev = jax.make_array_from_single_device_arrays(
-                (S, R, W), sharding, slabs
-            )
-        self.stack_rebuilds += 1
-        kernels.note_transfer(nbytes, "h2d", dl_site=_DL_STACK)
-        qprofile.incr("stack_rebuilds")
-        # a BSI depth autogrow (or a standard view's row-set change)
-        # retires same-(mesh, shards, view) entries with a different
-        # row-axis length — they can never be hit again and would
-        # otherwise strand a full device stack under a dead key
-        for stale in [
-            k for k in caches
-            if k[:3] == cache_key[:3] and k[3] != cache_key[3]
-        ]:
-            old = caches.pop(stale, None)
-            if old is not None:
-                budget.release(old["bkey"])
-        while len(caches) >= self._STACK_CACHE_ENTRIES:
-            # the budget's _evict pops lock-free, so snapshot-scan and
-            # pop with defaults; retry when a concurrent pop races us
-            try:
-                lru_key = min(
-                    caches, key=lambda k: caches.get(k, {}).get("lru", -1)
-                )
-            except (RuntimeError, ValueError):
-                continue  # dict mutated mid-scan; re-check the bound
-            old = caches.pop(lru_key, None)  # least recently used
-            if old is not None:
-                budget.release(old["bkey"])
-        # Each cache entry carries its OWN budget key (two stacks per
-        # field may be live; one shared key would undercount) and is
-        # released whenever the entry is dropped.
-        bkey = object()
-        weakref.finalize(field, budget.release, bkey)
-        tracker = residency.default_tracker()
-        prefetched = tracker.in_prefetch()
-        if prefetched:
-            # built off the dispatch path by the residency
-            # prefetcher: the first query hit counts it useful
-            tracker.note_prefetch_upload(nbytes)
-        else:
-            tracker.note_miss()
-        entry = {
-            "versions": versions,
-            "slot_of": slot_of,
-            "dev": dev,
-            "bkey": bkey,
-            "lru": next(self._stack_lru_clock),
-            # use-stamp hit count feeds the pin policy: a stack this
-            # hot is exempted from budget eviction (residency.py)
-            "hits": 0,
-            "pinned": False,
-            "prefetched": prefetched,
-        }
-        caches[cache_key] = entry
-
-        def _evict(fref=weakref.ref(field), ck=cache_key):
-            f = fref()
-            if f is not None:
-                # lock-free atomic pop: the evicting thread may hold a
-                # different field's stack lock (AB-BA risk); a reader
-                # holding a reference to the popped entry just keeps
-                # using its (still-valid) device array
-                getattr(f, "_stack_caches", {}).pop(ck, None)
-
-        budget.admit(
-            bkey, nbytes, _evict, owner=f"stack_{field.field_type}"
-        )
-        return slot_of, dev
-
-    # incremental refresh only pays when few shards changed; past this
-    # fraction a single bulk re-upload wins
-    _STACK_INCR_MAX_FRACTION = 0.5
-
-    def _stack_incremental_update(
-        self, field: Field, entry: dict, frags, shards: list[int], versions
-    ):
-        """Refresh changed shards of a cached stack in one device scatter;
-        None when a full rebuild is needed (row set grew, or too many
-        shards drifted)."""
-        slot_of = entry["slot_of"]
-        changed = [
-            si for si, (a, b) in enumerate(zip(entry["versions"], versions))
-            if a != b
-        ]
-        if not changed or len(changed) > max(
-            1, int(len(shards) * self._STACK_INCR_MAX_FRACTION)
-        ):
-            return None
-        R = len(slot_of)
-        W = field.n_words
-        blocks = np.zeros((len(changed), R, W), dtype=np.uint32)
-        for k, si in enumerate(changed):
-            f = frags.get(shards[si])
-            if f is None:
-                return None
-            # ONE locked snapshot: checking membership via a separate
-            # row_ids() call would race a concurrent ingest adding a row
-            # between the check and the copy
-            ids, matrix = f.rows_matrix_host()
-            dst = [slot_of.get(r) for r in ids]
-            if any(s is None for s in dst):
-                return None  # new row: shape change, full rebuild
-            if ids:
-                blocks[k, dst] = matrix
-        from pilosa_tpu.ops import kernels
-
-        dev = entry["dev"].at[kernels.h2d(changed, dtype=np.int32)].set(
-            kernels.h2d(blocks)
-        )
-        entry.pop("gram", None)  # cached gram matched the old snapshot
-        entry.pop("gram_misses", None)  # reuse restarts per snapshot
-        entry.pop("rowcounts", None)  # ditto the served counts vector
-        entry.pop("crossgram", None)  # ditto the cross-field gram
-        entry.pop("crossgram_misses", None)
-        entry.pop("bsi_agg", None)  # ditto the BSI aggregate scalars
-        entry["dev"] = dev  # dev before versions: a racing reader keyed on
-        entry["versions"] = versions  # versions must never see the old dev
-        self.stack_incremental += 1
-        qprofile.incr("stack_incremental")
-        return slot_of, dev
-
     def _lane_decline(self, lane: str, reason: str, n: int = 1) -> None:
         """``n`` items of a flight go back to the per-call path."""
         self.lane_declines[lane][reason] += n
@@ -877,82 +534,6 @@ class Executor:
         self.holder.stats.count_with_tags(
             "query_total", 1, 1.0, (f"index:{idx.name}", f"call:{call_name}")
         )
-
-    # Fields up to this many rows may get their FULL gram computed and
-    # cached on the stack entry — the reference's ranked cache analogue
-    # (cache.go): repeat Count(op(Row,Row)) batches against an unchanged
-    # field then answer from host memory with zero device work.
-    _GRAM_CACHE_MAX_ROWS = 1024
-    # subset-gram computations against one stack snapshot before the full
-    # gram pays for itself (write-interleaved workloads never invest)
-    _GRAM_CACHE_MIN_REUSE = 2
-
-    def _field_gram(self, field: Field, bits, uniq):
-        """(gram, pos) answering pair counts for the slot subset ``uniq``:
-        a full-row gram cached on the stack entry (identity positions) or
-        a fresh subset gram (enumerated positions); (None, None) when the
-        gram path declines entirely.
-
-        The cached gram is keyed to the entry's CURRENT device snapshot
-        (stored under the field's stack lock, which the incremental
-        refresh also holds) — a gram computed from an outdated ``bits``
-        is never installed, so cached answers always match the snapshot
-        the query reads.  The full gram is only computed when the subset
-        nearly covers the rows anyway or the snapshot has already served
-        _GRAM_CACHE_MIN_REUSE subset batches (observed reuse)."""
-        from pilosa_tpu.ops import kernels
-
-        R = bits.shape[1]
-        entry = self._stack_entry_for(field, bits)
-        if entry is not None and R <= self._GRAM_CACHE_MAX_ROWS:
-            cached = entry.get("gram")
-            if cached is not None and cached[0] is bits:
-                self.gram_cache_hits += 1
-                qprofile.incr("gram_cache_hits")
-                return cached[1], {s: s for s in uniq}
-            # the gram outlives the device stack: a budget-evicted field
-            # re-staged with UNCHANGED fragment versions reattaches its
-            # previous full gram ([R, R] host-tier metadata, tiny) with
-            # zero device work — under oversubscription the bytes churn,
-            # the derived artifacts shouldn't (docs/residency.md)
-            hostg = vars(field).get("_gram_host")
-            if hostg is not None and hostg[0] == (entry.get("versions"), R):
-                g = hostg[1]
-                lock = vars(field).setdefault(
-                    "_stack_lock", threading.RLock()
-                )
-                with lock:
-                    if entry.get("dev") is bits:
-                        entry["gram"] = (bits, g)
-                self.gram_cache_hits += 1
-                qprofile.incr("gram_cache_hits")
-                return g, {s: s for s in uniq}
-            if (
-                2 * len(uniq) >= R
-                or entry.get("gram_misses", 0) >= self._GRAM_CACHE_MIN_REUSE
-            ):
-                g = kernels.pair_gram(bits, list(range(R)))
-                if g is not None:
-                    lock = vars(field).setdefault(
-                        "_stack_lock", threading.RLock()
-                    )
-                    with lock:
-                        if entry.get("dev") is bits:  # snapshot current
-                            entry["gram"] = (bits, g)
-                            field._gram_host = (
-                                (entry.get("versions"), R), g,
-                            )
-                    return g, {s: s for s in uniq}
-            else:
-                # under the stack lock: _refresh pops entries under the
-                # same lock, so the increment can't land on a stale entry
-                lock = vars(field).setdefault("_stack_lock", threading.RLock())
-                with lock:
-                    entry["gram_misses"] = entry.get("gram_misses", 0) + 1
-        g = kernels.pair_gram(bits, uniq)
-        if g is None:
-            return None, None
-        return g, {s: k for k, s in enumerate(uniq)}
 
     # lone Count(op(Row,Row)) queries against one field seen before the
     # stack+gram investment is judged worthwhile for singles (the warm-up
@@ -967,176 +548,12 @@ class Executor:
         singles against this field prove reuse.  Once the cost ledger
         has priced both lanes, the measured comparison replaces the
         warm-up counter (exec/planner.py lane choice)."""
-        if self._stack_cached(field, shard_list):
+        if self.stacks.cached(field, shard_list):
             return True
-        lock = vars(field).setdefault("_stack_lock", threading.RLock())
-        with lock:
-            n = vars(field).get("_pair_single_demand", 0) + 1
-            field._pair_single_demand = n
         return self.planner.choose_lane(
-            "pair_count", n >= self._PAIR_SINGLE_WARM
+            "pair_count",
+            stacks_mod.note_single(field, "pair") >= self._PAIR_SINGLE_WARM,
         )
-
-    @staticmethod
-    def _stack_entry_for(field: Field, bits):
-        """The stack-cache entry whose device snapshot IS ``bits``, found
-        by identity rather than by rebuilding _field_stack's cache key
-        (which would silently go stale if the key shape ever changed);
-        the cache holds at most a handful of entries. The budget's _evict
-        pops the dict lock-free from arbitrary threads, so the scan
-        retries on a mid-iteration mutation and degrades to a cache miss
-        rather than failing the query."""
-        caches = getattr(field, "_stack_caches", None)
-        if not caches:
-            return None
-        for _ in range(3):
-            try:
-                return next(
-                    (
-                        e
-                        for e in list(caches.values())
-                        if e.get("dev") is bits
-                    ),
-                    None,
-                )
-            except RuntimeError:
-                continue  # dict mutated mid-scan; retry then miss
-        return None
-
-    def _stack_row_counts(self, field: Field, bits) -> np.ndarray:
-        """Per-slot row counts ``int64 [R]`` for a stack snapshot, cached
-        on the owning cache entry (keyed to the snapshot like the gram) —
-        repeat unfiltered TopN against an unchanged field is then served
-        from host memory with zero device work, the reference's
-        ranked-cache role (cache.go).  A cached full gram's diagonal is
-        reused instead of launching the count kernel."""
-        from pilosa_tpu.ops import kernels
-
-        entry = self._stack_entry_for(field, bits)
-        if entry is not None:
-            cached = entry.get("rowcounts")
-            if cached is not None and cached[0] is bits:
-                self.rowcount_cache_hits += 1
-                qprofile.incr("rowcount_cache_hits")
-                return cached[1]
-            gram = entry.get("gram")
-            if gram is not None and gram[0] is bits:
-                rc = np.diag(gram[1]).astype(np.int64)
-            else:
-                rc = kernels.pull(
-                    kernels.row_counts(bits), "row_counts"
-                ).astype(np.int64)
-            lock = vars(field).setdefault("_stack_lock", threading.RLock())
-            with lock:
-                if entry.get("dev") is bits:  # snapshot still current
-                    entry["rowcounts"] = (bits, rc)
-            return rc
-        return kernels.pull(
-            kernels.row_counts(bits), "row_counts"
-        ).astype(np.int64)
-
-    # live cross-gram slots kept per stack entry (one per partner field);
-    # each full gram is <= 8 MiB host memory at _GRAM_CACHE_MAX_ROWS
-    _CROSS_GRAM_SLOTS = 4
-
-    def _cross_slot(self, field: Field, bits, partner: str):
-        """The (own_bits, partner_weakref, gram) slot cached on
-        ``field``'s stack entry for ``partner``, dropping it if stale.
-        Also returns the owning entry (or None)."""
-        entry = self._stack_entry_for(field, bits)
-        if entry is None:
-            return None, None
-        slots = entry.get("crossgram")
-        t = slots.get(partner) if slots else None
-        if t is not None:
-            lock = vars(field).setdefault("_stack_lock", threading.RLock())
-            if not (t[0] is bits and t[1]() is not None):
-                # our snapshot moved, or the partner's was retired/
-                # evicted — drop the slot now rather than letting it
-                # linger
-                with lock:
-                    slots.pop(partner, None)
-                t = None
-            else:
-                # LRU: move the hit slot to the end so the eviction loop
-                # (which pops from the front) removes the coldest partner
-                with lock:
-                    cur = slots.pop(partner, None)
-                    if cur is not None:
-                        slots[partner] = cur
-        return entry, t
-
-    def _cross_gram(
-        self, f1: Field, bits1, f2: Field, bits2, sub1: list, sub2: list
-    ):
-        """Cross-field intersection counts ``int64 [len(sub1), len(sub2)]``
-        for two stack snapshots, with the same invest-on-reuse caching as
-        ``_field_gram``: once repeat 2-level GroupBys against unchanged
-        fields prove reuse, the FULL cross gram is computed once and every
-        later combination matrix is sliced from host memory with zero
-        device work.  Slots live on the first field's stack entry, one per
-        partner field (so alternating partners don't thrash), and hold the
-        partner's snapshot only WEAKLY — a cached gram must never keep a
-        retired or budget-evicted device stack alive.  None when the gram
-        path declines."""
-        from pilosa_tpu.ops import kernels
-
-        R1, R2 = bits1.shape[1], bits2.shape[1]
-        if (
-            R1 <= self._GRAM_CACHE_MAX_ROWS
-            and R2 <= self._GRAM_CACHE_MAX_ROWS
-        ):
-            entry, t = self._cross_slot(f1, bits1, f2.name)
-            if t is not None and t[1]() is bits2:
-                self.crossgram_cache_hits += 1
-                qprofile.incr("crossgram_cache_hits")
-                return t[2][np.ix_(sub1, sub2)]
-            # the reversed field order may already hold this gram
-            # transposed (GroupBy(f, g) then GroupBy(g, f))
-            _, t2 = self._cross_slot(f2, bits2, f1.name)
-            if t2 is not None and t2[1]() is bits1:
-                self.crossgram_cache_hits += 1
-                qprofile.incr("crossgram_cache_hits")
-                return t2[2].T[np.ix_(sub1, sub2)]
-            if entry is not None:
-                misses = entry.setdefault("crossgram_misses", {})
-                nearly_full = 2 * len(sub1) >= R1 and 2 * len(sub2) >= R2
-                if (
-                    nearly_full
-                    or misses.get(f2.name, 0) >= self._GRAM_CACHE_MIN_REUSE
-                ):
-                    g = kernels.cross_pair_gram(
-                        bits1, bits2, list(range(R1)), list(range(R2))
-                    )
-                    if g is not None:
-                        lock = vars(f1).setdefault(
-                            "_stack_lock", threading.RLock()
-                        )
-                        with lock:
-                            if entry.get("dev") is bits1:  # still current
-                                slots = entry.setdefault("crossgram", {})
-                                # pop-then-insert so an overwrite lands
-                                # at the end (freshest LRU position)
-                                slots.pop(f2.name, None)
-                                slots[f2.name] = (
-                                    bits1,
-                                    weakref.ref(bits2),
-                                    g,
-                                )
-                                while len(slots) > self._CROSS_GRAM_SLOTS:
-                                    k = next(iter(slots), None)
-                                    if k is None:
-                                        break
-                                    slots.pop(k, None)
-                        return g[np.ix_(sub1, sub2)]
-                else:
-                    # same-lock discipline as the install path above
-                    lock = vars(f1).setdefault(
-                        "_stack_lock", threading.RLock()
-                    )
-                    with lock:
-                        misses[f2.name] = misses.get(f2.name, 0) + 1
-        return kernels.cross_pair_gram(bits1, bits2, sub1, sub2)
 
     def _batch_pair_counts(
         self, idx: Index, calls: list[Call], shards: list[int] | None,
@@ -1172,21 +589,15 @@ class Executor:
             ):
                 self._lane_decline("pair_counts", "demand")
                 continue
-            stack = self._field_stack(field, shard_list)
+            stack = self.stacks.get(field, shard_list)
             if stack is None:
                 self._lane_decline("pair_counts", "budget", len(items))
                 if len(items) < 2:
                     # over-budget field: restart the warm-up so singles
                     # don't pay a declined build attempt on every query
-                    # (same lock as _pair_single_ready's read-modify-write,
-                    # or a concurrent increment could overwrite the reset)
-                    lock = vars(field).setdefault(
-                        "_stack_lock", threading.RLock()
-                    )
-                    with lock:
-                        field._pair_single_demand = 0
+                    stacks_mod.reset_single(field, "pair")
                 continue
-            slot_of, bits = stack
+            slot_of, bits = stack.slot_of, stack.bits
             launch: list[tuple[int, str, int, int]] = []
             for i, op, ra, rb in items:
                 sa, sb = slot_of.get(ra), slot_of.get(rb)
@@ -1213,7 +624,7 @@ class Executor:
             ).set_tag("n", len(launch)), _DL_PAIR.launch(
                 sig=f"gram n{len(launch)}", n=len(launch)
             ):
-                gram, pos = self._field_gram(field, bits, uniq)
+                gram, pos = self.stacks.gram(field, stack, bits, uniq)
                 if gram is not None:
                     with tracing.start_span("executor.demux").set_tag(
                         "n", len(launch)
@@ -1278,105 +689,20 @@ class Executor:
 
     # ------------------------------------------ general AST one-launch path
 
-    _UNRESOLVED = object()  # serving_mesh() may itself be None
-
-    @staticmethod
-    def _stack_key(
-        shards: list[int],
-        view_name: str,
-        n_fixed_rows: int | None,
-        mesh=_UNRESOLVED,
-    ) -> tuple:
-        """Stack-cache key. The mesh is part of the key: a device-set/
-        configure_serving change must invalidate stacks built with the
-        old sharding. View + row-axis length too: the standard and BSI
-        stacks of one field share the cache dict, and a BSI depth
-        autogrow must build a fresh (wider) stack. Pass ``mesh`` when the
-        caller has already resolved it (and uses it for layout) so key
-        and layout can never disagree."""
-        from pilosa_tpu.parallel.mesh import serving_mesh
-
-        if mesh is Executor._UNRESOLVED:
-            mesh = serving_mesh()
-        return (mesh, tuple(shards), view_name, n_fixed_rows)
-
-    def _stack_cached(
-        self,
-        field: Field,
-        shard_list: list[int],
-        view_name: str = VIEW_STANDARD,
-        n_fixed_rows: int | None = None,
-    ) -> bool:
-        """Whether a serving stack for this (field, shards) is already
-        live — a peek that never builds."""
-        caches = getattr(field, "_stack_caches", None)
-        if not caches:
-            return False
-        return self._stack_key(shard_list, view_name, n_fixed_rows) in caches
-
-    def prefetch_stack(
-        self,
-        field: Field,
-        shard_list: list[int],
-        view_name: str = VIEW_STANDARD,
-    ) -> None:
-        """Build (or refresh) the field's serving stack off the dispatch
-        path — the residency prefetcher's target (server/prefetch.py).
-        Runs on the uploader thread inside the tracker's prefetch
-        context, so _field_stack books the transfer as prefetch traffic
-        rather than a query miss; a stack the budget declines is simply
-        not built (the dispatch falls back exactly as before).
-
-        The derived serving artifacts ride along: a re-staged stack's
-        pair-count gram is recomputed here too (same cache + snapshot
-        discipline as _field_gram), so an evicted-then-prefetched field
-        serves its next flight from the host gram with zero device work
-        instead of paying the gram launch inside the dispatch."""
-        st = self._field_stack(field, shard_list, view_name)
-        if st is None:
-            return
-        _, bits = st
-        R = bits.shape[1]
-        if R > self._GRAM_CACHE_MAX_ROWS:
-            return
-        entry = self._stack_entry_for(field, bits)
-        if entry is None:
-            return
-        cached = entry.get("gram")
-        if cached is not None and cached[0] is bits:
-            return
-        lock = vars(field).setdefault("_stack_lock", threading.RLock())
-        hostg = vars(field).get("_gram_host")
-        if hostg is not None and hostg[0] == (entry.get("versions"), R):
-            # versions unchanged since the last full gram: reattach the
-            # host copy instead of relaunching
-            with lock:
-                if entry.get("dev") is bits:
-                    entry["gram"] = (bits, hostg[1])
-            return
-        from pilosa_tpu.ops import kernels
-
-        g = kernels.pair_gram(bits, list(range(R)))
-        if g is not None:
-            with lock:
-                if entry.get("dev") is bits:  # snapshot still current
-                    entry["gram"] = (bits, g)
-                    field._gram_host = ((entry.get("versions"), R), g)
-
     def _stack_on_demand(
         self, field: Field, shard_list: list[int], view_name: str,
         demand: int,
     ):
-        """The view's serving stack entry for a batch lane's leaf, or
-        None when it declines.  A live stack serves for free; a cold one
-        is built only when >= 2 calls of the flight read it (stack builds
-        are full-field uploads), a heuristic that stands until the ledger
+        """The view's stack for a batch lane's leaf, or None when it
+        declines.  A live stack serves for free; a cold one is built
+        only when >= 2 calls of the flight read it (stack builds are
+        full-field uploads), a heuristic that stands until the ledger
         prices the batch-vs-solo lanes."""
-        if self._stack_cached(
+        if self.stacks.cached(
             field, shard_list, view_name
         ) or self.planner.choose_lane("tree_count", demand >= 2):
-            return self._field_stack(field, shard_list, view_name=view_name)
-        self.stack_refusals["demand"] += 1
+            return self.stacks.get(field, shard_list, view_name)
+        self.stacks.refusals["demand"] += 1
         return None
 
     def _batch_general(
@@ -1427,9 +753,9 @@ class Executor:
             return
         shard_list = self._shards_for(idx, shards)
 
-        # (field, view) -> stack entry | None (declined) | _ABSENT (no
-        # such view: an all-zero leaf, e.g. an empty period of a
-        # time-range cover)
+        # (field, view) -> (slot_of, bits) of its stack | the reason it
+        # declined | _ABSENT (no such view: an all-zero leaf, e.g. an
+        # empty period of a time-range cover)
         _ABSENT = object()
         stacks_by_view: dict[tuple[str, str], Any] = {}
 
@@ -1453,9 +779,13 @@ class Executor:
                     elif field.view(vname) is None:
                         stacks_by_view[pair] = _ABSENT
                     else:
-                        stacks_by_view[pair] = self._stack_on_demand(
+                        stack = self._stack_on_demand(
                             field, shard_list, vname, demand.get(pair, 0)
-                        ) or "budget"
+                        )
+                        stacks_by_view[pair] = (
+                            "budget" if stack is None
+                            else (stack.slot_of, stack.bits)
+                        )
                 entry = stacks_by_view[pair]
                 if isinstance(entry, str):
                     return entry
@@ -1835,16 +1165,17 @@ class Executor:
             field = fields[fname]
             if shard_list is None:
                 shard_list = self._shards_for(idx, shards)
-            if len(items) < 2 and not self._bsi_stack_live(
+            if len(items) < 2 and not self.stacks.bsi_cached(
                 field, shard_list
             ):
                 self._lane_decline("bsi", "demand")
                 continue
-            bits = self._bsi_stack(field, shard_list)
-            if bits is None:
+            stack = self.stacks.bsi(field, shard_list)
+            if stack is None:
                 # over budget: per-fragment path answers
                 self._lane_decline("bsi", "budget", len(items))
                 continue
+            bits = stack.bits
             groups: dict[str, list[tuple[int, Any]]] = {}
             # filtered range counts group by their filter stacks too
             filtered: dict[tuple, list[tuple[int, Any, tuple]]] = {}
@@ -1858,7 +1189,8 @@ class Executor:
                 "field", fname
             ).set_tag("n", len(items)):
                 self._batch_bsi_field(
-                    idx, field, bits, groups, shard_list, calls, results
+                    idx, field, stack, bits, groups, shard_list, calls,
+                    results,
                 )
                 for pairs, fitems in filtered.items():
                     self._batch_bsi_filtered_counts(
@@ -1867,7 +1199,7 @@ class Executor:
                     )
 
     def _batch_bsi_field(
-        self, idx: Index, field: Field, bits, groups, shard_list,
+        self, idx: Index, field: Field, stack, bits, groups, shard_list,
         calls: list[Call], results: list[Any],
     ) -> None:
         """One field's grouped BSI launches against its live stack."""
@@ -1944,20 +1276,20 @@ class Executor:
         count_items = groups.get(astbatch.BSI_RANGE_COUNT, [])
         if count_items:
             pending: list[tuple[int, Any]] = []
-            puts: list = []
+            keys: list = []
             for i, cond in count_items:
                 keyed = self._range_count_key(idx, calls[i].children[0])
-                cached, put = (
-                    self._bsi_agg_cache(field, bits, keyed[1])
-                    if keyed is not None
-                    else (None, lambda v: None)
+                key = keyed[1] if keyed is not None else None
+                cached = (
+                    self.stacks.bsi_agg(stack, bits, key)
+                    if key is not None else None
                 )
                 if cached is not None:
                     results[i] = cached
                     self._count_stat(idx)
                 else:
                     pending.append((i, cond))
-                    puts.append(put)
+                    keys.append(key)
             if pending:
                 try:
                     queries = [
@@ -1979,8 +1311,9 @@ class Executor:
                     with tracing.start_span("executor.demux").set_tag(
                         "n", len(pending)
                     ):
-                        for (i, _), put, n in zip(pending, puts, counts):
-                            put(n)
+                        for (i, _), key, n in zip(pending, keys, counts):
+                            if key is not None:
+                                self.stacks.put_bsi_agg(stack, bits, key, n)
                             results[i] = n
                             self._count_stat(idx)
 
@@ -1990,7 +1323,8 @@ class Executor:
         sum_items = groups.get(astbatch.BSI_SUM, [])
         if sum_items:
             self._batch_bsi_sums(
-                idx, field, bits, sum_items, shard_list, calls, results
+                idx, field, stack, bits, sum_items, shard_list, calls,
+                results,
             )
 
         # -- Min/Max: one cached scalar per (field, kind); grouped here
@@ -2024,17 +1358,17 @@ class Executor:
         from pilosa_tpu.ops import kernels
 
         lane = "bsi_filtered_counts"
-        entries = []
+        entries = []  # (slot_of, bits) of each filter stack
         for fname, vname in pairs:
-            entry = self._stack_on_demand(
+            fstack = self._stack_on_demand(
                 idx.field(fname), shard_list, vname,
                 demand.get((fname, vname), 0),
             )
-            if entry is None:
+            if fstack is None:
                 # cold and under-demanded, or over budget
                 self._lane_decline(lane, "budget", len(items))
                 return
-            entries.append(entry)
+            entries.append((fstack.slot_of, fstack.bits))
         layout = kernels.shards_axis_of(bits)
         if kernels.stack_spans_processes(bits) or any(
             kernels.shards_axis_of(e[1]) != layout for e in entries
@@ -2076,7 +1410,7 @@ class Executor:
                     self._count_stat(idx)
 
     def _batch_bsi_sums(
-        self, idx: Index, field: Field, bits, sum_items, shard_list,
+        self, idx: Index, field: Field, stack, bits, sum_items, shard_list,
         calls: list[Call], results: list[Any],
     ) -> None:
         from pilosa_tpu.ops import kernels
@@ -2101,7 +1435,7 @@ class Executor:
             # one cached stacked compute answers them all
             try:
                 tc = self._bsi_agg_serve(
-                    field, (bits, None, shard_list), "sum",
+                    field, (stack, bits, None, shard_list), "sum",
                     lambda p, e, s, fw: bsi.sum_host(
                         p, e, s, fw, depth=depth
                     ),
@@ -2350,29 +1684,6 @@ class Executor:
             )
         raise ExecuteError(f"unsupported condition op: {op}")
 
-    def _bsi_stack(self, field: Field, shards: list[int]):
-        """The raw ``uint32[S, depth+2, W]`` stacked BSI tensor (rows:
-        exists=0, sign=1, planes 2..) or None (no view / over budget) —
-        split into views via ``_bsi_split`` only when actually
-        computing, so a cache-served aggregate pays no device dispatch.
-        The stack is the same budget-accounted, incrementally-refreshed,
-        mesh-sharded cache as standard-view stacks, with the row axis
-        pinned to the BSI layout (exists=0, sign=1, planes 2.., reference
-        fragment.go:90-96) so every Range/Sum/Min/Max batches all shards
-        into one launch (reference fragment.go:1271-1534 runs the same
-        scan per fragment)."""
-        depth = field.bit_depth
-        stack = self._field_stack(
-            field,
-            shards,
-            view_name=field.bsi_view_name(),
-            fixed_rows=range(2 + depth),
-        )
-        if stack is None:
-            return None
-        _, bits = stack  # [S, depth+2, W]
-        return bits
-
     @staticmethod
     def _bsi_split(bits):
         """(exists, sign, planes) slices of a raw BSI stack.  Each slice
@@ -2395,14 +1706,6 @@ class Executor:
     # first query, i.e. the pre-round-4 behavior)
     _BSI_SINGLE_WARM = 4
 
-    def _bsi_stack_live(self, field: Field, shards: list[int]) -> bool:
-        """Peek (never build): whether the field's BSI stack is cached
-        for these shards — the ONE place spelling the BSI stack key
-        shape, shared by the warm-up decision and the agg-cache gate."""
-        return self._stack_cached(
-            field, shards, field.bsi_view_name(), 2 + field.bit_depth
-        )
-
     def _bsi_single_ready(self, field: Field, shards: list[int]) -> bool:
         """Whether a LONE BSI predicate should take the device stack
         path — mirror of _pair_single_ready's warm-up economics: a live
@@ -2410,13 +1713,9 @@ class Executor:
         the full-field device upload before a lone query pays it."""
         if self._BSI_SINGLE_WARM <= 0:
             return True
-        if self._bsi_stack_live(field, shards):
+        if self.stacks.bsi_cached(field, shards):
             return True
-        lock = vars(field).setdefault("_stack_lock", threading.RLock())
-        with lock:
-            n = vars(field).get("_bsi_single_demand", 0) + 1
-            field._bsi_single_demand = n
-        return n >= self._BSI_SINGLE_WARM
+        return stacks_mod.note_single(field, "bsi") >= self._BSI_SINGLE_WARM
 
     def _bsi_rows(
         self, field: Field, shards: list[int], kernel,
@@ -2470,9 +1769,9 @@ class Executor:
             for si, (s, _) in enumerate(frags):
                 out.segments[s] = mask[si]
             return out
-        st = self._bsi_stack(field, shards)
-        if st is not None:
-            exists, sign, planes = self._bsi_split(st)
+        stack = self.stacks.bsi(field, shards)
+        if stack is not None:
+            exists, sign, planes = self._bsi_split(stack.bits)
             self.bsi_stack_launches += 1
             from pilosa_tpu.ops import kernels
 
@@ -2533,16 +1832,17 @@ class Executor:
             # peek, never build: a lone cold range count must not pay a
             # full-field device upload for the agg cache (the host BSI
             # tier below answers it; repeat demand builds the stack)
-            ready = self._BSI_SINGLE_WARM <= 0 or self._bsi_stack_live(
+            ready = self._BSI_SINGLE_WARM <= 0 or self.stacks.bsi_cached(
                 field, shard_list
             )
-            bits = self._bsi_stack(field, shard_list) if ready else None
-            if bits is not None:
-                cached, put = self._bsi_agg_cache(field, bits, key)
+            stack = self.stacks.bsi(field, shard_list) if ready else None
+            if stack is not None:
+                bits = stack.bits
+                cached = self.stacks.bsi_agg(stack, bits, key)
                 if cached is not None:
                     return cached
                 n = self._bitmap_call(idx, child, shard_list).count()
-                put(n)
+                self.stacks.put_bsi_agg(stack, bits, key, n)
                 return n
         # Latency tier: a lone Count over a pair or single row — the
         # gram fast path has already declined (cold field / single
@@ -2729,7 +2029,7 @@ class Executor:
         """Shared scaffold for Sum/Min/Max: resolve the BSI field and the
         optional filter child; returns (field, stacked_or_None,
         per_shard_generator).  The stacked form is a DEFERRED
-        (raw_bits, filter_row, shards) triple — ``_bsi_tensors``
+        (stack, raw_bits, filter_row, shards) tuple — ``_bsi_tensors``
         materializes the (planes, exists, sign, filter-words) views on a
         cache miss, answering the aggregate in one launch; the generator
         is the per-fragment fallback when the stack declines (over
@@ -2740,11 +2040,11 @@ class Executor:
         view = field.view(field.bsi_view_name())
 
         stacked = None
-        bits = self._bsi_stack(field, shards)
-        if bits is not None:
+        stack = self.stacks.bsi(field, shards)
+        if stack is not None:
             # split + filter materialization deferred to _bsi_tensors:
             # a cache-served aggregate pays zero device dispatches
-            stacked = (bits, filt, shards)
+            stacked = (stack, stack.bits, filt, shards)
 
         def per_shard():
             if view is None:
@@ -2764,56 +2064,11 @@ class Executor:
 
         return field, stacked, per_shard()
 
-    # scalar aggregates kept per BSI stack snapshot (sum + min/max +
-    # repeat range-count bounds; each entry is a handful of ints)
-    _BSI_AGG_SLOTS = 128
-
-    def _bsi_agg_cache(self, field: Field, dev, key: str):
-        """Per-snapshot cache of unfiltered BSI aggregate scalars on the
-        BSI stack's cache entry (same identity-keyed, write-invalidated
-        scheme as the gram/row-count serving caches): repeat unfiltered
-        Sum/Min/Max against an unchanged field are host dictionary hits.
-        Returns (cached tuple | None, setter)."""
-        entry = self._stack_entry_for(field, dev)
-        if entry is None:
-            return None, lambda v: None
-        slots = entry.get("bsi_agg")
-        t = slots.get(key) if slots else None
-        if t is not None and t[0] is dev:
-            self.bsi_agg_cache_hits += 1
-            qprofile.incr("bsi_agg_cache_hits")
-            # LRU: move the hit key to the dict end so put()'s bounded
-            # eviction (front-first) removes the coldest key, not a hot
-            # one that happened to be inserted early
-            lock = vars(field).setdefault("_stack_lock", threading.RLock())
-            with lock:
-                cur = slots.pop(key, None)
-                if cur is not None:
-                    slots[key] = cur
-            return t[1], lambda v: None
-
-        def put(v):
-            lock = vars(field).setdefault("_stack_lock", threading.RLock())
-            with lock:
-                if entry.get("dev") is dev:  # snapshot still current
-                    slots2 = entry.setdefault("bsi_agg", {})
-                    slots2.pop(key, None)  # re-insert at the LRU end
-                    slots2[key] = (dev, v)
-                    # range-count keys are open-ended (one per distinct
-                    # bound); bound the dict, oldest first
-                    while len(slots2) > self._BSI_AGG_SLOTS:
-                        k = next(iter(slots2), None)
-                        if k is None:
-                            break
-                        slots2.pop(k, None)
-
-        return None, put
-
     def _bsi_tensors(self, field: Field, stacked):
         """Materialize a deferred stacked tuple: split the raw stack and
         build the filter words (device dispatches — run only on a cache
         miss)."""
-        bits, filt, shards = stacked
+        _, bits, filt, shards = stacked
         exists, sign, planes = self._bsi_split(bits)
         if filt is None:
             # the kernels compute f = exists & filter, so exists
@@ -2845,11 +2100,9 @@ class Executor:
         queries always compute — their result depends on the filter)."""
         from pilosa_tpu.ops import kernels
 
-        bits, filt, _ = stacked
-        cached, put = (
-            self._bsi_agg_cache(field, bits, key)
-            if filt is None
-            else (None, lambda v: None)
+        stack, bits, filt, _ = stacked
+        cached = (
+            self.stacks.bsi_agg(stack, bits, key) if filt is None else None
         )
         if cached is None:
             planes, exists, sign, fw = self._bsi_tensors(field, stacked)
@@ -2857,7 +2110,8 @@ class Executor:
             with _DL_STACK.launch(sig=f"bsi_agg/{key.split(':', 1)[0]}") as w:
                 w.mesh = kernels._multi_device(planes)
                 cached = compute(planes, exists, sign, fw)
-            put(cached)
+            if filt is None:
+                self.stacks.put_bsi_agg(stack, bits, key, cached)
         return cached
 
     def _execute_sum(self, idx: Index, call: Call, shards: list[int] | None) -> ValCount:
@@ -3125,21 +2379,19 @@ class Executor:
             # filter loop, fragment.go:1586-1655).
             from pilosa_tpu.ops import kernels
 
-            stack = self._field_stack(field, shards)
-            if stack is not None:
-                # masked counts run in-program (psum) on
-                # process-spanning stacks too; the only decline left is
-                # totals past even a single-shard psum slice's int32
-                # bound — the per-fragment loop below answers then
-                if not kernels.row_counts_supported(stack[1]):
-                    stack = None
-            if stack is not None:
-                slot_of, bits = stack
+            stack = self.stacks.get(field, shards)
+            bits = stack.bits if stack is not None else None
+            # masked counts run in-program (psum) on process-spanning
+            # stacks too; the only decline left is totals past even a
+            # single-shard psum slice's int32 bound — the per-fragment
+            # loop below answers then
+            if stack is not None and kernels.row_counts_supported(bits):
+                slot_of = stack.slot_of
                 S, _, W = bits.shape
                 filt = self._row_to_shard_matrix(src, shards, S, W)
                 mc = kernels.masked_row_counts(bits, filt)
                 rc = (
-                    self._stack_row_counts(field, bits)
+                    self.stacks.row_counts(stack, bits)
                     if has_tanimoto else None
                 )
                 with tracing.start_span("executor.demux").set_tag(
@@ -3442,12 +2694,12 @@ class Executor:
             return []
         if n_combo > self._GROUPBY_BATCH_MAX:
             return None
-        s1 = self._field_stack(f1, shards)
-        s2 = self._field_stack(f2, shards) if f2 is not f1 else s1
+        s1 = self.stacks.get(f1, shards)
+        s2 = self.stacks.get(f2, shards) if f2 is not f1 else s1
         if s1 is None or s2 is None:
             return None
-        slot1, bits1 = s1
-        slot2, bits2 = s2
+        slot1, bits1 = s1.slot_of, s1.bits
+        slot2, bits2 = s2.slot_of, s2.bits
         present1 = [r for r in rows1 if r in slot1]
         present2 = [r for r in rows2 if r in slot2]
         if not present1 or not present2:
@@ -3461,16 +2713,16 @@ class Executor:
             counts2d = None
             if f2 is f1:
                 uniq = sorted({slot1[r] for r in present1 + present2})
-                g, pos = self._field_gram(f1, bits1, uniq)
+                g, pos = self.stacks.gram(f1, s1, bits1, uniq)
                 if g is not None:
                     pa = np.array([pos[slot1[r]] for r in present1])
                     pb = np.array([pos[slot1[r]] for r in present2])
                     counts2d = g[np.ix_(pa, pb)]
             else:
-                counts2d = self._cross_gram(
-                    f1,
+                counts2d = self.stacks.cross_gram(
+                    s1,
                     bits1,
-                    f2,
+                    s2,
                     bits2,
                     [slot1[r] for r in present1],
                     [slot2[r] for r in present2],
@@ -3571,10 +2823,10 @@ class Executor:
 
         stacks = []
         for _, f, _ in levels:
-            st = self._field_stack(f, shards)
+            st = self.stacks.get(f, shards)
             if st is None:
                 return None
-            stacks.append(st)
+            stacks.append((st.slot_of, st.bits))
         slot0, bits0 = stacks[0]
         if kernels.stack_spans_processes(bits0):
             # combo-count kernels return per-shard partials, not host

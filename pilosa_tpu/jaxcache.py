@@ -3,7 +3,7 @@
 The system jits one program per (kernel, shape, Q-bucket, depth); there
 are hundreds and most compile in well under a second, so a process that
 starts with no cache pays for all of them again.  Every entry point that
-is about to jit (``cli server``, ``bench.py``, ``tools/loadharness.py``,
+is about to jit (``cli server``, ``tools/loadharness.py``,
 ``tools/kernel_census.py``) calls :func:`configure` first.
 
 The cache directory is part of the cache key's environment, so it has to

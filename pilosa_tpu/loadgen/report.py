@@ -1,6 +1,6 @@
 """SLO report: the machine-readable artifact one harness run emits.
 
-``SLO_r*.json`` sits next to ``BENCH_*.json`` and makes the north-star
+``SLO_r*.json`` makes the north-star
 ("serve heavy mixed traffic inside objectives") a regressable number:
 per-op-class client-side p50/p99/p999, error-budget burn from the
 server's own tracker, and a pass/fail verdict per objective-bearing
@@ -203,8 +203,7 @@ def validate_report(report: dict) -> None:
 
 
 def next_report_path(directory: str = ".") -> str:
-    """Next free SLO_rNN.json in ``directory`` (numbering mirrors the
-    BENCH_r*.json convention)."""
+    """Next free SLO_rNN.json in ``directory``."""
     n = 1
     for entry in os.listdir(directory):
         if entry.startswith("SLO_r") and entry.endswith(".json"):

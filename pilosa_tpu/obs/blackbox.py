@@ -575,7 +575,7 @@ class BlackBox:
         return None
 
     def stats(self) -> dict:
-        """Writer self-accounting for /debug/vars and the bench lane."""
+        """Writer self-accounting for /debug/vars."""
         with self._lock:
             out = dict(self._stats)
         out["interval"] = self.interval

@@ -21,7 +21,7 @@ synchronously in the calling thread.  That event wraps jax's
 back from disk; jax announces those with ``/jax/compilation_cache/cache_hits``
 just before, and the listener books them apart as ``persistentCacheHits``
 — a retrieval is not a compile, and must trip neither the storm detector
-nor a bench lane's ``forbid_compiles`` guard.
+nor the benchmark's ``window_compiles`` check.
 The listener attributes each event to the innermost active *launch window*
 (``with site.launch(sig=...)``) on that thread; sites that report after the
 fact (the ops.kernels dispatch funnel) claim the thread's stashed events
@@ -696,7 +696,7 @@ class Ledger:
 
     # -- exposition -------------------------------------------------------
     def counters(self) -> dict:
-        """Flat counter map for cheap before/after deltas (bench, loadgen,
+        """Flat counter map for cheap before/after deltas (loadgen,
         flight recorder segments)."""
         with self._lock:
             out = {

@@ -699,7 +699,7 @@ class MetricsHistory:
             }
 
     def stats(self) -> dict:
-        """Sampler self-accounting for /debug/vars and the bench lane."""
+        """Sampler self-accounting for /debug/vars."""
         with self._lock:
             return {
                 "cadence": self.cadence,

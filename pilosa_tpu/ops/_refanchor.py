@@ -2,7 +2,7 @@
 (native/refanchor.cpp): a compiled port of the semantic work of the
 reference's hot benchmark paths (roaring containers, AddN, CountRange,
 intersectionCount, snapshot serialization), used as the measured
-comparison baseline in bench.py / tools/ref_anchor.py.
+comparison baseline in tools/ref_anchor.py (BASELINE.md).
 
 Built on demand through the shared loader (pilosa_tpu/nativelib.py);
 ``load()`` returns None when no toolchain exists — callers must skip

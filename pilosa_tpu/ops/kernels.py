@@ -116,7 +116,7 @@ _PALLAS_FALLBACK_LOG_EVERY = 10
 _fallback_lock = threading.Lock()
 
 # Process-wide kernel/dispatch telemetry, rendered as ``pilosa_kernel_*``
-# by /metrics and snapshotted into /debug/vars and bench records.  Lives
+# by /metrics and snapshotted into /debug/vars.  Lives
 # here rather than on the holder because dispatch decisions are made in
 # this module, below any holder plumbing.
 kernel_stats = MemStatsClient()
@@ -336,8 +336,8 @@ def record_host_op(kernel: str) -> None:
 
 
 def telemetry_snapshot() -> dict:
-    """JSON-safe kernel-telemetry rollup for /debug/vars, bench records
-    and tests: dispatch-lane counts, compile-cache proxy, transfer
+    """JSON-safe kernel-telemetry rollup for /debug/vars and tests:
+    dispatch-lane counts, compile-cache proxy, transfer
     bytes, pallas gate states."""
     snap = kernel_stats.snapshot()
     lanes: dict[str, int] = {}

@@ -12,7 +12,7 @@ A node advertises itself by registering its holder here on ``start()``
 and withdrawing on ``stop()`` (server/node.py).  In production — one
 process per host — only the local node ever registers, so the registry
 is a no-op and every peer stays on the HTTP fan-out.  In an
-``InProcessCluster`` (tests, bench, a future one-process-many-chips
+``InProcessCluster`` (tests, a future one-process-many-chips
 deployment) every member registers, so the whole cluster collapses onto
 the mesh.
 

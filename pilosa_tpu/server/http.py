@@ -450,17 +450,17 @@ class Handler(BaseHTTPRequestHandler):
         ex = getattr(self.api, "executor", None)
         if ex is not None:
             snap["serving_cache"] = {
-                "gram_hits": ex.gram_cache_hits,
-                "rowcount_hits": ex.rowcount_cache_hits,
-                "crossgram_hits": ex.crossgram_cache_hits,
-                "bsi_agg_hits": ex.bsi_agg_cache_hits,
-                "stack_rebuilds": ex.stack_rebuilds,
-                "stack_incremental": ex.stack_incremental,
+                "gram_hits": ex.stacks.gram_hits,
+                "rowcount_hits": ex.stacks.rowcount_hits,
+                "crossgram_hits": ex.stacks.crossgram_hits,
+                "bsi_agg_hits": ex.stacks.bsi_agg_hits,
+                "stack_rebuilds": ex.stacks.rebuilds,
+                "stack_incremental": ex.stacks.incremental,
                 "bsi_stack_launches": ex.bsi_stack_launches,
-                # stacks not built, by reason, and flight items a batch
-                # lane handed back to the per-call path, by lane and
-                # reason (exec/executor.py)
-                "stack_refusals": dict(ex.stack_refusals),
+                # stacks not built, by reason (exec/stacks.py), and
+                # flight items a batch lane handed back to the per-call
+                # path, by lane and reason (exec/executor.py)
+                "stack_refusals": dict(ex.stacks.refusals),
                 "lane_declines": {
                     lane: dict(by_reason)
                     for lane, by_reason in ex.lane_declines.items()

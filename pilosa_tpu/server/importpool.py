@@ -79,7 +79,7 @@ class ImportPool:
         self._local = threading.local()
         self._closed = False
         self.stats = stats
-        # submit-side counters (read by /debug/vars and the bench)
+        # submit-side counters (read by /debug/vars)
         self.blocked_submits = 0
         self.blocked_seconds = 0.0
         self.jobs_run = 0

@@ -5,14 +5,14 @@ The batcher's admission queue is an oracle the storage tier never had:
 at window close (and at every submit) the full (index, query, shards)
 set of an upcoming flight is known before any kernel launches.  This
 module resolves that set to the *field stacks* the batched dispatch will
-consume (exec/executor.py ``_field_stack`` — the serving tier's
+consume (exec/stacks.py ``Stacks.get`` — the serving tier's
 device-resident unit; per-call reads answer from host mirrors), filters
 to the ones not currently cached, and rides them onto the ingest
 ``DeviceUploader``'s low-priority queue (ingest/pipeline.py) — the H2D
 build overlaps the in-flight dispatch instead of stalling the next one.
 Everything here is advisory and bounded:
 
-* resolution never takes a stack lock (the ``_stack_cached`` peek is
+* resolution never takes a stack lock (the ``Stacks.cached`` peek is
   racy by design; a stale read costs at most a wasted, booked build);
 * fully-resident processes skip the whole path (a budget with no cap
   can never evict, so there is nothing to predict);
@@ -21,8 +21,8 @@ Everything here is advisory and bounded:
   behavior.
 
 Accounting flows through core/residency.py: issued at submit, useful on
-the first query hit against a prefetch-built stack (the lane-level bar
-is useful/issued >= 0.5, bench.py residency lane).
+the first query hit against a prefetch-built stack (useful/issued is the
+lane-level proof that staging pays).
 """
 
 from __future__ import annotations
@@ -40,7 +40,7 @@ MAX_TARGETS_PER_FLIGHT = 32
 
 # Once a stack is staged, don't re-issue it for this long: the uploader
 # dedups keys while they sit in its queue, but between dequeue and the
-# build landing in the cache the racy ``_stack_cached`` peek reads cold
+# build landing in the cache the racy ``Stacks.cached`` peek reads cold
 # and a burst would book one issued-but-wasted build per submit.  Kept
 # short — it only needs to cover that dequeue->landed gap; anything
 # longer blocks legitimate RE-staging after the budget evicts the stack
@@ -91,7 +91,7 @@ class _StackTarget:
         self.prefetch_key = (id(field), tuple(shards), view)
 
     def device_bits(self):
-        self.executor.prefetch_stack(self.field, self.shards, self.view)
+        self.executor.stacks.prefetch(self.field, self.shards, self.view)
 
 
 def stack_pairs_of_query(idx, query) -> list[tuple[str, str]]:
@@ -157,7 +157,7 @@ class FlightPrefetcher:
             if field is None or field.view(vname) is None:
                 continue
             # racy peek by design: a stale read costs one wasted build
-            if self.executor._stack_cached(field, shard_list, vname):
+            if self.executor.stacks.cached(field, shard_list, vname):
                 continue
             yield _StackTarget(self.executor, field, shard_list, vname)
 

@@ -240,21 +240,20 @@ class TestBSIStacks:
         got = ex.execute("i", "Sum(Row(tag=1), field=v)")[0]
         # fallback path: stack disabled
         ex2 = type(ex)(ex.holder)
-        ex2._bsi_stack = lambda *a, **k: None
+        ex2.stacks.bsi = lambda *a, **k: None
         want = ex2.execute("i", "Sum(Row(tag=1), field=v)")[0]
         assert got == want
         assert got.value == sum(self.vals[c] for c in cols)
 
     def test_stack_declines_over_budget_falls_back(self, ex3, monkeypatch):
-        import pilosa_tpu.exec.executor as exmod
+        from pilosa_tpu.exec import stacks
 
         _, ex = ex3
-        monkeypatch.setattr(exmod, "_STACK_BUDGET_BYTES", 0)
-        # fresh field dict: drop any cached stack
+        monkeypatch.setattr(stacks, "STACK_BUDGET_BYTES", 0)
+        # drop any cached stack
         idx_obj = ex.holder.index("i")
         f = idx_obj.field("v")
-        if hasattr(f, "_stack_caches"):
-            f._stack_caches.clear()
+        stacks.drop(f)
         res = ex.execute("i", "Range(v >= 250)")[0]
         want = {c for c, v in self.vals.items() if v >= 250}
         assert set(res.columns().tolist()) == want
@@ -281,15 +280,17 @@ class TestBSIStacks:
         )
         f = ex.holder.index("i").field("w")
         f.import_values([1, 2], [3, 7])  # depth grows to observed values
+        shards = ex._shards_for(ex.holder.index("i"), None)
+        view, small = f.bsi_view_name(), 2 + f.bit_depth
         ex.execute("i", "Range(w < 5)")  # build stack at small depth
-        keys_before = set(f._stack_caches)
+        assert ex.stacks.cached(f, shards, view, small)
         f.import_values([3], [100000])  # depth grows (reference
         # field.go:1050-1067 bitDepth autogrow on import)
         res = ex.execute("i", "Range(w < 5)")[0]  # rebuild at grown depth
         assert set(res.columns().tolist()) == {1}
-        bsi_keys = [k for k in f._stack_caches if k[3] is not None]
-        assert len(bsi_keys) == 1  # old-depth entry purged
-        assert bsi_keys[0] not in keys_before
+        assert 2 + f.bit_depth > small and ex.stacks.bsi_cached(f, shards)
+        # old-depth entry purged
+        assert not ex.stacks.cached(f, shards, view, small)
 
 
 class TestBSIAggServing:
@@ -324,12 +325,12 @@ class TestBSIAggServing:
         _, ex = ex3
         first = ex.execute("i", "Sum(field=v)Min(field=v)Max(field=v)")
         launches = ex.bsi_stack_launches
-        hits = ex.bsi_agg_cache_hits
+        hits = ex.stacks.bsi_agg_hits
         for _ in range(3):
             again = ex.execute("i", "Sum(field=v)Min(field=v)Max(field=v)")
             assert again == first
         assert ex.bsi_stack_launches == launches  # no further device work
-        assert ex.bsi_agg_cache_hits >= hits + 9
+        assert ex.stacks.bsi_agg_hits >= hits + 9
 
     def test_write_invalidates_cached_aggregates(self, ex3):
         _, ex = ex3
@@ -393,7 +394,7 @@ class TestRangeCountServing:
         ]:
             assert ex.execute("i", op)[0] == want
         launches = ex.bsi_stack_launches
-        hits = ex.bsi_agg_cache_hits
+        hits = ex.stacks.bsi_agg_hits
         for op, want in [
             ("Count(Row(v < 50))", sum(1 for v in self.vals.values() if v < 50)),
             ("Count(Row(v >= -10))", sum(1 for v in self.vals.values() if v >= -10)),
@@ -402,7 +403,7 @@ class TestRangeCountServing:
             for _ in range(2):
                 assert ex.execute("i", op)[0] == want
         assert ex.bsi_stack_launches == launches
-        assert ex.bsi_agg_cache_hits >= hits + 6
+        assert ex.stacks.bsi_agg_hits >= hits + 6
 
     def test_distinct_bounds_cached_separately(self, ex2):
         _, ex = ex2

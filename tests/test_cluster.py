@@ -452,11 +452,10 @@ def _delayed_client(dist, delay):
 
 def test_parallel_node_fanout():
     """Remote nodes are queried concurrently, not serially: with an
-    injected per-remote-call delay, total query wall time stays under
-    the sum of delays (reference goroutine-per-node mapper,
-    executor.go:2520-2573)."""
-    import time
-
+    injected per-remote-call delay, the calls to two remote nodes are in
+    flight at once (reference goroutine-per-node mapper,
+    executor.go:2520-2573).  Overlap is counted, not timed: a wall-clock
+    bound fails on a loaded machine with nothing wrong."""
     # mesh_dispatch=False: this test measures HTTP fan-out concurrency;
     # mesh-local dispatch would answer without any remote calls to overlap
     with InProcessCluster(3, replica_n=1, mesh_dispatch=False) as c:
@@ -470,23 +469,16 @@ def test_parallel_node_fanout():
         )
         dist = c.nodes[coord].api.dist
         assert dist is not None
-        delay = 0.75
-        with _delayed_client(dist, delay) as stats:
-            t0 = time.monotonic()
+        with _delayed_client(dist, 0.75) as stats:
             res = c.query(coord, "pf", "Count(Row(f=0))")
-            wall = time.monotonic() - t0
         assert res["results"][0] == 12
-        # concurrency proven deterministically by overlap; the wall bound
-        # (serial would be >= 2*delay) has slack for loaded machines
         assert stats["max_inflight"] >= 2, "remote queries never overlapped"
-        assert wall < 2 * delay, f"fan-out serialized: wall={wall:.2f}s"
 
 
 def test_parallel_replica_write_fanout():
     """Point writes hit every replica concurrently (reference
-    executor.go:2140-2207 fans replica writes)."""
-    import time
-
+    executor.go:2140-2207 fans replica writes): the two remote replicas'
+    writes are in flight at once, and every replica holds the bit."""
     with InProcessCluster(3, replica_n=3) as c:
         c.create_index("pw")
         c.create_field("pw", "f")
@@ -494,15 +486,10 @@ def test_parallel_replica_write_fanout():
             i for i, n in enumerate(c.nodes) if n.node_id == c.coordinator_id
         )
         dist = c.nodes[coord].api.dist
-        delay = 0.75
-        with _delayed_client(dist, delay) as stats:
-            t0 = time.monotonic()
+        with _delayed_client(dist, 0.75) as stats:
             res = c.query(coord, "pw", "Set(3, f=7)")
-            wall = time.monotonic() - t0
         assert res["results"][0] is True
-        assert stats["max_inflight"] >= 2
-        # 2 remote replicas: serial write fan would take >= 2*delay
-        assert wall < 2 * delay, f"write fan serialized: wall={wall:.2f}s"
+        assert stats["max_inflight"] >= 2, "replica writes never overlapped"
         # the write really landed everywhere
         for n in c.nodes:
             frag = n.holder.fragment("pw", "f", "standard", 0)

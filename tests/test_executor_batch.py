@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from pilosa_tpu.core.holder import Holder
+from pilosa_tpu.exec import stacks
 from pilosa_tpu.exec.executor import Executor
 
 
@@ -159,7 +160,7 @@ def test_interleaved_writes_update_stack_incrementally(setup):
     h, ex = setup
     q = _pairs_query([(0, 1), (2, 3)])
     ex.execute("i", q)  # build + cache the stack
-    rebuilds0 = ex.stack_rebuilds
+    rebuilds0 = ex.stacks.rebuilds
     width = h.n_words * 32
     for i in range(4):
         # rows 0/1 already exist in shard 0; no new rows => incremental
@@ -167,8 +168,8 @@ def test_interleaved_writes_update_stack_incrementally(setup):
         got = ex.execute("i", q)
         want = [ex.execute("i", _pairs_query([p]))[0] for p in [(0, 1), (2, 3)]]
         assert got == want
-    assert ex.stack_incremental >= 4
-    assert ex.stack_rebuilds == rebuilds0  # no full re-upload happened
+    assert ex.stacks.incremental >= 4
+    assert ex.stacks.rebuilds == rebuilds0  # no full re-upload happened
 
 
 def test_two_shard_sets_keep_separate_cache_entries(setup):
@@ -178,12 +179,12 @@ def test_two_shard_sets_keep_separate_cache_entries(setup):
     q = _pairs_query([(0, 1), (2, 3)])
     ex.execute("i", q)
     ex.execute("i", q, shards=[0])
-    r0 = ex.stack_rebuilds
+    r0 = ex.stacks.rebuilds
     # both entries warm: neither call rebuilds
     ex.execute("i", q)
     ex.execute("i", q, shards=[0])
     ex.execute("i", q)
-    assert ex.stack_rebuilds == r0
+    assert ex.stacks.rebuilds == r0
 
 
 def test_new_row_forces_full_rebuild(setup):
@@ -192,11 +193,11 @@ def test_new_row_forces_full_rebuild(setup):
     _, ex = setup
     q = _pairs_query([(0, 1), (2, 3)])
     ex.execute("i", q)
-    r0 = ex.stack_rebuilds
+    r0 = ex.stacks.rebuilds
     ex.execute("i", "Set(77, f=40)")  # row 40 did not exist
     got = ex.execute("i", q + " Count(Intersect(Row(f=40), Row(f=40)))")
     assert got[2] == 1
-    assert ex.stack_rebuilds == r0 + 1
+    assert ex.stacks.rebuilds == r0 + 1
 
 
 def test_groupby_fast_path_matches_recursive(setup):
@@ -239,14 +240,12 @@ def test_filtered_topn_matches_per_fragment(setup):
     fast = ex.execute("i", q)[0]
     # force the per-fragment path by disabling the stack
     field = h.index("i").field("f")
-    from pilosa_tpu.exec import executor as ex_mod
-
-    old = ex_mod.Executor._field_stack
+    old = stacks.Stacks.get
     try:
-        ex_mod.Executor._field_stack = lambda self, f, s: None
+        stacks.Stacks.get = lambda self, f, s: None
         slow = ex.execute("i", q)[0]
     finally:
-        ex_mod.Executor._field_stack = old
+        stacks.Stacks.get = old
     assert [(p.id, p.count) for p in fast] == [(p.id, p.count) for p in slow]
     assert fast  # non-trivial
 
@@ -255,14 +254,12 @@ def test_filtered_topn_tanimoto_matches(setup):
     h, ex = setup
     q = "TopN(f, Row(g=1), n=6, tanimotoThreshold=5)"
     fast = ex.execute("i", q)[0]
-    from pilosa_tpu.exec import executor as ex_mod
-
-    old = ex_mod.Executor._field_stack
+    old = stacks.Stacks.get
     try:
-        ex_mod.Executor._field_stack = lambda self, f, s: None
+        stacks.Stacks.get = lambda self, f, s: None
         slow = ex.execute("i", q)[0]
     finally:
-        ex_mod.Executor._field_stack = old
+        stacks.Stacks.get = old
     assert [(p.id, p.count) for p in fast] == [(p.id, p.count) for p in slow]
 
 
@@ -304,7 +301,6 @@ class TestGramCache:
         full gram is only invested after observed reuse on one
         snapshot."""
         from pilosa_tpu.ops import kernels
-        from pilosa_tpu.exec.executor import Executor
 
         _, ex = setup
         seen = []
@@ -315,7 +311,7 @@ class TestGramCache:
             return orig(bits, rows, *a, **k)
 
         monkeypatch.setattr(kernels, "pair_gram", recording)
-        monkeypatch.setattr(Executor, "_GRAM_CACHE_MIN_REUSE", 2)
+        monkeypatch.setattr(stacks, "GRAM_CACHE_MIN_REUSE", 2)
         q = _pairs_query([(0, 1), (1, 0)])  # 2 of 6 rows: a small subset
         ex.execute("i", q)
         assert seen and seen[-1] == 2  # subset gram, not full
@@ -340,10 +336,10 @@ class TestSinglePairServing:
         want = ex.execute("i", q)[0]
         # enough repeats to pass the warm-up threshold and the gram's
         # observed-reuse investment gate
-        for _ in range(ex._PAIR_SINGLE_WARM + ex._GRAM_CACHE_MIN_REUSE + 2):
+        for _ in range(ex._PAIR_SINGLE_WARM + stacks.GRAM_CACHE_MIN_REUSE + 2):
             assert ex.execute("i", q)[0] == want
-        assert ex.gram_cache_hits >= 1
-        hits, rebuilds = ex.gram_cache_hits, ex.stack_rebuilds
+        assert ex.stacks.gram_hits >= 1
+        hits, rebuilds = ex.stacks.gram_hits, ex.stacks.rebuilds
         # steady state: every further single is a pure host cache hit —
         # no stack rebuild, correct answers for other pairs too
         q2 = "Count(Union(Row(f=2), Row(f=3)))"
@@ -351,8 +347,8 @@ class TestSinglePairServing:
         for _ in range(3):
             assert ex.execute("i", q)[0] == want
             assert ex.execute("i", q2)[0] == want2
-        assert ex.gram_cache_hits >= hits + 6
-        assert ex.stack_rebuilds == rebuilds
+        assert ex.stacks.gram_hits >= hits + 6
+        assert ex.stacks.rebuilds == rebuilds
 
     def test_cold_singles_stay_on_per_call_path(self, setup):
         """A few one-off pair counts must NOT pay the stack build."""
@@ -360,14 +356,14 @@ class TestSinglePairServing:
         q = "Count(Intersect(Row(f=0), Row(f=1)))"
         for _ in range(2):
             ex.execute("i", q)
-        assert ex.stack_rebuilds == 0
+        assert ex.stacks.rebuilds == 0
 
     def test_write_invalidates_served_gram(self, setup):
         """A write between served singles must be visible (the gram is
         keyed to the stack snapshot, never stale)."""
         _, ex = setup
         q = "Count(Intersect(Row(f=0), Row(f=1)))"
-        for _ in range(ex._PAIR_SINGLE_WARM + ex._GRAM_CACHE_MIN_REUSE + 2):
+        for _ in range(ex._PAIR_SINGLE_WARM + stacks.GRAM_CACHE_MIN_REUSE + 2):
             before = ex.execute("i", q)[0]
         # add a column present in both rows: count must rise by 1
         free = 777_777
@@ -459,9 +455,10 @@ class TestTopNServing:
         for _ in range(3):
             ex.execute("i", q)
         field = h.index("i").field("f")
-        entries = list(vars(field)["_stack_caches"].values())
-        entry = next(e for e in entries if e.get("gram"))
-        entry.pop("rowcounts", None)
+        stack = ex.stacks.get(field, ex._shards_for(h.index("i"), None))
+        bits = stack.bits
+        gram = stack.get("gram", bits)
+        assert gram is not None and stack.get("rowcounts", bits) is None
         monkeypatch.setattr(
             kernels,
             "row_counts",
@@ -469,10 +466,8 @@ class TestTopNServing:
                 "must serve from the cached gram diagonal"
             ),
         )
-        rc = ex._stack_row_counts(field, entry["dev"])
-        import numpy as np
-
-        assert np.array_equal(rc, np.diag(entry["gram"][1]).astype(np.int64))
+        rc = ex.stacks.row_counts(stack, bits)
+        assert np.array_equal(rc, np.diag(gram).astype(np.int64))
 
     def test_write_invalidates_served_topn(self, setup):
         _, ex = setup
@@ -495,15 +490,15 @@ class TestGroupByCrossGramServing:
         q = "GroupBy(Rows(f), Rows(g))"
         want = ex.execute("i", q)[0]
         # warm past the observed-reuse investment gate
-        for _ in range(ex._GRAM_CACHE_MIN_REUSE + 2):
+        for _ in range(stacks.GRAM_CACHE_MIN_REUSE + 2):
             assert ex.execute("i", q)[0] == want
-        hits = ex.crossgram_cache_hits
+        hits = ex.stacks.crossgram_hits
         for _ in range(3):
             assert ex.execute("i", q)[0] == want
-        assert ex.crossgram_cache_hits >= hits + 3
+        assert ex.stacks.crossgram_hits >= hits + 3
         # the reversed field order must serve from the SAME cached gram,
         # transposed, without a second device investment
-        hits = ex.crossgram_cache_hits
+        hits = ex.stacks.crossgram_hits
         rev = {
             tuple(sorted((fr.field, fr.row_id) for fr in gc.group)): gc.count
             for gc in ex.execute("i", "GroupBy(Rows(g), Rows(f))")[0]
@@ -513,14 +508,14 @@ class TestGroupByCrossGramServing:
             for gc in ex.execute("i", q)[0]
         }
         assert rev == fwd
-        assert ex.crossgram_cache_hits >= hits + 2
+        assert ex.stacks.crossgram_hits >= hits + 2
 
     def test_write_to_second_field_invalidates(self, setup):
         """The cross gram is keyed to BOTH snapshots: a write to the
         second field must be visible immediately."""
         h, ex = setup
         q = "GroupBy(Rows(f), Rows(g))"
-        for _ in range(ex._GRAM_CACHE_MIN_REUSE + 3):
+        for _ in range(stacks.GRAM_CACHE_MIN_REUSE + 3):
             before = {
                 tuple((fr.field, fr.row_id) for fr in gc.group): gc.count
                 for gc in ex.execute("i", q)[0]
@@ -553,14 +548,14 @@ class TestGroupByCrossGramServing:
         qa, qb = "GroupBy(Rows(f), Rows(g))", "GroupBy(Rows(f), Rows(h))"
         wa = ex.execute("i", qa)[0]
         wb = ex.execute("i", qb)[0]
-        for _ in range(ex._GRAM_CACHE_MIN_REUSE + 2):
+        for _ in range(stacks.GRAM_CACHE_MIN_REUSE + 2):
             assert ex.execute("i", qa)[0] == wa
             assert ex.execute("i", qb)[0] == wb
-        hits = ex.crossgram_cache_hits
+        hits = ex.stacks.crossgram_hits
         for _ in range(3):
             assert ex.execute("i", qa)[0] == wa
             assert ex.execute("i", qb)[0] == wb
-        assert ex.crossgram_cache_hits >= hits + 6  # both served
+        assert ex.stacks.crossgram_hits >= hits + 6  # both served
 
     def test_cached_cross_gram_does_not_pin_partner_stack(self, setup):
         """The slot holds the partner snapshot weakly: dropping the
@@ -572,14 +567,13 @@ class TestGroupByCrossGramServing:
         h_, ex = setup
         q = "GroupBy(Rows(f), Rows(g))"
         want = ex.execute("i", q)[0]
-        for _ in range(ex._GRAM_CACHE_MIN_REUSE + 2):
+        for _ in range(stacks.GRAM_CACHE_MIN_REUSE + 2):
             ex.execute("i", q)
         g_field = h_.index("i").field("g")
-        caches = vars(g_field)["_stack_caches"]
-        [gentry] = list(caches.values())
-        ref = wr.ref(gentry["dev"])
-        caches.clear()  # budget-evict g's stack entry
-        del gentry
+        gstack = ex.stacks.get(g_field, ex._shards_for(h_.index("i"), None))
+        ref = wr.ref(gstack.bits)
+        stacks.drop(g_field)  # as a budget eviction would
+        del gstack
         gc.collect()
         assert ref() is None  # nothing pins the retired device stack
         assert ex.execute("i", q)[0] == want  # recomputes, still right
@@ -638,29 +632,25 @@ class TestSpanningMeshDecline:
         monkeypatch.setattr(kernels, "pair_count_two_batched", boom)
 
     def test_pair_scan_declines_to_per_call(self, setup, monkeypatch):
-        from pilosa_tpu.exec.executor import Executor
-
         _, ex = setup
         pairs = [(0, 1), (2, 3), (4, 5)]
         want = [ex.execute("i", _pairs_query([p]))[0] for p in pairs]
         # gram declines (as if > GRAM_MAX_ROWS distinct rows) ...
         monkeypatch.setattr(
-            Executor, "_field_gram", lambda self, f, bits, uniq: (None, None)
+            stacks.Stacks, "gram", lambda self, f, st, bits, uniq: (None, None)
         )
         # ... and the mocked mesh rejects the scan lane too
         self._force_unsupported(monkeypatch)
         assert ex.execute("i", _pairs_query(pairs)) == want
 
     def test_groupby_batch_declines_to_recursion(self, setup, monkeypatch):
-        from pilosa_tpu.exec.executor import Executor
-
         _, ex = setup
         q = "GroupBy(Rows(f), Rows(g))"
         want = ex.execute("i", q)[0]
         assert want  # non-trivial combos
         monkeypatch.setattr(
-            Executor, "_field_gram", lambda self, f, bits, uniq: (None, None)
+            stacks.Stacks, "gram", lambda self, f, st, bits, uniq: (None, None)
         )
-        monkeypatch.setattr(Executor, "_cross_gram", lambda *a, **k: None)
+        monkeypatch.setattr(stacks.Stacks, "cross_gram", lambda *a, **k: None)
         self._force_unsupported(monkeypatch)
         assert ex.execute("i", q)[0] == want

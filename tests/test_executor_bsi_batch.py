@@ -111,11 +111,11 @@ def test_range_count_served_from_agg_cache(setup):
     q = "Count(Row(v < 77)) Count(Row(v > 5))"
     first = ex.execute("i", q)
     before = ex.bsi_stack_launches
-    hits0 = ex.bsi_agg_cache_hits
+    hits0 = ex.stacks.bsi_agg_hits
     second = ex.execute("i", q)
     assert second == first
     assert ex.bsi_stack_launches == before  # both served from cache
-    assert ex.bsi_agg_cache_hits > hits0
+    assert ex.stacks.bsi_agg_hits > hits0
 
 
 def test_batcher_coalesces_concurrent_bsi_reads(setup):

@@ -221,7 +221,7 @@ class TestBSIHostTier:
             got = set(ex.execute("i", q)[0].columns().tolist())
             assert got == want, q
         # the cold queries above must NOT have built the device stack
-        assert not ex._bsi_stack_live(
+        assert not ex.stacks.bsi_cached(
             field, ex._shards_for(ex.holder.index("i"), None)
         )
 
@@ -234,7 +234,7 @@ class TestBSIHostTier:
         for _ in range(ex._BSI_SINGLE_WARM + 3):
             assert ex.execute("i", q)[0] == want
         field = ex.holder.index("i").field("v")
-        assert ex._bsi_stack_live(
+        assert ex.stacks.bsi_cached(
             field, ex._shards_for(ex.holder.index("i"), None)
         )
 

@@ -11,6 +11,7 @@ import pytest
 
 from pilosa_tpu.core import membudget
 from pilosa_tpu.core.holder import Holder
+from pilosa_tpu.exec import stacks
 from pilosa_tpu.exec.executor import Executor
 
 
@@ -225,13 +226,13 @@ def test_field_stack_respects_budget_and_evicts(restore_budget):
     field = h.index("i").field("f")
     # generous budget: stack builds and is accounted
     budget = membudget.configure(64 << 20)
-    stack = ex._field_stack(field, shards)
+    stack = ex.stacks.get(field, shards)
     assert stack is not None
     assert budget.used() > 0
     # tiny budget: stack declines, cache cleared on next eviction pressure
     membudget.configure(1024)
-    field._stack_caches = {}
-    assert ex._field_stack(field, shards) is None
+    stacks.drop(field)
+    assert ex.stacks.get(field, shards) is None
 
 
 # ---------------------------------------------------------------------------
@@ -476,7 +477,7 @@ def test_concurrent_admit_touch_evict_storm_accounting_exact():
 
 
 def test_concurrent_stack_cache_hit_vs_evict_no_leak(restore_budget):
-    """exec/executor.py stack-cache storm: concurrent _field_stack hits
+    """exec/stacks.py stack-cache storm: concurrent Stacks.get hits
     against budget evictions triggered by other fields' builds must not
     leak budget bytes or resurrect evicted entries — releasing every
     surviving cache entry at the end must zero the budget."""
@@ -508,7 +509,7 @@ def test_concurrent_stack_cache_hit_vs_evict_no_leak(restore_budget):
         for _ in range(40):
             field = idx.field(f"f{r.randrange(n_fields)}")
             try:
-                ex._field_stack(field, shards)
+                ex.stacks.get(field, shards)
             except Exception as e:  # pragma: no cover
                 errors.append(e)
 
@@ -524,9 +525,5 @@ def test_concurrent_stack_cache_hit_vs_evict_no_leak(restore_budget):
     assert budget.snapshot()["evictErrors"] == 0
     # exact accounting: every surviving entry released -> zero bytes
     for fi in range(n_fields):
-        field = idx.field(f"f{fi}")
-        caches = getattr(field, "_stack_caches", {})
-        for entry in list(caches.values()):
-            budget.release(entry["bkey"])
-        caches.clear()
+        stacks.drop(idx.field(f"f{fi}"))
     assert budget.used() == 0

@@ -55,9 +55,9 @@ def test_field_stack_is_mesh_sharded(setup):
     h, ex = setup
     field = h.index("i").field("f")
     shards = sorted(h.index("i").available_shards())
-    stack = ex._field_stack(field, shards)
+    stack = ex.stacks.get(field, shards)
     assert stack is not None
-    _, bits = stack
+    bits = stack.bits
     assert len(bits.sharding.device_set) == len(jax.devices())
     assert kernels.shards_axis_of(bits) is not None
     # the shard axis padded to a mesh multiple

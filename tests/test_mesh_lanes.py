@@ -12,7 +12,7 @@ import pytest
 
 from pilosa_tpu.core.field import FieldOptions
 from pilosa_tpu.core.holder import Holder
-from pilosa_tpu.exec import executor as executor_mod
+from pilosa_tpu.exec import stacks
 from pilosa_tpu.exec.executor import Executor
 from pilosa_tpu.obs import devledger, tracing
 from pilosa_tpu.parallel import mesh
@@ -250,29 +250,29 @@ def test_every_stack_lies_on_the_four_devices(served):
     ex, _, _, _ = served
     idx = ex.holder.index("taxi")
     shards = list(range(SHARDS))
-    stacks = [ex._field_stack(idx.field(name), shards)[1] for name in FIELDS]
-    stacks.append(ex._bsi_stack(idx.field("total_amount"), shards))
-    for bits in stacks:
+    on_device = [ex.stacks.get(idx.field(name), shards).bits for name in FIELDS]
+    on_device.append(ex.stacks.bsi(idx.field("total_amount"), shards).bits)
+    for bits in on_device:
         assert bits.shape[0] == SHARDS and len(bits.sharding.device_set) == DEVICES
         assert {s.data.shape[0] for s in bits.addressable_shards} == {SHARDS // DEVICES}
-    assert ex.stack_refusals["array_budget"] == ex.stack_refusals["hbm_budget"] == 0
+    assert ex.stacks.refusals["array_budget"] == ex.stacks.refusals["hbm_budget"] == 0
 
 
 # -------------------------------------------------------------- stack budget
 
 @pytest.fixture()
 def small_budget(rides, monkeypatch):
-    """``_STACK_BUDGET_BYTES`` between a quarter of ``drop_grid_id``'s stack
+    """``STACK_BUDGET_BYTES`` between a quarter of ``drop_grid_id``'s stack
     and the whole of it."""
     h, _ = rides
     field = h.index("taxi").field("drop_grid_id")
     whole = SHARDS * FIELDS["drop_grid_id"] * field.n_words * 4
-    monkeypatch.setattr(executor_mod, "_STACK_BUDGET_BYTES", whole // 2)
-    vars(field).pop("_stack_caches", None)
+    monkeypatch.setattr(stacks, "STACK_BUDGET_BYTES", whole // 2)
+    stacks.drop(field)
     try:
         yield h, field, whole
     finally:
-        vars(field).pop("_stack_caches", None)
+        stacks.drop(field)
         mesh.configure_serving(None)
 
 
@@ -281,15 +281,16 @@ def test_budget_holds_against_a_devices_share(small_budget):
     shards = list(range(SHARDS))
     mesh.configure_serving(1)
     one = Executor(h, rescache_entries=0)
-    assert one._field_stack(field, shards) is None
-    assert one.stack_refusals == {"array_budget": 1, "hbm_budget": 0, "demand": 0}
+    assert one.stacks.get(field, shards) is None
+    assert one.stacks.refusals == {"array_budget": 1, "hbm_budget": 0, "demand": 0}
     mesh.configure_serving(DEVICES)
     ex = Executor(h, rescache_entries=0)
     before = tracing.spans_snapshot()["executor"]["stackBuild"]["count"]
-    slot_of, bits = ex._field_stack(field, shards)
+    stack = ex.stacks.get(field, shards)
+    slot_of, bits = stack.slot_of, stack.bits
     assert len(slot_of) == FIELDS["drop_grid_id"] and bits.nbytes == whole
     assert len(bits.sharding.device_set) == DEVICES
-    assert ex.stack_refusals["array_budget"] == 0
+    assert ex.stacks.refusals["array_budget"] == 0
     assert tracing.spans_snapshot()["executor"]["stackBuild"]["count"] == before + 1
 
 
@@ -301,13 +302,13 @@ def test_lane_hands_back_under_budget_when_its_stack_is_refused(small_budget):
     got = ex.execute("taxi", f"{q} {q}")
     assert got[0] == got[1] > 0
     assert ex.lane_declines["general"]["budget"] == 2
-    assert ex.stack_refusals["array_budget"] >= 1
+    assert ex.stacks.refusals["array_budget"] >= 1
 
 
 def test_host_side_of_a_build_holds_one_devices_share(rides, monkeypatch):
     h, data = rides
     field = h.index("taxi").field("pickup_grid_id")
-    vars(field).pop("_stack_caches", None)
+    stacks.drop(field)
     whole = SHARDS * FIELDS["pickup_grid_id"] * field.n_words * 4
     sizes = []
     zeros = np.zeros
@@ -317,11 +318,11 @@ def test_host_side_of_a_build_holds_one_devices_share(rides, monkeypatch):
         sizes.append(out.nbytes)
         return out
 
-    monkeypatch.setattr(executor_mod.np, "zeros", watched)
+    monkeypatch.setattr(stacks.np, "zeros", watched)
     try:
         mesh.configure_serving(DEVICES)
         ex = Executor(h, rescache_entries=0)
-        _, bits = ex._field_stack(field, list(range(SHARDS)))
+        bits = ex.stacks.get(field, list(range(SHARDS))).bits
         monkeypatch.undo()
         assert sizes and max(sizes) == whole // DEVICES and sum(sizes) == whole
         # and what was put is the field: row 3's rides, shard by shard
@@ -329,5 +330,5 @@ def test_host_side_of_a_build_holds_one_devices_share(rides, monkeypatch):
         want = np.packbits(data["pickup_grid_id"] == 3, bitorder="little")
         assert np.array_equal(got.reshape(-1), want)
     finally:
-        vars(field).pop("_stack_caches", None)
+        stacks.drop(field)
         mesh.configure_serving(None)

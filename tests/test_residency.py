@@ -345,7 +345,7 @@ def test_prefetcher_stages_and_scores_useful_under_cap(clean_residency):
         cold_fi = next(
             fi
             for fi in range(n_fields)
-            if not api.executor._stack_cached(
+            if not api.executor.stacks.cached(
                 idx.field(f"f{fi}"), shard_list, "standard"
             )
         )

@@ -196,7 +196,7 @@ def test_histogram_snapshot_carries_inf_overflow_bucket():
 def test_histogram_buckets_resolve_sub_millisecond():
     from pilosa_tpu.obs.stats import HISTOGRAM_BUCKETS
 
-    # the serving floor is 0.07-0.16 ms/op (BENCH_r05); bucket edges
+    # host-served reads finish well under a millisecond; bucket edges
     # below 1 ms keep those observations distinguishable
     sub_ms = [b for b in HISTOGRAM_BUCKETS if b < 0.001]
     assert len(sub_ms) >= 4

@@ -155,7 +155,10 @@ class TestFragmentFile:
         # the reference's own sample fragment file (testdata/sample_view/0);
         # decoded read-only (never attach a FragmentFile to the read-only
         # reference mount)
-        data = open("/root/reference/testdata/sample_view/0", "rb").read()
+        path = "/root/reference/testdata/sample_view/0"
+        if not os.path.exists(path):
+            pytest.skip(f"the reference checkout is not mounted: {path}")
+        data = open(path, "rb").read()
         positions = roaring.deserialize(data)
         assert len(positions) == 35001
         # round-trip through our serializer preserves the bit set

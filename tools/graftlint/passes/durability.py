@@ -6,8 +6,8 @@ shutdown).  ``os.replace``/``os.rename`` of freshly written bytes is
 only atomic-AND-durable if those bytes were fsync'd first — otherwise a
 power cut can leave the renamed file empty or torn.  Likewise a
 ``close()`` that hands a data-file handle back to the OS without fsync
-leaves the tail of the op log in the page cache (the exact bug class of
-the round-5 ADVICE medium finding on FragmentFile.close).
+leaves the tail of the op log in the page cache (the bug
+FragmentFile.close once had; fixed in PR 1).
 
 Heuristics, per function in storage/:
 

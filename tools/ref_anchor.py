@@ -124,7 +124,7 @@ def bench_intersection_count(results: dict) -> None:
         "elements; repo: dense 2x128KB fused and+popcount — the dense "
         "layout streams 77x the bytes for a sparse lone pair; the "
         "framework serves repeats from the gram cache and batches on "
-        "the MXU instead (see serving_* in bench.py)",
+        "the MXU instead (PERF.md)",
     }
 
 
@@ -323,8 +323,8 @@ def update_baseline_md(results: dict, path: str) -> None:
         "",
         "repo/anchor > 1 means the repo is faster. The lone sparse",
         "IntersectionCount is the dense layout's worst case by design —",
-        "see docs/parity.md; batched and repeat serving regimes are",
-        "covered by bench.py's serving_* and batched figures.",
+        "see docs/parity.md; what the batched and repeat serving regimes",
+        "do on a chip is in PERF.md and PERF_LEDGER.jsonl.",
         "",
         MD_END,
     ]

@@ -9,8 +9,8 @@ actual HTTP, and asserts the working-set manager engaged end to end:
   under cap;
 * queries still answered correctly while stacks churned;
 * the flight-driven prefetcher **issued** predictive stagings, and a
-  prefetch-built stack scored a query **hit** (the useful half of the
-  ``useful/issued`` bar the bench lane holds at >= 0.5);
+  prefetch-built stack scored a query **hit** (the useful half of
+  ``useful/issued``);
 * the operator surfaces carry it: ``pilosa_device_*`` gauges in
   ``/metrics``, the ``residency`` + ``deviceBudget`` blocks in
   ``/debug/vars``, per-fragment tier/pin/heat in ``/debug/fragments``,
@@ -137,7 +137,7 @@ def main() -> int:
         cold = next(
             fi
             for fi in range(N_FIELDS)
-            if not node.api.executor._stack_cached(
+            if not node.api.executor.stacks.cached(
                 idx.field(f"f{fi}"), shard_list, "standard"
             )
         )
@@ -164,7 +164,7 @@ def main() -> int:
         cold2 = next(
             fi
             for fi in range(N_FIELDS)
-            if not node.api.executor._stack_cached(
+            if not node.api.executor.stacks.cached(
                 idx.field(f"f{fi}"), shard_list, "standard"
             )
         )
