@@ -370,11 +370,28 @@ class Executor:
         launch (server/batcher.py).  Returns the translated result list,
         or None when any call misses (the query then takes the normal
         path — the probe counts no miss twice since lookup tokens are
-        discarded)."""
+        discarded).
+
+        A request of one call whose index and fields hold no keys gets
+        the cached object itself (``rescache.Served``): translation
+        would write nothing, so the hit neither copies nor walks its
+        answer, and the listener may send the bytes an earlier hit left
+        with the entry.  The list is read-only by contract.  A request
+        of several calls, or one that collects a profile, takes the
+        copying path below."""
         idx = self.holder.index(index_name)
         if idx is None or not q.calls or q.write_calls():
             return None
         try:
+            if len(q.calls) == 1 and not qprofile.profiling():
+                call = q.calls[0].clone()
+                self._translate_call(idx, call)
+                hit = self.rescache.lookup_shared(idx, call, shards)
+                if hit is rescache.MISS:
+                    return None
+                if not isinstance(hit, rescache.Served):
+                    self._translate_result(idx, q.calls[0], hit[0])
+                return hit
             results = []
             for orig in q.calls:
                 call = orig.clone()

@@ -34,9 +34,18 @@ host reduce, not a device launch.  When the accumulated write delta
 entry demotes back to ordinary cache-on-miss and the next miss rebuilds
 it from scratch.
 
-Thread safety: one lock around the table; results are copied on hit
-(:func:`copy_result`) so callers can attach keys/attrs without
-mutating the cached object.
+Thread safety: one lock around the table, held for the table's own
+bookkeeping and never while an answer is walked.  A hit takes the
+reference under the lock and copies (:func:`copy_result`) after it is
+released, so callers can attach keys/attrs without mutating the cached
+object.  Where nothing downstream can write — the index and every field
+the call reads hold no keys (:meth:`ResultCache.lookup_shared`) — the
+hit hands the cached object out as it is, **read-only by contract**: a
+:class:`Served` list that the holder may read and encode for as long as
+it likes and must never write to.  The entry keeps the answer in two
+forms under one key and one invalidation: the result and, once a hit
+has been sent, the bytes it was sent as (``_Entry.held``, one tuple,
+so whatever replaces the result drops the bytes in the same statement).
 """
 
 from __future__ import annotations
@@ -228,7 +237,7 @@ class _Token:
 class _Entry:
     __slots__ = (
         "vector",
-        "result",
+        "held",
         "hits",
         "fields",
         "index_name",
@@ -246,6 +255,39 @@ class _Entry:
         self.recompute = recompute
         self.maintained = False
         self.delta_accum = 0
+
+    # ``held`` is ``(result, body)``: the answer and the encoded response
+    # a hit of it was sent as (None until one was).  One tuple, as
+    # ``Stack._snap`` (exec/stacks.py): a refresh that sets the result
+    # cannot leave the old result's bytes behind.
+    @property
+    def result(self):
+        return self.held[0]
+
+    @result.setter
+    def result(self, value) -> None:
+        self.held = (value, None)
+
+
+class Served(list):
+    """``[result]`` of a hit, uncopied: the cached object itself, handed
+    out because nothing the caller reaches can write to it (no keys on
+    the index or on any field the call reads).  Read-only by contract,
+    for as long as the holder likes: the cache never writes to a result
+    either, it replaces it.  ``body`` is the encoded response an earlier
+    hit of this result left with the entry, or None; the first holder to
+    encode it calls :meth:`remember`."""
+
+    __slots__ = ("body", "_cache", "_key")
+
+    def __init__(self, cache: "ResultCache", key: tuple, held: tuple):
+        super().__init__((held[0],))
+        self.body: bytes | None = held[1]
+        self._cache = cache
+        self._key = key
+
+    def remember(self, body: bytes) -> None:
+        self._cache._remember(self._key, self[0], body)
 
 
 class ResultCache:
@@ -280,6 +322,10 @@ class ResultCache:
         self.demotions = 0
         self.maintained_hits = 0
         self.degraded_hits = 0
+        # hits handed out uncopied (Served), and those of them that found
+        # the encoded body of an earlier hit with the entry
+        self.uncopied_hits = 0
+        self.encoded_hits = 0
         self.stores = 0
         self.evictions = 0
 
@@ -305,21 +351,58 @@ class ResultCache:
 
     # ----------------------------------------------------------- lookups
 
+    def _address(self, idx: Index, call: Call, shards: list[int] | None):
+        """``(key, vector, fields)`` of a cacheable call, else None."""
+        fields = collect_fields(idx, call)
+        if not fields:
+            return None
+        vec = version_vector(idx, fields, shards)
+        if not vec:
+            return None
+        return self._key(idx, call, shards), vec, fields
+
     def lookup(
         self, idx: Index, call: Call, shards: list[int] | None
     ) -> tuple[Any, _Token | None]:
         """Returns ``(result, None)`` on a hit, ``(MISS, token)`` on a
         cacheable miss (pass the token to :meth:`store` after
-        computing), and ``(MISS, None)`` when the call is uncacheable."""
-        fields = collect_fields(idx, call)
-        if not fields:
+        computing), and ``(MISS, None)`` when the call is uncacheable.
+        The result is the caller's own copy."""
+        addr = self._address(idx, call, shards)
+        if addr is None:
             return MISS, None
-        vec = version_vector(idx, fields, shards)
-        if not vec:
-            return MISS, None
-        key = self._key(idx, call, shards)
+        key, vec, fields = addr
         with qprofile.span("rescache.lookup", call=call.name):
-            return self._probe_locked(key, vec, fields, idx.name)
+            held, token = self._probe_locked(key, vec, fields, idx.name)
+            if held is MISS:
+                return MISS, token
+            return copy_result(held[0]), None
+
+    def lookup_shared(
+        self, idx: Index, call: Call, shards: list[int] | None
+    ) -> Any:
+        """The hit of a whole one-call request (``Executor.
+        rescache_probe``): :data:`MISS`, or the answer as the request's
+        result list.  Where the index and every field the call reads
+        hold no keys, result translation has nothing to attach and the
+        list is a :class:`Served`: the cached object, uncopied.
+        Otherwise it is a plain list holding the caller's own copy."""
+        addr = self._address(idx, call, shards)
+        if addr is None:
+            return MISS
+        key, vec, fields = addr
+        shared = not idx.keys and not any(
+            getattr(idx.field(f), "keys", False) for f in fields
+        )
+        with qprofile.span("rescache.lookup", call=call.name):
+            held, _tok = self._probe_locked(
+                key, vec, fields, idx.name, shared=shared
+            )
+            if held is MISS:
+                return MISS
+            if shared:
+                return Served(self, key, held)
+            return [copy_result(held[0])]
 
     def lookup_stale(
         self, idx: Index, call: Call, shards: list[int] | None
@@ -341,17 +424,23 @@ class ResultCache:
                     return MISS
                 self.degraded_hits += 1
                 self.stats.count("rescache_degraded_hits", 1)
-                return copy_result(entry.result)
+                result = entry.result
+            return copy_result(result)
 
     def probe_raw(self, key: tuple, vector: tuple) -> Any:
         """Distributed partial probe: explicit key + precomputed vector
         (which the caller captured before dispatch).  Returns the
-        result or :data:`MISS`."""
+        caller's own copy of the result (its callers merge into what
+        they get) or :data:`MISS`."""
         with qprofile.span("rescache.lookup", raw=True):
-            res, _tok = self._probe_locked(key, vector, None, None)
-        return res
+            held, _tok = self._probe_locked(key, vector, None, None)
+            return MISS if held is MISS else copy_result(held[0])
 
-    def _probe_locked(self, key, vec, fields, index_name):
+    def _probe_locked(self, key, vec, fields, index_name, shared=False):
+        """``(held, None)`` on a hit, ``(MISS, token)`` on a miss.  The
+        lock covers the table's bookkeeping only: ``held`` is the
+        entry's ``(result, body)`` reference, which the caller copies,
+        or hands out when ``shared``, after the lock is released."""
         with self._lock:
             entry = self._entries.get(key)
             if entry is not None and entry.vector == vec:
@@ -367,22 +456,29 @@ class ResultCache:
                     entry.maintained = True
                     self.promotions += 1
                     self.stats.count("rescache_promotions", 1)
-                return copy_result(entry.result), None
+                return self._hand_out_locked(entry.held, shared), None
             if entry is not None:
                 # stale — refresh maintained entries in place, drop the
                 # rest (that drop IS the precise invalidation)
                 refreshed = self._refresh_locked(key, entry, vec)
                 if refreshed is not MISS:
-                    return refreshed, None
+                    return self._hand_out_locked(refreshed, shared), None
             self.misses += 1
             self.stats.count("rescache_misses", 1)
             return MISS, _Token(key, vec, fields, index_name)
 
+    def _hand_out_locked(self, held: tuple, shared: bool) -> tuple:
+        if shared:
+            self.uncopied_hits += 1
+            if held[1] is not None:
+                self.encoded_hits += 1
+        return held
+
     def _refresh_locked(self, key, entry: _Entry, vec) -> Any:
         """Serve a promoted entry through a version change by
         recomputing from the maintained counts; demote when the write
-        drift exceeds the rebuild threshold.  Returns MISS when the
-        entry was dropped instead."""
+        drift exceeds the rebuild threshold.  Returns the entry's new
+        ``held``, or MISS when the entry was dropped instead."""
         if entry.maintained and entry.recompute is not None:
             drift = _version_sum(vec) - _version_sum(entry.vector)
             entry.delta_accum += max(drift, 1)
@@ -398,14 +494,14 @@ class ResultCache:
                 finally:
                     self._lock.acquire()
                 if fresh is not None and self._entries.get(key) is entry:
-                    entry.result = fresh
+                    entry.result = fresh  # and no body: it was the old result's
                     entry.vector = vec
                     entry.hits += 1
                     self.maintained_hits += 1
                     self.hits += 1
                     self.stats.count("rescache_hits", 1)
                     self.stats.count("rescache_maintained_hits", 1)
-                    return copy_result(fresh)
+                    return entry.held
                 return MISS
             self.demotions += 1
             self.stats.count("rescache_demotions", 1)
@@ -413,6 +509,16 @@ class ResultCache:
         self.invalidations += 1
         self.stats.count("rescache_invalidations", 1)
         return MISS
+
+    def _remember(self, key: tuple, result: Any, body: bytes) -> None:
+        """Leave the bytes a hit was sent as with its entry, if the entry
+        is still there and still holds the object they were built from
+        (the rule ``Stack.put`` has).  Built by the hitting handler with
+        no lock held; the install is the one compare under it."""
+        with self._lock:
+            entry = self._entries.get(key)
+            if entry is not None and entry.held[0] is result:
+                entry.held = (result, body)
 
     # ------------------------------------------------------------ stores
 
@@ -518,6 +624,21 @@ class ResultCache:
         with self._lock:
             return len(self._entries)
 
+    def entries(self) -> list[dict]:
+        """The table, least recently used first: what a test or a
+        debugging session may know of an entry without reaching in."""
+        with self._lock:
+            return [
+                {
+                    "call": key[3],
+                    "hits": e.hits,
+                    "maintained": e.maintained,
+                    "deltaAccum": e.delta_accum,
+                    "encodedBytes": None if e.held[1] is None else len(e.held[1]),
+                }
+                for key, e in self._entries.items()
+            ]
+
     def snapshot(self) -> dict:
         """The /debug/vars block (server/http.py r_debug_vars)."""
         with self._lock:
@@ -528,6 +649,8 @@ class ResultCache:
                 "entries": len(self._entries),
                 "maxEntries": self.max_entries,
                 "hits": self.hits,
+                "uncopiedHits": self.uncopied_hits,
+                "encodedHits": self.encoded_hits,
                 "misses": self.misses,
                 "invalidations": self.invalidations,
                 "promotions": self.promotions,

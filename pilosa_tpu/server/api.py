@@ -9,6 +9,7 @@ transfer and abort. A single node sits in NORMAL.
 from __future__ import annotations
 
 import io
+import json
 import logging
 import random
 import threading
@@ -29,6 +30,7 @@ from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.core import timequantum
 from pilosa_tpu.core.view import VIEW_STANDARD
 from pilosa_tpu.exec.executor import ExecuteError, Executor
+from pilosa_tpu.exec.rescache import Served
 from pilosa_tpu.exec.result import result_to_json
 from pilosa_tpu.storage import roaring
 from pilosa_tpu.storage.disk import HolderStore
@@ -49,6 +51,27 @@ _RESIZING_METHODS = {
     "Status", "Info", "Version", "ClusterMessage", "Hosts",
     "FragmentData", "ResizeAbort",
 }
+
+
+def encode_json(obj) -> bytes:
+    """A JSON response's body as the listener sends it (``http._send_json``)."""
+    return (json.dumps(obj) + "\n").encode()
+
+
+def _as_dict(results) -> dict:
+    return {"results": result_to_json(results)}
+
+
+def _as_body(results) -> "dict | bytes":
+    """The response of a ``rescache.Served`` hit as its encoded body, built
+    once an entry's result and kept with it; any other as the dict."""
+    if not isinstance(results, Served):
+        return _as_dict(results)
+    body = results.body
+    if body is None:
+        body = encode_json(_as_dict(results))
+        results.remember(body)
+    return body
 
 
 class ApiError(Exception):
@@ -298,6 +321,26 @@ class API:
         a profile is also collected — without being returned — whenever
         the slow-query log is armed, so threshold breaches capture a
         full tree."""
+        return self._query(index, pql, shards, remote, profile, _as_dict)
+
+    def query_encoded(
+        self,
+        index: str,
+        pql: str,
+        shards: list[int] | None = None,
+        remote: bool = False,
+        profile: bool = False,
+    ) -> "dict | bytes":
+        """:meth:`query` for the listener, which sends bytes: where the
+        result cache answered the request with an object nobody will
+        write to (``rescache.Served``), the response is the encoded body
+        itself — the bytes the entry's first hit was sent as, byte for
+        byte what :func:`encode_json` makes of :meth:`query`'s dict.
+        That first hit builds them here, as ever, and leaves them with
+        the entry.  Every other request gets :meth:`query`'s dict."""
+        return self._query(index, pql, shards, remote, profile, _as_body)
+
+    def _query(self, index, pql, shards, remote, profile, render):
         self._validate("Query")
         # Fail fast if the budget is already spent (e.g. a forwarded
         # sub-query whose header arrived expired) — DeadlineExceeded is
@@ -327,13 +370,15 @@ class API:
                         resp = {"wireResults": encode_results(results)}
                     else:
                         results = self._execute_query(index, pql, shards)
-                        resp = {"results": result_to_json(results)}
                         # Degraded tier is EXPLICIT: a last-known
                         # answer served under QoS pressure stage 2 is
                         # marked in the envelope (server/qos.py sets
                         # the request-scoped note in batcher.submit)
                         if qos_mod.take_degraded():
+                            resp = _as_dict(results)
                             resp["degraded"] = True
+                        else:
+                            resp = render(results)
                 except (ExecuteError, ParseError, ValueError, TypeError) as e:
                     err = str(e)
                     raise ApiError(str(e))
@@ -346,6 +391,7 @@ class API:
                 prof.finish(time.perf_counter() - t0, error=err)
                 self.slow_queries.observe(prof)
         if prof is not None and profile:
+            # a dict: no request that collects a profile is Served
             resp["profile"] = prof.to_dict()
         return resp
 

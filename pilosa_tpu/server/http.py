@@ -38,7 +38,7 @@ from urllib.parse import parse_qs, urlparse
 from pilosa_tpu import deadline
 from pilosa_tpu.deadline import DeadlineExceeded
 from pilosa_tpu.obs import devledger, slo, tracestore, tracing
-from pilosa_tpu.server.api import API, ApiError
+from pilosa_tpu.server.api import API, ApiError, encode_json
 from pilosa_tpu.server.qos import ShedError
 
 logger = logging.getLogger(__name__)
@@ -206,10 +206,7 @@ class Handler(BaseHTTPRequestHandler):
         headers: dict | None = None,
         gzip_ok: bool = False,
     ) -> None:
-        self._send(
-            code, (json.dumps(obj) + "\n").encode(), headers=headers,
-            gzip_ok=gzip_ok,
-        )
+        self._send(code, encode_json(obj), headers=headers, gzip_ok=gzip_ok)
 
     def _body(self) -> bytes:
         length = int(self.headers.get("Content-Length") or 0)
@@ -821,11 +818,15 @@ class Handler(BaseHTTPRequestHandler):
             ]
         if self.query_params.get("profile", [""])[0].lower() in ("1", "true"):
             profile = True
-        resp = self.api.query(
+        resp = self.api.query_encoded(
             index, pql, shards=shards, remote=remote, profile=profile
         )
         with tracing.start_span("http.encode"):
-            self._send_json(200, resp)
+            if isinstance(resp, bytes):
+                # a result-cache hit whose body an earlier hit encoded
+                self._send(200, resp)
+            else:
+                self._send_json(200, resp)
 
     def r_create_index(self, index: str):
         body = self._json_body()

@@ -6,7 +6,8 @@ structures in lock-step under one lock — the LRU entry map, the
 ``(index, field) -> keys`` reverse map that makes ``note_write``
 precise, and the hit/miss/invalidation counters that feed
 ``pilosa_rescache_*``.  Every legal mutation lives in rescache.py
-behind ``lookup()``/``store()``/``note_write()``/``snapshot()``.
+behind ``lookup()``/``store()``/``note_write()``/``snapshot()``
+(``entries()`` lists the table for a test that wants to look).
 Touching a private attribute through a ``rescache`` receiver anywhere
 else (``executor.rescache._entries.pop(...)``, reading
 ``.rescache._by_field`` without the lock) desynchronizes the maps — an
@@ -60,6 +61,8 @@ _COUNTERS = frozenset(
         "promotions",
         "demotions",
         "maintained_hits",
+        "uncopied_hits",
+        "encoded_hits",
         "stores",
         "evictions",
     }
