@@ -1,15 +1,37 @@
 """The comparison that decides ``correct``.
 
-Every number compared is exact, so every limit is 0: how many sampled
-reads of the window disagree with the reference, how many programs
-compiled inside the window, how many requests failed.  The mixes of the
-grid only read, so every answer has one right value: the reference's over
-the data the load stage imported and the server acknowledged.
+Every number compared is exact, so every limit is 0: how many sampled reads
+of the window disagree with the reference, how many programs compiled
+inside the window, how many requests failed.
+
+Under a mix that only reads, every answer has one right value: the
+reference's over the data the load stage imported and the server
+acknowledged.  Under a mix that streams imports beside its readers a read
+is held to the configuration's guarantee, "an acknowledged import is
+visible to every later read", by the two logs alone (one clock,
+``time.monotonic``).  Over the fields its PQL names, an import request is
+*certain* for a read when it was acknowledged before the read was sent,
+and *uncertain* when it was sent before the read's answer arrived and is
+not certain; any other was sent after the answer and cannot be in it.
+
+- No uncertain request: the answer is the reference's at the certain
+  state, exactly.
+- One to three: it is the reference's at one of the states in between,
+  each uncertain request applied or not.
+- More: the read is not judged.  The run logs how many; it is no number
+  of ``compared``, since nothing a control breaks moves it (the controls
+  answer over the run's own two logs).  What holds the judge to its work
+  is ``classes_unjudged``: every class needs an answer judged exactly.
+
+So an answer from before an acknowledged import fails, and so does one
+ahead of every import that was sent.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
+import math
 
 import numpy as np
 
@@ -111,7 +133,26 @@ class LossyReference(Reference):
             self.one[name][shard, lo + self.REM:lo + self.slab:self.MOD] = blank
 
 
-CONTROLS = {"lossy": LossyReference}
+class StaleReference(Reference):
+    """Breaks "an acknowledged import is visible to every later read":
+    every answer is exact for a state the store once had, but a streamed
+    import shows only when the same field's next one has been acknowledged,
+    one slab late.  (``judge_reads`` hands it the imports as they become
+    certain.)"""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self._held: dict[str, dict] = {}
+
+    def apply_import(self, imp):
+        shown = self._held.get(imp["field"])
+        self._held[imp["field"]] = imp
+        if shown is not None:
+            super().apply_import(shown)
+
+
+CONTROLS = {"lossy": LossyReference, "stale": StaleReference}
+UNCERTAIN_MAX = 3  # 2^3 states a read at the most
 
 
 # ---------------------------------------------------------------------------
@@ -119,23 +160,86 @@ CONTROLS = {"lossy": LossyReference}
 # ---------------------------------------------------------------------------
 
 
-def judge_reads(ref: Reference, reads: list[dict], control: Reference | None = None) -> dict:
-    """``reads``: cls, pql, body (the served JSON bytes).  With ``control``
-    the control's answers stand in for the served ones."""
+def by_ack(imports: list[dict]) -> list[dict]:
+    """The import log in the order of its acknowledgements; a request that
+    failed was never acknowledged."""
+    return sorted(imports, key=lambda m: m["t_ack"] if m["status"] == 200 else math.inf)
+
+
+def _served(r: dict):
+    try:
+        return json.loads(r["body"])["results"][0]
+    except (ValueError, KeyError, IndexError, TypeError):
+        return None
+
+
+def _between(ref: Reference, name: str, got, pql: str, uncertain: list[dict]) -> str | None:
+    """None when ``got`` is the reference's answer at the present state
+    with some of ``uncertain`` applied; else what is wrong with it at the
+    present state."""
+    first = None
+    n = len(uncertain)
+    for size in range(n + 1):
+        for some in itertools.combinations(range(n), size):
+            for i in some:
+                ref.apply_import(uncertain[i])
+            why = check_answer(name, got, ref.answer(pql))
+            for i in some:
+                ref.revert_import(uncertain[i])
+            if why is None:
+                return None
+            first = first or why
+    return first if not n else f"{first} (nor with any of {n} imports in flight)"
+
+
+def judge_reads(ref: Reference, reads: list[dict], imports: list[dict] = (),
+                control: Reference | None = None) -> dict:
+    """``reads``: cls, pql, body (the served JSON bytes) and, where there
+    are ``imports`` (field, shard, slab, t_send, t_ack, status), t_send and
+    t_recv.  ``ref`` is at the state of the load and is left at the certain
+    state of the last read.  With ``control`` the control's answers stand in
+    for the served ones."""
     mismatches: list[str] = []
-    per_class: dict[str, int] = {}
+    per_class: dict[str, int] = {}  # answers judged exactly: nothing in flight beside them
+    in_flight: dict[int, int] = {}
+    unjudged = 0
+    log = by_ack(imports)
+    done = 0  # log[:done] are certain
+    if log:
+        reads = sorted(reads, key=lambda r: r["t_send"])
     for r in reads:
-        name = parse(r["pql"]).name
-        if control is not None:
-            got = to_json(name, control.answer(r["pql"]))
-        else:
-            try:
-                got = json.loads(r["body"])["results"][0]
-            except (ValueError, KeyError, IndexError, TypeError):
-                got = None
-        per_class[r["cls"]] = per_class.get(r["cls"], 0) + 1
-        why = check_answer(name, got, ref.answer(r["pql"]))
+        while done < len(log) and log[done]["status"] == 200 and log[done]["t_ack"] < r["t_send"]:
+            ref.apply_import(log[done])
+            if control is not None:
+                control.apply_import(log[done])
+            done += 1
+        call, named = ref.call(r["pql"])
+        uncertain = [m for m in log[done:] if m["t_send"] < r["t_recv"] and m["field"] in named]
+        in_flight[len(uncertain)] = in_flight.get(len(uncertain), 0) + 1
+        if len(uncertain) > UNCERTAIN_MAX:
+            unjudged += 1
+            continue
+        got = _served(r) if control is None else to_json(call.name, control.answer(r["pql"]))
+        if not uncertain:
+            per_class[r["cls"]] = per_class.get(r["cls"], 0) + 1
+        why = _between(ref, call.name, got, r["pql"], sorted(uncertain, key=lambda m: m["t_send"]))
         if why is not None:
             mismatches.append(f"{r['cls']}: {r['pql']}: {why}"[:300])
-    return {"compared": len(reads), "mismatches": len(mismatches),
-            "per_class": per_class, "examples": mismatches[:8]}
+    return {"compared": len(reads) - unjudged, "mismatches": len(mismatches), "unjudged": unjudged,
+            "per_class": per_class, "in_flight": dict(sorted(in_flight.items())),
+            "certain": done, "examples": mismatches[:8]}
+
+
+def judge_readback(ref: Reference, imports: list[dict], answers: list[dict]) -> dict:
+    """``answers`` (pql, body) were asked after the last acknowledgement:
+    each is the reference's with every acknowledged import applied."""
+    for m in imports:
+        if m["status"] == 200 and (m["shard"], m["slab"]) not in ref.applied.get(m["field"], ()):
+            ref.apply_import(m)
+    mismatches = []
+    for r in answers:
+        call, _ = ref.call(r["pql"])
+        why = check_answer(call.name, _served(r), ref.answer(r["pql"]))
+        if why is not None:
+            mismatches.append(f"read-back: {r['pql']}: {why}"[:300])
+    return {"compared": len(answers), "mismatches": len(mismatches), "examples": mismatches[:8]}

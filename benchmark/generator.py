@@ -17,6 +17,20 @@ Mix file::
 Every class reads; each connection sends its next request when the last
 has answered (a closed loop).
 
+A mix may write beside its readers, through a ``stream`` block::
+
+     "stream": {"every_s": 4.0}
+
+One connection of its own imports the configuration's free slabs (those of
+every shard past the load's ``columns``), one slab every ``every_s``
+seconds from the scheduled time, round-robin over the shards
+(``Mix.slabs``); a slab's data is ``datagen.gen_slab`` of the seed.  The
+warm-up imports into one shard and into two (``run.SWEEP_SHARDS``) and reads
+after each (``Mix.every_variant``), so that the program has refreshed its
+device copies in the shapes a window meets, and filled a flight with the
+misses an emptied result cache brings, before the window.  A mix
+without the block sends what it sent before there was one.
+
 Classes are dealt from a shuffled deck that holds each class ``weight``
 times, so every seed sends the classes in the same shares, in another
 order.  Slot picks:
@@ -32,7 +46,7 @@ import hashlib
 
 import numpy as np
 
-from datagen import fields_by_name, popularity_order
+from datagen import fields_by_name, popularity_order, slabs_per_shard, width_of
 
 PHASES = {"window": 0, "warm": 1}
 
@@ -46,6 +60,7 @@ class Mix:
         self.deck = [name for name, c in self.classes.items() for _ in range(int(c["weight"]))]
         self._cdf: dict[int, np.ndarray] = {}
         self._order = {n: popularity_order(f) for n, f in self.fields.items() if f["kind"] == "set"}
+        self.streams = mix.get("stream")  # None: the mix only reads
 
     def _zipf(self, rng, n: int) -> int:
         if n not in self._cdf:
@@ -129,6 +144,70 @@ class Mix:
                     if len(calls) < k:
                         break
                     k *= 2
+
+    def twins(self, seed: int, largest: int):
+        """(class, calls): of every variant one request that holds one call
+        twice, a call ``sweep`` has not sent, where the variant has one left.
+        Two connections that send the same call into one flight before its
+        answer is cached make the planner evaluate the subtree the two share
+        once and apart, outside the batch lanes; a range predicate under an
+        ``Intersect`` then runs through programs that no other request of
+        the mix compiles.  With thresholds uniform over 20,050 values a
+        window meets that about once in sixteen runs, so the warm-up has to."""
+        sent = {pql for _, calls in self.sweep(seed, largest) for pql in calls}
+        rng = np.random.default_rng([int(seed), PHASES["warm"], 0x2B1D])
+        for cls, c in self.classes.items():
+            for variant in range(len(c["variants"])):
+                for _ in range(40):
+                    pql = self._draw(rng, cls, variant, uniform=True)[0]
+                    if pql not in sent:
+                        sent.add(pql)
+                        yield cls, [pql, pql]
+                        break
+
+    def slabs(self, seed: int) -> list[tuple[int, int, int]]:
+        """What the write stream imports, in order: (k, shard, slab) over
+        every free slab of the configuration, round-robin over the shards,
+        so that any ``shards`` in a row go to distinct shards.  Every seed
+        has the same schedule from another first shard; slab ``k`` of a
+        phase is due ``k * every_s`` after the phase's start."""
+        shards = int(self.cfg["shards"])
+        first, last = slabs_per_shard(self.cfg), width_of(self.cfg) // int(self.cfg["slab_rides"])
+        order = [(int(seed) + i) % shards for i in range(shards)]
+        return [(k, shard, slab) for k, (slab, shard) in enumerate(
+            (slab, shard) for slab in range(first, last) for shard in order)]
+
+    def every_variant(self, seed: int, round_: int, size: int):
+        """(class, calls): what the warm-up reads after the imports of round
+        ``round_``, when no answer over an imported field is cached any
+        more.  One request of one call of every variant of every class, so
+        that every field the mix names is read in every lane the mix reads
+        it in.  Then, of all the variants of a class in turn and of each
+        variant alone, one request of ``size`` distinct calls, or of as many
+        as the variant has left: a class with few distinct requests (21
+        filtered sums) never fills a large batch in ``sweep``, where a call
+        sent once is answered from the result cache for good; beside a
+        stream it does, every time an import has emptied that cache.  Rows
+        uniform; no call twice in a round."""
+        rng = np.random.default_rng([int(seed), PHASES["warm"], int(round_), 0x1A9E])
+        sent: set[str] = set()
+        for cls, c in self.classes.items():
+            alone = [[v] for v in range(len(c["variants"]))]
+            for v in range(len(alone)):
+                pql = self._draw(rng, cls, v, uniform=True)[0]
+                sent.add(pql)
+                yield cls, [pql]
+            for group in ([sum(alone, [])] if len(alone) > 1 else []) + alone:
+                calls: list[str] = []
+                for t in range(40 * size):
+                    pql = self._draw(rng, cls, group[t % len(group)], uniform=True)[0]
+                    if pql not in sent:
+                        sent.add(pql)
+                        calls.append(pql)
+                        if len(calls) == size:
+                            break
+                if calls:
+                    yield cls, calls
 
     def stream(self, seed: int, phase: str, conn: int):
         """Endless (class, pql) for one connection."""

@@ -8,6 +8,10 @@ answers each with one JSON line on stdout:
 ``run``    drive connections from ``start_at`` for ``seconds`` (the warm-up
            or the window), or until the file ``stop_file`` appears, and
            pickle the per-request log to ``log``
+``stream`` import the slabs of ``slabs`` on one connection, slab ``i`` from
+           ``start_at + i * every_s`` and never before, until they are all
+           acknowledged or ``stop_file`` appears; the per-request log is in
+           the answer (a worker that streams drives no readers)
 ``quit``
 
 It is fed only what the generator makes from the seed; it imports nothing
@@ -95,6 +99,51 @@ def load_shards(cfg: dict, seed: int, port: int, shards: list[int]) -> dict:
 
 
 # ---------------------------------------------------------------------------
+# the write stream
+# ---------------------------------------------------------------------------
+
+
+def slab_requests(cfg: dict, seed: int, shard: int, slab: int) -> list[tuple[str, str, bytes, int]]:
+    """(field, path, body, bits) of the import-roaring requests that carry
+    one slab, one per field, at the slab's own columns of the shard."""
+    values = datagen.gen_slab(cfg, seed, shard, slab)
+    col0 = slab * int(cfg["slab_rides"])
+    out = []
+    for f in cfg["fields"]:
+        view, blob, n = datagen.slab_import(cfg, f, values[f["name"]], col0)
+        out.append((f["name"], f"/index/{cfg['index']}/field/{f['name']}/import-roaring/{shard}{view}",
+                    blob, n))
+    return out
+
+
+def send_slab(c: Conn, requests: list, k: int, shard: int, slab: int, due: float) -> list[dict]:
+    """The slab's requests one after another; every one logged on the clock
+    the readers log on.  A request that fails is logged and the rest still go."""
+    out = []
+    for field, path, blob, bits in requests:
+        t_send = time.monotonic()
+        status, _ = c.send(path, blob, "application/octet-stream")
+        out.append({"k": k, "field": field, "shard": shard, "slab": slab, "due": due, "bits": bits,
+                    "t_send": t_send, "t_ack": time.monotonic(), "status": status})
+    return out
+
+
+def stream(job: dict, cfg: dict) -> dict:
+    c = Conn(job["port"])
+    imports: list[dict] = []
+    for i, (k, shard, slab) in enumerate(job["slabs"]):
+        due = job["start_at"] + i * job["every_s"]
+        requests = slab_requests(cfg, job["seed"], shard, slab)  # made ahead of its time
+        while not (stopped := os.path.exists(job["stop_file"])) and time.monotonic() < due:
+            time.sleep(max(0.0, min(due - time.monotonic(), 0.02)))
+        if stopped:
+            break
+        imports += send_slab(c, requests, k, shard, slab, due)
+    c.close()
+    return {"imports": imports}
+
+
+# ---------------------------------------------------------------------------
 # a run: connections between two instants
 # ---------------------------------------------------------------------------
 
@@ -161,6 +210,8 @@ def main() -> int:
                 out = load_shards(cfg, job["seed"], job["port"], job["shards"])
             elif job["cmd"] == "run":
                 out = run(job, cfg, mix_data)
+            elif job["cmd"] == "stream":
+                out = stream(job, cfg)
             else:
                 raise ValueError(f"unknown command {job['cmd']!r}")
             out["ok"] = True

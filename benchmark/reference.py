@@ -4,6 +4,11 @@ Independent of ``pilosa_tpu``: it parses the PQL text the generator sent
 with a small parser of its own and evaluates it over data it generated
 itself from the seed (``datagen``): one row id (set field) or one value
 (int field) per column.
+
+It answers at a *state*: the load, and on top of it the streamed imports
+(one field of one slab of one shard each, ``apply_import``) that have been
+applied and not taken back (``revert_import``).  A mix that streams nothing
+never leaves the state of the load.
 """
 
 from __future__ import annotations
@@ -114,12 +119,14 @@ def _parse_call(toks, i):
 
 
 class Reference:
-    def __init__(self, cfg: dict, seed: int):
+    def __init__(self, cfg: dict, seed: int, extent: int | None = None):
+        """``extent``: the columns of a shard it holds; the load's
+        ``columns`` unless a stream writes past them."""
         self.cfg, self.seed = cfg, int(seed)
         self.width = width_of(cfg)
         self.shards = int(cfg["shards"])
         self.slab = int(cfg["slab_rides"])
-        self.hi = int(cfg["columns"])
+        self.hi = int(cfg["columns"] if extent is None else extent)
         self.fields = fields_by_name(cfg)
         # [shards, columns]: the row id (set field) or the value (int field) of each column
         self.one: dict[str, np.ndarray] = {
@@ -127,11 +134,39 @@ class Reference:
                         else np.full((self.shards, self.hi), UNSET, np.uint16))
             for f in cfg["fields"]}
         self._answers: dict = {}
+        self._calls: dict[str, tuple[Call, tuple]] = {}
+        # the state: per field, the streamed (shard, slab) applied on top of the load
+        self.applied: dict[str, frozenset] = {}
+        self._slabs: dict[tuple[int, int], dict] = {}
 
     def apply_slab(self, shard: int, slab: int, values: dict) -> None:
         lo = slab * self.slab
         for name, v in values.items():
             self.one[name][shard, lo:lo + self.slab] = v
+
+    def _slab(self, shard: int, slab: int) -> dict:
+        """A streamed slab's values; imports come a slab at a time, so the
+        last few are kept."""
+        key = (shard, slab)
+        if key not in self._slabs:
+            if len(self._slabs) >= 4:
+                self._slabs.pop(next(iter(self._slabs)))
+            self._slabs[key] = gen_slab(self.cfg, self.seed, shard, slab)
+        return self._slabs[key]
+
+    def apply_import(self, imp: dict) -> None:
+        """One streamed import request (``field``, ``shard``, ``slab``)
+        becomes part of the state."""
+        name, key = imp["field"], (imp["shard"], imp["slab"])
+        self.apply_slab(*key, {name: self._slab(*key)[name]})
+        self.applied[name] = self.applied.get(name, frozenset()) | {key}
+
+    def revert_import(self, imp: dict) -> None:
+        """Take back an import that ``apply_import`` applied: its columns
+        were free before it."""
+        name, key = imp["field"], (imp["shard"], imp["slab"])
+        self.apply_slab(*key, {name: -1 if self.fields[name]["kind"] == "int" else UNSET})
+        self.applied[name] = self.applied[name] - {key}
 
     def load(self) -> None:
         """The load stage's data: every slab of every shard."""
@@ -173,14 +208,26 @@ class Reference:
         n = int(self.fields[name]["rows"])
         return np.bincount(v.ravel(), minlength=UNSET + 1)[:n].astype(np.int64)
 
+    def call(self, pql: str) -> tuple[Call, tuple]:
+        """The parsed PQL text and the fields it reads, sorted."""
+        if pql not in self._calls:
+            if len(self._calls) > 4096:
+                self._calls.clear()
+            c = parse(pql)
+            self._calls[pql] = c, tuple(sorted(_names(c) & set(self.fields)))
+        return self._calls[pql]
+
     def answer(self, pql: str):
-        """``evaluate`` of the PQL text, remembered: a dashboard repeats
-        its unparametrised panels."""
-        if pql not in self._answers:
+        """``evaluate`` of the PQL text at the present state, remembered
+        per text and state of the fields it reads: a dashboard repeats its
+        unparametrised panels."""
+        c, named = self.call(pql)
+        key = (pql, tuple(self.applied.get(f) or None for f in named))
+        if key not in self._answers:
             if len(self._answers) > 512:
                 self._answers.clear()
-            self._answers[pql] = self.evaluate(parse(pql))
-        return self._answers[pql]
+            self._answers[key] = self.evaluate(c)
+        return self._answers[key]
 
     def evaluate(self, c: Call):
         """The call's answer in a plain form ``compare`` understands:
@@ -212,6 +259,20 @@ class Reference:
         cols = np.flatnonzero(self.bitmap(c).ravel())
         # [shards, columns] -> global column ids
         return (cols // self.hi) * self.width + cols % self.hi
+
+
+def _names(c: Call) -> set:
+    """Every identifier of a call that can name a field: the keys of a
+    ``Row``, the field of a condition, of ``Sum(field=)``, of ``TopN`` and
+    ``Rows``; the caller keeps those that are fields."""
+    out = set(c.kw) | {v for v in c.kw.values() if isinstance(v, str)}
+    out |= {p for p in c.pos if isinstance(p, str)}
+    if c.cond is not None:
+        out.add(c.cond[0])
+    for p in [*c.pos, *c.kw.values()]:
+        if isinstance(p, Call):
+            out |= _names(p)
+    return out
 
 
 def _compare(v: np.ndarray, op: str, x) -> np.ndarray:

@@ -5,8 +5,10 @@
 One run of one cell of ``BENCHMARK.json``: start the server child, make the
 schema, load the configuration's data from the seed through the import
 route, warm up every shape of the cell's mix, drive the mix for the
-window, stop the server, judge the window's own answers against the numpy
-reference, and print one result line built from the manifest.
+window (its readers and, where the mix has a ``stream`` block, a paced
+stream of imports beside them), read back, stop the server, judge the
+window's own answers against the numpy reference, and print one result
+line built from the manifest.
 
 This process never imports JAX: the server child holds the chip.  The
 cell's configuration, traffic mix and per-layer metrics are files found by
@@ -17,9 +19,9 @@ itself inside ``RUN_LIMIT_S`` (``README.md``, "How a run ends").
 
 ``--rehearsal`` drives the same stages on the CPU at the tiny shape each
 configuration file gives under ``rehearsal``; it says so, exits 3 and can
-never print a passing result.  ``--control lossy`` judges the reference
-with a stated guarantee broken in the program's place, which has to come
-out as not correct.
+never print a passing result.  ``--control lossy`` and ``--control stale``
+judge the reference with a stated guarantee broken in the program's place,
+which has to come out as not correct.
 """
 
 from __future__ import annotations
@@ -54,6 +56,12 @@ WARM_POLL_S = 1.0
 WARM_QUIET_S = 10.0     # warm-up ends after this long with nothing compiled or retrieved
 WARM_LIMIT_S = 900.0
 LOADERS = 4             # processes that import the load stage, each its share of the shards
+# Changed shards of one field's device copy that the warm-up makes the program refresh at once
+# (``sweep_refresh``).  A window meets 1: the stream sends a field's import once in ``every_s`` and the
+# readers read every field the mix names many times in between.  It meets 2 where no reader got to
+# the field between two slabs, a stall of the dispatcher as long as the stream's gap.  3 would take
+# a stall of two gaps; a window that meets one says so (``window_compiles``, the program's name in the log).
+SWEEP_SHARDS = (1, 2)
 # Spawn to result line.  Three times the slowest whole run the ledger holds (cold
 # cache: set-up 205.58 s, window 50, reference 11-13, trace reduction); a cell whose
 # cold run does not fit is too large for the grid (README.md, "How a run ends").
@@ -326,6 +334,8 @@ def load_cell(manifest: dict, workload: str, rehearsal: bool) -> tuple[dict, dic
     cfg = mf.read_json(mf.config_entry(manifest, cell["config"])["file"])
     if rehearsal:
         cfg.update(cfg.get("rehearsal", {}))
+        for f in cfg["fields"]:  # a field's keys replaced at the tiny shape, by name
+            f.update(cfg.get("rehearsal_fields", {}).get(f["name"], {}))
     mix = mf.read_json(os.path.join("benchmark", "traffic", cell["traffic"] + ".json"))
     if rehearsal:
         mix.update(mix.get("rehearsal", {}))
@@ -357,16 +367,23 @@ def new_programs(before: dict, after: dict) -> list[str]:
 class Driver:
     """Sends ``run`` jobs to the workers and gathers their logs."""
 
-    def __init__(self, workers: list[Worker], work: str, base: dict):
+    def __init__(self, workers: list[Worker], work: str, base: dict, streamer: Worker | None = None,
+                 every_s: float = 0.0):
         self.workers, self.work, self.base = workers, work, base
+        self.streamer, self.every_s = streamer, every_s
         self.n_jobs = 0
 
-    def start(self, phase: str, seconds: float, lead: float = 0.15) -> dict:
+    def start(self, phase: str, seconds: float, lead: float = 0.15, slabs: list | None = None) -> dict:
         """Send the job to every worker; ``finish`` gathers the logs.  The
-        workers stop at ``seconds`` or when ``stop`` is called."""
+        workers stop at ``seconds`` or when ``stop`` is called.  ``slabs``
+        go to the worker that streams, slab ``i`` due ``i * every_s`` after
+        the readers' start."""
         start_at = time.monotonic() + lead
         self.stop_file = os.path.join(self.work, f"stop_{self.n_jobs}")
         active = []
+        if slabs:
+            self.streamer.send(dict(self.base, cmd="stream", start_at=start_at, every_s=self.every_s,
+                                    slabs=slabs, stop_file=self.stop_file))
         for w in self.workers:
             if not w.conns:
                 continue
@@ -376,13 +393,16 @@ class Driver:
                        log=os.path.join(self.work, f"log_{self.n_jobs}.pkl"))
             w.send(job)
             active.append((w, job))
-        return {"start_at": start_at, "end_at": start_at + seconds, "phase": phase, "active": active}
+        return {"start_at": start_at, "end_at": start_at + seconds, "phase": phase, "active": active,
+                "slabs_due": len(slabs or [])}
 
     def stop(self) -> None:
         open(self.stop_file, "w").close()
 
     def finish(self, run: dict) -> dict:
-        out = dict(run, cpu_share=[], reads=[])
+        out = dict(run, cpu_share=[], reads=[], imports=[])
+        if out["slabs_due"]:  # the last acknowledgement is awaited
+            out["imports"] = self.streamer.reply()["imports"]
         for w, job in out.pop("active"):
             rep = w.reply()
             if rep["stuck_threads"]:
@@ -394,14 +414,80 @@ class Driver:
         return out
 
 
-def warm_up(drv: Driver, c: Http, cfg: dict, mix: dict, seed: int, seconds: float) -> dict:
+class Stream:
+    """The write stream of a run: the mix, its plan (``generator.Mix.slabs``:
+    the free slabs in the order they go), how far it has got, and the log of
+    every import request sent, by whom ever."""
+
+    def __init__(self, m, seed: int, seconds: float):
+        self.mix = m
+        self.every_s = float(m.streams["every_s"])
+        self.sweep = [k for k in SWEEP_SHARDS if k <= int(m.cfg["shards"])]
+        self.plan = m.slabs(seed)
+        self.next = 0
+        self.imports: list[dict] = []
+        # slab i is due i * every_s after the start; a window takes those whose whole period lies in it
+        self.in_window = int(seconds / self.every_s + 1e-9)
+        if not self.in_window:
+            raise RunFailure(f"a window of {seconds:g} s is shorter than the stream's period of {self.every_s:g} s")
+        if sum(self.sweep) + self.in_window > len(self.plan):
+            raise RunFailure(f"the stream runs out of free columns before the window ends: "
+                             f"{len(self.plan)} free slabs, the warm-up's sweep imports {sum(self.sweep)} "
+                             f"and a window of {seconds:g} s is due {self.in_window}")
+
+    def take(self, n: int) -> list:
+        self.next += n
+        return self.plan[self.next - n:self.next]
+
+    def sent(self, imports: list[dict]) -> None:
+        """Log a phase's requests; slabs taken for it and never sent are free again."""
+        self.imports += imports
+        self.next = 1 + self.imports[-1]["k"]
+
+
+def sweep_refresh(c: Http, seed: int, stream: Stream, largest: int, peak_bytes) -> None:
+    """The warm-up's requests for a mix that streams: import a slab into
+    each of k distinct shards, for every k of ``SWEEP_SHARDS``, and after
+    each k send the requests of ``Mix.every_variant``.  The
+    program re-uploads the changed shards of a field's device copy on the
+    field's next read, in one program per number of changed shards; and an
+    import drops every cached answer over its field, so the reads that
+    follow are misses again, as many to a flight as there are connections:
+    the batches go from ``largest`` calls after the first k down by halves."""
+    import loadgen
+
+    m, cfg = stream.mix, stream.mix.cfg
+    path = f"/index/{cfg['index']}/query"
+    for round_, k in enumerate(stream.sweep):
+        for n, shard, slab in stream.take(k):
+            sent = loadgen.send_slab(c, loadgen.slab_requests(cfg, seed, shard, slab), n, shard, slab,
+                                     time.monotonic())
+            stream.sent(sent)
+            bad = [x for x in sent if x["status"] != 200]
+            if bad:
+                raise RunFailure(f"warm-up import {bad[0]['field']}/{shard} slab {slab} -> {bad[0]['status']}")
+        for cls, calls in m.every_variant(seed, round_, max(1, largest >> round_)):
+            status, body = c.request("POST", path, " ".join(calls).encode(), "text/plain")
+            if status != 200:
+                raise RunFailure(f"warm-up request of class {cls} after imports into {k} shards -> "
+                                 f"{status}: {body[:300]!r}")
+        log(f"warm-up: imported into {k} shard(s) and read every variant; device memory peak so far "
+            f"{peak_bytes()}")
+
+
+def warm_up(drv: Driver, c: Http, cfg: dict, mix: dict, seed: int, seconds: float,
+            stream: Stream | None = None, peak_bytes=None) -> dict:
     """Two passes.  The sweep sends every variant of every class as one
     request of 1, 2, 4, ... calls (``generator.Mix.sweep``): the shapes a
-    flight can take, in a fixed order.  Then the whole mix runs at the
-    window's concurrency until nothing has compiled or been retrieved from
-    the cache for a while: ``WARM_QUIET_S``, or a window's length in a
-    process that has compiled (a checkout's first runs), since a shape
-    that rare still has to be in the cache before a window meets it."""
+    flight can take, in a fixed order; then one call twice in a request
+    (``Mix.twins``: what two connections with the same question make of a
+    flight); for a mix that streams, then the imports and reads of
+    ``sweep_refresh``.  Then the whole mix runs at the
+    window's concurrency, its stream beside it for as many slabs as a window
+    is due, until nothing has compiled or been retrieved from the cache for
+    a while: ``WARM_QUIET_S``, or a window's length in a process that has
+    compiled (a checkout's first runs), since a shape that rare still has to
+    be in the cache before a window meets it."""
     from generator import Mix  # numpy only
 
     t0 = time.monotonic()
@@ -409,16 +495,31 @@ def warm_up(drv: Driver, c: Http, cfg: dict, mix: dict, seed: int, seconds: floa
     while largest < int(mix["connections"]):
         largest *= 2
     path = f"/index/{cfg['index']}/query"
-    for cls, calls in Mix(cfg, mix).sweep(seed, largest):
-        status, body = c.request("POST", path, " ".join(calls).encode(), "text/plain")
-        if status != 200:
-            raise RunFailure(f"warm-up request of class {cls} -> {status}: {body[:300]!r}")
+    m = Mix(cfg, mix)
+
+    def send(requests) -> None:
+        for cls, calls in requests:
+            status, body = c.request("POST", path, " ".join(calls).encode(), "text/plain")
+            if status != 200:
+                raise RunFailure(f"warm-up request of class {cls} -> {status}: {body[:300]!r}")
+
+    send(m.sweep(seed, largest))
+    before = ledger(c.json("GET", "/debug/vars"))
+    send(m.twins(seed, largest))
+    after = ledger(c.json("GET", "/debug/vars"))
+    log(f"warm-up twins (one call twice in a request): compiles {after[0] - before[0]}, "
+        f"retrievals {after[1] - before[1]}")
+    slabs = None
+    if stream is not None:
+        sweep_refresh(c, seed, stream, largest, peak_bytes)
+        # what the window meets, the warm-up met; the window's own slabs are kept for it
+        slabs = stream.take(min(stream.in_window, len(stream.plan) - stream.next - stream.in_window))
     dbg = c.json("GET", "/debug/vars")
     swept = ledger(dbg)
     sweep_s = time.monotonic() - t0
     log(f"warm-up sweep: {sweep_s:.1f}s, compiles {swept[0]}, retrievals {swept[1]}")
     seen = swept
-    run = drv.start("warm", WARM_LIMIT_S)
+    run = drv.start("warm", WARM_LIMIT_S, slabs=slabs)
     t_new = run["start_at"]
     while True:
         time.sleep(WARM_POLL_S)
@@ -434,7 +535,10 @@ def warm_up(drv: Driver, c: Http, cfg: dict, mix: dict, seed: int, seconds: floa
             raise RunFailure(f"warm-up still compiling after {WARM_LIMIT_S:.0f}s "
                              f"(compiles {seen[0]}, retrievals {seen[1]})")
     drv.stop()
-    drv.finish(run)
+    run = drv.finish(run)
+    if stream is not None:
+        stream.sent(run["imports"])
+        log(f"warm-up: {stream.next} slabs imported so far, {len(slabs)} at most beside the mixed flights")
     return {"seconds": time.monotonic() - t0, "sweep_s": sweep_s, "compiles": seen[0],
             "persistent_cache_hits": seen[1], "after_sweep": [seen[0] - swept[0], seen[1] - swept[1]]}
 
@@ -478,6 +582,57 @@ def window_numbers(win: dict, setup_s: float):
     e2e = {"read_qps": len(done) / (t1 - t0), "read_p50_ms": percentile(lat, 0.50),
            "read_p95_ms": percentile(lat, 0.95), "setup_s": setup_s}
     return reads, done, window, e2e, failed
+
+
+def stream_numbers(win: dict, window: dict) -> tuple[dict, int]:
+    """The window's import log reduced: counts into ``window`` (``imports``,
+    ``import_bits``: requests acknowledged inside it), the stream's own
+    numbers, and how many of its slabs were not acknowledged inside it."""
+    t1 = win["end_at"]
+    acked = [m for m in win["imports"] if m["status"] == 200 and m["t_ack"] <= t1]
+    if not acked:
+        raise RunFailure("no import was acknowledged inside the window")
+    window["imports"], window["import_bits"] = len(acked), sum(m["bits"] for m in acked)
+    slabs: dict[int, list[dict]] = {}
+    for m in win["imports"]:
+        slabs.setdefault(m["k"], []).append(m)
+    whole = sum(all(m["status"] == 200 and m["t_ack"] <= t1 for m in ms) for ms in slabs.values())
+    due = win["slabs_due"]
+    ack = sorted((m["t_ack"] - m["t_send"]) * 1e3 for m in win["imports"])
+    late = [(min(m["t_send"] for m in ms) - ms[0]["due"]) * 1e3 for ms in slabs.values()]
+    slab_s = [max(m["t_ack"] for m in ms) - min(m["t_send"] for m in ms) for ms in slabs.values()]
+    out = {"slabs": whole, "ack_p50_ms": percentile(ack, 0.50), "ack_p95_ms": percentile(ack, 0.95),
+           "late_mean_ms": sum(late) / len(late), "late_max_ms": max(late),
+           "slab_mean_s": sum(slab_s) / len(slab_s), "slab_max_s": max(slab_s)}
+    log(f"stream: {whole} of {due} slabs due acknowledged in the window ({len(acked)} requests, "
+        f"{window['import_bits']} bits); " + ", ".join(f"{k} {v:.3f}" for k, v in out.items() if k != "slabs"))
+    return out, due - whole
+
+
+def read_back(c: Http, m, seed: int) -> list[dict]:
+    """After the last acknowledgement: one request of every class of the
+    mix ``m``, ``TopN`` of every set field and ``Sum`` of every int field, for
+    the judge to hold against the reference with every import applied."""
+    rng = np.random.default_rng([int(seed), 0xBAC])
+    asks = [m.request(rng, cls) for cls in m.classes]
+    asks += [f"TopN({f['name']})" if f["kind"] == "set" else f"Sum(field={f['name']})"
+             for f in m.cfg["fields"]]
+    path = f"/index/{m.cfg['index']}/query"
+    out = []
+    for pql in asks:
+        status, body = c.request("POST", path, pql.encode(), "text/plain")
+        out.append({"pql": pql, "body": body if status == 200 else b""})
+    return out
+
+
+def log_spans(spans: dict | None, top: int = 14) -> None:
+    """The window's span table (``/debug/vars`` ``spans``, after minus before):
+    the rows with the most seconds, where the host's time went by name."""
+    rows = sorted(((row["seconds"], f"{block}.{name}", row) for block, names in (spans or {}).items()
+                   for name, row in names.items() if row.get("count")), reverse=True)[:top]
+    if rows:
+        log("spans of the window, count / seconds / self seconds: " + ", ".join(
+            f"{name} {row['count']} / {sec:.3f} / {row['self_seconds']:.3f}" for sec, name, row in rows))
 
 
 def check_sample(reads: list[dict], seed: int, check_max: int) -> list[dict]:
@@ -538,9 +693,10 @@ def read_layer_metric(name: str, ctx: dict) -> float:
 # ---------------------------------------------------------------------------
 
 
-def main(argv=None, child_script: str | None = None) -> int:
-    """``child_script`` is for the tests: a server child with the timed path
-    broken, or one that never serves."""
+def main(argv=None, child_script: str | None = None, manifest: dict | None = None) -> int:
+    """``child_script`` and ``manifest`` are for the tests: a server child
+    with the timed path broken, or one that never serves; ``BENCHMARK.json``
+    with a cell beside it that the grid does not hold yet."""
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=1)
@@ -548,7 +704,7 @@ def main(argv=None, child_script: str | None = None) -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearsal", action="store_true",
                     help="CPU, tiny shape, labelled, never a pass")
-    ap.add_argument("--control", choices=("lossy",),
+    ap.add_argument("--control", choices=("lossy", "stale"),
                     help="judge the reference with a guarantee broken in the program's place")
     ap.add_argument("--limit", type=float, default=RUN_LIMIT_S,
                     help="seconds from spawn to result line; for tests and for rehearsing a "
@@ -559,7 +715,7 @@ def main(argv=None, child_script: str | None = None) -> int:
         print("benchmark: the program is not here (no pilosa_tpu/cli.py); nothing to run",
               file=sys.stderr)
         return 2
-    manifest = mf.load()
+    manifest = manifest or mf.load()
     procs, work, cut = Procs(), None, None
     with Watch(args.limit) as watch:
         try:
@@ -590,6 +746,15 @@ def one_run(args, manifest: dict, procs: Procs, watch: Watch, work: str,
     seconds = float(args.seconds if args.seconds is not None else manifest["run_seconds"])
     if traced:
         seconds = min(seconds, TRACE_CAP_S)
+    from generator import Mix  # numpy only
+
+    try:
+        stream = Stream(Mix(cfg, mix), args.seed, seconds) if "stream" in mix else None
+        if args.control == "stale" and stream is None:
+            raise RunFailure(f"--control stale: the mix {cell['traffic']} streams nothing to be stale about")
+    except RunFailure as e:
+        print(f"benchmark: {e}", file=sys.stderr)
+        return 1, None
 
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
@@ -629,6 +794,8 @@ def one_run(args, manifest: dict, procs: Procs, watch: Watch, work: str,
         n_read = int(mix["processes"])
         n_load = min(LOADERS, int(cfg["shards"]))
         workers = [Worker(procs, i, work, cfg, mix) for i in range(max(n_read, n_load))]
+        # the stream has a process to itself: its pace is not the readers' interpreter's
+        streamer = Worker(procs, len(workers), work, cfg, mix) if stream else None
         for cid in range(int(mix["connections"])):
             workers[cid % n_read].conns.append(cid)
         for i, w in enumerate(workers[:n_load]):
@@ -642,8 +809,10 @@ def one_run(args, manifest: dict, procs: Procs, watch: Watch, work: str,
 
         watch.stage("warm-up")
         drv = Driver(workers, work, {"seed": args.seed, "port": srv.port, "index": cfg["index"],
-                                     "check_one_in": int(mix.get("check_one_in", 40))})
-        warm = warm_up(drv, c, cfg, mix, args.seed, seconds)
+                                     "check_one_in": int(mix.get("check_one_in", 40))},
+                     streamer, stream.every_s if stream else 0.0)
+        warm = warm_up(drv, c, cfg, mix, args.seed, seconds, stream,
+                       lambda: srv.control("device")["memory_peak_bytes"])
         log(f"warm-up: {warm['seconds']:.1f}s, compiles {warm['compiles']}, retrievals "
             f"{warm['persistent_cache_hits']}; after the sweep {warm['after_sweep']}")
 
@@ -654,7 +823,10 @@ def one_run(args, manifest: dict, procs: Procs, watch: Watch, work: str,
             srv.control(f"trace_start {trace_dir}")
             t_trace0 = time.monotonic()
         vars0 = c.json("GET", "/debug/vars")
-        win = drv.finish(drv.start("window", seconds, lead=0.3))
+        win = drv.finish(drv.start("window", seconds, lead=0.3,
+                                   slabs=stream.take(stream.in_window) if stream else None))
+        if stream is not None:
+            stream.sent(win["imports"])
         vars1 = c.json("GET", "/debug/vars")
         if traced:
             window_s = time.monotonic() - t_trace0
@@ -666,10 +838,11 @@ def one_run(args, manifest: dict, procs: Procs, watch: Watch, work: str,
         setup_s = win["start_at"] - srv.t_spawn
         log(f"window of {seconds:.1f}s done; generator CPU share per process {win['cpu_share']}")
         device = srv.control("device")
+        readback = read_back(c, stream.mix, args.seed) if stream else []
 
         watch.stage("stop")
         c.close()
-        for w in workers:
+        for w in workers + ([streamer] if streamer else []):
             w.stop()
         srv.stop()
     except RunFailure as e:
@@ -700,29 +873,48 @@ def one_run(args, manifest: dict, procs: Procs, watch: Watch, work: str,
                          "persistent_cache_hits": hits0, "load_bits_per_s": load_bits / load_s},
                "window": window, "vars": delta(vars1, vars0), "vars_start": vars0,
                "trace": trace, "e2e": e2e}
+        log_spans(ctx["vars"].get("spans"))
+        if stream is not None:
+            ctx["stream"], short = stream_numbers(win, window)
 
         # ---- judge: the window's own answers against the reference ---------
         watch.stage("judge")
-        from compare import CONTROLS, judge_reads
+        from compare import CONTROLS, judge_readback, judge_reads
         from reference import Reference
+
+        def reference(kind=Reference):
+            """At the state of the load; a stream writes past the load's columns."""
+            ref = kind(cfg, args.seed, extent=(1 << int(cfg["shard_width_exp"])) if stream else None)
+            ref.load()
+            return ref
 
         t_ref = time.monotonic()
         sample = check_sample(reads, args.seed, int(mix.get("check_max", 128)))
-        ref = Reference(cfg, args.seed)
-        ref.load()
-        verdict = judge_reads(ref, sample)
+        imports = stream.imports if stream else []
+        ref = reference()
+        verdict = judge_reads(ref, sample, imports)
         compared = {
             "read_mismatches": [verdict["mismatches"], 0],
             "window_compiles": [compiles1 - compiles0, 0],
             "failed_requests": [failed, 0],
-            # read classes that answered in the window and had no answer judged
+            # read classes that answered in the window and had no answer judged exactly
             "classes_unjudged": [len({r["cls"] for r in done} - set(verdict["per_class"])), 0],
         }
         examples = verdict["examples"]
+        if stream is not None:
+            back = judge_readback(ref, imports, readback)
+            examples += back["examples"]
+            compared.update({
+                "imports_failed": [sum(m["status"] != 200 for m in imports), 0],
+                "readback_mismatches": [back["mismatches"], 0],
+                # slabs due in the window and not acknowledged in it
+                "stream_slabs_short": [short, 0],
+            })
+            log(f"imports: {len(imports)} requests in the run, {verdict['certain']} certain for the last "
+                f"judged read; reads by imports in flight beside them {verdict['in_flight']}, "
+                f"{verdict['unjudged']} of them not judged; read-back of {back['compared']} answers")
         if args.control:
-            control = CONTROLS[args.control](cfg, args.seed)
-            control.load()
-            cv = judge_reads(ref, sample, control=control)
+            cv = judge_reads(reference(), sample, imports, control=reference(CONTROLS[args.control]))
             compared["control_mismatches"] = [cv["mismatches"], 0]
             examples += ["control " + args.control + ": " + x for x in cv["examples"][:3]]
         if args.rehearsal:

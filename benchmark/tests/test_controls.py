@@ -2,7 +2,8 @@
 
 The control puts the reference in the program's place with a stated
 guarantee broken (``compare.py``): ``lossy``, a store that acknowledged
-every import and lost one column in sixteen, so that no answer is exact.
+every import and lost one column in sixteen, so that no answer is exact
+(``stale``, for a mix that writes, is in ``test_ingest.py``).
 It has to come out as not correct while the program's own answers in the
 same run stay sound.  And with the timed path itself broken underneath
 (every Count one too high, ``broken_child.py``) the run's own verdict is

@@ -93,3 +93,69 @@ def test_encoder_speaks_the_programs_roaring():
     dense = np.unique(rng.integers(1 << 22, (1 << 22) + (1 << 17), 90000)).astype(np.uint64)
     pos = np.concatenate([sparse, dense])
     assert np.array_equal(roaring.deserialize(datagen.encode_roaring(pos)), pos)
+
+
+@pytest.mark.parametrize("workload", CELLS)
+def test_twins_hold_one_unsent_call_twice(workload):
+    """After the sweep, every variant once more: one request of one call
+    twice, a call the sweep has not sent; a function of the seed."""
+    _, cfg, mix = run.load_cell(MANIFEST, workload, rehearsal=False)
+    m = generator.Mix(cfg, mix)
+    a = list(m.twins(2**31 + 7, 32))
+    assert a == list(m.twins(2**31 + 7, 32)) and a != list(m.twins(2**31 + 8, 32))
+    swept = {c for _, calls in m.sweep(2**31 + 7, 32) for c in calls}
+    firsts = [calls[0] for _, calls in a]
+    assert all(len(calls) == 2 and calls[0] == calls[1] for _, calls in a)
+    assert len(set(firsts)) == len(firsts) and not swept & set(firsts)
+    for cls, c in mix["classes"].items():
+        for v in c["variants"]:
+            if "Intersect" in v:  # what the planner shares; a variant of few rows is all sent by then
+                assert any(k == cls and calls[0].startswith(v.split("{")[0]) for k, calls in a), v
+
+
+def test_after_the_twins_one_question_twice_in_a_flight_compiles_nothing():
+    """What ``Mix.twins`` is for, on the program itself: two requests with
+    the same filtered range count in one flight, neither cached, go through
+    the planner's shared-subtree pass and ``Executor._bsi_rows`` over the
+    live stack.  Once the warm-up's requests of the class have been sent,
+    such a pair compiles nothing, for ``<`` and for ``>`` (without the twins
+    it compiles 3 + 1 programs on the CPU; 7 inside a window on the chip)."""
+    from pilosa_tpu.core.field import FieldOptions
+    from pilosa_tpu.core.holder import Holder
+    from pilosa_tpu.exec.executor import Executor
+    from pilosa_tpu.obs import devledger
+
+    _, cfg, mix = run.load_cell(MANIFEST, "taxi.dashboard-c32", rehearsal=True)
+    m = generator.Mix(cfg, mix)
+    fields = datagen.fields_by_name(cfg)
+    holder = Holder()
+    idx = holder.create_index(cfg["index"])
+    amount = fields["total_amount"]
+    for name in ("cab_type", "pickup_year"):
+        idx.create_field(name, FieldOptions())
+    idx.create_field("total_amount", FieldOptions(field_type="int", min_=amount["min"], max_=amount["max"]))
+    ex = Executor(holder)
+    rng = np.random.default_rng(5)
+    cabs, years = datagen.popularity_order(fields["cab_type"]), datagen.popularity_order(fields["pickup_year"])
+    for lo in range(0, 2000, 100):
+        ex.execute(cfg["index"], " ".join(
+            f"Set({i}, cab_type={rng.choice(cabs)}) Set({i}, pickup_year={rng.choice(years)}) "
+            f"Set({i}, total_amount={rng.integers(amount['min'], amount['max'] + 1)})" for i in range(lo, lo + 100)))
+
+    def flight(*requests):
+        before = devledger.counters()["compiles"]
+        out = ex.execute_batch(cfg["index"], [(r, None) for r in requests])
+        assert not any(isinstance(x, Exception) for x in out), out
+        return devledger.counters()["compiles"] - before
+
+    # the classes before this one have built the filter fields' stacks, as in a run
+    flight(" ".join(f"Count(Intersect(Row(cab_type={a}), Row(pickup_year={b})))" for a in cabs[:2] for b in years[:2]))
+    twins = [calls for cls, calls in m.twins(3, 4) if cls == "range_count"]
+    assert len(twins) == 4
+    for calls in [calls for cls, calls in m.sweep(3, 4) if cls == "range_count"] + twins:
+        flight(" ".join(calls))
+    lt = "Count(Intersect(Row(cab_type={c}), Row(total_amount < {v})))"
+    gt = "Count(Intersect(Row(pickup_year={y}), Row(total_amount > {v})))"
+    assert lt in mix["classes"]["range_count"]["variants"] and gt in mix["classes"]["range_count"]["variants"]
+    for text in (lt.format(c=cabs[0], v=4321), gt.format(y=years[1], v=8765)):
+        assert flight(text, text) == 0, text

@@ -9,6 +9,7 @@ false, the exit code is 3, and the one number over its limit is
 reference (``classes_unjudged`` 0), none disagrees, and nothing compiled
 inside the window (``window_compiles`` 0).  And a run that ends well leaves
 no process behind (``test_processes.py`` has the runs that end otherwise).
+``test_ingest.py`` holds a cell that the grid does not have yet to the same.
 """
 
 import json
@@ -27,8 +28,9 @@ MANIFEST = mf.load()
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 
 
-def rehearse(capfd, *extra, child_script=None):
-    rc = run.main(["--seed", "11", "--seconds", "3", "--rehearsal", *extra], child_script=child_script)
+def rehearse(capfd, *extra, child_script=None, manifest=None):
+    rc = run.main(["--seed", "11", "--seconds", "3", "--rehearsal", *extra], child_script=child_script,
+                  manifest=manifest)
     out, err = capfd.readouterr()
     assert children_of(os.getpid()) == [], "the run left a process"
     lines = out.strip().splitlines()
@@ -36,18 +38,24 @@ def rehearse(capfd, *extra, child_script=None):
     return rc, json.loads(lines[-1]), err
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-@pytest.mark.parametrize("workload", CELLS)
-def test_result_line_is_the_manifests(capfd, workload, trace):
-    rc, line, err = rehearse(capfd, "--workload", workload, "--trace", str(trace))
+def rehearsed_line(capfd, manifest, workload, trace):
+    """(line, stderr) of a rehearsal that came out as ``manifest`` says."""
+    rc, line, err = rehearse(capfd, "--workload", workload, "--trace", str(trace), manifest=manifest)
     assert rc == 3 and line["correct"] is False
-    assert mf.validate_line(MANIFEST, workload, bool(trace), line) == []
-    want = [m["name"] for m in mf.metrics_for(MANIFEST, workload, bool(trace))]
+    assert mf.validate_line(manifest, workload, bool(trace), line) == []
+    want = [m["name"] for m in mf.metrics_for(manifest, workload, bool(trace))]
     assert list(line["metrics"]) == want and want
     assert list(line)[-1] == "compared"
     over = {k: v for k, (v, limit) in line["compared"].items() if v > limit}
     assert over == {"rehearsal": 1}, err[-3000:]
     assert line["device"]["platform"] == "cpu"
+    return line, err
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+@pytest.mark.parametrize("workload", CELLS)
+def test_result_line_is_the_manifests(capfd, workload, trace):
+    rehearsed_line(capfd, MANIFEST, workload, trace)
 
 
 def test_no_program_no_result(tmp_path, capfd):
