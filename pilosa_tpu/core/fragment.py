@@ -990,6 +990,37 @@ class Fragment:
             n = len(self._rowids)
             return list(self._rowids), self._host[:n].copy()
 
+    def stack_block(self, slot_of: dict[int, int], on_device: bool):
+        """This shard's ``[R, W]`` block of a serving stack whose row axis
+        is ``slot_of`` (row id -> position; exec/stacks.py refreshes a
+        stack from it after a write), in one locked look.  ``(dev,
+        slots)`` where ``on_device`` and the device copy is at hand: the
+        copy, synced, and per position the slot to gather from it — the
+        zero row for a row this fragment lacks — so the block never
+        passes through the host.  Else ``(None, block)``, gathered from
+        the host mirror.  None when the fragment holds a row the stack has
+        no position for."""
+        with self._lock:
+            n = len(self._rowids)
+            dst = np.fromiter(
+                (slot_of.get(r, -1) for r in self._rowids), np.int64, n
+            )
+            if (dst < 0).any():
+                return None
+            resident = (
+                on_device
+                and self._device is not None
+                and not self._evict_pending
+                and self._device.shape[0] == self.capacity + 1
+            )
+            if resident:
+                slots = np.full(len(slot_of), self.capacity, dtype=np.int32)
+                slots[dst] = np.arange(n, dtype=np.int32)
+                return self.device_bits(), slots
+            block = np.zeros((len(slot_of), self.n_words), dtype=np.uint32)
+            block[dst] = self._host[:n]
+            return None, block
+
     def row_count(self, row: int) -> int:
         with self._lock:
             s = self._slot_of.get(row)
@@ -1178,16 +1209,23 @@ class Fragment:
         (to_host_rows + np.stack would copy the mirror twice).
         All-zero rows are kept; they serialize to zero containers."""
         with self._lock:
-            if not self._slot_of:
-                return (
-                    np.empty(0, dtype=np.uint64),
-                    np.empty((0, self.n_words), dtype=np.uint32),
-                )
+            rids, slots, host = self.snapshot_source()
+            return rids, host[slots]
+
+    def snapshot_source(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """(ascending row ids, their slots, the host mirror): what
+        :meth:`snapshot_rows` copies, ``host[slots]``, for a caller that
+        makes the copy itself once the lock is let go (524 MB for a field
+        of 4,000 rows: half a second in which every reader of the
+        fragment would wait) and drops it if the fragment was written
+        meanwhile (storage/fragmentfile.py: ``mut_seq``, read under this
+        lock before and after)."""
+        with self._lock:
             rids = np.array(sorted(self._slot_of), dtype=np.uint64)
             slots = np.array(
                 [self._slot_of[int(r)] for r in rids], dtype=np.int64
             )
-            return rids, self._host[slots]
+            return rids, slots, self._host
 
     def load_host_rows(self, rows: dict[int, np.ndarray]) -> None:
         with self._lock:

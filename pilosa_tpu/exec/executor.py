@@ -572,6 +572,7 @@ class Executor:
             stacks_mod.note_single(field, "pair") >= self._PAIR_SINGLE_WARM,
         )
 
+    @stacks_mod.reading()
     def _batch_pair_counts(
         self, idx: Index, calls: list[Call], shards: list[int] | None,
         results: list[Any],
@@ -722,6 +723,7 @@ class Executor:
         self.stacks.refusals["demand"] += 1
         return None
 
+    @stacks_mod.reading()
     def _batch_general(
         self, idx: Index, calls: list[Call], shards: list[int] | None,
         results: list[Any],
@@ -1039,6 +1041,7 @@ class Executor:
             return sorted(shards)
         return sorted(idx.available_shards())
 
+    @stacks_mod.reading()
     def _execute_call(self, idx: Index, call: Call, shards: list[int] | None) -> Any:
         name = call.name
         # Stop before starting a shard scan the caller will never wait
@@ -1136,6 +1139,7 @@ class Executor:
             return ValCount()
         return ValCount(value=total + count * field.base, count=count)
 
+    @stacks_mod.reading()
     def _batch_bsi(
         self, idx: Index, calls: list[Call], shards: list[int] | None,
         results: list[Any],

@@ -16,13 +16,17 @@ The counters are per executor: :class:`Stacks`.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import weakref
+from functools import lru_cache
 
 import numpy as np
 
 import jax
+import jax.numpy as jnp
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from pilosa_tpu.core import membudget, residency
@@ -72,19 +76,115 @@ _FIELD_KEY = "_serving_stacks"
 _lru_clock = itertools.count()
 
 
+def _put_gathered(bits, si, dev, slots):
+    """Shard ``si`` of a stack := rows ``slots`` of a fragment's device
+    copy (a position the fragment has no row for gathers its zero row)."""
+    return lax.dynamic_update_index_in_dim(bits, dev[slots], si, 0)
+
+
+def _put_block(bits, si, block):
+    """Shard ``si`` of a stack := ``block``."""
+    return lax.dynamic_update_index_in_dim(bits, block, si, 0)
+
+
+@lru_cache(maxsize=16)
+def _put_block_mesh(mesh, axis):
+    """:func:`_put_block` over a stack whose shard axis is split over
+    ``axis`` of ``mesh``: every device looks whether ``si`` falls into its
+    own share and writes there, so the array stays where it lies."""
+
+    def local(b, si, block):
+        n = b.shape[0]
+        k = si - lax.axis_index(axis) * n
+        mine = (k >= 0) & (k < n)
+        k = jnp.clip(k, 0, n - 1)
+        kept = lax.dynamic_index_in_dim(b, k, 0, keepdims=False)
+        return _put_block(b, k, jnp.where(mine, block, kept))
+
+    P = PartitionSpec
+    return jax.jit(
+        shard_map(
+            local, mesh=mesh,
+            in_specs=(P(axis, None, None), P(), P(None, None)),
+            out_specs=P(axis, None, None),
+        ),
+        donate_argnums=0,
+    )
+
+
+# The stack is donated: the write goes into the array that is there.  One
+# program a stack shape whatever the number of changed shards (a refresh
+# writes them one by one, ``si`` traced).
+_PUT_GATHERED = jax.jit(_put_gathered, donate_argnums=0)
+_PUT_BLOCK = jax.jit(_put_block, donate_argnums=0)
+
+
+# per thread: [depth, {stacks on lease}] of the open scope, or None
+_tls = threading.local()
+
+
+class reading(contextlib.ContextDecorator):
+    """The lease scope of the calling thread, ``with reading():`` or
+    ``@reading()``: every stack whose ``bits`` the thread reads inside it
+    is on lease to it until the outermost scope of the thread ends (a
+    scope opened inside another joins it).  The executor opens one around
+    each batch lane and each per-call execution, ``Stacks.prefetch`` one
+    of its own: a scope ends once its launches are enqueued and never
+    spans a write of the same request."""
+
+    def __enter__(self):
+        scope = getattr(_tls, "scope", None)
+        if scope is None:
+            scope = _tls.scope = [0, set()]
+        scope[0] += 1
+        return self
+
+    def __exit__(self, *exc):
+        scope = _tls.scope
+        scope[0] -= 1
+        if not scope[0]:
+            _tls.scope = None
+            for stack in scope[1]:
+                with stack._lock:
+                    stack._leased -= 1
+        return False
+
+
+def _held(stack: "Stack") -> bool:
+    """Whether the calling thread's open scope has ``stack`` on lease."""
+    scope = getattr(_tls, "scope", None)
+    return scope is not None and stack in scope[1]
+
+
 class Stack:
     """One view of a field over one shard list, on the device.
 
     ``slot_of`` maps row id to position on the row axis and never changes
-    while the stack lives; ``bits`` is the current device snapshot,
-    replaced in place by an incremental refresh.  A caller reads
-    ``bits`` once and hands ``(stack, bits)`` to whatever caches against
-    it: a derived value is served only for exactly the snapshot it was
-    computed from."""
+    while the stack lives; ``bits`` is the current device snapshot.  A
+    caller reads ``bits`` once and hands ``(stack, bits)`` to whatever
+    caches against it: a derived value is served only for exactly the
+    snapshot it was computed from.
+
+    **The snapshot rule.**  An incremental refresh after a write goes IN
+    PLACE: the changed shards are written into the array that is there
+    by a program the array is donated to, so the old snapshot is deleted
+    (``is_deleted()``) and a new array object over the same memory takes
+    its place.  Who reads ``bits`` inside a lease scope
+    (:class:`reading`) holds the stack on lease until the scope ends,
+    and a leased stack is never refreshed in place: another thread's
+    refresh then copies (out of place, as every refresh did before;
+    ``Stacks.refresh_out_of_place``), and the leaseholder's own scope is
+    handed the stack as it holds it (:meth:`Stacks.get`).  Lease and
+    refresh both run under the field's lock, so neither can slip
+    between the other's check and act.  A launch already enqueued on a
+    snapshot outlives its donation (the runtime orders the write after
+    the reads), which is why a scope may end before its results are
+    pulled.  ``bits`` read outside any scope (tests, tools) is valid
+    only until the next refresh."""
 
     __slots__ = (
         "name", "versions", "slot_of", "bkey", "lru", "hits", "pinned",
-        "prefetched", "_lock", "_snap",
+        "prefetched", "_lock", "_snap", "_leased",
     )
 
     def __init__(self, name, versions, slot_of, bits, bkey, prefetched, lock):
@@ -105,10 +205,18 @@ class Stack:
         # (snapshot, {name: {key: value}}): the device array and every
         # value derived from it, swapped together in one statement
         self._snap = (bits, {})
+        # scopes that hold the stack on lease (under the field's lock)
+        self._leased = 0
 
     @property
     def bits(self):
-        return self._snap[0]
+        scope = getattr(_tls, "scope", None)
+        if scope is None or self in scope[1]:
+            return self._snap[0]
+        with self._lock:
+            self._leased += 1
+            scope[1].add(self)
+            return self._snap[0]
 
     def refresh(self, bits, versions) -> None:
         """A new snapshot; every derived value and reuse count of the old
@@ -246,6 +354,14 @@ class Stacks:
         # replace full re-uploads on write-interleaved workloads)
         self.rebuilds = 0
         self.incremental = 0
+        # what the incremental refreshes wrote on the device (the changed
+        # shards' blocks), what of it was gathered on the host and
+        # shipped (0 where the fragments' device copies were the
+        # source), and how many had to copy the stack because a reader
+        # held its snapshot on lease
+        self.refresh_bytes = 0
+        self.refresh_host_bytes = 0
+        self.refresh_out_of_place = 0
         # pair counts answered from the cached host gram (zero device
         # work — the serving mode for repeat sequential queries)
         self.gram_hits = 0
@@ -317,10 +433,13 @@ class Stacks:
 
         Maintenance is INCREMENTAL: when cached fragment versions drift
         but the row set is unchanged, only the changed shards' row blocks
-        are scattered into the device stack (one launch) instead of
-        re-uploading the whole field — the write-batch analogue of the
-        reference applying ops to an mmap'd fragment in place
-        (fragment.go:2284-2293). None when over budget or empty."""
+        are written into the device stack, in place (:meth:`_refresh`),
+        instead of re-uploading the whole field — the write-batch
+        analogue of the reference applying ops to an mmap'd fragment in
+        place (fragment.go:2284-2293).  A stack the calling thread's
+        scope already holds on lease is handed back as it is held
+        (:class:`Stack`, the snapshot rule).  None when over budget or
+        empty."""
         v = field.view(view)
         if v is None:
             return None
@@ -347,6 +466,8 @@ class Stacks:
             entries = state.entries
             stack = entries.get(cache_key)
             if stack is not None:
+                if _held(stack):
+                    return stack  # as the scope holds it: the snapshot rule
                 # LRU: stamp the stack on every hit; eviction below drops
                 # the min-stamp one.  A stamp (vs dict pop/reinsert)
                 # leaves the budget's lock-free _evict pop as the only
@@ -360,9 +481,16 @@ class Stacks:
                 stack.hits += 1
                 tracker = residency.default_tracker()
                 fresh = stack.versions == versions
-                if fresh or self._refresh(
-                    field, stack, frags, shards, versions
-                ):
+                try:
+                    current = fresh or self._refresh(
+                        field, stack, frags, shards, versions
+                    )
+                except BaseException:
+                    # a write that failed may have consumed the array it
+                    # was donated
+                    _retire(entries, cache_key, budget)
+                    raise
+                if current:
                     budget.touch(stack.bkey)
                     if not tracker.in_prefetch():
                         tracker.note_stack_hit()
@@ -385,6 +513,9 @@ class Stacks:
                         tracker.note_prefetch_upload(0)
                     return stack
                 _retire(entries, cache_key, budget)
+                # the build below uploads the successor: the retired
+                # array must not live on in this frame beside it
+                del stack
 
             with tracing.start_span("executor.stackBuild").set_tag(
                 "field", field.name
@@ -394,6 +525,7 @@ class Stacks:
                     versions, budget, state, sp,
                 )
 
+    @reading()
     def prefetch(
         self, field: Field, shards: list[int], view: str = VIEW_STANDARD
     ) -> None:
@@ -554,10 +686,19 @@ class Stacks:
     def _refresh(
         self, field: Field, stack: Stack, frags, shards: list[int], versions
     ) -> bool:
-        """Refresh changed shards of a cached stack in one device scatter;
-        False when a full rebuild is needed (row set grew, or too many
-        shards drifted)."""
-        slot_of = stack.slot_of
+        """Write the changed shards of a cached stack into the array that
+        is there (under the field's lock); False when a full rebuild is
+        needed (row set grew, or too many shards drifted).
+
+        Each changed shard's ``[R, W]`` block goes in by one program the
+        stack is donated to, so a refresh needs the block beside the
+        stack and never a second stack.  The block is gathered on the
+        device from the fragment's own copy where that is at hand
+        (``Fragment.stack_block``: no host gather, nothing shipped), else
+        on the host; a stack laid over a mesh takes the host's block,
+        every device writing its own share.  Only a stack some reader
+        holds on lease is copied first, by the runtime and by no program
+        of its own, and the copy is written as any stack is."""
         changed = [
             si for si, (a, b) in enumerate(zip(stack.versions, versions))
             if a != b
@@ -566,28 +707,60 @@ class Stacks:
             1, int(len(shards) * INCR_MAX_FRACTION)
         ):
             return False
-        R = len(slot_of)
-        W = field.n_words
-        blocks = np.zeros((len(changed), R, W), dtype=np.uint32)
-        for k, si in enumerate(changed):
-            f = frags.get(shards[si])
-            if f is None:
-                return False
-            # ONE locked snapshot: checking membership via a separate
-            # row_ids() call would race a concurrent ingest adding a row
-            # between the check and the copy
-            ids, matrix = f.rows_matrix_host()
-            dst = [slot_of.get(r) for r in ids]
-            if any(s is None for s in dst):
-                return False  # new row: shape change, full rebuild
-            if ids:
-                blocks[k, dst] = matrix
-        stack.refresh(
-            stack.bits.at[kernels.h2d(changed, dtype=np.int32)].set(
-                kernels.h2d(blocks)
-            ),
-            versions,
-        )
+        if any(frags.get(shards[si]) is None for si in changed):
+            return False
+        slot_of = stack.slot_of
+        bits = stack._snap[0]  # not ``.bits``: that would take a lease
+        layout = kernels.shards_axis_of(bits)
+        block_bytes = bits.nbytes // bits.shape[0]
+        leased = bool(stack._leased)
+        if leased:
+            # a leased snapshot stays as it is: the writes go into a copy
+            # the runtime makes
+            bits = jax.device_put(bits, may_alias=False)
+        written = host_bytes = 0
+        with tracing.start_span("stacks.refresh").set_tag(
+            "field", field.name
+        ).set_tag("shards", len(changed)) as sp:
+            try:
+                for si in changed:
+                    src = frags[shards[si]].stack_block(
+                        slot_of, on_device=layout is None
+                    )
+                    if src is None:
+                        return False  # new row: shape change, full rebuild
+                    dev, rows = src
+                    if dev is not None:
+                        put, args = _PUT_GATHERED, (dev, rows)
+                    else:
+                        host_bytes += rows.nbytes
+                        if layout is None:
+                            put, to = _PUT_BLOCK, None
+                        else:
+                            put = _put_block_mesh(*layout)
+                            to = NamedSharding(layout[0], PartitionSpec())
+                        args = (kernels.h2d(rows, to),)
+                    with DL_STACK.launch(
+                        sig=f"refresh {bits.shape}"
+                    ), kernels.enqueue("stack_refresh"):
+                        bits = put(bits, np.int32(si), *args)
+                    written += 1
+            finally:
+                if written:
+                    # the donated array is gone: what was written is the
+                    # snapshot now, under the new versions if all of it was
+                    stack.refresh(
+                        bits,
+                        versions if written == len(changed)
+                        else stack.versions,
+                    )
+                sp.set_tag("bytes", written * block_bytes).set_tag(
+                    "route", "host" if host_bytes else "device"
+                )
+                self.refresh_bytes += written * block_bytes
+                self.refresh_host_bytes += host_bytes
+                kernels.note_transfer(host_bytes, "h2d", dl_site=DL_STACK)
+        self.refresh_out_of_place += leased
         self.incremental += 1
         qprofile.incr("stack_incremental")
         return True

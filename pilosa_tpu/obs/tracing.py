@@ -130,6 +130,7 @@ SPAN_TABLE = (
     ("executor.groupByBatch", _LANES, _HOST_MS),
     ("executor.groupByKLevel", _LANES, _HOST_MS),
     ("executor.stackBuild", _LANES, _HOST_MS),
+    ("stacks.refresh", _LANES, "stacks.refresh_ms_per_import"),
     ("executor.bsiSplit", _LANES, _HOST_MS),
     ("executor.demux", _LANES, _HOST_MS),
     ("executor.mapReduce", _CLUSTER, _HOST_MS),

@@ -453,6 +453,12 @@ class Handler(BaseHTTPRequestHandler):
                 "bsi_agg_hits": ex.stacks.bsi_agg_hits,
                 "stack_rebuilds": ex.stacks.rebuilds,
                 "stack_incremental": ex.stacks.incremental,
+                # the incremental refreshes: bytes written on the device,
+                # bytes of them gathered on the host and shipped, and
+                # refreshes that copied a stack a reader held on lease
+                "stack_refresh_bytes": ex.stacks.refresh_bytes,
+                "stack_refresh_host_bytes": ex.stacks.refresh_host_bytes,
+                "stack_refresh_out_of_place": ex.stacks.refresh_out_of_place,
                 "bsi_stack_launches": ex.bsi_stack_launches,
                 # stacks not built, by reason (exec/stacks.py), and
                 # flight items a batch lane handed back to the per-call

@@ -344,11 +344,13 @@ class FragmentFile:
         """Atomic rewrite: temp file + rename (reference
         fragment.go:2335-2381).
 
-        The expensive work (position extraction + roaring encode + fsync)
-        runs WITHOUT the fragment lock, from a copied state — a snapshot
-        worker must not stall concurrent queries/ingest for the whole
-        rewrite. The swap then happens under the lock only if no op was
-        appended since the copy (an op landing in between would be in the
+        The expensive work (the copy of the mirror, the roaring encode)
+        runs WITHOUT the fragment lock — a snapshot worker must not stall
+        concurrent queries/ingest for the whole rewrite, nor for the
+        copy: an import of more than MAX_OP_N bits snapshots its fragment,
+        and a reader beside it waited half a second for 4,000 rows. The
+        swap then happens under the lock only if no op was appended since
+        the source was taken (an op landing in between would be in the
         fragment's mirror but lost from the replaced file's op log);
         otherwise retry with a fresh copy, degrading to the fully locked
         path after _SNAPSHOT_RETRIES so a continuous writer can't
@@ -372,8 +374,10 @@ class FragmentFile:
                         # cleanup) must not resurrect the deleted file.
                         return
                     seq_at = self.mut_seq
-                rids, rwords = self.fragment.snapshot_rows()
-            data = self._encode_rows(rids, rwords)
+                rids, slots, host = self.fragment.snapshot_source()
+            # the copy too is made with no lock held: a write landing
+            # in it advances mut_seq like one landing in the encode
+            data = self._encode_rows(rids, host[slots])
             with self.fragment._lock, self._lock:
                 if self._closed:
                     return

@@ -444,14 +444,14 @@ def test_profiler_session_holds_the_programs_spans_nested(tmp_path):
     files = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
     assert len(files) == 1
     lines = _host_events(files[0])
-    ours = {ln: [e for e in evs if e[0].split(".")[0] in ("batcher", "executor", "kernels", "planner")]
+    ours = {ln: [e for e in evs if e[0].split(".")[0] in ("batcher", "executor", "kernels", "planner", "stacks")]
             for ln, evs in lines.items()}
     dispatcher = [ln for ln, evs in ours.items() if any(e[0] == "batcher.flight" for e in evs)]
     assert len(dispatcher) == 1, {ln: sorted({e[0] for e in evs}) for ln, evs in ours.items() if evs}
     evs = ours[dispatcher[0]]
     names = {e[0] for e in evs}
     assert {"batcher.collect", "batcher.flight", "executor.ExecuteBatch", "executor.batchPairCount",
-            "kernels.enqueue", "kernels.pull"} <= names
+            "stacks.refresh", "kernels.enqueue", "kernels.pull"} <= names
 
     def inside(inner, outer):
         return outer[1] <= inner[1] and inner[2] <= outer[2]
@@ -459,9 +459,11 @@ def test_profiler_session_holds_the_programs_spans_nested(tmp_path):
     flight = next(e for e in evs if e[0] == "batcher.flight")
     batch = next(e for e in evs if e[0] == "executor.ExecuteBatch")
     lane = next(e for e in evs if e[0] == "executor.batchPairCount")
-    assert inside(batch, flight) and inside(lane, batch)
+    # the write made the lane's stack stale: it is refreshed where the lane takes it
+    refresh = next(e for e in evs if e[0] == "stacks.refresh")
+    assert inside(batch, flight) and inside(lane, batch) and inside(refresh, batch)
     for leaf in ("kernels.enqueue", "kernels.pull"):
-        assert all(inside(e, lane) for e in evs if e[0] == leaf), leaf
+        assert all(inside(e, lane) or inside(e, refresh) for e in evs if e[0] == leaf), leaf
     collect = next(e for e in evs if e[0] == "batcher.collect")
     assert collect[2] <= flight[1]  # siblings: the window closes before the flight starts
     # the member-side spans were built after the fact: the table has them, the trace has not

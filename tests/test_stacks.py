@@ -171,3 +171,348 @@ def test_drop_retires_the_stacks_and_gives_their_bytes_back(served):
     assert ex.stacks.cached(field, shards) and budget.used() > used
     stacks.drop(field)
     assert not ex.stacks.cached(field, shards) and budget.used() == used
+
+
+# --------------------------------------------------------------------------
+# The refresh after a write: in place, from the fragments' device copies
+# where they are at hand, and the snapshot rule that makes donation safe.
+# --------------------------------------------------------------------------
+
+import json
+import threading
+import urllib.request
+import weakref
+
+from pilosa_tpu.exec import stacks
+from pilosa_tpu.parallel import mesh
+
+SHARDS = 4
+F_ROWS, DEPTH_MAX = 9, 4000
+
+
+@pytest.fixture(params=[1, 4], ids=["one-device", "mesh-of-4"])
+def devices(request):
+    mesh.configure_serving(request.param)
+    yield request.param
+    mesh.configure_serving(None)
+
+
+def _imported(seed=3):
+    """(executor, index): a set field ``f`` (row 8 in shard 0 alone) and an
+    int field ``v`` over four shards, stacks not built yet."""
+    h = Holder()
+    idx = h.create_index("i")
+    width = idx.n_words * 32
+    rng = np.random.default_rng(seed)
+    cols = rng.choice(SHARDS * width, size=1200, replace=False).astype(np.uint64)
+    rows = rng.integers(0, F_ROWS - 1, size=cols.size).astype(np.uint64)
+    rows[cols < width][:5] = F_ROWS - 1
+    f = idx.create_field("f")
+    f.import_bits(rows, cols)
+    f.import_bits(np.full(5, F_ROWS - 1, np.uint64), np.arange(5, dtype=np.uint64))
+    v = idx.create_field(
+        "v", FieldOptions(field_type="int", min_=0, max_=DEPTH_MAX)
+    )
+    v.import_values(cols, rng.integers(0, DEPTH_MAX, size=cols.size))
+    return Executor(h, rescache_entries=0), idx
+
+
+def _get(ex, idx, name):
+    field = idx.field(name)
+    shards = list(range(SHARDS))
+    return ex.stacks.bsi(field, shards) if field.is_bsi() else ex.stacks.get(
+        field, shards
+    )
+
+
+def _frags(idx, name):
+    field = idx.field(name)
+    view = field.view(field.bsi_view_name() if field.is_bsi() else "standard")
+    return [view.fragments[s] for s in range(SHARDS)]
+
+
+def _as_built(idx, name, stack) -> np.ndarray:
+    """What a build gathers, from the host mirrors: the stack's shape."""
+    frags = _frags(idx, name)
+    out = np.zeros(
+        (stack.bits.shape[0], len(stack.slot_of), idx.n_words), np.uint32
+    )
+    for si, frag in enumerate(frags):
+        ids, matrix = frag.rows_matrix_host()
+        for k, r in enumerate(ids):
+            out[si, stack.slot_of[r]] = matrix[k]
+    return out
+
+
+def _write(idx, name, rng, shards):
+    """A seeded import into rows the field has, in ``shards`` only."""
+    width = idx.n_words * 32
+    for s in shards:
+        cols = (s * width + rng.choice(width, size=30, replace=False)).astype(
+            np.uint64
+        )
+        if idx.field(name).is_bsi():
+            idx.field(name).import_values(
+                cols, rng.integers(0, DEPTH_MAX, size=cols.size)
+            )
+        else:
+            idx.field(name).import_bits(
+                rng.integers(0, F_ROWS - 1, size=cols.size).astype(np.uint64),
+                cols,
+            )
+
+
+@pytest.mark.parametrize("name", ["f", "v"])
+@pytest.mark.parametrize("route", ["device", "host"])
+def test_a_refresh_writes_the_changed_blocks_into_the_array_that_is_there(
+    devices, route, name
+):
+    """Seeded imports, set and BSI field, one device and a mesh of four,
+    both routes: the old snapshot is deleted, the counters hold the
+    changed blocks' bytes and not the stack's, and the stack is bit for
+    bit what a fresh build makes."""
+    ex, idx = _imported()
+    stack = _get(ex, idx, name)
+    block = len(stack.slot_of) * idx.n_words * 4
+    rng = np.random.default_rng([5, devices, name == "v"])
+    for round_ in range(6):
+        if route == "device":
+            for frag in _frags(idx, name):
+                frag.device_bits()  # the ingest uploader keeps them current
+        old = stack.bits
+        changed = sorted(
+            rng.choice(SHARDS, size=1 + round_ % 2, replace=False).tolist()
+        )
+        before = (
+            ex.stacks.refresh_bytes, ex.stacks.refresh_host_bytes,
+            ex.stacks.incremental, ex.stacks.rebuilds,
+        )
+        _write(idx, name, rng, changed)
+        assert _get(ex, idx, name) is stack
+        assert old.is_deleted() and stack.bits is not old
+        on_host = route == "host" or devices > 1
+        assert (
+            ex.stacks.refresh_bytes, ex.stacks.refresh_host_bytes,
+            ex.stacks.incremental, ex.stacks.rebuilds,
+        ) == (
+            before[0] + len(changed) * block,
+            before[1] + on_host * len(changed) * block,
+            before[2] + 1, before[3],
+        )
+        assert ex.stacks.refresh_out_of_place == 0
+        assert np.array_equal(
+            np.asarray(stack.bits), _as_built(idx, name, stack)
+        )
+    refreshed = np.asarray(stack.bits)
+    stacks.drop(idx.field(name))
+    fresh = _get(ex, idx, name)
+    assert fresh is not stack and fresh.slot_of == stack.slot_of
+    assert np.array_equal(np.asarray(fresh.bits), refreshed)
+
+
+@pytest.mark.parametrize("route", ["device", "host"])
+def test_a_row_the_fragment_lacks_reads_zeros_and_a_new_row_rebuilds(
+    devices, route
+):
+    ex, idx = _imported()
+    stack = _get(ex, idx, "f")
+    lone = stack.slot_of[F_ROWS - 1]  # shard 0 alone holds the row
+    if route == "device":
+        for frag in _frags(idx, "f"):
+            frag.device_bits()
+    _write(idx, "f", np.random.default_rng(1), [2])
+    assert _get(ex, idx, "f") is stack and ex.stacks.incremental == 1
+    bits = np.asarray(stack.bits)
+    assert bits[0, lone].any() and not bits[1:, lone].any()
+    assert np.array_equal(bits, _as_built(idx, "f", stack))
+    width = idx.n_words * 32
+    idx.field("f").import_bits(
+        np.array([99], np.uint64), np.array([2 * width + 1], np.uint64)
+    )
+    rebuilt = _get(ex, idx, "f")
+    assert rebuilt is not stack and 99 in rebuilt.slot_of
+    assert (ex.stacks.incremental, ex.stacks.rebuilds) == (1, 2)
+    assert np.array_equal(
+        np.asarray(rebuilt.bits), _as_built(idx, "f", rebuilt)
+    )
+
+
+def test_a_leased_snapshot_is_left_alone(devices):
+    """The snapshot rule: the scope that holds a stack is handed it as it
+    holds it; another thread's refresh copies, by no program of its own;
+    once the lease is back the next refresh is in place again."""
+    ex, idx = _imported()
+    stack = _get(ex, idx, "f")
+    rng = np.random.default_rng(2)
+    _write(idx, "f", rng, [0])
+    assert _get(ex, idx, "f") is stack  # the refresh's program is compiled
+    programs = stacks.DL_STACK.snapshot()["compiles"]
+    with stacks.reading():
+        held = stack.bits
+        was = np.asarray(held).copy()
+        _write(idx, "f", rng, [1])
+        assert _get(ex, idx, "f") is stack and stack.bits is held
+        assert ex.stacks.incremental == 1  # not refreshed under its reader
+        other = threading.Thread(target=_get, args=(ex, idx, "f"))
+        other.start()
+        other.join()
+        assert ex.stacks.incremental == 2
+        assert ex.stacks.refresh_out_of_place == 1
+        assert not held.is_deleted() and np.array_equal(np.asarray(held), was)
+        copy = stack.bits
+        assert copy is not held
+        assert np.array_equal(np.asarray(copy), _as_built(idx, "f", stack))
+    assert stack._leased == 0
+    _write(idx, "f", rng, [3])
+    assert _get(ex, idx, "f") is stack
+    assert copy.is_deleted() and not held.is_deleted()
+    assert ex.stacks.refresh_out_of_place == 1
+    assert stacks.DL_STACK.snapshot()["compiles"] == programs
+    assert np.array_equal(
+        np.asarray(stack.bits), _as_built(idx, "f", stack)
+    )
+
+
+def test_a_rebuild_lets_go_of_the_retired_array_first(devices, monkeypatch):
+    """More than half the shards changed between two reads: the stack is
+    rebuilt, and its old array is gone before the new one is uploaded."""
+    ex, idx = _imported()
+    old = weakref.ref(_get(ex, idx, "f").bits)
+    _write(idx, "f", np.random.default_rng(4), [0, 1, 3])
+    alive = []
+    build = stacks.Stacks._build
+
+    def spy(self, *args, **kwargs):
+        alive.append(old() is not None)
+        return build(self, *args, **kwargs)
+
+    monkeypatch.setattr(stacks.Stacks, "_build", spy)
+    assert _get(ex, idx, "f") is not None
+    assert alive == [False] and ex.stacks.rebuilds == 2
+
+
+@pytest.mark.parametrize("devices", [1], indirect=True)  # launches are quickest
+def test_three_kinds_of_reader_beside_a_writer(devices, served):
+    """The dispatcher's flights, the per-call path and the prefetcher's
+    thread against a writer that makes every read refresh a stack: no
+    exception, and every answer the plain executor's.  The writer sets
+    bits of one row a field; the readers ask about the other rows."""
+    ex, plain, _ = served
+    idx = ex.holder.index("i")
+    shards = ex._shards_for(idx, None)
+    width = idx.n_words * 32
+    batch = [
+        ("Count(Intersect(Row(f=0), Row(f=1))) Count(Union(Row(f=1), Row(f=2)))", None),
+        ("Count(Intersect(Row(g=0), Row(f=2)))", None),
+        ("Count(Intersect(Row(f=1), Row(v > 100)))", None),
+    ]
+    single = [
+        "TopN(f, Row(g=0), ids=[0, 1, 2])",
+        "GroupBy(Rows(f, limit=3), Rows(g, limit=2))",
+        "Count(Row(v > 100))",
+    ]
+    # v: the writer rewrites column 7 with values under 100
+    ex.execute("i", "Set(7, v=5)")
+    want_batch = [plain.execute("i", q) for q, _ in batch]
+    want_single = [plain.execute("i", q) for q in single]
+    assert ex.execute_batch("i", batch) == want_batch  # stacks built
+    assert [ex.execute("i", q) for q in single] == want_single
+    errors, rounds, stop = [], 200, threading.Event()
+    turns = {}
+
+    def guarded(fn):
+        def run():
+            try:
+                while not stop.is_set():
+                    fn()
+                    turns[fn] = turns.get(fn, 0) + 1
+            except BaseException as e:  # noqa: BLE001
+                errors.append(e)
+                stop.set()
+        return threading.Thread(target=run)
+
+    def flights():
+        got = ex.execute_batch("i", batch)
+        assert got == want_batch, got
+
+    def calls():
+        for q, want in zip(single, want_single):
+            got = ex.execute("i", q)
+            assert got == want, (q, got)
+
+    def prefetcher():
+        for name in ("f", "g"):
+            ex.stacks.prefetch(idx.field(name), shards)
+
+    readers = [guarded(flights), guarded(calls), guarded(prefetcher)]
+    for t in readers:
+        t.start()
+    free = iter(range(3 * width - 1, 0, -1))
+    for n in range(rounds):
+        if stop.is_set():
+            break
+        c = next(free)
+        ex.execute("i", f"Set({c}, f=3) Set({c}, g=2) Set(7, v={n % 90})")
+        seen = dict(turns)  # a write a turn of the slowest reader
+        while not stop.is_set() and n < rounds - 1 and any(
+            turns.get(fn, 0) == seen.get(fn, 0) for fn in (flights, calls)
+        ):
+            stop.wait(0.001)
+    stop.set()
+    for t in readers:
+        t.join(60)
+    assert not errors, errors
+    assert ex.stacks.incremental > rounds
+    for q in [b[0] for b in batch] + single + [PAIRS, "Sum(field=v)"]:
+        assert ex.execute("i", q) == plain.execute("i", q)
+
+
+def test_debug_vars_shows_the_refresh(tmp_path):
+    """One import and one read on a CPU server: the span and the three
+    counters are in /debug/vars."""
+    from pilosa_tpu.server.api import API
+    from pilosa_tpu.server.http import Server
+    from pilosa_tpu.storage import roaring
+    from pilosa_tpu.storage.disk import HolderStore
+
+    holder = Holder()
+    store = HolderStore(holder, str(tmp_path / "data"))
+    store.open()
+    server = Server(API(holder, store), port=0)
+    server.serve_background()
+
+    def call(method, path, body=None):
+        req = urllib.request.Request(
+            f"http://localhost:{server.port}{path}", data=body, method=method
+        )
+        with urllib.request.urlopen(req, timeout=30) as resp:
+            return json.loads(resp.read() or b"{}")
+
+    try:
+        call("POST", "/index/i")
+        call("POST", "/index/i/field/f")
+        width = holder.n_words * 32
+        read = (
+            b"Count(Intersect(Row(f=0), Row(f=1)))"
+            b" Count(Union(Row(f=0), Row(f=1)))"
+        )
+        for cols in ([1, 2, 3], [2, 3, 4]):
+            blob = roaring.serialize(np.array(
+                [r * width + c for r in (0, 1) for c in cols], dtype=np.uint64
+            ))
+            # the span table is the process's: two readings subtract
+            before = call("GET", "/debug/vars")["spans"]["stacks"]["refresh"]
+            call("POST", "/index/i/field/f/import-roaring/0", blob)
+            answer = call("POST", "/index/i/query", read)["results"]
+        assert answer == [4, 4]
+        seen = call("GET", "/debug/vars")
+        span = seen["spans"]["stacks"]["refresh"]
+        cache = seen["serving_cache"]
+        assert span["count"] == before["count"] + 1
+        assert span["seconds"] > before["seconds"]
+        assert cache["stack_incremental"] == 1
+        assert cache["stack_refresh_bytes"] == 2 * holder.n_words * 4
+        assert cache["stack_refresh_host_bytes"] in (0, 2 * holder.n_words * 4)
+        assert cache["stack_refresh_out_of_place"] == 0
+    finally:
+        server.close()

@@ -415,6 +415,59 @@ class TestSnapshotConcurrentWrite:
         store.close()
 
 
+    def test_the_copy_holds_no_lock_and_a_write_landing_in_it_survives(
+        self, tmp_path, monkeypatch
+    ):
+        """The mirror is copied after the fragment's lock is let go (a
+        reader of the fragment does not wait for it), and a write that
+        lands between taking the source and the copy is in the file."""
+        import threading
+
+        from pilosa_tpu.core.fragment import Fragment
+        from pilosa_tpu.storage.fragmentfile import FragmentFile
+
+        frag = Fragment(n_words=64)
+        store = FragmentFile(frag, str(tmp_path / "frag"))
+        store.open()
+        frag.set_bit(1, 10)
+        real_source, real_encode = Fragment.snapshot_source, FragmentFile._encode_rows
+        free, sources = [], []
+
+        def racing_source(self):
+            out = real_source(self)
+            sources.append(out)
+            if len(sources) == 1:
+                frag.set_bit(2, 20)  # lands once the source is taken
+            return out
+
+        def watched_encode(self, rids, rwords):
+            def probe():
+                got = frag._lock.acquire(blocking=False)
+                free.append(got)
+                if got:
+                    frag._lock.release()
+
+            t = threading.Thread(target=probe)
+            t.start()
+            t.join()
+            return real_encode(self, rids, rwords)
+
+        monkeypatch.setattr(Fragment, "snapshot_source", racing_source)
+        monkeypatch.setattr(FragmentFile, "_encode_rows", watched_encode)
+        store.snapshot()
+        monkeypatch.undo()
+        assert free == [True, True]  # the raced attempt, then the clean one
+        assert len(sources) == 2 and store.op_n == 0
+        store.close()
+
+        frag2 = Fragment(n_words=64)
+        store2 = FragmentFile(frag2, str(tmp_path / "frag"))
+        store2.open()
+        rows = frag2.to_host_rows()
+        assert bool(rows[1][0] & (1 << 10)) and bool(rows[2][0] & (1 << 20))
+        store2.close()
+
+
 class TestAttrBlockPersistence:
     """Block-wise attr persistence (reference boltdb/attrstore.go:37-90:
     per-bucket writes + LRU read cache, replacing whole-JSON rewrites)."""
