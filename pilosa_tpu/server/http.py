@@ -30,10 +30,12 @@ import json
 import logging
 import math
 import re
+import ssl
 import threading
 import time
+from http import HTTPStatus
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from urllib.parse import parse_qs, urlparse
+from urllib.parse import parse_qs, urlsplit
 
 from pilosa_tpu import deadline
 from pilosa_tpu.deadline import DeadlineExceeded
@@ -156,6 +158,133 @@ tracing.register_family(
 )
 
 
+# the stdlib's limits on a request's head (http.client._MAXLINE,
+# _MAXHEADERS; the blank line that ends the head counts as one of the 100)
+_MAX_LINE = 65536
+_MAX_HEAD_LINES = 100
+# a response up to this size is joined to its head and sent as one buffer
+# (under a kilobyte the copy costs 0.3 us less than a second buffer does);
+# past it head and body go to the kernel as two buffers of one sendmsg and
+# the body is not copied
+_JOIN_MAX_BYTES = 2048
+
+
+class HeadRefused(Exception):
+    """A request's head passes one of the listener's limits."""
+
+
+class Headers:
+    """A request's header fields as the handler reads them: ``get``
+    ignores the name's case and answers the field's first value."""
+
+    __slots__ = ("_first",)
+
+    def __init__(self, first: dict[str, str]):
+        self._first = first
+
+    def get(self, name: str, default=None):
+        return self._first.get(name.lower(), default)
+
+
+def read_headers(rfile) -> Headers:
+    """The header lines of a request, read up to the blank line.
+
+    What ``http.client.parse_headers`` and the ``email`` parser under it
+    make of the same bytes, without the ``Message``: a field is
+    ``name:`` at the start of a line, the name of printable ASCII without
+    a space; a line that starts with a space or a tab continues the field
+    above it; a line that is neither ends the fields (the rest is read
+    and dropped).  A value loses the blanks after the colon and the line
+    ending; latin-1, as the stdlib decodes it.  Lines end at LF alone:
+    the ``email`` parser also breaks one at a bare CR, VT, FF and the
+    like, which lets a value smuggle a field in."""
+    first: dict[str, str] = {}
+    # the field a continuation line extends: the one just read, unless an
+    # earlier field of the same name already gave ``get`` its answer
+    name = None
+    open_ = True  # no line that is not a field has been seen
+    lines = 0
+    while True:
+        raw = rfile.readline(_MAX_LINE + 1)
+        if len(raw) > _MAX_LINE:
+            raise HeadRefused(
+                "Line too long",
+                "got more than %d bytes when reading header line" % _MAX_LINE,
+            )
+        lines += 1
+        if lines > _MAX_HEAD_LINES:
+            raise HeadRefused(
+                "Too many headers",
+                "got more than %d headers" % _MAX_HEAD_LINES,
+            )
+        if raw in (b"\r\n", b"\n", b""):
+            break
+        if not open_:
+            continue
+        line = raw.decode("iso-8859-1")
+        if line[0] in " \t":
+            if name is not None:
+                first[name] += line
+            continue
+        name = None
+        key, colon, rest = line.partition(":")
+        if not (colon and key.isascii() and key.isprintable()) or " " in key:
+            # an envelope line ("From ...") is skipped, as the stdlib
+            # skips it; any other line that names no field ends them
+            open_ = line.startswith("From ")
+        elif key:  # a line that starts with the colon is dropped
+            key = key.lower()
+            if key not in first:
+                first[key] = rest
+                name = key
+    for key, value in first.items():
+        first[key] = value.lstrip(" \t\r\n").rstrip("\r\n")
+    return Headers(first)
+
+
+class _HandlerCpu:
+    """Thread CPU the handlers spent on requests, and how many requests:
+    one cell a connection, written by that connection's thread alone, so a
+    request takes no lock for it; summed on read."""
+
+    class Cell:
+        __slots__ = ("seconds", "requests")
+
+        def __init__(self):
+            self.seconds = 0.0
+            self.requests = 0
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._open: set = set()
+        self._closed = self.Cell()
+
+    def open(self) -> "_HandlerCpu.Cell":
+        cell = self.Cell()
+        with self._lock:
+            self._open.add(cell)
+        return cell
+
+    def close(self, cell) -> None:
+        with self._lock:
+            self._open.discard(cell)
+            self._closed.seconds += cell.seconds
+            self._closed.requests += cell.requests
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            cells = [self._closed, *self._open]
+            return {
+                "handlerCpuSeconds": sum(c.seconds for c in cells),
+                "requests": sum(c.requests for c in cells),
+            }
+
+
+# process totals (thread CPU is the process's interpreter, whichever
+# listener of an in-process cluster the thread serves)
+handler_cpu = _HandlerCpu()
+
+
 class Handler(BaseHTTPRequestHandler):
     api: API = None  # set by make_server
     long_query_time: float = 0.0
@@ -175,6 +304,136 @@ class Handler(BaseHTTPRequestHandler):
     # gzip floor: tiny bodies cost more in header + CPU than they save
     _GZIP_MIN_BYTES = 512
 
+    # -- framing: a request's head in, one buffer out ----------------------
+
+    def setup(self):
+        super().setup()
+        self._cpu = handler_cpu.open()
+
+    def finish(self):
+        handler_cpu.close(self._cpu)
+        super().finish()
+
+    def handle_one_request(self):
+        """The stdlib's, between two reads of the thread's CPU clock: one
+        before the request's line is waited for (a blocked thread spends
+        none), one after the response is written and booked."""
+        t0 = time.thread_time()
+        self.raw_requestline = b""
+        super().handle_one_request()
+        if self.raw_requestline:
+            self._cpu.seconds += time.thread_time() - t0
+            self._cpu.requests += 1
+
+    def parse_request(self) -> bool:
+        """The request's line as the stdlib splits it, then the fields by
+        ``read_headers``: no ``email`` parser, no ``Message``.  The same
+        refusals (400, 505, 431), keep-alive rules and ``Expect``."""
+        self.command = None  # set in case of error on the first line
+        self.request_version = version = self.default_request_version
+        self.close_connection = True
+        self.requestline = requestline = str(
+            self.raw_requestline, "iso-8859-1"
+        ).rstrip("\r\n")
+        words = requestline.split()
+        if not words:
+            return False
+        if len(words) >= 3:  # enough to determine the protocol version
+            version = words[-1]
+            try:
+                if not version.startswith("HTTP/"):
+                    raise ValueError
+                base_version_number = version.split("/", 1)[1]
+                numbers = base_version_number.split(".")
+                # one ".", digits only, of a reasonable length
+                if len(numbers) != 2 or not all(
+                    c.isdigit() and len(c) <= 10 for c in numbers
+                ):
+                    raise ValueError
+                version_number = int(numbers[0]), int(numbers[1])
+            except ValueError:
+                self.send_error(
+                    HTTPStatus.BAD_REQUEST,
+                    "Bad request version (%r)" % version,
+                )
+                return False
+            if version_number >= (1, 1):
+                self.close_connection = False
+            if version_number >= (2, 0):
+                self.send_error(
+                    HTTPStatus.HTTP_VERSION_NOT_SUPPORTED,
+                    "Invalid HTTP version (%s)" % base_version_number,
+                )
+                return False
+            self.request_version = version
+        if not 2 <= len(words) <= 3:
+            self.send_error(
+                HTTPStatus.BAD_REQUEST,
+                "Bad request syntax (%r)" % requestline,
+            )
+            return False
+        command, path = words[:2]
+        if len(words) == 2:
+            self.close_connection = True
+            if command != "GET":
+                self.send_error(
+                    HTTPStatus.BAD_REQUEST,
+                    "Bad HTTP/0.9 request type (%r)" % command,
+                )
+                return False
+        # a target that starts with "//" would read as a URI without a
+        # scheme further on (gh-87389)
+        if path.startswith("//"):
+            path = "/" + path.lstrip("/")
+        self.command, self.path = command, path
+        try:
+            self.headers = read_headers(self.rfile)
+        except HeadRefused as e:
+            self.send_error(
+                HTTPStatus.REQUEST_HEADER_FIELDS_TOO_LARGE, *e.args
+            )
+            return False
+        conntype = self.headers.get("Connection", "").lower()
+        if conntype == "close":
+            self.close_connection = True
+        elif conntype == "keep-alive":
+            self.close_connection = False
+        if (
+            self.headers.get("Expect", "").lower() == "100-continue"
+            and self.request_version >= "HTTP/1.1"
+        ):
+            return self.handle_expect_100()
+        return True
+
+    # status line and ``Server`` by code; ``Date`` as of the second it was
+    # last formatted in: (second, line), replaced in one assignment
+    _status_lines: dict[int, str] = {}
+    _date_line: tuple[int, str] = (0, "")
+
+    def _head(
+        self, code: int, content_type: str, length: int, headers: dict | None
+    ) -> bytes:
+        if self.request_version == "HTTP/0.9":
+            return b""  # a request without a version gets the body alone
+        status = self._status_lines.get(code)
+        if status is None:
+            status = self._status_lines[code] = "%s %d %s\r\nServer: %s\r\n" % (
+                self.protocol_version,
+                code,
+                self.responses[code][0] if code in self.responses else "",
+                self.version_string(),
+            )
+        now = int(time.time())
+        second, date = Handler._date_line
+        if second != now:
+            date = f"Date: {self.date_time_string(now)}\r\n"
+            Handler._date_line = (now, date)
+        extra = "".join(f"{k}: {v}\r\n" for k, v in headers.items()) if headers else ""
+        return (
+            f"{status}{date}Content-Type: {content_type}\r\n"
+            f"Content-Length: {length}\r\n{extra}\r\n"
+        ).encode("latin-1", "strict")
+
     def _send(
         self,
         code: int,
@@ -183,6 +442,7 @@ class Handler(BaseHTTPRequestHandler):
         headers: dict | None = None,
         gzip_ok: bool = False,
     ) -> None:
+        """Every response leaves here, head and body in one write."""
         if (
             gzip_ok
             and len(body) >= self._GZIP_MIN_BYTES
@@ -191,13 +451,19 @@ class Handler(BaseHTTPRequestHandler):
             body = gzip_mod.compress(body, compresslevel=1)
             headers = dict(headers or {})
             headers["Content-Encoding"] = "gzip"
-        self.send_response(code)
-        self.send_header("Content-Type", content_type)
-        self.send_header("Content-Length", str(len(body)))
-        for k, v in (headers or {}).items():
-            self.send_header(k, v)
-        self.end_headers()
-        self.wfile.write(body)
+        head = self._head(code, content_type, len(body), headers)
+        conn = self.connection
+        if len(body) <= _JOIN_MAX_BYTES or isinstance(conn, ssl.SSLSocket):
+            # joining a small body costs less than a second buffer does,
+            # and a TLS connection has no sendmsg
+            conn.sendall(head + body)
+            return
+        sent = conn.sendmsg((head, body))
+        if sent < len(head):
+            conn.sendall(head[sent:])
+            sent = len(head)
+        if sent < len(head) + len(body):
+            conn.sendall(memoryview(body)[sent - len(head):])
 
     def _send_json(
         self,
@@ -247,12 +513,16 @@ class Handler(BaseHTTPRequestHandler):
             except OSError:
                 pass
             return
-        parsed = urlparse(self.path)
-        self.query_params = parse_qs(parsed.query)
+        target = self.path
+        if not target.startswith("/"):
+            # absolute form (a proxy's): drop scheme and authority
+            target = urlsplit(target)._replace(scheme="", netloc="").geturl()
+        path, _, query = target.partition("?")
+        self.query_params = parse_qs(query) if query else {}
         for m, rx, name in _ROUTES:
             if m != method:
                 continue
-            match = rx.match(parsed.path)
+            match = rx.match(path)
             if match:
                 t0 = time.monotonic()
                 # Route this request's spans into THIS node's trace
@@ -265,7 +535,7 @@ class Handler(BaseHTTPRequestHandler):
                 # (reference http/handler.go extracts opentracing headers).
                 parent = tracing.get_tracer().extract_headers(self.headers)
                 span = tracing.start_span(f"http.{name}", child_of=parent)
-                span.set_tag("method", method).set_tag("path", parsed.path)
+                span.set_tag("method", method).set_tag("path", path)
                 # Error budget: server-attributed failures only.  504s
                 # (deadline/batcher expiry) and 500s burn budget; 4xx
                 # client mistakes don't.
@@ -496,6 +766,9 @@ class Handler(BaseHTTPRequestHandler):
         snap["devledger"] = devledger.snapshot()
         snap["events"] = self.api.holder.events.snapshot_summary()
         snap["slo"] = self.api.holder.slo.summary()
+        # thread CPU the handlers spent from a request's line to its
+        # response written, and the requests it was spent on
+        snap["http"] = handler_cpu.snapshot()
         snap["translate"] = translate.telemetry_snapshot()
         batcher = getattr(self.api, "batcher", None)
         if batcher is not None:
@@ -984,8 +1257,7 @@ class Handler(BaseHTTPRequestHandler):
         self._send_json(200, {"keys": keys})
 
     def r_translate_log(self):
-        qs = parse_qs(urlparse(self.path).query)
-        offset = int(qs.get("offset", ["0"])[0])
+        offset = int(self.query_params.get("offset", ["0"])[0])
         self._send_json(200, self.api.translate_log(offset))
 
     def r_translate_restore(self):
@@ -1050,8 +1322,6 @@ class Server:
         self.httpd = _Listener((host, port), handler)
         self.tls = bool(tls_cert)
         if tls_cert:
-            import ssl
-
             ctx = ssl.SSLContext(ssl.PROTOCOL_TLS_SERVER)
             ctx.load_cert_chain(tls_cert, tls_key)
             self.httpd.socket = ctx.wrap_socket(
