@@ -32,6 +32,7 @@ from __future__ import annotations
 import itertools
 import json
 import math
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -128,7 +129,7 @@ class LossyReference(Reference):
     def apply_slab(self, shard, slab, values):
         super().apply_slab(shard, slab, values)
         lo = slab * self.slab
-        for name in values:
+        for name in values.keys() & self.one.keys():
             blank = -1 if self.fields[name]["kind"] == "int" else UNSET
             self.one[name][shard, lo + self.REM:lo + self.slab:self.MOD] = blank
 
@@ -153,6 +154,8 @@ class StaleReference(Reference):
 
 CONTROLS = {"lossy": LossyReference, "stale": StaleReference}
 UNCERTAIN_MAX = 3  # 2^3 states a read at the most
+JUDGE_THREADS = 4  # the reference is numpy over whole columns and lets the interpreter go
+ANSWERS_AHEAD = 256  # as many as ``Reference.answer`` remembers before it forgets them all
 
 
 # ---------------------------------------------------------------------------
@@ -207,6 +210,10 @@ def judge_reads(ref: Reference, reads: list[dict], imports: list[dict] = (),
     done = 0  # log[:done] are certain
     if log:
         reads = sorted(reads, key=lambda r: r["t_send"])
+    else:  # one state for every read: the answers ahead of the walk, numpy beside numpy
+        with ThreadPoolExecutor(JUDGE_THREADS) as pool:
+            for who in filter(None, (ref, control)):
+                list(pool.map(who.answer, sorted({r["pql"] for r in reads})[:ANSWERS_AHEAD]))
     for r in reads:
         while done < len(log) and log[done]["status"] == 200 and log[done]["t_ack"] < r["t_send"]:
             ref.apply_import(log[done])

@@ -11,6 +11,7 @@ A set field's file entry says how its rows share the columns (``rows`` is
 the id space, every column has exactly one row):
 
 ``weights``     the shares, one per row id
+``uniform``     true: every row id as likely, drawn as an integer (no table to search)
 ``zipf``        theta of a zipfian tail over the row ids, 0 the most common
 ``lognormal``   [mu, sigma]: the value rounded to the nearest row id
 ``scatter``     with ``zipf`` and ``present``: only ``present`` of the
@@ -20,8 +21,21 @@ the id space, every column has exactly one row):
                 occurs under every seed and the program's stack shapes (one
                 slot per row that occurs) are the same for every seed
 
-An int field is either ``lognormal`` itself or ``from_rows``: the row id of
-a set field times ``scale`` plus a uniform ``jitter``, so the two agree.
+``follows``     the field's row id is a fixed many-to-one map of another
+                set field's (its *parent*): ``div`` (parent id // div) or
+                ``map`` (one id per parent id, listed); nothing is drawn.  A
+                parent may follow another in turn (city -> nation -> region),
+                and the field gets the shares its parent implies
+
+An int field is ``lognormal`` itself, ``uniform`` ([lo, hi], both ends in),
+``from_rows`` (the row id of a set field times ``scale`` plus a uniform
+``jitter``, so the two agree), a ``product`` of two generated values, or a
+value ``scaled``: ``{"field": a, "less_pct": b}`` is ``a * (100 - b) // 100``,
+``b`` another generated value or a number.
+
+``"stored": false`` on a field of either kind: generated, so that others may
+follow or multiply it, but no field of the index (the day an order was made,
+the price of a part): absent from the schema, the load and the reference.
 """
 
 from __future__ import annotations
@@ -50,6 +64,11 @@ def fields_by_name(cfg: dict) -> dict:
     return {f["name"]: f for f in cfg["fields"]}
 
 
+def stored_fields(cfg: dict) -> list[dict]:
+    """The fields the index has: all but the generated-only ones."""
+    return [f for f in cfg["fields"] if f.get("stored", True)]
+
+
 def slabs_per_shard(cfg: dict) -> int:
     return int(cfg["columns"]) // int(cfg["slab_rides"])
 
@@ -67,18 +86,61 @@ def _lognormal_bins(n: int, mu: float, sigma: float) -> np.ndarray:
 _WEIGHTS: dict[str, np.ndarray] = {}
 
 
-def row_weights(field: dict) -> np.ndarray:
-    """The share of the columns that each row id of a set field gets; 0
-    for an id that never occurs."""
+def row_map(field: dict, fields: dict) -> np.ndarray:
+    """Of a field that ``follows`` another: its row id for each row id of
+    the parent."""
+    how = field["follows"]
+    parent_rows = int(fields[how["field"]]["rows"])
+    if "map" in how:
+        m = np.asarray(how["map"], np.int64)
+    else:
+        m = np.arange(parent_rows, dtype=np.int64) // int(how["div"])
+    if len(m) != parent_rows or m.min() < 0 or m.max() >= int(field["rows"]):
+        raise ValueError(f"{field['name']}: a map of {len(m)} ids up to {m.max()} for "
+                         f"{parent_rows} rows of {how['field']} and {field['rows']} of its own")
+    return m
+
+
+def ancestor_map(fields: dict, name: str, ancestor: str) -> np.ndarray:
+    """For each row id of the set field ``name``, the row id of ``ancestor``,
+    a field that follows it, directly or through others."""
+    chain = [ancestor]  # from the ancestor down to ``name``
+    while chain[-1] != name:
+        how = fields[chain[-1]].get("follows")
+        if how is None or how["field"] in chain:
+            raise ValueError(f"{ancestor} does not follow {name}")
+        chain.append(how["field"])
+    out = np.arange(int(fields[name]["rows"]), dtype=np.int64)
+    for follower in reversed(chain[:-1]):
+        out = row_map(fields[follower], fields)[out]
+    return out
+
+
+def _chain_key(field: dict, fields: dict | None) -> str:
     key = json.dumps(field, sort_keys=True)
+    if "follows" in field:
+        key += _chain_key(fields[field["follows"]["field"]], fields)
+    return key
+
+
+def row_weights(field: dict, fields: dict | None = None) -> np.ndarray:
+    """The share of the columns that each row id of a set field gets; 0
+    for an id that never occurs.  ``fields`` (name -> entry) where the
+    field follows another: it gets the shares its parent implies."""
+    key = _chain_key(field, fields)
     if key not in _WEIGHTS:
-        _WEIGHTS[key] = _row_weights(field)
+        _WEIGHTS[key] = _row_weights(field, fields)
     return _WEIGHTS[key]
 
 
-def _row_weights(field: dict) -> np.ndarray:
+def _row_weights(field: dict, fields: dict | None) -> np.ndarray:
     n = int(field["rows"])
-    if "weights" in field:
+    if "follows" in field:
+        parent = fields[field["follows"]["field"]]
+        return np.bincount(row_map(field, fields), weights=row_weights(parent, fields), minlength=n)
+    if field.get("uniform"):
+        w = np.ones(n)
+    elif "weights" in field:
         w = np.asarray(field["weights"], np.float64)
         if len(w) != n:
             raise ValueError(f"{field['name']}: {len(w)} weights for {n} rows")
@@ -98,25 +160,38 @@ def _row_weights(field: dict) -> np.ndarray:
     return w
 
 
-def popularity_order(field: dict) -> np.ndarray:
+def popularity_order(field: dict, fields: dict | None = None) -> np.ndarray:
     """The row ids that occur, the most common first (what a zipfian
     query draw ranks)."""
-    w = row_weights(field)
+    w = row_weights(field, fields)
     return np.argsort(-w, kind="stable")[:int(np.count_nonzero(w))]
 
 
 def gen_slab(cfg: dict, seed: int, shard: int, slab: int) -> dict[str, np.ndarray]:
     """Values of every field for the ``slab_rides`` columns of one slab:
     ``{field: array}``, uint16 row ids for set fields and int32 values for
-    int fields."""
+    int fields; the fields that are not stored among them.  Only a field's
+    own draw takes from the generator, in the file's order (set fields, then
+    int fields): what follows, multiplies or scales another draws nothing."""
     rng = np.random.default_rng([int(seed), int(shard), int(slab), 0x7A21])
     n = int(cfg["slab_rides"])
+    fields = fields_by_name(cfg)
     out: dict[str, np.ndarray] = {}
     for f in cfg["fields"]:
-        if f["kind"] == "set":
+        if f["kind"] == "set" and f.get("uniform"):
+            out[f["name"]] = rng.integers(0, int(f["rows"]), n).astype(np.uint16)
+        elif f["kind"] == "set" and "follows" not in f:
             cdf = np.cumsum(row_weights(f))
             cdf[-1] = 1.0
             out[f["name"]] = np.searchsorted(cdf, rng.random(n), side="right").astype(np.uint16)
+    todo = [f for f in cfg["fields"] if f["kind"] == "set" and "follows" in f]
+    while todo:  # a follower after its parent, however the file orders them
+        ready = [f for f in todo if f["follows"]["field"] in out]
+        if not ready:
+            raise ValueError(f"{todo[0]['name']} follows {todo[0]['follows']['field']}, which is never made")
+        for f in ready:
+            out[f["name"]] = row_map(f, fields)[out[f["follows"]["field"]]].astype(np.uint16)
+        todo = [f for f in todo if f["name"] not in out]
     for f in cfg["fields"]:
         if f["kind"] != "int":
             continue
@@ -124,6 +199,16 @@ def gen_slab(cfg: dict, seed: int, shard: int, slab: int) -> dict[str, np.ndarra
             src = f["from_rows"]
             lo, hi = src["jitter"]
             v = out[src["field"]].astype(np.int64) * int(src["scale"]) + rng.integers(lo, hi + 1, n)
+        elif "uniform" in f:
+            lo, hi = f["uniform"]
+            v = rng.integers(lo, hi + 1, n)
+        elif "product" in f:  # of generated values that stand before it in the file
+            a, b = f["product"]
+            v = out[a].astype(np.int64) * out[b].astype(np.int64)
+        elif "scaled" in f:
+            less = f["scaled"]["less_pct"]
+            less = out[less].astype(np.int64) if isinstance(less, str) else int(less)
+            v = out[f["scaled"]["field"]].astype(np.int64) * (100 - less) // 100
         else:
             mu, sigma = f["lognormal"]
             v = np.rint(np.exp(rng.normal(mu, sigma, n)))

@@ -38,6 +38,15 @@ order.  Slot picks:
 ``row``            a row of ``field``, zipfian by popularity -> ``{x}``
 ``int``            uniform in the int ``field``'s range       -> ``{x}``
 ``int_range``      two of those, ordered         -> ``{x[lo]}``, ``{x[hi]}``
+``int_band``       a centre uniform in the int ``field``'s range and the band
+                   ``around`` it, ``[below, above]``, clipped to the range,
+                   both ends in               -> ``{x[lo]}``, ``{x[hi]}``
+``row_run``        ``k`` consecutive row ids of ``field`` that all occur, the
+                   first drawn like a ``row`` -> ``{x[0]}`` .. ``{x[k-1]}``
+``row_under``      a row of ``field`` among those whose ancestor (a field
+                   that follows it, ``datagen``) has the row that slot ``of``
+                   picked in the same request; two such slots are two
+                   cities of one nation, each drawn alone     -> ``{x}``
 """
 
 from __future__ import annotations
@@ -46,7 +55,7 @@ import hashlib
 
 import numpy as np
 
-from datagen import fields_by_name, popularity_order, slabs_per_shard, width_of
+from datagen import ancestor_map, fields_by_name, popularity_order, slabs_per_shard, width_of
 
 PHASES = {"window": 0, "warm": 1}
 
@@ -59,7 +68,10 @@ class Mix:
         self.classes = mix["classes"]
         self.deck = [name for name, c in self.classes.items() for _ in range(int(c["weight"]))]
         self._cdf: dict[int, np.ndarray] = {}
-        self._order = {n: popularity_order(f) for n, f in self.fields.items() if f["kind"] == "set"}
+        self._order = {n: popularity_order(f, self.fields) for n, f in self.fields.items()
+                       if f["kind"] == "set"}
+        self._runs: dict[tuple[str, int], np.ndarray] = {}
+        self._under: dict[tuple[str, str], dict[int, np.ndarray]] = {}
         self.streams = mix.get("stream")  # None: the mix only reads
 
     def _zipf(self, rng, n: int) -> int:
@@ -68,14 +80,51 @@ class Mix:
             self._cdf[n] = cdf / cdf[-1]
         return min(int(np.searchsorted(self._cdf[n], rng.random(), side="right")), n - 1)
 
-    def _row(self, rng, field: str, uniform: bool) -> int:
-        order = self._order[field]
+    def _ranked(self, rng, order: np.ndarray, uniform: bool) -> int:
+        """One of ``order`` (row ids, the most common first): any as likely, or zipfian by rank."""
         return int(order[rng.integers(len(order)) if uniform else self._zipf(rng, len(order))])
 
-    def _pick(self, rng, spec: dict, uniform: bool):
+    def _row(self, rng, field: str, uniform: bool) -> int:
+        return self._ranked(rng, self._order[field], uniform)
+
+    def _run_starts(self, field: str, k: int) -> np.ndarray:
+        """The rows of ``field`` that start ``k`` consecutive ids which all occur, by popularity."""
+        if (field, k) not in self._runs:
+            order = self._order[field]
+            occurs = np.zeros(int(self.fields[field]["rows"]) + k, bool)
+            occurs[order] = True
+            starts = order[[bool(occurs[r:r + k].all()) for r in order]]
+            if not len(starts):
+                raise ValueError(f"{field} has no {k} consecutive rows that all occur")
+            self._runs[field, k] = starts
+        return self._runs[field, k]
+
+    def _children(self, field: str, ancestor: str) -> dict[int, np.ndarray]:
+        """Per row of ``ancestor``, the rows of ``field`` under it that occur, by popularity."""
+        if (field, ancestor) not in self._under:
+            order = self._order[field]
+            up = ancestor_map(self.fields, field, ancestor)[order]
+            self._under[field, ancestor] = {int(a): order[up == a] for a in np.unique(up)}
+        return self._under[field, ancestor]
+
+    def _pick(self, rng, spec: dict, uniform: bool, slots: dict | None = None, specs: dict | None = None):
         kind = spec["pick"]
         if kind == "row":
             return self._row(rng, spec["field"], uniform)
+        if kind == "int_band":
+            f = self.fields[spec["field"]]
+            centre = int(rng.integers(f["min"], f["max"] + 1))
+            below, above = spec["around"]
+            return {"lo": max(int(f["min"]), centre - int(below)), "hi": min(int(f["max"]), centre + int(above))}
+        if kind == "row_run":
+            k = int(spec["k"])
+            first = self._ranked(rng, self._run_starts(spec["field"], k), uniform)
+            return [first + i for i in range(k)]
+        if kind == "row_under":
+            above = specs[spec["of"]]
+            if above["pick"] != "row":
+                raise ValueError(f"row_under of slot {spec['of']!r}, which is no row pick")
+            return self._ranked(rng, self._children(spec["field"], above["field"])[slots[spec["of"]]], uniform)
         if kind == "int":
             f = self.fields[spec["field"]]
             return int(rng.integers(f["min"], f["max"] + 1))
@@ -85,17 +134,30 @@ class Mix:
             return {"lo": lo, "hi": hi + 1}
         raise ValueError(f"unknown slot pick {kind!r}")
 
+    def _slots(self, rng, specs: dict, uniform: bool) -> dict:
+        """A value for every slot of a class, in the file's order; a ``row_under`` after the rows it is drawn under."""
+        slots = {name: self._pick(rng, spec, uniform) for name, spec in specs.items()
+                 if spec["pick"] != "row_under"}
+        for name, spec in specs.items():
+            if spec["pick"] == "row_under":
+                slots[name] = self._pick(rng, spec, uniform, slots, specs)
+        return slots
+
     def _draw(self, rng, cls: str, variant: int | None, uniform: bool) -> tuple[str, set]:
         """One request of the class and the (field, row) pairs it names."""
         c = self.classes[cls]
         specs = c.get("slots", {})
-        slots = {name: self._pick(rng, spec, uniform) for name, spec in specs.items()}
+        slots = self._slots(rng, specs, uniform)
         variants = c["variants"]
         if variant is None:
             variant = int(rng.integers(len(variants)))
         text = variants[variant]
-        rows = {(spec["field"], slots[name]) for name, spec in specs.items()
-                if spec["pick"] == "row" and "{" + name + "}" in text}
+        rows = set()
+        for name, spec in specs.items():
+            if spec["pick"] in ("row", "row_run", "row_under") and (
+                    "{" + name + "}" in text or "{" + name + "[" in text):
+                picked = slots[name]
+                rows |= {(spec["field"], r) for r in (picked if isinstance(picked, list) else [picked])}
         return text.format(**slots), rows
 
     def request(self, rng, cls: str, variant: int | None = None) -> str:

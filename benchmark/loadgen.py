@@ -85,7 +85,7 @@ def load_shards(cfg: dict, seed: int, port: int, shards: list[int]) -> dict:
     t_first = time.monotonic()
     for shard in shards:
         slabs = [datagen.gen_slab(cfg, seed, shard, k) for k in range(datagen.slabs_per_shard(cfg))]
-        for f in cfg["fields"]:
+        for f in datagen.stored_fields(cfg):
             values = np.concatenate([s[f["name"]] for s in slabs])
             view, blob, n = datagen.slab_import(cfg, f, values, 0)
             status, body = c.send(f"/index/{index}/field/{f['name']}/import-roaring/{shard}{view}",
@@ -109,7 +109,7 @@ def slab_requests(cfg: dict, seed: int, shard: int, slab: int) -> list[tuple[str
     values = datagen.gen_slab(cfg, seed, shard, slab)
     col0 = slab * int(cfg["slab_rides"])
     out = []
-    for f in cfg["fields"]:
+    for f in datagen.stored_fields(cfg):
         view, blob, n = datagen.slab_import(cfg, f, values[f["name"]], col0)
         out.append((f["name"], f"/index/{cfg['index']}/field/{f['name']}/import-roaring/{shard}{view}",
                     blob, n))

@@ -17,7 +17,7 @@ import re
 
 import numpy as np
 
-from datagen import UNSET, fields_by_name, gen_slab, slabs_per_shard, width_of
+from datagen import UNSET, gen_slab, slabs_per_shard, stored_fields, width_of
 
 # ---------------------------------------------------------------------------
 # PQL: the calls the traffic mixes use
@@ -127,12 +127,12 @@ class Reference:
         self.shards = int(cfg["shards"])
         self.slab = int(cfg["slab_rides"])
         self.hi = int(cfg["columns"] if extent is None else extent)
-        self.fields = fields_by_name(cfg)
+        self.fields = {f["name"]: f for f in stored_fields(cfg)}  # what the index has
         # [shards, columns]: the row id (set field) or the value (int field) of each column
         self.one: dict[str, np.ndarray] = {
-            f["name"]: (np.full((self.shards, self.hi), -1, np.int32) if f["kind"] == "int"
-                        else np.full((self.shards, self.hi), UNSET, np.uint16))
-            for f in cfg["fields"]}
+            name: (np.full((self.shards, self.hi), -1, np.int32) if f["kind"] == "int"
+                   else np.full((self.shards, self.hi), UNSET, np.uint16))
+            for name, f in self.fields.items()}
         self._answers: dict = {}
         self._calls: dict[str, tuple[Call, tuple]] = {}
         # the state: per field, the streamed (shard, slab) applied on top of the load
@@ -142,7 +142,8 @@ class Reference:
     def apply_slab(self, shard: int, slab: int, values: dict) -> None:
         lo = slab * self.slab
         for name, v in values.items():
-            self.one[name][shard, lo:lo + self.slab] = v
+            if name in self.one:  # a slab holds the generated-only fields too
+                self.one[name][shard, lo:lo + self.slab] = v
 
     def _slab(self, shard: int, slab: int) -> dict:
         """A streamed slab's values; imports come a slab at a time, so the
@@ -249,12 +250,14 @@ class Reference:
             names = [r.pos[0] for r in c.pos]
             sizes = [int(self.fields[n]["rows"]) for n in names]
             valid = self.bitmap(c.kw["filter"]) if "filter" in c.kw else None
-            code = np.zeros((self.shards, self.hi), np.int32)
-            for n, size in zip(names, sizes):
+            for n in names:
                 v = self.one[n]
                 valid = (v != UNSET) if valid is None else valid & (v != UNSET)
-                code = code * np.int32(size) + v
-            counts = np.bincount(code[valid], minlength=int(np.prod(sizes)))
+            at = np.flatnonzero(valid.ravel())  # the filter first: the groups are counted over its columns only
+            code = np.zeros(len(at), np.int64)
+            for n, size in zip(names, sizes):
+                code = code * size + self.one[n].ravel()[at]
+            counts = np.bincount(code, minlength=int(np.prod(sizes)))
             return names, counts.reshape(sizes)
         cols = np.flatnonzero(self.bitmap(c).ravel())
         # [shards, columns] -> global column ids

@@ -49,6 +49,7 @@ REPO = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
 import manifest as mf  # noqa: E402
+from datagen import stored_fields  # noqa: E402  (numpy only)
 from loadgen import Conn  # noqa: E402  (numpy only)
 
 TRACE_CAP_S = 10.0      # a traced run's window: traces are large and tracing slows the host
@@ -344,7 +345,7 @@ def load_cell(manifest: dict, workload: str, rehearsal: bool) -> tuple[dict, dic
 
 def make_schema(c: Http, cfg: dict) -> None:
     c.json("POST", f"/index/{cfg['index']}", {})
-    for f in cfg["fields"]:
+    for f in stored_fields(cfg):
         opts = {"type": "int", "min": f["min"], "max": f["max"]} if f["kind"] == "int" else {}
         c.json("POST", f"/index/{cfg['index']}/field/{f['name']}", {"options": opts})
 
@@ -616,7 +617,7 @@ def read_back(c: Http, m, seed: int) -> list[dict]:
     rng = np.random.default_rng([int(seed), 0xBAC])
     asks = [m.request(rng, cls) for cls in m.classes]
     asks += [f"TopN({f['name']})" if f["kind"] == "set" else f"Sum(field={f['name']})"
-             for f in m.cfg["fields"]]
+             for f in stored_fields(m.cfg)]
     path = f"/index/{m.cfg['index']}/query"
     out = []
     for pql in asks:

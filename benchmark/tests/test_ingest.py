@@ -1,8 +1,7 @@
 """The write stream, the judge that holds reads to "an acknowledged import
-is visible", and the cell ``taxi.ingest-serve`` that needs both.  The cell
-is not in ``BENCHMARK.json`` (the program stands in its way on the chip:
-PERF.md section 7); its entries wait in ``staged_ingest_cell.json`` and are
-laid over the manifest here, in memory, so that the CPU rehearses it.
+is visible", and the cell ``taxi.ingest-serve`` that needs both (a cell of
+``BENCHMARK.json`` since PR 37; ``test_rehearsal.py`` rehearses it with the
+others, this file holds it to what a stream adds).
 
 The stream's plan and data are functions of the seed alone; the judge is
 driven over hand-made logs (nothing in flight, one, three, four imports in
@@ -29,13 +28,10 @@ import manifest as mf
 import reference
 import run
 
-from test_rehearsal import MANIFEST as GRID, rehearse, rehearsed_line
+from test_rehearsal import MANIFEST, rehearse, rehearsed_line
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CELL = "taxi.ingest-serve"
-with open(os.path.join(HERE, "staged_ingest_cell.json")) as _f:
-    STAGED = json.load(_f)
-MANIFEST = dict(GRID, **{k: GRID[k] + STAGED[k] for k in ("configs", "workloads", "per_layer")})
 
 
 # ---------------------------------------------------------------------------
@@ -61,17 +57,6 @@ def test_the_readers_are_dashboard_c32s():
     differ = {k for k in set(dash) | set(mix) if dash.get(k) != mix.get(k)}
     assert differ == {"name", "stream", "rehearsal"}
     assert set(mix["stream"]) == {"every_s", "why"} and mix["stream"]["every_s"] == 4.0
-
-
-def test_the_staged_entries_are_new_to_the_grid_and_name_what_is_there():
-    for k in ("configs", "workloads", "per_layer"):
-        assert not {e["name"] for e in STAGED[k]} & {e["name"] for e in GRID[k]}
-    assert [w["name"] for w in STAGED["workloads"]] == [CELL]
-    layers = {m["layer"] for m in GRID["per_layer"]}
-    e2e = {m["name"] for m in GRID["end_to_end"]}
-    for m in STAGED["per_layer"]:
-        assert m["workloads"] == [CELL] and m["layer"] in layers and m["moves"] in e2e
-        assert os.path.exists(os.path.join(mf.HERE, "layer_metrics", m["name"] + ".json"))
 
 
 def test_every_row_occurs_in_the_load_under_every_seed():
@@ -282,7 +267,7 @@ def test_the_reference_remembers_an_answer_per_state(tiny):
 
 
 @pytest.mark.parametrize("trace", [0, 1])
-def test_the_cells_line_is_the_staged_manifests(capfd, trace):
+def test_the_cells_line_holds_what_a_stream_adds(capfd, trace):
     """As ``test_rehearsal`` holds every cell of the grid; and every import
     is acknowledged, every slab due in the window is acknowledged in it, and
     the read-back after the last acknowledgement is sound."""
