@@ -768,18 +768,22 @@ class Fragment:
 
     def _to_device(self, padded: np.ndarray) -> jax.Array:
         """Upload the compute copy to the device that serves this shard.
-        On a multi-device host fragments are dealt round-robin by shard
-        number over the serving mesh's devices — every field's copy of
-        one shard on the same device, so per-shard Row algebra never
-        mixes placements — instead of piling every copy (and the whole
-        ingest upload stream) onto device 0."""
-        from pilosa_tpu.parallel.mesh import serving_mesh
+        On a multi-device host fragments are dealt over the serving
+        mesh's devices by the mesh's one rule (``chip_of_shard``) — every
+        field's copy of one shard on the same device, so per-shard Row
+        algebra never mixes placements, and on the device that holds the
+        shard's slice of every field stack, so a stack's refresh after a
+        write stays on that chip — instead of piling every copy (and the
+        whole ingest upload stream) onto device 0."""
+        from pilosa_tpu.parallel.mesh import chip_of_shard, serving_mesh
 
         mesh = serving_mesh()
         if mesh is None:
             return jnp.asarray(padded)
         devices = mesh.devices.flat
-        return jax.device_put(padded, devices[self.shard % len(devices)])
+        return jax.device_put(
+            padded, devices[chip_of_shard(self.shard, len(devices))]
+        )
 
     def device_declined(self) -> bool:
         """True when this fragment's full device copy alone would exceed
@@ -990,16 +994,16 @@ class Fragment:
             n = len(self._rowids)
             return list(self._rowids), self._host[:n].copy()
 
-    def stack_block(self, slot_of: dict[int, int], on_device: bool):
+    def stack_block(self, slot_of: dict[int, int]):
         """This shard's ``[R, W]`` block of a serving stack whose row axis
         is ``slot_of`` (row id -> position; exec/stacks.py refreshes a
         stack from it after a write), in one locked look.  ``(dev,
-        slots)`` where ``on_device`` and the device copy is at hand: the
-        copy, synced, and per position the slot to gather from it — the
-        zero row for a row this fragment lacks — so the block never
-        passes through the host.  Else ``(None, block)``, gathered from
-        the host mirror.  None when the fragment holds a row the stack has
-        no position for."""
+        slots)`` where the device copy is at hand: the copy, synced, and
+        per position the slot to gather from it — the zero row for a row
+        this fragment lacks — so the block never passes through the host
+        (the caller sees on which device the copy lies).  Else ``(None,
+        block)``, gathered from the host mirror.  None when the fragment
+        holds a row the stack has no position for."""
         with self._lock:
             n = len(self._rowids)
             dst = np.fromiter(
@@ -1008,8 +1012,7 @@ class Fragment:
             if (dst < 0).any():
                 return None
             resident = (
-                on_device
-                and self._device is not None
+                self._device is not None
                 and not self._evict_pending
                 and self._device.shape[0] == self.capacity + 1
             )

@@ -885,7 +885,9 @@ class Executor:
                     dev = kernels.pull(dev, "ast_bitmap")
                 with tracing.start_span("executor.demux").set_tag("n", 1):
                     segments = {
-                        s: dev[si] for si, s in enumerate(shard_list)
+                        s: dev[si] for si, s in stacks_mod.positions(
+                            shard_list, stacks[0]
+                        )
                     }
                     results[i] = Row(segments, n_words=idx.n_words)
                     self._count_stat(idx, calls[i].name)
@@ -1271,13 +1273,14 @@ class Executor:
                         # one pull for the flight
                         masks = kernels.pull(masks, "bsi_range_batch")
                 rows = []
+                placed = stacks_mod.positions(shard_list, bits)
                 with tracing.start_span("executor.demux").set_tag(
                     "n", len(mask_items)
                 ):
                     for qi in range(len(mask_items)):
                         row = Row(n_words=self.holder.n_words)
                         m = masks[qi]
-                        for si, s in enumerate(shard_list):
+                        for si, s in placed:
                             row.segments[s] = m[si]
                         rows.append(row)
                 for (i, _), row in zip(mask_items, rows):
@@ -1487,9 +1490,7 @@ class Executor:
             return
         fw = np.zeros((S_stack, P, W), np.uint32)
         for qi, (_, filt) in enumerate(filtered):
-            fw[:, qi, :] = self._row_to_shard_matrix(
-                filt, shard_list, S_stack, W
-            )
+            fw[:, qi, :] = self._row_to_shard_matrix(filt, shard_list, bits)
         if P > Q:
             kernels.note_pad(
                 "bsi_sum_batch", S_stack * P * W * 4, S_stack * Q * W * 4
@@ -1792,7 +1793,8 @@ class Executor:
             return out
         stack = self.stacks.bsi(field, shards)
         if stack is not None:
-            exists, sign, planes = self._bsi_split(stack.bits)
+            bits = stack.bits
+            exists, sign, planes = self._bsi_split(bits)
             self.bsi_stack_launches += 1
             from pilosa_tpu.ops import kernels
 
@@ -1805,7 +1807,7 @@ class Executor:
             ) > 1:
                 # one pull; avoid mixed placements
                 mask = kernels.pull(mask, "bsi_rows")
-            for si, s in enumerate(shards):
+            for si, s in stacks_mod.positions(shards, bits):
                 out.segments[s] = mask[si]
             return out
         view = field.view(field.bsi_view_name())
@@ -2101,12 +2103,7 @@ class Executor:
             # there is inert
             from pilosa_tpu.ops import kernels
 
-            S_stack = exists.shape[0]
-            fw_np = np.zeros((S_stack, field.n_words), np.uint32)
-            for si, s in enumerate(shards):
-                seg = filt.segments.get(s)
-                if seg is not None:
-                    fw_np[si] = kernels.pull(seg, "row_segment")
+            fw_np = self._row_to_shard_matrix(filt, shards, bits)
             sh = getattr(exists, "sharding", None)
             multi = sh is not None and len(getattr(sh, "device_set", ())) > 1
             # co-locate with a sharded stack
@@ -2408,8 +2405,7 @@ class Executor:
             # loop below answers then
             if stack is not None and kernels.row_counts_supported(bits):
                 slot_of = stack.slot_of
-                S, _, W = bits.shape
-                filt = self._row_to_shard_matrix(src, shards, S, W)
+                filt = self._row_to_shard_matrix(src, shards, bits)
                 mc = kernels.masked_row_counts(bits, filt)
                 rc = (
                     self.stacks.row_counts(stack, bits)
@@ -2813,22 +2809,36 @@ class Executor:
         return out
 
     @staticmethod
-    def _row_to_shard_matrix(row: Row, shards: list[int], S: int, W: int) -> np.ndarray:
+    def _row_to_shard_matrix(row: Row, shards: list[int], bits) -> np.ndarray:
         """A Row's per-shard segments as a dense ``uint32[S, W]`` matrix
-        aligned to a stack's (padded) shard axis; absent shards are
+        aligned to the shard axis of the stack ``bits`` (its padding and
+        its order over a mesh: ``stacks.positions``); absent shards are
         zero."""
         from pilosa_tpu.ops import kernels
 
-        filt = np.zeros((S, W), dtype=np.uint32)
-        for si, s in enumerate(shards):
+        filt = np.zeros((bits.shape[0], bits.shape[-1]), dtype=np.uint32)
+        for si, s in stacks_mod.positions(shards, bits):
             seg = row.segments.get(s)
             if seg is not None:
                 # a segment a device lane produced is a wait for the device
                 filt[si] = kernels.pull(seg, "row_segment")
         return filt
 
-    # prefix-mask memory ceiling for the k-level GroupBy batch
+    # prefix-mask memory ceiling for the k-level GroupBy batch: one
+    # device's share of the masks (the shard axis they inherit from the
+    # stack is split over the mesh), as stacks.STACK_BUDGET_BYTES is
     _GROUPBY_PREFIX_BUDGET_BYTES = 256 << 20
+
+    @classmethod
+    def _groupby_prefix_max(cls, bits) -> int:
+        """How many ``[S, W]`` prefix masks over the stack ``bits`` the
+        budget admits."""
+        from pilosa_tpu.ops import kernels
+
+        layout = kernels.shards_axis_of(bits)
+        n_dev = 1 if layout is None else layout[0].shape[layout[1]]
+        S, _, W = bits.shape
+        return max(1, cls._GROUPBY_PREFIX_BUDGET_BYTES // (S // n_dev * W * 4))
 
     def _groupby_k_level_batch(
         self, idx: Index, levels, shards: list[int], filt_row
@@ -2853,8 +2863,7 @@ class Executor:
             # combo-count kernels return per-shard partials, not host
             # addressable on a spanning stack; recursive path serves
             return None
-        S, _, W = bits0.shape
-        cmax = max(1, self._GROUPBY_PREFIX_BUDGET_BYTES // (S * W * 4))
+        cmax = self._groupby_prefix_max(bits0)
 
         rows1 = [r for r in levels[0][2] if r in slot0]
         if not rows1:
@@ -2865,7 +2874,7 @@ class Executor:
             bits0, kernels.h2d([slot0[r] for r in rows1], dtype=np.int32)
         )
         if filt_row is not None:
-            filt = self._row_to_shard_matrix(filt_row, shards, S, W)
+            filt = self._row_to_shard_matrix(filt_row, shards, bits0)
             prefix = prefix & kernels.h2d(filt)[None]
         combos: list[tuple[int, ...]] = [(r,) for r in rows1]
 

@@ -20,13 +20,11 @@ import contextlib
 import itertools
 import threading
 import weakref
-from functools import lru_cache
 
 import numpy as np
 
 import jax
-import jax.numpy as jnp
-from jax import lax, shard_map
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from pilosa_tpu.core import membudget, residency
@@ -87,36 +85,77 @@ def _put_block(bits, si, block):
     return lax.dynamic_update_index_in_dim(bits, block, si, 0)
 
 
-@lru_cache(maxsize=16)
-def _put_block_mesh(mesh, axis):
-    """:func:`_put_block` over a stack whose shard axis is split over
-    ``axis`` of ``mesh``: every device looks whether ``si`` falls into its
-    own share and writes there, so the array stays where it lies."""
-
-    def local(b, si, block):
-        n = b.shape[0]
-        k = si - lax.axis_index(axis) * n
-        mine = (k >= 0) & (k < n)
-        k = jnp.clip(k, 0, n - 1)
-        kept = lax.dynamic_index_in_dim(b, k, 0, keepdims=False)
-        return _put_block(b, k, jnp.where(mine, block, kept))
-
-    P = PartitionSpec
-    return jax.jit(
-        shard_map(
-            local, mesh=mesh,
-            in_specs=(P(axis, None, None), P(), P(None, None)),
-            out_specs=P(axis, None, None),
-        ),
-        donate_argnums=0,
-    )
-
-
 # The stack is donated: the write goes into the array that is there.  One
 # program a stack shape whatever the number of changed shards (a refresh
-# writes them one by one, ``si`` traced).
+# writes them one by one, ``si`` traced).  Over a mesh the two take ONE
+# chip's buffer of the stack (:class:`_Writing`), so they run on that
+# chip alone and compile once a chip.
 _PUT_GATHERED = jax.jit(_put_gathered, donate_argnums=0)
 _PUT_BLOCK = jax.jit(_put_block, donate_argnums=0)
+# a block gathered where the fragment's copy lies, to be sent to the chip
+# that keeps it (the ``peer`` route)
+_GATHER = jax.jit(lambda dev, slots: dev[slots])
+
+
+def positions(shards, bits) -> list[tuple[int, int]]:
+    """``(position, shard)`` for every shard of ``shards`` on the shard
+    axis of a stack laid out as the array ``bits`` is (the stack's own
+    array, or whatever a kernel made of it before any pull): position and
+    index in ``shards`` agree on one device only; over a mesh the axis
+    follows ``mesh.stack_order``, and a padded position holds no shard.
+    Everything that reads or fills the shard axis per shard (a filter's
+    segments going in, per-shard result words coming out) goes through
+    here."""
+    layout = kernels.shards_axis_of(bits)
+    n_dev = 1 if layout is None else layout[0].shape[layout[1]]
+    return [
+        (p, s)
+        for p, s in enumerate(mesh_mod.stack_order(tuple(shards), n_dev))
+        if s is not None
+    ]
+
+
+class _Writing:
+    """A stack's array while a refresh writes into it, taken apart into
+    the buffer each chip holds (on one device the array is its one part),
+    so that a write is a program of the one chip that keeps the shard.
+    Nothing is copied: the parts ARE the array's buffers (a part is
+    donated to its program, and what the program returns takes its
+    place), and the array is put together from them again
+    (``make_array_from_single_device_arrays``).  The array it was made from
+    is spent once a part was written: its other buffers live on in the
+    new one, untouched."""
+
+    def __init__(self, bits):
+        self.shape, self.sharding = bits.shape, bits.sharding
+        self.meshed = kernels.shards_axis_of(bits) is not None
+        # the buffers in the order of the shard axis
+        self.parts = [bits] if not self.meshed else [
+            sh.data for sh in sorted(
+                bits.addressable_shards,
+                key=lambda sh: sh.index[0].indices(self.shape[0])[0],
+            )
+        ]
+        # positions of the shard axis a chip holds
+        self.share = self.shape[0] // len(self.parts)
+
+    def home(self, chip: int):
+        """The device that keeps ``chip``'s share."""
+        return next(iter(self.parts[chip].devices()))
+
+    def put(self, chip: int, put, at: int, *args) -> None:
+        """Position ``at`` of ``chip``'s share, by a program of that chip."""
+        with DL_STACK.launch(
+            sig=f"refresh {self.parts[chip].shape}"
+        ), kernels.enqueue("stack_refresh"):
+            self.parts[chip] = put(self.parts[chip], np.int32(at), *args)
+
+    def whole(self):
+        return (
+            jax.make_array_from_single_device_arrays(
+                self.shape, self.sharding, self.parts
+            ) if self.meshed else self.parts[0]
+        )
 
 
 # per thread: [depth, {stacks on lease}] of the open scope, or None
@@ -357,10 +396,12 @@ class Stacks:
         # what the incremental refreshes wrote on the device (the changed
         # shards' blocks), what of it was gathered on the host and
         # shipped (0 where the fragments' device copies were the
-        # source), and how many had to copy the stack because a reader
-        # held its snapshot on lease
+        # source), what of it one chip sent another (0 where the copies
+        # lie on the chip that holds the shard's slice), and how many had
+        # to copy the stack because a reader held its snapshot on lease
         self.refresh_bytes = 0
         self.refresh_host_bytes = 0
+        self.refresh_peer_bytes = 0
         self.refresh_out_of_place = 0
         # pair counts answered from the cached host gram (zero device
         # work — the serving mode for repeat sequential queries)
@@ -567,11 +608,11 @@ class Stacks:
             )
         if not row_ids:
             return None
-        S, R, W = len(shards), len(row_ids), field.n_words
-        n_dev = 1
-        if mesh is not None:
-            n_dev = mesh.devices.size
-            S = -(-S // n_dev) * n_dev  # pad so the mesh divides the axis
+        n_dev = 1 if mesh is None else mesh.devices.size
+        # a chip's share of the axis holds the shards whose fragment
+        # copies lie on that chip, padded so the mesh divides the axis
+        order = mesh_mod.stack_order(tuple(shards), n_dev)
+        S, R, W = len(order), len(row_ids), field.n_words
         nbytes = S * R * W * 4
         # the array limit holds against ONE device's share (the shard
         # axis is split over the mesh); the budget's cap is the sum of
@@ -591,10 +632,11 @@ class Stacks:
 
         def host_rows(lo: int, hi: int) -> np.ndarray:
             """Stack positions ``lo..hi`` of the shard axis, gathered on
-            the host (positions past ``shards`` are the mesh's padding)."""
+            the host (a position that holds no shard is the mesh's
+            padding)."""
             block = np.zeros((hi - lo, R, W), dtype=np.uint32)
-            for si in range(lo, min(hi, len(shards))):
-                f = frags.get(shards[si])
+            for si in range(lo, hi):
+                f = frags.get(order[si])
                 if f is None:
                     continue
                 # bulk matrix copy, not one Python call per row
@@ -692,13 +734,24 @@ class Stacks:
 
         Each changed shard's ``[R, W]`` block goes in by one program the
         stack is donated to, so a refresh needs the block beside the
-        stack and never a second stack.  The block is gathered on the
-        device from the fragment's own copy where that is at hand
-        (``Fragment.stack_block``: no host gather, nothing shipped), else
-        on the host; a stack laid over a mesh takes the host's block,
-        every device writing its own share.  Only a stack some reader
-        holds on lease is copied first, by the runtime and by no program
-        of its own, and the copy is written as any stack is."""
+        stack and never a second stack.  Three routes, by where the
+        fragment's own copy lies (``Fragment.stack_block``):
+
+        * ``device``: on the chip that holds the shard's slice of the
+          stack, which is where ``mesh.chip_of_shard`` puts both.  The
+          block is gathered there and written into that chip's buffer of
+          the stack: no host gather, nothing shipped, and over a mesh the
+          other chips take no part.
+        * ``peer``: on another chip (a shard list the rule could not
+          align, ``mesh.stack_order``; a copy made under another mesh).
+          Gathered where the copy lies and sent to the one chip that keeps
+          it.
+        * ``host``: no device copy at hand.  Gathered from the host mirror
+          and uploaded to the one chip that keeps it.
+
+        Only a stack some reader holds on lease is copied first, by the
+        runtime and by no program of its own, and the copy is written as
+        any stack is."""
         changed = [
             si for si, (a, b) in enumerate(zip(stack.versions, versions))
             if a != b
@@ -711,54 +764,56 @@ class Stacks:
             return False
         slot_of = stack.slot_of
         bits = stack._snap[0]  # not ``.bits``: that would take a lease
-        layout = kernels.shards_axis_of(bits)
+        pos_of = {s: p for p, s in positions(shards, bits)}
         block_bytes = bits.nbytes // bits.shape[0]
         leased = bool(stack._leased)
         if leased:
             # a leased snapshot stays as it is: the writes go into a copy
             # the runtime makes
             bits = jax.device_put(bits, may_alias=False)
-        written = host_bytes = 0
+        into = _Writing(bits)
+        del bits  # spent with the first write
+        written = host_bytes = peer_bytes = 0
         with tracing.start_span("stacks.refresh").set_tag(
             "field", field.name
         ).set_tag("shards", len(changed)) as sp:
             try:
                 for si in changed:
-                    src = frags[shards[si]].stack_block(
-                        slot_of, on_device=layout is None
-                    )
+                    src = frags[shards[si]].stack_block(slot_of)
                     if src is None:
                         return False  # new row: shape change, full rebuild
                     dev, rows = src
-                    if dev is not None:
-                        put, args = _PUT_GATHERED, (dev, rows)
-                    else:
+                    pos = pos_of[shards[si]]
+                    chip, at = divmod(pos, into.share)
+                    home = into.home(chip)
+                    if dev is None:
                         host_bytes += rows.nbytes
-                        if layout is None:
-                            put, to = _PUT_BLOCK, None
-                        else:
-                            put = _put_block_mesh(*layout)
-                            to = NamedSharding(layout[0], PartitionSpec())
-                        args = (kernels.h2d(rows, to),)
-                    with DL_STACK.launch(
-                        sig=f"refresh {bits.shape}"
-                    ), kernels.enqueue("stack_refresh"):
-                        bits = put(bits, np.int32(si), *args)
+                        block = kernels.h2d(rows, SingleDeviceSharding(home))
+                        into.put(chip, _PUT_BLOCK, at, block)
+                    elif dev.devices() == {home}:
+                        into.put(chip, _PUT_GATHERED, at, dev, rows)
+                    else:
+                        block = jax.device_put(_GATHER(dev, rows), home)
+                        peer_bytes += block.nbytes
+                        into.put(chip, _PUT_BLOCK, at, block)
                     written += 1
             finally:
                 if written:
                     # the donated array is gone: what was written is the
                     # snapshot now, under the new versions if all of it was
                     stack.refresh(
-                        bits,
+                        into.whole(),
                         versions if written == len(changed)
                         else stack.versions,
                     )
                 sp.set_tag("bytes", written * block_bytes).set_tag(
-                    "route", "host" if host_bytes else "device"
+                    "route",
+                    "host" if host_bytes else "peer" if peer_bytes
+                    else "device",
                 )
                 self.refresh_bytes += written * block_bytes
                 self.refresh_host_bytes += host_bytes
+                self.refresh_peer_bytes += peer_bytes
                 kernels.note_transfer(host_bytes, "h2d", dl_site=DL_STACK)
         self.refresh_out_of_place += leased
         self.incremental += 1

@@ -3,8 +3,8 @@
 One mesh axis covers this workload's parallelism (SURVEY §2.3):
 
 * ``shards`` — the data-parallel axis: columns striped into 2^20-wide
-  shards, each device slice owning a contiguous set of shards (the
-  analogue of the reference's shard→node jump-hash placement,
+  shards, each device owning the shards :func:`chip_of_shard` deals it
+  (the analogue of the reference's shard→node jump-hash placement,
   cluster.go:858-934, made static because TPU meshes are static).
 
 A second ``rows`` (tensor-parallel-style) axis existed through round 4
@@ -26,6 +26,8 @@ launch over ``serving_mesh()`` instead of an HTTP relay — the cluster
 disappears into the mesh (docs/serving.md "Cluster on the mesh")."""
 
 from __future__ import annotations
+
+from functools import lru_cache
 
 import numpy as np
 
@@ -62,8 +64,8 @@ def configure_serving(max_devices: int | None) -> None:
 
 def serving_mesh() -> Mesh | None:
     """1-D ``("shards",)`` mesh over the visible devices, used by the
-    serving executor's field stacks so each device owns a contiguous
-    slice of shards — the reference's shard→node placement
+    serving executor's field stacks so each device owns its share of
+    the shards (:func:`stack_order`) — the reference's shard→node placement
     (cluster.go:858-934) made static. None on a single-device host (the
     plain single-device path is faster than a degenerate mesh)."""
     global _serving_mesh
@@ -78,6 +80,44 @@ def serving_mesh() -> Mesh | None:
     if _serving_mesh is None or list(_serving_mesh.devices.flat) != devices:
         _serving_mesh = Mesh(np.array(devices), ("shards",))
     return _serving_mesh
+
+
+def chip_of_shard(shard: int, n_dev: int) -> int:
+    """Which of a serving mesh's ``n_dev`` chips holds shard ``shard``:
+    THE rule, read by both sides.  A fragment's device copy goes there
+    (core/fragment.py ``_to_device``: a fragment is made knowing only its
+    own number), and a field stack puts the shard into that chip's share
+    of its shard axis (:func:`stack_order`), so a refresh after a write
+    gathers the block on the chip that keeps it (exec/stacks.py)."""
+    return shard % n_dev
+
+
+@lru_cache(maxsize=256)
+def stack_order(shards: tuple[int, ...], n_dev: int) -> tuple[int | None, ...]:
+    """The shard at every position of a stack's shard axis over ``n_dev``
+    chips, None where the axis is padded.  The axis is ``n_dev`` shares of
+    ``ceil(len(shards) / n_dev)`` positions, chip ``d`` holding positions
+    ``[d * k, (d + 1) * k)``; a share takes the shards of its own chip
+    (:func:`chip_of_shard`) in the order of ``shards``, as many as fit.
+    Where the list has more of one chip's shards than a share holds (a
+    list with gaps) the rest fill what other shares have left: the stack
+    stays as small as it was, and those shards' refreshes cross chips.
+    A pure function of its arguments, so stacks of different fields over
+    one shard list share their positions (the cross-field kernels need
+    that).  On one device the order is ``shards`` itself."""
+    if n_dev <= 1:
+        return shards
+    k = -(-len(shards) // n_dev)
+    shares: list[list[int | None]] = [[] for _ in range(n_dev)]
+    spill = []
+    for s in shards:
+        own = shares[chip_of_shard(s, n_dev)]
+        (own if len(own) < k else spill).append(s)
+    spill.reverse()
+    for own in shares:
+        while len(own) < k:
+            own.append(spill.pop() if spill else None)
+    return tuple(s for own in shares for s in own)
 
 
 def init_multihost(

@@ -724,10 +724,12 @@ class Handler(BaseHTTPRequestHandler):
                 "stack_rebuilds": ex.stacks.rebuilds,
                 "stack_incremental": ex.stacks.incremental,
                 # the incremental refreshes: bytes written on the device,
-                # bytes of them gathered on the host and shipped, and
-                # refreshes that copied a stack a reader held on lease
+                # bytes of them gathered on the host and shipped, bytes of
+                # them one chip sent another, and refreshes that copied a
+                # stack a reader held on lease
                 "stack_refresh_bytes": ex.stacks.refresh_bytes,
                 "stack_refresh_host_bytes": ex.stacks.refresh_host_bytes,
+                "stack_refresh_peer_bytes": ex.stacks.refresh_peer_bytes,
                 "stack_refresh_out_of_place": ex.stacks.refresh_out_of_place,
                 "bsi_stack_launches": ex.bsi_stack_launches,
                 # stacks not built, by reason (exec/stacks.py), and

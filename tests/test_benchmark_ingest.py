@@ -22,8 +22,10 @@ sys.path.insert(0, os.path.join(REPO, "benchmark"))
 import manifest as mf  # noqa: E402
 
 CELL = "taxi.ingest-serve"
+# the cells beside a stream: this one, and since PR 40 the four-chip server's
+STREAM_CELLS = [CELL, "taxi-x4.ingest-serve"]
 MANIFEST = mf.load()
-# what a stream adds to a cell's per-layer metrics (each lists this cell alone)
+# what a stream adds to a cell's per-layer metrics (each lists the stream's cells alone)
 STREAMED = [
     "ingest.import_ack_p95_ms", "ingest.stream_late_ms",
     "rescache.invalidations_per_import", "stacks.refreshes_per_import",
@@ -55,13 +57,23 @@ def test_the_readers_are_dashboard_c32s_and_the_pace_is_the_issues():
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("taxi-ingest", mix["name"], 1)
 
 
-def test_the_cells_own_metrics_list_it_alone_and_have_data_readers():
-    own = [m for m in MANIFEST["per_layer"] if CELL in m.get("workloads", ())]
+@pytest.mark.parametrize("cell", STREAM_CELLS)
+def test_the_cells_own_metrics_list_it_alone_and_have_data_readers(cell):
+    """The stream's seven list the stream's cells and no other, in the order
+    the cells entered the grid, and each has a reader that is data."""
+    own = [m for m in MANIFEST["per_layer"]
+           if cell in m.get("workloads", ()) and m["name"] in STREAMED]
     assert [m["name"] for m in own] == STREAMED
     e2e = {m["name"] for m in MANIFEST["end_to_end"]}
     for m in own:
-        assert m["workloads"] == [CELL] and m["moves"] in e2e
+        assert m["workloads"] == STREAM_CELLS and m["moves"] in e2e
         assert os.path.exists(os.path.join(REPO, "benchmark", "layer_metrics", m["name"] + ".json"))
+    mix = mf.cell(MANIFEST, cell)["traffic"]
+    assert "stream" in mf.read_json(f"benchmark/traffic/{mix}.json")
+    # and no cell without a stream lists one of them
+    for m in MANIFEST["per_layer"]:
+        if m["name"] in STREAMED:
+            assert set(m["workloads"]) == set(STREAM_CELLS)
 
 
 @pytest.mark.parametrize("trace", [0, 1])

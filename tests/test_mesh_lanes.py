@@ -3,9 +3,11 @@ numpy reference written here and to the one-device executor's answers: an
 index of the ``taxi`` shape (benchmark/configs/taxi.json: a handful of its set
 fields, the amount as an int field) at the suite's shard width, eight shards,
 every column filled, and one case per class of the ``dashboard-c32`` mix in
-flights of 1, 5 and 32 calls.  Then the stack budget, which holds against one
-device's share of a stack, and the host side of a build, which never holds
-more than that share."""
+flights of 1, 5 and 32 calls.  The same again after a seeded slab was imported
+into one, two and three shards of a half-loaded index (the benchmark's
+``ingest-serve-c32`` beside four chips).  Then the stack budget, which holds
+against one device's share of a stack, and the host side of a build, which
+never holds more than that share."""
 
 import numpy as np
 import pytest
@@ -26,22 +28,20 @@ FIELDS = {
 AMOUNT_MAX = 20049
 
 
-@pytest.fixture(scope="module")
-def rides():
-    """(holder, numpy columns by field): one row a ride in every set field,
-    ``total_amount`` on nine rides in ten."""
-    h = Holder()
-    idx = h.create_index("taxi")
-    n = SHARDS * idx.n_words * 32
-    rng = np.random.default_rng(29)
-    cols = np.arange(n, dtype=np.uint64)
+def _load(idx, cols, rng, starts=(0,)) -> dict:
+    """The rides of columns ``cols`` imported: one row a ride in every set
+    field, ``total_amount`` on nine rides in ten; the numpy columns by
+    field, ride ``k`` being ``cols[k]``'s."""
+    n = cols.size
     data = {}
     for name, rows in FIELDS.items():
-        # a skewed draw: some rows rare, every row present
+        # a skewed draw: some rows rare, every row present (from each of
+        # ``starts`` on)
         data[name] = np.minimum(
             rng.geometric(3.0 / rows, size=n) - 1, rows - 1
         ).astype(np.int64)
-        data[name][:rows] = np.arange(rows)
+        for k in starts:
+            data[name][k:][:rows] = np.arange(rows)
         idx.create_field(name).import_bits(data[name].astype(np.uint64), cols)
     amount = rng.integers(0, AMOUNT_MAX + 1, size=n)
     has = rng.random(n) < 0.9
@@ -49,7 +49,18 @@ def rides():
     idx.create_field(
         "total_amount", FieldOptions(field_type="int", min_=0, max_=AMOUNT_MAX)
     ).import_values(cols[has], amount[has])
-    return h, data
+    return data
+
+
+@pytest.fixture(scope="module")
+def rides():
+    """(holder, numpy columns by field): every column of eight shards."""
+    h = Holder()
+    idx = h.create_index("taxi")
+    n = SHARDS * idx.n_words * 32
+    return h, _load(
+        idx, np.arange(n, dtype=np.uint64), np.random.default_rng(29)
+    )
 
 
 def _queries(cls: str, n: int, rng) -> list[str]:
@@ -258,6 +269,123 @@ def test_every_stack_lies_on_the_four_devices(served):
     assert ex.stacks.refusals["array_budget"] == ex.stacks.refusals["hbm_budget"] == 0
 
 
+# ----------------------------------------------------------- after an import
+
+def _slab(idx, data, shard: int, rng) -> dict:
+    """A seeded slab of rides into the free half of ``shard``: one
+    import a field, as the benchmark's stream sends them; the numpy
+    columns with the slab's rides appended."""
+    width = idx.n_words * 32
+    n = width // 4
+    cols = (shard * width + width // 2 + rng.choice(
+        width // 2, size=n, replace=False
+    )).astype(np.uint64)
+    out = {}
+    for name, rows in FIELDS.items():
+        drawn = rng.integers(0, rows, size=n)
+        idx.field(name).import_bits(drawn.astype(np.uint64), cols)
+        out[name] = np.concatenate([data[name], drawn])
+    amount = rng.integers(0, AMOUNT_MAX + 1, size=n)
+    has = rng.random(n) < 0.9
+    idx.field("total_amount").import_values(cols[has], amount[has])
+    out["total_amount"] = np.concatenate(
+        [data["total_amount"], np.where(has, amount, -1)]
+    )
+    return out
+
+
+def _fragments(idx):
+    for name in (*FIELDS, "total_amount"):
+        field = idx.field(name)
+        for view in field.views.values():
+            yield from view.fragments.values()
+
+
+def _warm_stacks(ex) -> int:
+    idx, shards = ex.holder.index("taxi"), list(range(SHARDS))
+    return sum(
+        ex.stacks.cached(idx.field(name), shards) for name in FIELDS
+    ) + ex.stacks.bsi_cached(idx.field("total_amount"), shards)
+
+
+@pytest.fixture(scope="module", params=[1, 2, 3], ids="slabs-into-{}".format)
+def streamed(request):
+    """(mesh executor, numpy columns, flights, one-device answers, the
+    counters as the imports found them): the ``rides`` index with the
+    first half of every shard loaded, every class's stacks warm over the
+    four devices and every fragment's copy on its device, as the ingest
+    uploader keeps them; then a seeded slab into one, two or three
+    shards.  The one-device executor answers the state after the import,
+    the mesh executor has not read it yet."""
+    k = request.param
+    h = Holder()
+    idx = h.create_index("taxi")
+    width = idx.n_words * 32
+    rng = np.random.default_rng([31, k])
+    cols = np.concatenate([
+        s * width + np.arange(width // 2) for s in range(SHARDS)
+    ]).astype(np.uint64)
+    # every row in every shard: no stack grows with a slab
+    data = _load(
+        idx, cols, rng, starts=[s * (width // 2) for s in range(SHARDS)]
+    )
+    flights = {
+        (cls, n): _queries(cls, n, np.random.default_rng([31, j, n]))
+        for j, cls in enumerate(LANE_SPAN) for n in (1, 5, 32)
+    }
+    try:
+        mesh.configure_serving(DEVICES)
+        ex = Executor(h, rescache_entries=0)
+        for cls in LANE_SPAN:
+            ex.execute("taxi", " ".join(_queries(cls, 4, np.random.default_rng(7))))
+        for frag in _fragments(idx):
+            frag.device_bits()
+        was = (_warm_stacks(ex), ex.stacks.incremental, ex.stacks.rebuilds)
+        for shard in rng.choice(SHARDS, size=k, replace=False).tolist():
+            data = _slab(idx, data, shard, rng)
+        mesh.configure_serving(1)
+        one = Executor(h, rescache_entries=0)
+        solo = {
+            q: _plain(one.execute("taxi", q)[0])
+            for qs in flights.values() for q in qs
+        }
+        mesh.configure_serving(DEVICES)
+        yield ex, data, flights, solo, was
+    finally:
+        mesh.configure_serving(None)
+
+
+@pytest.mark.parametrize("n", [1, 5, 32])
+@pytest.mark.parametrize("cls", sorted(LANE_SPAN))
+def test_class_on_the_mesh_after_an_import_matches_reference_and_one_device(
+    streamed, cls, n
+):
+    """The plain reference of the same semantics, at a small size on the
+    CPU: what the benchmark's judge holds the four-chip cell to while it
+    is being imported."""
+    ex, data, flights, solo, _ = streamed
+    qs = flights[cls, n]
+    got = ex.execute("taxi", " ".join(qs))
+    assert len(got) == n
+    for q, r in zip(qs, got):
+        _check(q, r, _reference(data, q))
+        assert _plain(r) == solo[q], q
+
+
+def test_the_imports_were_refreshed_on_the_chips_that_hold_them(streamed):
+    """Every stack that was warm was refreshed once, none rebuilt, nothing
+    through the host and nothing from chip to chip."""
+    ex, _, flights, _, (warm, incremental, rebuilds) = streamed
+    for qs in flights.values():
+        ex.execute("taxi", " ".join(qs))
+    assert warm >= len(FIELDS) - 1
+    assert ex.stacks.incremental == incremental + warm
+    # built since: only what no warm-up flight had read twice
+    assert ex.stacks.rebuilds - rebuilds == _warm_stacks(ex) - warm
+    assert ex.stacks.refresh_host_bytes == ex.stacks.refresh_peer_bytes == 0
+    assert sum(by["mesh"] for by in ex.lane_declines.values()) == 0
+
+
 # -------------------------------------------------------------- stack budget
 
 @pytest.fixture()
@@ -305,6 +433,56 @@ def test_lane_hands_back_under_budget_when_its_stack_is_refused(small_budget):
     assert ex.stacks.refusals["array_budget"] >= 1
 
 
+def test_prefix_budget_holds_against_a_devices_share(rides, monkeypatch):
+    """The k-level ``GroupBy`` keeps its prefix masks ``[C, S, W]`` split
+    over the mesh as the stack is, so its budget is one device's share: a
+    call whose prefixes fit a quarter of the shard axis and not the whole
+    keeps the batch path on four devices and leaves it on one (PR 40: at
+    32 shards over four chips the mix's three-level call left it, 10 s of
+    the dispatcher a call)."""
+    from pilosa_tpu.ops import kernels
+
+    h, data = rides
+    idx = h.index("taxi")
+    q = "GroupBy(Rows(passenger_count), Rows(pickup_year), Rows(dist_miles))"
+    prefixes = len(set(zip(data["passenger_count"], data["pickup_year"])))
+    share = prefixes * (SHARDS // DEVICES) * idx.n_words * 4
+    monkeypatch.setattr(Executor, "_GROUPBY_PREFIX_BUDGET_BYTES", share)
+    took, inner = [], Executor._groupby_k_level_batch
+
+    def watched(self, *args):
+        out = inner(self, *args)
+        took.append(out is not None)
+        return out
+
+    monkeypatch.setattr(Executor, "_groupby_k_level_batch", watched)
+    try:
+        mesh.configure_serving(1)
+        one = Executor(h, rescache_entries=0).execute("taxi", q)[0]
+        mesh.configure_serving(DEVICES)
+        ex = Executor(h, rescache_entries=0)
+        got = ex.execute("taxi", q)[0]
+        assert took == [False, True]
+        assert [(g.group, g.count) for g in got] == [
+            (g.group, g.count) for g in one
+        ] and len(got) > prefixes
+        bits = ex.stacks.get(idx.field("passenger_count"), list(range(SHARDS))).bits
+        assert Executor._groupby_prefix_max(bits) == prefixes
+        masks = kernels.gather_prefix(bits, kernels.h2d([0, 1, 2], dtype=np.int32))
+        assert {sh.data.shape for sh in masks.addressable_shards} == {
+            (3, SHARDS // DEVICES, idx.n_words)
+        }
+        masks = kernels.refine_prefix(
+            masks, bits, kernels.h2d([0, 2], dtype=np.int32),
+            kernels.h2d([1, 1], dtype=np.int32),
+        )
+        assert {sh.data.shape for sh in masks.addressable_shards} == {
+            (2, SHARDS // DEVICES, idx.n_words)
+        }
+    finally:
+        mesh.configure_serving(None)
+
+
 def test_host_side_of_a_build_holds_one_devices_share(rides, monkeypatch):
     h, data = rides
     field = h.index("taxi").field("pickup_grid_id")
@@ -325,8 +503,14 @@ def test_host_side_of_a_build_holds_one_devices_share(rides, monkeypatch):
         bits = ex.stacks.get(field, list(range(SHARDS))).bits
         monkeypatch.undo()
         assert sizes and max(sizes) == whole // DEVICES and sum(sizes) == whole
-        # and what was put is the field: row 3's rides, shard by shard
-        got = np.asarray(bits[:, 3]).view(np.uint8)
+        # and what was put is the field: row 3's rides, shard by shard,
+        # each where the stack's own order over the mesh has it
+        shards = list(range(SHARDS))
+        at = [p for p, _ in sorted(
+            stacks.positions(shards, bits), key=lambda ps: ps[1]
+        )]
+        assert sorted(at) == shards and at != shards
+        got = np.asarray(bits[:, 3])[at].view(np.uint8)
         want = np.packbits(data["pickup_grid_id"] == 3, bitorder="little")
         assert np.array_equal(got.reshape(-1), want)
     finally:

@@ -183,7 +183,10 @@ import threading
 import urllib.request
 import weakref
 
+import jax
+
 from pilosa_tpu.exec import stacks
+from pilosa_tpu.obs import tracing
 from pilosa_tpu.parallel import mesh
 
 SHARDS = 4
@@ -197,19 +200,35 @@ def devices(request):
     mesh.configure_serving(None)
 
 
-def _imported(seed=3):
-    """(executor, index): a set field ``f`` (row 8 in shard 0 alone) and an
-    int field ``v`` over four shards, stacks not built yet."""
+@pytest.fixture()
+def routes():
+    """The ``route`` tag of every ``stacks.refresh`` span, as it ends."""
+    rec, old = tracing.RecordingTracer(), tracing.get_tracer()
+    tracing.set_tracer(rec)
+    yield lambda: [s.tags["route"] for s in rec.finished("stacks.refresh")]
+    tracing.set_tracer(old)
+
+
+def _imported(seed=3, shard_list=range(SHARDS)):
+    """(executor, index): a set field ``f`` (row 8 in the first shard
+    alone) and an int field ``v`` over four shards, or over those of
+    ``shard_list``; stacks not built yet."""
     h = Holder()
     idx = h.create_index("i")
     width = idx.n_words * 32
     rng = np.random.default_rng(seed)
-    cols = rng.choice(SHARDS * width, size=1200, replace=False).astype(np.uint64)
+    shard_list = np.array(shard_list, np.int64)
+    cols = rng.choice(
+        shard_list.size * width, size=300 * shard_list.size, replace=False
+    )
+    cols = (shard_list[cols // width] * width + cols % width).astype(np.uint64)
     rows = rng.integers(0, F_ROWS - 1, size=cols.size).astype(np.uint64)
-    rows[cols < width][:5] = F_ROWS - 1
     f = idx.create_field("f")
     f.import_bits(rows, cols)
-    f.import_bits(np.full(5, F_ROWS - 1, np.uint64), np.arange(5, dtype=np.uint64))
+    f.import_bits(
+        np.full(5, F_ROWS - 1, np.uint64),
+        int(shard_list[0]) * width + np.arange(5, dtype=np.uint64),
+    )
     v = idx.create_field(
         "v", FieldOptions(field_type="int", min_=0, max_=DEPTH_MAX)
     )
@@ -217,31 +236,49 @@ def _imported(seed=3):
     return Executor(h, rescache_entries=0), idx
 
 
-def _get(ex, idx, name):
+def _get(ex, idx, name, shard_list=range(SHARDS)):
     field = idx.field(name)
-    shards = list(range(SHARDS))
+    shards = list(shard_list)
     return ex.stacks.bsi(field, shards) if field.is_bsi() else ex.stacks.get(
         field, shards
     )
 
 
-def _frags(idx, name):
+def _frags(idx, name, shard_list=range(SHARDS)):
     field = idx.field(name)
     view = field.view(field.bsi_view_name() if field.is_bsi() else "standard")
-    return [view.fragments[s] for s in range(SHARDS)]
+    return [view.fragments[s] for s in shard_list]
 
 
-def _as_built(idx, name, stack) -> np.ndarray:
-    """What a build gathers, from the host mirrors: the stack's shape."""
-    frags = _frags(idx, name)
+def _as_built(idx, name, stack, shard_list=range(SHARDS)) -> np.ndarray:
+    """What a build gathers, from the host mirrors: the stack's shape, its
+    shards where the stack's own order has them."""
+    frags = dict(zip(shard_list, _frags(idx, name, shard_list)))
     out = np.zeros(
         (stack.bits.shape[0], len(stack.slot_of), idx.n_words), np.uint32
     )
-    for si, frag in enumerate(frags):
-        ids, matrix = frag.rows_matrix_host()
+    for si, s in stacks.positions(list(shard_list), stack.bits):
+        ids, matrix = frags[s].rows_matrix_host()
         for k, r in enumerate(ids):
             out[si, stack.slot_of[r]] = matrix[k]
     return out
+
+
+def _spent(old) -> bool:
+    """Whether a refresh wrote into ``old``'s memory: the array it was
+    donated whole is deleted; over a mesh the buffer of the chip that
+    was written is, and a read of the array raises."""
+    return old.is_deleted() or any(
+        sh.data.is_deleted() for sh in old.addressable_shards
+    )
+
+
+def _buffers(bits) -> dict:
+    """device -> where its buffer of the array lies."""
+    return {
+        sh.device: sh.data.unsafe_buffer_pointer()
+        for sh in bits.addressable_shards
+    }
 
 
 def _write(idx, name, rng, shards):
@@ -265,12 +302,14 @@ def _write(idx, name, rng, shards):
 @pytest.mark.parametrize("name", ["f", "v"])
 @pytest.mark.parametrize("route", ["device", "host"])
 def test_a_refresh_writes_the_changed_blocks_into_the_array_that_is_there(
-    devices, route, name
+    devices, route, name, routes
 ):
     """Seeded imports, set and BSI field, one device and a mesh of four,
-    both routes: the old snapshot is deleted, the counters hold the
+    both routes: the old snapshot is spent, the counters hold the
     changed blocks' bytes and not the stack's, and the stack is bit for
-    bit what a fresh build makes."""
+    bit what a fresh build makes.  The mesh is held to the device route
+    as one device is: nothing through the host, nothing from chip to
+    chip, and the chips that hold no changed shard keep their buffers."""
     ex, idx = _imported()
     stack = _get(ex, idx, name)
     block = len(stack.slot_of) * idx.n_words * 4
@@ -287,10 +326,25 @@ def test_a_refresh_writes_the_changed_blocks_into_the_array_that_is_there(
             ex.stacks.refresh_bytes, ex.stacks.refresh_host_bytes,
             ex.stacks.incremental, ex.stacks.rebuilds,
         )
+        was = _buffers(old)
         _write(idx, name, rng, changed)
         assert _get(ex, idx, name) is stack
-        assert old.is_deleted() and stack.bits is not old
-        on_host = route == "host" or devices > 1
+        assert _spent(old) and stack.bits is not old
+        on_host = route == "host"
+        assert routes()[-1] == route and ex.stacks.refresh_peer_bytes == 0
+        if devices > 1 and route == "device":
+            # shard s on chip s: the changed chips' buffers were donated
+            # (where the program writes is the runtime's to say); the
+            # others are the old array's own, where they lay
+            now = _buffers(stack.bits)
+            assert all(
+                now[d] == was[d]
+                for s, d in enumerate(jax.devices()[:devices])
+                if s not in changed
+            )
+            assert [
+                sh.data.is_deleted() for sh in old.addressable_shards
+            ] == [s in changed for s in range(SHARDS)]
         assert (
             ex.stacks.refresh_bytes, ex.stacks.refresh_host_bytes,
             ex.stacks.incremental, ex.stacks.rebuilds,
@@ -358,14 +412,14 @@ def test_a_leased_snapshot_is_left_alone(devices):
         other.join()
         assert ex.stacks.incremental == 2
         assert ex.stacks.refresh_out_of_place == 1
-        assert not held.is_deleted() and np.array_equal(np.asarray(held), was)
+        assert not _spent(held) and np.array_equal(np.asarray(held), was)
         copy = stack.bits
         assert copy is not held
         assert np.array_equal(np.asarray(copy), _as_built(idx, "f", stack))
     assert stack._leased == 0
     _write(idx, "f", rng, [3])
     assert _get(ex, idx, "f") is stack
-    assert copy.is_deleted() and not held.is_deleted()
+    assert _spent(copy) and not _spent(held)
     assert ex.stacks.refresh_out_of_place == 1
     assert stacks.DL_STACK.snapshot()["compiles"] == programs
     assert np.array_equal(
@@ -389,6 +443,119 @@ def test_a_rebuild_lets_go_of_the_retired_array_first(devices, monkeypatch):
     monkeypatch.setattr(stacks.Stacks, "_build", spy)
     assert _get(ex, idx, "f") is not None
     assert alive == [False] and ex.stacks.rebuilds == 2
+
+
+@pytest.mark.parametrize("n_shards", [4, 12, 32])
+@pytest.mark.parametrize("devices", [4], indirect=True)
+def test_a_shards_fragment_copies_and_stack_slice_lie_on_one_device(
+    devices, n_shards
+):
+    """The mesh's one rule, read by both sides: every shard's fragment
+    copy is on the device that holds the shard's position of every stack,
+    set field and BSI field alike, and both stacks share their order."""
+    shard_list = list(range(n_shards))
+    ex, idx = _imported(shard_list=shard_list)
+    for name in ("f", "v"):
+        bits = _get(ex, idx, name, shard_list).bits
+        assert bits.shape[0] == n_shards
+        home = {}
+        for sh in bits.addressable_shards:
+            lo, hi, _ = sh.index[0].indices(bits.shape[0])
+            home.update(dict.fromkeys(range(lo, hi), sh.device))
+        placed = stacks.positions(shard_list, bits)
+        assert sorted(s for _, s in placed) == shard_list
+        assert placed == [
+            (p, s) for p, s in enumerate(
+                mesh.stack_order(tuple(shard_list), devices)
+            )
+        ]
+        for (p, s), frag in zip(
+            sorted(placed, key=lambda ps: ps[1]), _frags(idx, name, shard_list)
+        ):
+            assert frag.device_bits().devices() == {home[p]}, (name, s)
+            assert home[p] == jax.devices()[mesh.chip_of_shard(s, devices)]
+
+
+@pytest.mark.parametrize(
+    "shard_list, crossing",
+    [
+        # two shards a chip and six of chip 0's: four lie in other
+        # chips' shares
+        ([0, 4, 8, 12, 16, 20], [8, 12, 16, 20]),
+        # a list with a gap, 6 shards over 4 devices: chip 0 has one
+        # more than its share
+        ([0, 1, 2, 4, 8, 11], [8]),
+    ],
+    ids=["one-chips-shards", "a-gap"],
+)
+@pytest.mark.parametrize("name", ["f", "v"])
+@pytest.mark.parametrize("devices", [4], indirect=True)
+def test_a_shard_the_rule_could_not_align_goes_from_chip_to_chip(
+    devices, name, shard_list, crossing, routes
+):
+    """Its block is gathered where the fragment's copy lies and sent to
+    the one chip that keeps it: the peer route, its bytes counted, none
+    through the host, the stack bit for bit a fresh build and the reads
+    right.  A shard of the same list that does align stays on its chip."""
+    ex, idx = _imported(shard_list=shard_list)
+    plain = Executor(ex.holder, rescache_entries=0)
+    plain.stacks.get = lambda *a, **k: None
+    stack = _get(ex, idx, name, shard_list)
+    block = len(stack.slot_of) * idx.n_words * 4
+    home = {
+        s: p // (stack.bits.shape[0] // devices)
+        for p, s in stacks.positions(shard_list, stack.bits)
+    }
+    assert [
+        s for s in shard_list if home[s] != mesh.chip_of_shard(s, devices)
+    ] == crossing
+    rng = np.random.default_rng([8, name == "v"])
+    aligned = next(s for s in shard_list if s not in crossing)
+    for changed, route, peers in (
+        ([crossing[0]], "peer", 1), ([aligned], "device", 0),
+        ([aligned, crossing[-1]], "peer", 1),
+    ):
+        for frag in _frags(idx, name, shard_list):
+            frag.device_bits()
+        before = ex.stacks.refresh_peer_bytes
+        _write(idx, name, rng, changed)
+        assert _get(ex, idx, name, shard_list) is stack
+        assert routes()[-1] == route
+        assert ex.stacks.refresh_peer_bytes == before + peers * block
+        assert ex.stacks.refresh_host_bytes == 0
+        assert np.array_equal(
+            np.asarray(stack.bits), _as_built(idx, name, stack, shard_list)
+        )
+    assert (ex.stacks.incremental, ex.stacks.rebuilds) == (3, 1)
+    queries = (
+        ["Sum(field=v)", "Count(Row(v > 2000))", "Sum(Row(f=1), field=v)"]
+        if name == "v" else
+        [PAIRS, "TopN(f, Row(f=2), n=3)", "Count(Union(Row(f=0), Row(f=8)))"]
+    )
+    for q in queries:
+        assert ex.execute("i", q) == plain.execute("i", q), q
+
+
+@pytest.mark.parametrize("devices", [4], indirect=True)
+def test_a_fragment_with_no_device_copy_takes_the_host_route(devices, routes):
+    """Over a mesh as on one device: the block comes from the host mirror
+    (``route`` ``host``, its bytes counted) only where the fragment has
+    no copy on a device; beside it a shard that has one stays there."""
+    ex, idx = _imported()
+    stack = _get(ex, idx, "f")
+    block = len(stack.slot_of) * idx.n_words * 4
+    frags = _frags(idx, "f")
+    assert all(f._device is None for f in frags)
+    _write(idx, "f", np.random.default_rng(6), [2])
+    assert _get(ex, idx, "f") is stack and routes() == ["host"]
+    assert ex.stacks.refresh_host_bytes == block
+    frags[1].device_bits()
+    _write(idx, "f", np.random.default_rng(7), [1])
+    assert _get(ex, idx, "f") is stack and routes() == ["host", "device"]
+    assert (ex.stacks.refresh_host_bytes, ex.stacks.refresh_peer_bytes) == (
+        block, 0
+    )
+    assert np.array_equal(np.asarray(stack.bits), _as_built(idx, "f", stack))
 
 
 @pytest.mark.parametrize("devices", [1], indirect=True)  # launches are quickest
@@ -513,6 +680,7 @@ def test_debug_vars_shows_the_refresh(tmp_path):
         assert cache["stack_incremental"] == 1
         assert cache["stack_refresh_bytes"] == 2 * holder.n_words * 4
         assert cache["stack_refresh_host_bytes"] in (0, 2 * holder.n_words * 4)
+        assert cache["stack_refresh_peer_bytes"] == 0
         assert cache["stack_refresh_out_of_place"] == 0
     finally:
         server.close()
