@@ -14,6 +14,8 @@ Union/Xor/Not/Shift (executor.go:653-680)."""
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import os
 import threading
 import time
@@ -70,7 +72,10 @@ _DL_PAIR = devledger.site("executor.pair_counts")
 # an operand's mesh layout, a stack that was not to be had (stacks.refusals
 # says why), a lone item of a cold field (the latency tier's by design),
 # a shape the lane's kernel does not take, trouble with the item itself
-_LANES = ("pair_counts", "general", "bsi", "bsi_filtered_counts", "bsi_sums")
+_LANES = (
+    "pair_counts", "general", "bsi", "bsi_filtered_counts", "bsi_sums",
+    "groupby",
+)
 _DECLINE_REASONS = ("mesh", "budget", "demand", "shape", "error")
 
 _PAIR_OPS = {
@@ -187,6 +192,13 @@ class Executor:
         self.lane_declines = {
             lane: dict.fromkeys(_DECLINE_REASONS, 0) for lane in _LANES
         }
+        # the GroupBy lane (_groupby_lane): calls it took, pulls it made,
+        # the levels enqueued and not yet pulled summed over those pulls
+        # (the pulled one included: 1 a pull is the per-call order), and
+        # levels the byte bound held back
+        self.groupby_lane = dict.fromkeys(
+            ("calls", "pulls", "inflight_sum", "budget_waits"), 0
+        )
 
     # ------------------------------------------------------------------ API
 
@@ -233,7 +245,8 @@ class Executor:
                     results[i] = res
             self._batch_pair_counts(idx, calls[:first_write], shards, results)
             self._batch_general(idx, calls[:first_write], shards, results)
-            self._batch_bsi(idx, calls[:first_write], shards, results)
+            with self._groupby_lane(idx, calls[:first_write], shards, results):
+                self._batch_bsi(idx, calls[:first_write], shards, results)
             for i, call in enumerate(calls):
                 if results[i] is _UNSET:
                     with tracing.start_span(execute_span(call.name)):
@@ -322,7 +335,8 @@ class Executor:
                     )
                 self._batch_pair_counts(idx, flat_calls, shards, flat_results)
                 self._batch_general(idx, flat_calls, shards, flat_results)
-                self._batch_bsi(idx, flat_calls, shards, flat_results)
+                with self._groupby_lane(idx, flat_calls, shards, flat_results):
+                    self._batch_bsi(idx, flat_calls, shards, flat_results)
                 pos = 0
                 for qi in qis:
                     calls = cloned[qi]
@@ -1113,6 +1127,136 @@ class Executor:
                 if field is not None and isinstance(v, int) and not isinstance(v, bool):
                     row.attrs = field.row_attrs.attrs(v)
         return row
+
+    # ------------------------------------------------- batched GroupBy lane
+
+    @contextlib.contextmanager
+    def _groupby_lane(
+        self, idx: Index, calls: list[Call], shards: list[int] | None,
+        results: list[Any],
+    ):
+        """The GroupBy lane, a scope around the BSI lane: on entry every
+        unanswered ``GroupBy`` the batch paths take (:meth:`
+        _groupby_batchable`; one whose filter the BSI lane signs stays
+        that lane's) has its filter evaluated and its first level's count
+        ENQUEUED, call after call and none awaited, so the device counts
+        while the BSI lane's host work runs; on exit the calls in flight
+        are resumed in turn (pull, prune, enqueue the next level) until
+        each has ended.  Same launches as call by call, in another order;
+        a flight with one such call runs as it did.  The unfiltered
+        two-level call keeps :meth:`_groupby_two_level_batch` and its
+        cached cross gram, answered on entry.
+
+        Levels enqueued and not yet pulled hold at most
+        ``_GROUPBY_LANE_BUDGET_BYTES`` between them, as the steps reckon
+        them: a level that does not fit waits for the oldest pull, and
+        one is always admitted.  A later level launches on the stack
+        snapshot read on entry, so one lease scope spans entry, the BSI
+        lane and exit: another thread's refresh in between copies.
+
+        Steps that return None are answered by the recursive path here,
+        once.  Per-item trouble leaves the slot _UNSET for the per-call
+        path, which re-raises inside the owning query's demux scope."""
+        from pilosa_tpu.exec import astbatch
+
+        taken = [
+            i for i, call in enumerate(calls)
+            if results[i] is _UNSET and call.name == "GroupBy"
+            # a paged call and a call of one level are the per-call path's
+            and len(call.children) >= 2 and "previous" not in call.args
+            and astbatch.match_bsi(idx, call) is None
+        ]
+        if not taken:
+            yield
+            return
+        shard_list = self._shards_for(idx, shards)
+        stats = self.groupby_lane
+        budget = self._GROUPBY_LANE_BUDGET_BYTES
+        # per call: (levels, filter row, limit), for the recursive path
+        asked: dict[int, tuple] = {}
+        # (slot, steps, bytes): asked and held back; enqueued, oldest first
+        waiting: collections.deque = collections.deque()
+        inflight: collections.deque = collections.deque()
+        held = 0
+
+        def answer(i: int, groups) -> None:
+            levels, filt_row, limit = asked[i]
+            if groups is None:
+                groups = self._groupby_recursive(
+                    levels, shard_list, filt_row, None, limit
+                )
+            results[i] = groups[:limit] if limit else groups
+            self._count_stat(idx, "GroupBy")
+
+        def resume(i: int, steps, nbytes=None) -> None:
+            """Call ``i``'s steps to their next stop: a level enqueued (in
+            flight), a level the byte bound holds back (waiting), or
+            their end (answered).  ``nbytes``: the level they asked for
+            last, now admitted."""
+            nonlocal held
+            try:
+                with tracing.start_span("executor.groupByKLevel").set_tag(
+                    "levels", len(asked[i][0])
+                ):
+                    try:
+                        if nbytes is None:
+                            nbytes = next(steps)  # pulls the level in flight
+                            if waiting or (
+                                inflight and held + nbytes > budget
+                            ):
+                                stats["budget_waits"] += 1
+                                waiting.append((i, steps, nbytes))
+                                return
+                        next(steps)  # enqueues the level
+                        inflight.append((i, steps, nbytes))
+                        held += nbytes
+                    except StopIteration as end:
+                        answer(i, end.value)
+            except Exception:
+                # per-call path re-raises per query
+                self._lane_decline("groupby", "error")
+
+        with stacks_mod.reading():
+            with tracing.start_span("executor.batchGroupBy") as sp:
+                for i in taken:
+                    try:
+                        levels, filt_row, limit, previous = self._groupby_plan(
+                            idx, calls[i], shard_list
+                        )
+                        if not self._groupby_batchable(levels, previous):
+                            continue  # the per-call path's, by design
+                        asked[i] = (levels, filt_row, limit)
+                        stats["calls"] += 1
+                        if len(levels) == 2 and filt_row is None:
+                            answer(i, self._groupby_two_level_batch(
+                                idx, levels, shard_list
+                            ))
+                            continue
+                    except Exception:
+                        self._lane_decline("groupby", "error")
+                        continue
+                    resume(i, self._groupby_k_level_steps(
+                        levels, shard_list, filt_row, deferred=True
+                    ))
+                sp.set_tag("n", len(asked)).set_tag("levels_max", max(
+                    (len(a[0]) for a in asked.values()), default=0
+                ))
+            yield
+            with tracing.start_span("executor.batchGroupBy").set_tag(
+                "n", len(inflight) + len(waiting)
+            ):
+                while inflight or waiting:
+                    while waiting and (
+                        not inflight or held + waiting[0][2] <= budget
+                    ):
+                        resume(*waiting.popleft())
+                    if not inflight:
+                        continue  # the admitted level ended its call
+                    i, steps, nbytes = inflight.popleft()
+                    stats["pulls"] += 1
+                    stats["inflight_sum"] += len(inflight) + 1
+                    held -= nbytes
+                    resume(i, steps)
 
     # ------------------------------------------------ batched BSI fast path
 
@@ -2592,16 +2736,13 @@ class Executor:
 
     # --------------------------------------------------------------- GroupBy
 
-    def _execute_groupby(
-        self, idx: Index, call: Call, shards: list[int] | None,
-        filt_row=_UNSET,
-    ) -> list[GroupCount]:
-        """reference executor.go:1071-1275: nested cross-product of Rows()
-        children, each level intersected with the previous.  ``filt_row``
-        lets the batched BSI lane hand in a precomputed filter row (its
-        Range filter rode a shared range_batch launch); the _UNSET
-        default computes it from the call as before."""
-        shards = self._shards_for(idx, shards)
+    def _groupby_plan(
+        self, idx: Index, call: Call, shards: list[int], filt_row=_UNSET
+    ):
+        """A ``GroupBy`` validated and read (reference executor.go:1071-1100):
+        ``(levels, filter row or None, limit or 0, previous or None)``,
+        ``levels`` being ``[(field name, field, row ids)]``, one a ``Rows``
+        child.  ``filt_row``: as :meth:`_execute_groupby` takes it."""
         if not call.children:
             raise ExecuteError("GroupBy requires at least one Rows() child")
         for c in call.children:
@@ -2614,12 +2755,10 @@ class Executor:
             raise ExecuteError(
                 "'previous' argument must have a value for each GroupBy field"
             )
-
         if filt_row is _UNSET:
             filt_row = (
                 self._bitmap_call(idx, filt_call, shards) if has_filt else None
             )
-
         levels = []
         for c in call.children:
             fname = c.args.get("_field")
@@ -2628,27 +2767,68 @@ class Executor:
                 raise FieldNotFoundError(f"field not found: {fname}")
             row_ids = self._execute_rows(idx, c, shards).rows
             levels.append((fname, field, row_ids))
+        return (
+            levels, filt_row, limit if has_limit and limit > 0 else 0,
+            previous if has_prev else None,
+        )
 
-        results: list[GroupCount] = []
-        use_limit = has_limit and limit > 0
-
-        if not has_prev and all(
+    @staticmethod
+    def _groupby_batchable(levels, previous) -> bool:
+        """Whether the batch paths take the call: no paging, two levels
+        or more, every level with a standard view."""
+        return previous is None and len(levels) >= 2 and all(
             f.view(VIEW_STANDARD) is not None for _, f, _ in levels
-        ):
-            fast = None
+        )
+
+    def _execute_groupby(
+        self, idx: Index, call: Call, shards: list[int] | None,
+        filt_row=_UNSET,
+    ) -> list[GroupCount]:
+        """reference executor.go:1071-1275: nested cross-product of Rows()
+        children, each level intersected with the previous.  ``filt_row``
+        lets the batched BSI lane hand in a precomputed filter row (its
+        Range filter rode a shared range_batch launch); the _UNSET
+        default computes it from the call as before.
+
+        The per-call driver of the k-level steps: it resumes them to
+        their end at once, every level awaited before the next is
+        launched (the lane driver is :meth:`_groupby_lane`)."""
+        shards = self._shards_for(idx, shards)
+        levels, filt_row, limit, previous = self._groupby_plan(
+            idx, call, shards, filt_row
+        )
+        fast = None
+        if self._groupby_batchable(levels, previous):
             if len(levels) == 2 and filt_row is None:
                 # Two-level fast path: the pair-count kernel needs no
                 # prefix masks at all (reference executor.go:3208-3211).
                 fast = self._groupby_two_level_batch(idx, levels, shards)
-            elif len(levels) >= 2:
+            else:
                 # k-level: one batched intersect-count launch per level
                 # over running prefix masks, pruning empty combos.
-                fast = self._groupby_k_level_batch(
-                    idx, levels, shards, filt_row
-                )
-            if fast is not None:
-                return fast[: limit if use_limit else len(fast)]
+                steps = self._groupby_k_level_steps(levels, shards, filt_row)
+                with tracing.start_span("executor.groupByKLevel").set_tag(
+                    "levels", len(levels)
+                ):
+                    try:
+                        while True:
+                            next(steps)
+                    except StopIteration as end:
+                        fast = end.value
+        if fast is None:
+            return self._groupby_recursive(
+                levels, shards, filt_row, previous, limit
+            )
+        return fast[:limit] if limit else fast
 
+    def _groupby_recursive(
+        self, levels, shards: list[int], filt_row, previous, limit: int
+    ) -> list[GroupCount]:
+        """The reference's own order of work, one intersection a
+        combination: what answers a paged call, a call of one level, and
+        whatever the batch paths decline."""
+        results: list[GroupCount] = []
+        has_prev = previous is not None
         # one device gather per (level, row), not per combination
         row_cache: dict[tuple[int, int], Row] = {}
 
@@ -2659,7 +2839,7 @@ class Executor:
             return row_cache[key]
 
         def done() -> bool:
-            return use_limit and len(results) >= limit
+            return limit > 0 and len(results) >= limit
 
         def recurse(level: int, acc: Row | None, group: list[FieldRow], on_bound: bool):
             """Depth-first cross product in row order. ``on_bound`` tracks
@@ -2828,28 +3008,51 @@ class Executor:
     # device's share of the masks (the shard axis they inherit from the
     # stack is split over the mesh), as stacks.STACK_BUDGET_BYTES is
     _GROUPBY_PREFIX_BUDGET_BYTES = 256 << 20
+    # what the GroupBy lane's levels that are enqueued and not yet pulled
+    # may hold between them, reckoned as _groupby_k_level_steps yields it
+    # (one device's share again).  On a v5e the allocator's peak did not
+    # move between 2 and 8 GiB (PERF.md, PR 41), and 2 GiB held back every
+    # second level of a 12-shard flight
+    _GROUPBY_LANE_BUDGET_BYTES = 8 << 30
 
-    @classmethod
-    def _groupby_prefix_max(cls, bits) -> int:
-        """How many ``[S, W]`` prefix masks over the stack ``bits`` the
-        budget admits."""
+    @staticmethod
+    def _groupby_mask_bytes(bits) -> int:
+        """One device's share of one ``[S, W]`` mask over the stack
+        ``bits``."""
         from pilosa_tpu.ops import kernels
 
         layout = kernels.shards_axis_of(bits)
         n_dev = 1 if layout is None else layout[0].shape[layout[1]]
         S, _, W = bits.shape
-        return max(1, cls._GROUPBY_PREFIX_BUDGET_BYTES // (S // n_dev * W * 4))
+        return S // n_dev * W * 4
 
-    def _groupby_k_level_batch(
-        self, idx: Index, levels, shards: list[int], filt_row
-    ) -> list[GroupCount] | None:
+    @classmethod
+    def _groupby_prefix_max(cls, bits) -> int:
+        """How many ``[S, W]`` prefix masks over the stack ``bits`` the
+        budget admits."""
+        return max(
+            1, cls._GROUPBY_PREFIX_BUDGET_BYTES // cls._groupby_mask_bytes(bits)
+        )
+
+    def _groupby_k_level_steps(
+        self, levels, shards: list[int], filt_row, deferred: bool = False
+    ):
         """All k-level combination counts with O(1) launches per level:
         maintain [C, S, W] intersection masks for surviving combos, count
         every (combo, next-row) pair in one scan launch, prune zeros,
-        refine. None when stacks are unavailable or the surviving combo
-        set would exceed the prefix budget (callers fall back to the
-        recursive path). Matches reference semantics executor.go:3057-3230
-        (DFS row order, count = intersection of all levels + filter)."""
+        refine. Matches reference semantics executor.go:3057-3230
+        (DFS row order, count = intersection of all levels + filter).
+
+        A generator that stops where it would wait, twice a level: it
+        yields the bytes the level's count is about to hold on a device
+        (``C`` prefix masks and ``Rl`` gathered rows) and enqueues the
+        launch when resumed; it yields None with the launch enqueued and
+        pulls when resumed.  Its driver chooses when: at once
+        (:meth:`_execute_groupby`), or once its flight-mates' levels are
+        enqueued too (:meth:`_groupby_lane`, which passes ``deferred``:
+        ``kernels.combo_counts_gram``).  It returns the groups, or
+        None when stacks are unavailable or the surviving combo set would
+        exceed the prefix budget (the recursive path then answers)."""
         from pilosa_tpu.ops import kernels
 
         stacks = []
@@ -2864,6 +3067,7 @@ class Executor:
             # addressable on a spanning stack; recursive path serves
             return None
         cmax = self._groupby_prefix_max(bits0)
+        mask_bytes = self._groupby_mask_bytes(bits0)
 
         rows1 = [r for r in levels[0][2] if r in slot0]
         if not rows1:
@@ -2878,57 +3082,55 @@ class Executor:
             prefix = prefix & kernels.h2d(filt)[None]
         combos: list[tuple[int, ...]] = [(r,) for r in rows1]
 
-        with tracing.start_span("executor.groupByKLevel").set_tag(
-            "levels", len(levels)
-        ):
-            for li in range(1, len(levels)):
-                slotL, bitsL = stacks[li]
-                rows = [r for r in levels[li][2] if r in slotL]
-                if not rows:
-                    return []
-                idxL = kernels.h2d([slotL[r] for r in rows], dtype=np.int32)
-                # MXU cross gram when safe (one prefix read per level);
-                # per-shard scan partials otherwise
-                counts = kernels.combo_counts_gram(prefix, bitsL, idxL)
-                if counts is None:
-                    counts = kernels.pull(
-                        kernels.combo_counts(prefix, bitsL, idxL),
-                        "combo_counts",
-                    ).astype(np.int64).sum(axis=2)  # [C, Rl]
-                live = np.argwhere(counts > 0)  # row-major: DFS order
-                if li == len(levels) - 1:
-                    with tracing.start_span("executor.demux").set_tag(
-                        "n", len(live)
-                    ):
-                        return [
-                            GroupCount(
-                                group=[
-                                    FieldRow(
-                                        field=levels[k][0], row_id=rid
-                                    )
-                                    for k, rid in enumerate(
-                                        combos[ci] + (rows[ri],)
-                                    )
-                                ],
-                                count=int(counts[ci, ri]),
-                            )
-                            for ci, ri in live
-                        ]
-                if len(live) == 0:
-                    return []
-                if len(live) > cmax or len(live) > self._GROUPBY_BATCH_MAX:
-                    return None
-                prefix = kernels.refine_prefix(
-                    prefix,
-                    bitsL,
-                    kernels.h2d(live[:, 0], dtype=np.int32),
-                    kernels.h2d(
-                        [slotL[rows[ri]] for ri in live[:, 1]], dtype=np.int32
-                    ),
-                )
-                combos = [
-                    combos[ci] + (rows[ri],) for ci, ri in live
-                ]
+        for li in range(1, len(levels)):
+            slotL, bitsL = stacks[li]
+            rows = [r for r in levels[li][2] if r in slotL]
+            if not rows:
+                return []
+            yield (len(combos) + len(rows)) * mask_bytes
+            idxL = kernels.h2d([slotL[r] for r in rows], dtype=np.int32)
+            # MXU cross gram when safe (one prefix read per level);
+            # per-shard scan partials otherwise
+            launched = kernels.combo_counts_gram(prefix, bitsL, idxL, deferred)
+            gram = launched is not None
+            if not gram:
+                launched = kernels.combo_counts(prefix, bitsL, idxL)
+            yield None
+            counts = kernels.pull(
+                launched, "combo_gram" if gram else "combo_counts"
+            ).astype(np.int64)
+            if not gram:
+                counts = counts.sum(axis=2)  # per-shard partials -> [C, Rl]
+            live = np.argwhere(counts > 0)  # row-major: DFS order
+            if li == len(levels) - 1:
+                with tracing.start_span("executor.demux").set_tag(
+                    "n", len(live)
+                ):
+                    return [
+                        GroupCount(
+                            group=[
+                                FieldRow(field=levels[k][0], row_id=rid)
+                                for k, rid in enumerate(
+                                    combos[ci] + (rows[ri],)
+                                )
+                            ],
+                            count=int(counts[ci, ri]),
+                        )
+                        for ci, ri in live
+                    ]
+            if len(live) == 0:
+                return []
+            if len(live) > cmax or len(live) > self._GROUPBY_BATCH_MAX:
+                return None
+            prefix = kernels.refine_prefix(
+                prefix,
+                bitsL,
+                kernels.h2d(live[:, 0], dtype=np.int32),
+                kernels.h2d(
+                    [slotL[rows[ri]] for ri in live[:, 1]], dtype=np.int32
+                ),
+            )
+            combos = [combos[ci] + (rows[ri],) for ci, ri in live]
         return []
 
     # --------------------------------------------------------------- Options

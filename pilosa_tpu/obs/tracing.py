@@ -127,6 +127,7 @@ SPAN_TABLE = (
     ("executor.bsiRangeCountBatch", _LANES, _HOST_MS),
     ("executor.bsiFilteredCountBatch", _LANES, _HOST_MS),
     ("executor.bsiSumBatch", _LANES, _HOST_MS),
+    ("executor.batchGroupBy", _LANES, _HOST_MS),
     ("executor.groupByBatch", _LANES, _HOST_MS),
     ("executor.groupByKLevel", _LANES, _HOST_MS),
     ("executor.stackBuild", _LANES, _HOST_MS),

@@ -689,7 +689,9 @@ def gram_matrix_traced(bits: jax.Array) -> jax.Array:
     return gram_matrix_xla(bits)
 
 
-def _with_gram_fallback(pallas_fn, fallback_fn, gate=None, kernel="gram"):
+def _with_gram_fallback(
+    pallas_fn, fallback_fn, gate=None, kernel="gram", deferred=False
+):
     """The gram family's shared probe/demote contract: the first success
     proves the gate; every failure — probe-time or proven — is answered
     by ``fallback_fn``, counted visibly, and charged against
@@ -699,17 +701,22 @@ def _with_gram_fallback(pallas_fn, fallback_fn, gate=None, kernel="gram"):
     tolerance as proven-kernel failures: one device-OOM blip on the
     first-ever call must not silently lose the fused path for the
     process lifetime, while a genuinely broken kernel (compile error)
-    still demotes after MAX_FAILS bounded re-probes."""
+    still demotes after MAX_FAILS bounded re-probes.
+
+    ``deferred`` is for the caller that pulls later and answers a failed
+    pull itself (the GroupBy lane re-runs the call on the per-call path,
+    which comes through here undeferred): a PROVEN kernel is then
+    enqueued and not awaited.  An unproven one is probed as ever."""
     gate = gate or _self_gram_gate
     try:
-        # always synchronize INSIDE the try: async dispatch would let a
+        # synchronize INSIDE the try: async dispatch would let a
         # runtime failure (e.g. device OOM) surface at the caller's
-        # np.asarray instead of being re-answered by the fallback — and
-        # every call site pulls the result immediately anyway
+        # np.asarray instead of being re-answered by the fallback
         t0 = time.perf_counter()
         with enqueue(kernel):
             out = pallas_fn()
-        out = wait(out, kernel)
+        if not (deferred and gate.ok):
+            out = wait(out, kernel)
         if gate.ok is None:
             gate.ok = True
         _note_dispatch(kernel, "pallas", wall=time.perf_counter() - t0)
@@ -1485,13 +1492,17 @@ def _combo_gram_fused(prefix: jax.Array, bits: jax.Array, idx: jax.Array):
     return cross_gram_traced(jnp.transpose(prefix, (1, 0, 2)), bits[:, idx])
 
 
-def combo_counts_gram(prefix: jax.Array, bits: jax.Array, idx) -> np.ndarray | None:
-    """``int64 numpy [C, Rl]`` totals of every (prefix combo, row)
-    intersection as ONE cross gram on the MXU — the k-level GroupBy's
-    per-level count (reference executor.go:3208-3211), reading the
-    prefix masks once instead of once per row.  None when a total could
-    wrap int32 (S * W * 32 past the limit) or the level is too small for
-    the unpack to pay off; callers fall back to :func:`combo_counts`."""
+def combo_counts_gram(
+    prefix: jax.Array, bits: jax.Array, idx, deferred: bool = False
+):
+    """``int32[C, Rl]`` totals of every (prefix combo, row) intersection
+    as ONE cross gram on the MXU — the k-level GroupBy's per-level count
+    (reference executor.go:3208-3211), reading the prefix masks once
+    instead of once per row.  The launch only: the device's array,
+    enqueued and not pulled (``deferred``: see
+    :func:`_with_gram_fallback`).  None when a total could wrap int32
+    (S * W * 32 past the limit) or the level is too small for the unpack
+    to pay off; callers fall back to :func:`combo_counts`."""
     C = prefix.shape[0]
     S, _, W = bits.shape
     if not _gram_int32_safe(S, W) or C * len(idx) < 32:
@@ -1513,15 +1524,14 @@ def combo_counts_gram(prefix: jax.Array, bits: jax.Array, idx) -> np.ndarray | N
     # shards axis, >1 device) must keep the XLA path, which partitions
     # cleanly
     if not _multi_device(bits) and _cross_pallas_engages(C, len(idx), W):
-        out = _with_gram_fallback(
+        return _with_gram_fallback(
             lambda: _combo_gram_fused(prefix, bits, idx_dev),
             lambda: _combo_gram_xla(prefix, bits, idx_dev),
             gate=_cross_gram_gate,
             kernel="combo_gram",
+            deferred=deferred,
         )
-    else:
-        out = _timed_xla("combo_gram", _combo_gram_xla, prefix, bits, idx_dev)
-    return pull(out, "combo_gram").astype(np.int64)
+    return _timed_xla("combo_gram", _combo_gram_xla, prefix, bits, idx_dev)
 
 
 @jax.jit

@@ -732,6 +732,13 @@ class Handler(BaseHTTPRequestHandler):
                 "stack_refresh_peer_bytes": ex.stacks.refresh_peer_bytes,
                 "stack_refresh_out_of_place": ex.stacks.refresh_out_of_place,
                 "bsi_stack_launches": ex.bsi_stack_launches,
+                # the GroupBy lane: calls it took, pulls it made, levels
+                # enqueued and not yet pulled summed over those pulls,
+                # levels its byte bound held back (exec/executor.py)
+                **{
+                    f"groupby_lane_{k}": v
+                    for k, v in ex.groupby_lane.items()
+                },
                 # stacks not built, by reason (exec/stacks.py), and
                 # flight items a batch lane handed back to the per-call
                 # path, by lane and reason (exec/executor.py)

@@ -90,7 +90,7 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert mf.validate_line(MANIFEST, CELL, bool(trace), line) == []
     want = [m["name"] for m in mf.metrics_for(MANIFEST, CELL, bool(trace))]
-    assert list(line["metrics"]) == want and len(want) == (26 if trace else 3)
+    assert list(line["metrics"]) == want and len(want) == (27 if trace else 3)
     assert line["correct"] is False
     compared = {k: v for k, (v, _) in line["compared"].items()}
     assert {k: v for k, (v, limit) in line["compared"].items() if v > limit} == {"rehearsal": 1}, err
@@ -102,7 +102,8 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
         value = {k: v["value"] for k, v in line["metrics"].items()}
         # the stream's own, then what later PRs gave every cell
         assert [n for n in want if n in STREAMED] == STREAMED
-        assert want[-1] == "listener.cpu_ms_per_read" and value[want[-1]] > 0, err
+        assert want[-2:] == ["listener.cpu_ms_per_read", "executor.groupby_inflight_per_pull"]
+        assert value[want[-2]] > 0 and value[want[-1]] >= 1, err  # groupby3 rode the lane
         # counts, not times: stacks were refreshed, none was rebuilt, and on one
         # device every block came from a fragment's device copy
         assert value["stacks.refreshes_per_import"] > 0, err

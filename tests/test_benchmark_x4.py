@@ -46,7 +46,7 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert mf.validate_line(MANIFEST, CELL, bool(trace), line) == []
     want = [m["name"] for m in mf.metrics_for(MANIFEST, CELL, bool(trace))]
-    assert list(line["metrics"]) == want and len(want) == (22 if trace else 3)
+    assert list(line["metrics"]) == want and len(want) == (23 if trace else 3)
     assert line["correct"] is False
     over = {k: v for k, (v, limit) in line["compared"].items() if v > limit}
     assert over == {"rehearsal": 1}, err

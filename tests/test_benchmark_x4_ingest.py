@@ -103,7 +103,7 @@ def test_the_cells_metrics_are_the_streams_the_meshs_and_its_own():
     listed = [m["name"] for m in MANIFEST["per_layer"] if CELL in m.get("workloads", ())]
     assert listed == MESHED + STREAMED + [OWN]
     own = next(m for m in MANIFEST["per_layer"] if m["name"] == OWN)
-    assert own["workloads"] == [CELL] and MANIFEST["per_layer"][-1] is own
+    assert own["workloads"] == [CELL]  # the entry exists and lists the cell alone
     assert (own["layer"], own["moves"], own["source"], own["better"], own["unit"]) == (
         "executor lanes", "read_p95_ms", "program_counter", "lower", "MB")
     # a reader that is code, so that a tree without the counter reads 0
@@ -127,6 +127,24 @@ def test_the_peer_reader_reads_zero_on_a_tree_without_the_counter():
     ) == 0.0
 
 
+@pytest.mark.parametrize("lane, reads", [
+    (None, 0.0),  # a tree without the counter
+    ({"groupby_lane_inflight_sum": 0, "groupby_lane_pulls": 0}, 0.0),  # a window without a pull
+    ({"groupby_lane_inflight_sum": 57, "groupby_lane_pulls": 12}, 4.75),
+], ids=["no-counter", "no-pull", "ratio"])
+def test_the_groupby_lanes_reader_reads_levels_in_flight_a_pull(lane, reads):
+    """PR 41's metric, which every cell reports (no ``workloads`` list: it
+    moves ``read_qps``): a reader that is code, 1.0 the call-by-call order."""
+    import run
+
+    name = "executor.groupby_inflight_per_pull"
+    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
+    assert "workloads" not in entry and (entry["layer"], entry["moves"], entry["source"], entry["better"]) == (
+        "executor lanes", "read_qps", "program_counter", "higher")
+    served = {} if lane is None else {"serving_cache": dict(lane, stack_rebuilds=3)}
+    assert run.read_layer_metric(name, {"vars": served, "window": {"reads": 300}}) == pytest.approx(reads)
+
+
 @pytest.mark.parametrize("trace", [0, 1])
 def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     p = subprocess.run(
@@ -139,7 +157,7 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert mf.validate_line(MANIFEST, CELL, bool(trace), line) == []
     want = [m["name"] for m in mf.metrics_for(MANIFEST, CELL, bool(trace))]
-    assert list(line["metrics"]) == want and len(want) == (30 if trace else 3)
+    assert list(line["metrics"]) == want and len(want) == (31 if trace else 3)
     assert line["correct"] is False
     compared = {k: v for k, (v, _) in line["compared"].items()}
     assert {k: v for k, (v, limit) in line["compared"].items() if v > limit} == {"rehearsal": 1}, err
@@ -150,7 +168,7 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     if trace:
         value = {k: v["value"] for k, v in line["metrics"].items()}
         assert [n for n in want if n in STREAMED] == STREAMED
-        assert [n for n in want if n in MESHED] == MESHED and want[-1] == OWN
+        assert [n for n in want if n in MESHED] == MESHED and OWN in want
         # counts, not times: stacks laid over the mesh were refreshed, none was
         # rebuilt, and every block was gathered on the chip that keeps it
         assert value["stacks.refreshes_per_import"] > 0, err

@@ -654,3 +654,219 @@ class TestSpanningMeshDecline:
         monkeypatch.setattr(stacks.Stacks, "cross_gram", lambda *a, **k: None)
         self._force_unsupported(monkeypatch)
         assert ex.execute("i", q)[0] == want
+
+
+# ------------------------------------------------------- the GroupBy lane
+
+from pilosa_tpu import pql  # noqa: E402
+from pilosa_tpu.core.field import FieldOptions  # noqa: E402
+from pilosa_tpu.ops import kernels  # noqa: E402
+
+# what a flight is cut from, in this order: k-level calls under filters
+# first, so that a flight of two already has two levels to put side by side
+LANE_POOL = [
+    "GroupBy(Rows(f), Rows(g), Rows(h), filter=Row(h=0))",
+    "GroupBy(Rows(f), Rows(g), filter=Intersect(Union(Row(h=0), Row(h=1)), "
+    "Union(Row(f=0), Row(f=1), Row(f=2))))",
+    "GroupBy(Rows(g), Rows(h), filter=Intersect(Row(f=1), Row(v < 500)))",  # a BSI range leaf
+    "GroupBy(Rows(f), Rows(g), Rows(h), Rows(f))",  # four levels
+    "GroupBy(Rows(f), Rows(g), Rows(h), limit=4)",
+    "GroupBy(Rows(f), Rows(g))",  # unfiltered two-level: the cross gram's
+    "GroupBy(Rows(f), Rows(e), filter=Row(h=1))",  # an empty level
+    "GroupBy(Rows(f), Rows(g), previous=[1, 0])",  # paged: per call
+    "GroupBy(Rows(f), Rows(g), filter=Row(v > 200))",  # the BSI lane's
+    "GroupBy(Rows(f), Rows(h), filter=Row(h=9))",  # a filter no column meets
+    "GroupBy(Rows(g), Rows(f), Rows(h), filter=Union(Row(h=2), Row(h=3)))",
+    "GroupBy(Rows(f))",  # one level: per call
+    "Sum(Intersect(Row(f=1), Row(h=0)), field=v)",
+    "Sum(Intersect(Row(f=2), Row(h=0)), field=v)",
+]
+# which of them the lane takes, and which of those on the k-level steps
+LANE_TAKES = {0, 1, 2, 3, 4, 5, 6, 9, 10}
+LANE_K_LEVEL = LANE_TAKES - {5}
+UNKNOWN = "GroupBy(Rows(f), Rows(nope), filter=Row(h=0))"
+
+
+def _groups(res):
+    return [([(fr.field, fr.row_id) for fr in gc.group], gc.count) for gc in res] \
+        if isinstance(res, list) else (res.value, res.count)
+
+
+@pytest.fixture()
+def grouped():
+    h = Holder()
+    idx = h.create_index("i")
+    for name in "fghe":
+        idx.create_field(name)
+    idx.create_field("v", FieldOptions(field_type="int", min_=0, max_=1000))
+    ex = Executor(h, rescache_entries=0)
+    rng = np.random.default_rng(41)
+    pool = rng.integers(0, 3 * h.n_words * 32, size=160)
+    writes = [
+        f"Set({int(c)}, {name}={row})"
+        for name, rows in (("f", 6), ("g", 3), ("h", 4))
+        for row in range(rows)
+        for c in rng.choice(pool, size=70, replace=False)
+    ]
+    writes += [f"Set({int(c)}, v={int(rng.integers(0, 1000))})" for c in pool[:120]]
+    ex.execute("i", " ".join(writes))
+    ex.execute("i", "Set(1, e=0) Clear(1, e=0)")  # a view, and no row
+    return h, idx, ex
+
+
+def _call_by_call(ex, idx, q):
+    """The per-call driver's answer: ``_execute_call`` of the one call."""
+    call = pql.parse(q).calls[0].clone()
+    ex._translate_call(idx, call)
+    return _groups(ex._execute_call(idx, call, None))
+
+
+def _flight(n):
+    return [LANE_POOL[k % len(LANE_POOL)] for k in range(n)]
+
+
+@pytest.mark.parametrize("squeeze", ["none", "prefix", "lane"])
+@pytest.mark.parametrize("entry", ["execute_batch", "execute"])
+@pytest.mark.parametrize("n", [1, 2, 6, 16])
+def test_groupby_lane_answers_as_call_by_call_and_as_the_recursive_path(
+    grouped, monkeypatch, n, entry, squeeze
+):
+    """Flights of 1, 2, 6 and 16 calls through both entries: every answer
+    is the per-call driver's and the recursive path's; a query with an
+    unknown field fails alone; with a prefix budget of four masks the
+    calls past it take the recursive path once, inside the lane; with a
+    byte bound of one byte the lane keeps the call-by-call order."""
+    h, idx, ex = grouped
+    qs = _flight(n)
+    want = [_call_by_call(ex, idx, q) for q in qs]
+    with monkeypatch.context() as m:
+        m.setattr(Executor, "_GROUPBY_BATCH_MAX", 0)  # the recursive path
+        assert [_call_by_call(ex, idx, q) for q in qs] == want
+    assert all(w for q, w in zip(qs, want) if "e)" not in q and "h=9" not in q)
+    if squeeze == "prefix":
+        mask = Executor._groupby_mask_bytes(
+            ex.stacks.get(idx.field("f"), ex._shards_for(idx, None)).bits
+        )
+        monkeypatch.setattr(Executor, "_GROUPBY_PREFIX_BUDGET_BYTES", 4 * mask)
+    elif squeeze == "lane":
+        monkeypatch.setattr(Executor, "_GROUPBY_LANE_BUDGET_BYTES", 1)
+    recursive, per_call = [], []
+    inner_rec, inner_call = ex._groupby_recursive, ex._execute_call
+    monkeypatch.setattr(
+        ex, "_groupby_recursive",
+        lambda *a: recursive.append(1) or inner_rec(*a),
+    )
+    monkeypatch.setattr(
+        ex, "_execute_call",
+        lambda i, c, s: per_call.append(c.name) or inner_call(i, c, s),
+    )
+    before = dict(ex.groupby_lane)
+    if entry == "execute_batch":
+        out = ex.execute_batch("i", [(q, None) for q in qs] + [(UNKNOWN, None)])
+        assert isinstance(out[-1], Exception) and "nope" in str(out[-1])
+        got = [_groups(r[0]) for r in out[:-1]]
+    else:
+        got = [_groups(r) for r in ex.execute("i", " ".join(qs))]
+    assert got == want
+    lane = {k: ex.groupby_lane[k] - before[k] for k in before}
+    flight = [k % len(LANE_POOL) for k in range(n)]
+    taken = [k for k in flight if k in LANE_TAKES]
+    k_level = [k for k in taken if k in LANE_K_LEVEL]
+    assert lane["calls"] == len(taken)
+    # the lane's calls never reach the per-call path, whatever answered
+    # them: the paged call, the call of one level and the unknown field do
+    left = sum(k in (7, 11) for k in flight)
+    assert per_call.count("GroupBy") == left + (entry == "execute_batch")
+    if squeeze == "prefix":
+        # a first level of f is six masks, past the four; g's three fit,
+        # g x f's survivors (10) do not.  The BSI lane's call (8) goes
+        # through the same steps
+        past = sum(k in (0, 1, 3, 4, 6, 8, 9, 10) for k in flight)
+        assert len(recursive) == left + past > 0
+    else:
+        assert len(recursive) == left
+    if squeeze == "lane":
+        assert lane["inflight_sum"] == lane["pulls"]
+        assert (lane["budget_waits"] > 0) == (len(k_level) > 1)
+    elif squeeze == "none":
+        assert lane["budget_waits"] == 0
+        if len(k_level) > 1:
+            assert lane["inflight_sum"] > lane["pulls"] >= len(k_level) - 2
+
+
+@pytest.mark.parametrize("bound", [None, 1])
+def test_groupby_lane_launches_every_first_level_before_it_awaits_one(
+    grouped, monkeypatch, bound
+):
+    """The order of work: every admitted call's first level is launched
+    before the first ``GroupBy`` pull, the BSI lane runs between the
+    lane's start and its finish; under a byte bound of one byte one
+    level is in flight at a time."""
+    h, idx, ex = grouped
+    qs = [q for k, q in enumerate(LANE_POOL) if k in (0, 1, 2, 3, 10, 12, 13)]
+    want = [_call_by_call(ex, idx, q) for q in qs]
+    if bound is not None:
+        monkeypatch.setattr(Executor, "_GROUPBY_LANE_BUDGET_BYTES", bound)
+    events = []
+    for name in ("combo_counts_gram", "combo_counts"):
+        inner = getattr(kernels, name)
+
+        def launch(*a, _inner=inner, **kw):
+            out = _inner(*a, **kw)
+            if out is not None:
+                events.append("launch")
+            return out
+
+        monkeypatch.setattr(kernels, name, launch)
+    inner_pull, inner_bsi = kernels.pull, ex._batch_bsi
+
+    def pull(out, kernel=""):
+        if kernel in ("combo_gram", "combo_counts"):
+            events.append("pull")
+        return inner_pull(out, kernel)
+
+    monkeypatch.setattr(kernels, "pull", pull)
+    monkeypatch.setattr(
+        ex, "_batch_bsi", lambda *a: events.append("bsi") or inner_bsi(*a)
+    )
+    waits = ex.groupby_lane["budget_waits"]
+    out = ex.execute_batch("i", [(q, None) for q in qs])
+    assert [_groups(r[0]) for r in out] == want
+    assert events.count("bsi") == 1 and events.count("launch") == events.count("pull")
+    at = events.index("bsi")
+    assert "pull" not in events[:at]
+    inflight = peak = 0
+    for e in events:
+        inflight += {"launch": 1, "pull": -1, "bsi": 0}[e]
+        peak = max(peak, inflight)
+    if bound is None:
+        assert events[:at] == ["launch"] * 5  # five GroupBy calls, five first levels
+        assert ex.groupby_lane["budget_waits"] == waits and peak == 5
+    else:
+        assert events[:at] == ["launch"] and peak == 1
+        assert ex.groupby_lane["budget_waits"] > waits
+
+
+def test_groupby_lane_trouble_on_the_recursive_path_fails_its_query_alone(
+    grouped, monkeypatch
+):
+    """Steps that decline are answered by the recursive path inside the
+    lane's per-item ``try``: trouble there is counted, the slot is left to
+    the per-call path, and its error lands on the owning query alone."""
+    h, idx, ex = grouped
+    qs = [LANE_POOL[0], LANE_POOL[2], LANE_POOL[12]]  # f first: past four masks; g first: fits
+    want = [_call_by_call(ex, idx, q) for q in qs]
+    mask = Executor._groupby_mask_bytes(
+        ex.stacks.get(idx.field("f"), ex._shards_for(idx, None)).bits
+    )
+    monkeypatch.setattr(Executor, "_GROUPBY_PREFIX_BUDGET_BYTES", 4 * mask)
+
+    def broken(*a):
+        raise RuntimeError("no rows today")
+
+    monkeypatch.setattr(ex, "_groupby_recursive", broken)
+    errors = ex.lane_declines["groupby"]["error"]
+    out = ex.execute_batch("i", [(q, None) for q in qs])
+    assert isinstance(out[0], RuntimeError) and "no rows today" in str(out[0])
+    assert [_groups(r[0]) for r in out[1:]] == want[1:]
+    assert ex.lane_declines["groupby"]["error"] == errors + 1

@@ -490,6 +490,27 @@ class TestGramGatePolicy:
         assert kernels._with_gram_fallback(boom, lambda: "x", gate=gate) == "x"
         assert gate.ok is False  # lifetime cap reached
 
+    def test_deferred_leaves_only_a_proven_kernel_unawaited(self, monkeypatch):
+        """The GroupBy lane's launches: an unproven gate is probed and
+        awaited as ever, so a failing probe still meets the fallback; a
+        proven one is enqueued and left to its caller's pull."""
+        gate, waited = self._gate(), []
+        inner = kernels.wait
+        monkeypatch.setattr(
+            kernels, "wait", lambda out, k="": waited.append(k) or inner(out, k)
+        )
+        ok = lambda: jnp.zeros(())
+        assert kernels._with_gram_fallback(
+            ok, lambda: "x", gate=gate, kernel="probe", deferred=True
+        ) is not None
+        assert gate.ok is True and waited == ["probe"]
+        kernels._with_gram_fallback(
+            ok, lambda: "x", gate=gate, kernel="proven", deferred=True
+        )
+        assert waited == ["probe"]
+        kernels._with_gram_fallback(ok, lambda: "x", gate=gate, kernel="proven")
+        assert waited == ["probe", "proven"]
+
     def test_gates_are_independent(self):
         g1, g2 = self._gate(), self._gate()
 

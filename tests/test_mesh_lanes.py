@@ -197,8 +197,8 @@ LANE_SPAN = {
     "sum_filtered": ("executor.bsiSumBatch", 5),  # two filtered sums make a launch
     "pair_count": ("executor.batchCountTree", 1),
     "topn_filtered": ("executor.executeTopN", 1),
-    "groupby2": ("executor.executeGroupBy", 1),
-    "groupby3": ("executor.executeGroupBy", 1),
+    "groupby2": ("executor.batchGroupBy", 1),
+    "groupby3": ("executor.batchGroupBy", 1),
     "q1": ("executor.executeTopN", 1),
 }
 
@@ -253,6 +253,40 @@ def test_class_on_the_mesh_matches_reference_and_one_device(served, cls, n):
     assert sum(by["mesh"] for by in ex.lane_declines.values()) == mesh_declines0
     totals = devledger.snapshot()["totals"]
     # whatever the flight launched ran over the mesh
+    assert totals["meshLaunches"] - launches0["meshLaunches"] == \
+        totals["launches"] - launches0["launches"]
+
+
+def test_groupby_lane_keeps_a_flights_levels_in_flight_on_the_mesh(served):
+    """Six three-level ``GroupBy`` calls under filters in one flight, over
+    stacks sharded four ways (the gram declines, ``combo_counts`` is the
+    launch): every first level is enqueued before one is pulled, and each
+    answer is the reference's and the one-device executor's."""
+    ex, data, _, _ = served
+    qs = [
+        "GroupBy(Rows(passenger_count), Rows(pickup_year), Rows(cab_type), "
+        f"filter=Row(pickup_month={m}))" for m in range(6)
+    ]
+    try:
+        mesh.configure_serving(1)
+        one = [_plain(r[0]) for r in Executor(ex.holder, rescache_entries=0).execute_batch(
+            "taxi", [(q, None) for q in qs])]
+    finally:
+        mesh.configure_serving(DEVICES)
+    spans0, lane0 = _span_count("executor.batchGroupBy"), dict(ex.groupby_lane)
+    launches0 = devledger.snapshot()["totals"]
+    got = ex.execute_batch("taxi", [(q, None) for q in qs])
+    for q, r, w in zip(qs, got, one):
+        _check(q, r[0], _reference(data, q))
+        assert _plain(r[0]) == w
+    lane = {k: ex.groupby_lane[k] - lane0[k] for k in lane0}
+    assert lane["calls"] == 6 and lane["pulls"] == 12 and lane["budget_waits"] == 0
+    # six in flight at each pull of a first level (the call's second joins
+    # the tail), then 6, 5, ... 1 as the second levels are pulled
+    assert lane["inflight_sum"] == 6 * 6 + 21
+    assert _span_count("executor.batchGroupBy") == spans0 + 2  # start and finish
+    assert ex.lane_declines["groupby"] == dict.fromkeys(ex.lane_declines["groupby"], 0)
+    totals = devledger.snapshot()["totals"]
     assert totals["meshLaunches"] - launches0["meshLaunches"] == \
         totals["launches"] - launches0["launches"]
 
@@ -448,14 +482,14 @@ def test_prefix_budget_holds_against_a_devices_share(rides, monkeypatch):
     prefixes = len(set(zip(data["passenger_count"], data["pickup_year"])))
     share = prefixes * (SHARDS // DEVICES) * idx.n_words * 4
     monkeypatch.setattr(Executor, "_GROUPBY_PREFIX_BUDGET_BYTES", share)
-    took, inner = [], Executor._groupby_k_level_batch
+    took, inner = [], Executor._groupby_k_level_steps
 
-    def watched(self, *args):
-        out = inner(self, *args)
+    def watched(self, *args, **kwargs):
+        out = yield from inner(self, *args, **kwargs)
         took.append(out is not None)
         return out
 
-    monkeypatch.setattr(Executor, "_groupby_k_level_batch", watched)
+    monkeypatch.setattr(Executor, "_groupby_k_level_steps", watched)
     try:
         mesh.configure_serving(1)
         one = Executor(h, rescache_entries=0).execute("taxi", q)[0]

@@ -427,6 +427,57 @@ def test_a_leased_snapshot_is_left_alone(devices):
     )
 
 
+def test_an_import_inside_the_groupby_lane_copies_and_deletes_no_operand(
+    devices, monkeypatch
+):
+    """The GroupBy lane launches a later level on the snapshot it read at
+    its start, so its lease spans start, BSI lane and finish: an import
+    into a level's field from another thread in between, and that
+    thread's refresh, go out of place; no operand is deleted under the
+    lane and every answer is exactly the state's before the import (or
+    after it), as a stackless executor counts it."""
+    ex, idx = _imported()
+    plain = Executor(ex.holder, rescache_entries=0)
+    plain.stacks.get = lambda *a, **k: None
+    qs = [
+        f"GroupBy(Rows(f), Rows(f), Rows(f), filter=Row(f={r}))" for r in range(4)
+    ] + ["GroupBy(Rows(f), Rows(f), filter=Union(Row(f=0), Row(f=5)))"]
+    flight = [(q, None) for q in qs]
+    rng = np.random.default_rng(6)
+    ex.execute_batch("i", flight)  # stacks built, programs compiled
+    stack = _get(ex, idx, "f")
+    _write(idx, "f", rng, [0])
+    assert _get(ex, idx, "f") is stack and ex.stacks.incremental == 1
+    before = plain.execute_batch("i", flight)
+    held, inner = [], ex._batch_bsi
+
+    def between(*args):
+        held.append(stack._snap[0])  # what the lane's levels will read
+
+        def other():
+            _write(idx, "f", rng, [1, 2])
+            assert _get(ex, idx, "f") is stack
+
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+        return inner(*args)
+
+    monkeypatch.setattr(ex, "_batch_bsi", between)
+    got = ex.execute_batch("i", flight)
+    after = plain.execute_batch("i", flight)
+    assert (ex.stacks.incremental, ex.stacks.refresh_out_of_place) == (2, 1)
+    assert stack._leased == 0 and stack._snap[0] is not held[0]
+    assert not _spent(held[0])
+    assert ex.lane_declines["groupby"]["error"] == 0
+    assert before != after
+    for g, b, a in zip(got, before, after):
+        assert not isinstance(g, Exception) and g in (b, a)
+    assert got == before  # every level read the snapshot of the start
+    monkeypatch.setattr(ex, "_batch_bsi", inner)
+    assert ex.execute_batch("i", flight) == after
+
+
 def test_a_rebuild_lets_go_of_the_retired_array_first(devices, monkeypatch):
     """More than half the shards changed between two reads: the stack is
     rebuilt, and its old array is gone before the new one is uploaded."""
