@@ -60,7 +60,7 @@ UNATTRIBUTED = "(unattributed)"
 _MAX_PRINCIPALS = 512
 _OVERFLOW_PRINCIPAL = ("~overflow", "-", "-")
 _MAX_TENANT_LEN = 64
-_MAX_TRACKED = 8192  # per-site identity set cap (mirrors kernels._seen_programs)
+_MAX_TRACKED = 8192  # per-site identity set cap
 
 # jax.monitoring event keys.  backend_compile fires once per backend
 # compile request — a new compile unless a persistent-cache hit was

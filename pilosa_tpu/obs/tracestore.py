@@ -95,6 +95,8 @@ def _span_dict(span, node_id: str) -> dict:
         "node": node_id,
         "startUnixMs": span.start_unix_ns // 1_000_000,
         "durationMs": round((span.duration or 0.0) * 1e3, 3),
+        # what the span's thread ran of that; the rest it waited
+        "cpuMs": round(span.cpu_ns * 1e-6, 3),
         "tags": {
             k: v for k, v in span.tags.items() if k != "logs"
         },

@@ -61,10 +61,13 @@ def _new_span_id() -> int:
 
 _new_trace_id = new_trace_id  # the name tests/test_tracestore.py seeds by
 
-# A span reads one clock, the monotonic one, at each end.  Its wall-clock
-# start is that reading plus this anchor, taken once per process, so an
-# exporter never derives it from the time of export.
+# A span reads the monotonic clock at each end, and its thread's CPU clock
+# beside it where the span table says so (``CPU_TREE``).  Its wall-clock
+# start is the monotonic reading plus this anchor, taken once per
+# process, so an exporter never derives it from the time of export.
 _UNIX_ANCHOR_NS = time.time_ns() - time.monotonic_ns()
+
+_get_ident = threading.get_ident
 
 _active_span: contextvars.ContextVar["Span | None"] = contextvars.ContextVar(
     "pilosa_active_span", default=None
@@ -93,6 +96,23 @@ def set_span_sink(sink) -> None:
 
 TRACE_ONLY = "trace only"
 
+# Marks, a fourth entry of a row of the table.  The thread's CPU clock is
+# a system call, and where it costs microseconds (PERF.md section 3: 5.6
+# us a read on the chip's host, in 10 ms ticks, and under one GIL three
+# to four times its own time in reads/s) a span cannot read it by
+# default.  It is read by every span below a ``CPU_TREE`` root (the one
+# dispatcher's two) but a ``CPU_LEAF`` (a funnel of microseconds opened
+# dozens of times a flight: what it runs stays in its parent's
+# ``self_cpu_seconds``), and by a ``DEVICE_WAIT`` span: the served path
+# waits for a device result under that name, what such a span's thread
+# did not run is its device wait, and that is carried up the tree
+# (``device_wait_seconds`` of every row above it).  A handler's root
+# ``http.<route>`` reads none: the handler books the request's CPU, which
+# it reads anyway, into it (``book_cpu``).
+CPU_TREE = "cpu clock, its tree"
+CPU_LEAF = "no cpu clock"
+DEVICE_WAIT = "cpu clock, device wait"
+
 _LISTENER = "listener"
 _BATCHER = "QoS / batcher"
 _PLANNER = "planner / rescache"
@@ -102,20 +122,28 @@ _CLUSTER = "cluster"
 _INGEST = "ingest"
 
 _HOST_MS = "executor.host_ms_per_flight"
+_STALLED_READ = "listener.stalled_ms_per_read"
+# its wall time, and the three it is the sum of; the executor's shares of
+# a flight divide by its seconds or its count
+_FLIGHT = (
+    "batcher.flight_busy_pct, batcher.cpu_ms_per_flight,"
+    " batcher.device_wait_ms_per_flight, batcher.stalled_ms_per_flight, "
+    + _HOST_MS + ", executor.bsi_pct_of_flight, executor.percall_pct_of_flight"
+)
 
 SPAN_TABLE = (
-    # name, layer, metric
-    ("http.query", _LISTENER, "listener.ms_per_read"),
+    # name, layer, every metric that reads the row[, mark]
+    ("http.query", _LISTENER, "listener.ms_per_read, " + _STALLED_READ),
     ("http.decode", _LISTENER, "listener.ms_per_read"),
     ("api.parse", _LISTENER, "listener.ms_per_read"),
     ("http.encode", _LISTENER, "listener.ms_per_read"),
     ("qos.admit", _BATCHER, "qos.admit_ms_per_read"),
     ("qos.tick", _BATCHER, TRACE_ONLY),
     ("rescache.probe", _PLANNER, TRACE_ONLY),
-    ("batcher.queueWait", _BATCHER, "batcher.queue_wait_ms"),
-    ("batcher.dispatch", _BATCHER, TRACE_ONLY),
-    ("batcher.collect", _BATCHER, TRACE_ONLY),
-    ("batcher.flight", _BATCHER, "batcher.flight_busy_pct"),
+    ("batcher.queueWait", _BATCHER, "batcher.queue_wait_ms, " + _STALLED_READ),
+    ("batcher.dispatch", _BATCHER, _STALLED_READ),
+    ("batcher.collect", _BATCHER, TRACE_ONLY, CPU_TREE),
+    ("batcher.flight", _BATCHER, _FLIGHT, CPU_TREE),
     ("planner.plan", _PLANNER, _HOST_MS),
     ("executor.Execute", _LANES, _HOST_MS),
     ("executor.ExecuteBatch", _LANES, _HOST_MS),
@@ -133,11 +161,11 @@ SPAN_TABLE = (
     ("executor.stackBuild", _LANES, _HOST_MS),
     ("stacks.refresh", _LANES, "stacks.refresh_ms_per_import"),
     ("executor.bsiSplit", _LANES, _HOST_MS),
-    ("executor.demux", _LANES, _HOST_MS),
+    ("executor.demux", _LANES, _HOST_MS, CPU_LEAF),
     ("executor.mapReduce", _CLUSTER, _HOST_MS),
-    ("kernels.h2d", _KERNELS, TRACE_ONLY),
-    ("kernels.enqueue", _KERNELS, TRACE_ONLY),
-    ("kernels.pull", _KERNELS, "kernels.pull_ms_per_launch"),
+    ("kernels.h2d", _KERNELS, TRACE_ONLY, CPU_LEAF),
+    ("kernels.enqueue", _KERNELS, TRACE_ONLY, CPU_LEAF),
+    ("kernels.pull", _KERNELS, "kernels.pull_ms_per_launch", DEVICE_WAIT),
     ("dist.fanout", _CLUSTER, TRACE_ONLY),
     ("dist.httpFanout", _CLUSTER, TRACE_ONLY),
     ("dist.meshDispatch", _CLUSTER, TRACE_ONLY),
@@ -146,34 +174,50 @@ SPAN_TABLE = (
 )
 
 
+# What a span does with the CPU clock (``Span._timed``): nothing; read it
+# and book the rest of its time as device wait; read it and have every
+# span below read it.  A row says what its spans do before their parents'
+# say (``_LEAF``: nothing, whatever the parent).
+_LEAF, _UNTIMED, _TIMED_WAIT, _TIMED_TREE = -1, 0, 1, 2
+_ROW_TIMED = {"": _UNTIMED, CPU_TREE: _TIMED_TREE, CPU_LEAF: _LEAF, DEVICE_WAIT: _TIMED_WAIT}
+
+
 class _Row:
     """One name's totals.  Plain adds: a span finishes on the thread that
     ran it, and between reading and storing an int attribute CPython
     switches no thread, so the path takes no lock."""
 
-    __slots__ = ("name", "layer", "metric", "count", "ns", "self_ns", "items")
+    __slots__ = (
+        "name", "layer", "metric", "mark", "timed", "count", "ns",
+        "self_ns", "items", "cpu_ns", "self_cpu_ns", "device_wait_ns",
+    )
 
-    def __init__(self, name: str, layer: str, metric: str):
+    def __init__(self, name: str, layer: str, metric: str, mark: str = ""):
         self.name = name
         self.layer = layer
         self.metric = metric
+        self.mark = mark
+        self.timed = _ROW_TIMED[mark]
         self.count = 0
         self.ns = 0
         self.self_ns = 0
         self.items = 0
+        self.cpu_ns = 0
+        self.self_cpu_ns = 0
+        self.device_wait_ns = 0
 
 
 _rows: dict[str, _Row] = {}
 
 
-def register(name: str, layer: str, metric: str = TRACE_ONLY) -> None:
+def register(name: str, layer: str, metric: str = TRACE_ONLY, mark: str = "") -> None:
     """Add one name to the table (idempotent).  A name has exactly two
     segments; the first is the block it is served under."""
     parts = name.split(".")
     if len(parts) != 2 or not all(parts):
         raise ValueError(f"span name {name!r} is not <layer>.<what>")
     if name not in _rows:
-        _rows[name] = _Row(name, layer, metric)
+        _rows[name] = _Row(name, layer, metric, mark)
 
 
 def register_family(prefix: str, whats, layer: str, metric: str = TRACE_ONLY) -> None:
@@ -183,18 +227,26 @@ def register_family(prefix: str, whats, layer: str, metric: str = TRACE_ONLY) ->
         register(f"{prefix}{what}", layer, metric)
 
 
-for _name, _layer, _metric in SPAN_TABLE:
-    register(_name, _layer, _metric)
+for _entry in SPAN_TABLE:
+    register(*_entry)
 
 
 def registered() -> list[tuple[str, str, str]]:
-    """(name, layer, metric) of every registered name, in table order."""
-    return [(r.name, r.layer, r.metric) for r in _rows.values()]
+    """(name, layer, metric) of every registered name, in table order;
+    a row's mark stands after its metric."""
+    return [
+        (r.name, r.layer, f"{r.metric}; {r.mark}" if r.mark else r.metric)
+        for r in _rows.values()
+    ]
 
 
 def spans_snapshot() -> dict:
     """The table for ``/debug/vars``: ``{first segment: {second segment:
-    {count, seconds, self_seconds, items}}}``."""
+    {count, seconds, self_seconds, items, cpu_seconds, self_cpu_seconds,
+    device_wait_seconds}}}``.  For any row ``seconds - cpu_seconds -
+    device_wait_seconds`` is what its threads neither ran nor waited for
+    the device: runnable without the interpreter, or waiting for a lock,
+    an upload or a queue."""
     out: dict = {}
     for r in list(_rows.values()):
         block, what = r.name.split(".")
@@ -203,6 +255,9 @@ def spans_snapshot() -> dict:
             "seconds": r.ns * 1e-9,
             "self_seconds": r.self_ns * 1e-9,
             "items": r.items,
+            "cpu_seconds": r.cpu_ns * 1e-9,
+            "self_cpu_seconds": r.self_cpu_ns * 1e-9,
+            "device_wait_seconds": r.device_wait_ns * 1e-9,
         }
     return out
 
@@ -257,12 +312,22 @@ class Span:
 
     At ``finish`` a span adds its duration to its parent's ``child_ns``
     and to its name's row of the span table, self time (duration minus
-    what its children covered) included, so neither needs the tree."""
+    what its children covered) included, so neither needs the tree.
+
+    ``cpu_ns`` is what its thread ran between its two ends (same-thread
+    children included) where the span reads that clock (``CPU_TREE``
+    above; a handler's root is given its request's), and goes up as ``child_cpu_ns`` only to a parent opened on
+    that thread: a fan-out leg's CPU is not in its parent's clock.  A
+    span that finishes on another thread than it opened on, or was built
+    after the fact (``record_span``), books none: it is a wait by
+    construction.  ``wait_ns`` is the device wait of its tree on its
+    thread: that of the ``DEVICE_WAIT`` spans under it, carried up the
+    same way."""
 
     __slots__ = (
         "tracer", "name", "parent_id", "local_root", "context", "start_ns",
-        "duration", "tags", "child_ns", "_parent", "_row", "_token",
-        "_phandle", "_ann",
+        "duration", "tags", "child_ns", "cpu_ns", "child_cpu_ns", "wait_ns",
+        "_parent", "_row", "_tid", "_timed", "_cpu0", "_token", "_phandle", "_ann",
     )
 
     def __init__(
@@ -289,7 +354,19 @@ class Span:
         self.context = SpanContext(trace_id, _new_span_id())
         self._parent = parent_span
         self.child_ns = 0
+        self.cpu_ns = 0
+        self.child_cpu_ns = 0
+        self.wait_ns = 0
+        self._tid = _get_ident()
+        timed = row.timed
+        if timed == _UNTIMED:
+            if parent_span is not None and parent_span._timed == _TIMED_TREE:
+                timed = _TIMED_TREE
+        elif timed == _LEAF:
+            timed = _UNTIMED
+        self._timed = timed
         self.start_ns = time.monotonic_ns()
+        self._cpu0 = time.thread_time_ns() if timed else 0
         self.duration = None
         self.tags: dict = {}
         self._token = None
@@ -310,37 +387,61 @@ class Span:
 
     def finish(self, end_ns: int | None = None) -> None:
         if self.duration is None:
-            ns = (end_ns if end_ns is not None else time.monotonic_ns()) - self.start_ns
+            tid = self._tid
+            timed = self._timed
+            cpu = 0
+            if end_ns is None and _get_ident() == tid:
+                ns = time.monotonic_ns() - self.start_ns
+                if timed:
+                    cpu = self.cpu_ns = time.thread_time_ns() - self._cpu0
+            else:  # built after the fact, or ended by another thread
+                ns = (end_ns if end_ns is not None else time.monotonic_ns()) - self.start_ns
+                timed = tid = 0
             self.duration = ns * 1e-9
             row = self._row
+            wait = self.wait_ns
+            if timed == _TIMED_WAIT and ns > cpu:
+                # (a tick clock can read more CPU than a short span lasted)
+                wait = self.wait_ns = wait + ns - cpu
             row.count += 1
             row.ns += ns
+            row.cpu_ns += cpu
             # children that ran side by side (fan-out legs) can cover
             # more than the parent's own time
-            row.self_ns += max(ns - self.child_ns, 0)
+            own = ns - self.child_ns
+            if own > 0:
+                row.self_ns += own
+            own = cpu - self.child_cpu_ns
+            if own > 0:
+                row.self_cpu_ns += own
+            if wait:
+                row.device_wait_ns += wait
             n = self.tags.get("n")
             if n:
                 row.items += n
             parent = self._parent
             if parent is not None:
                 parent.child_ns += ns
+                if parent._tid == tid:
+                    parent.child_cpu_ns += cpu
+                    parent.wait_ns += wait
                 self._parent = None  # a kept span keeps no tree alive
             self.tracer._record(self)
             if _span_sink is not None:
                 _span_sink(self)
 
-    # context-manager + ambient-activation protocol.  Entering a span
-    # also enters a profiler annotation of the same name, so a profiler
-    # session running in the process (the program starts none) holds the
-    # program's spans on its own clock; with no session that is the
-    # native no-op.  Under ``?profile=true`` the span is mirrored into
-    # the active QueryProfile as well, whatever the tracer.
+    # context-manager + ambient-activation protocol.  While a profiler
+    # session runs in the process (the program starts none), entering a
+    # span also enters a profiler annotation of the same name, so the
+    # session holds the program's spans on its own clock; with no session
+    # that is one native test.  Under ``?profile=true`` the span is
+    # mirrored into the active QueryProfile as well, whatever the tracer.
     def __enter__(self) -> "Span":
         self._token = _active_span.set(self)
         if qprofile.profiling():
             self._phandle = qprofile.span_enter(self.name)
         cls = _annotation or _find_annotation()
-        if cls is not None:
+        if cls is not None and cls.is_enabled():
             ann = self._ann = cls(self.name)
             ann.__enter__()
         return self
@@ -508,7 +609,8 @@ def record_span(
     dispatcher times a member's queue wait and dispatch; the member
     records them on wake-up).  It reaches the table, the store and an
     active profile like any span; it was never entered, so the
-    profiler's trace does not hold it."""
+    profiler's trace does not hold it, and it books no CPU: it is a
+    wait."""
     span = start_span(name)
     span.start_ns = start_ns
     if tags:
@@ -516,6 +618,20 @@ def record_span(
     qprofile.annotate(name, (end_ns - start_ns) * 1e-6, **span.tags)
     span.finish(end_ns)
     return span
+
+
+def book_cpu(span: Span, cpu_ns: int) -> None:
+    """Thread CPU read elsewhere for a finished span that read none: the
+    HTTP handler's own pair around a request (server/http.py
+    ``handle_one_request``: from before the request's line to the
+    response written and booked, a little more than the root span
+    covers), so that a request costs no clock read beside those."""
+    span.cpu_ns = cpu_ns
+    row = span._row
+    row.cpu_ns += cpu_ns
+    own = cpu_ns - span.child_cpu_ns
+    if own > 0:
+        row.self_cpu_ns += own
 
 
 def active_span() -> Span | None:

@@ -121,10 +121,6 @@ _fallback_lock = threading.Lock()
 # this module, below any holder plumbing.
 kernel_stats = MemStatsClient()
 
-_dispatch_lock = threading.Lock()
-_seen_programs: set = set()
-_MAX_SEEN_PROGRAMS = 4096
-
 
 def pallas_fallback_count() -> int:
     with _fallback_lock:
@@ -177,22 +173,17 @@ def _note_dispatch(
     """Record one kernel dispatch: tagged counters/timings into
     ``kernel_stats`` plus a per-kernel record into the active query
     profile.  ``wall`` is launch wall time — device work may still be in
-    flight unless the caller synchronized.  The jit compile-cache
-    hit/miss is a proxy: first sight of (kernel, lane, arg shapes) in
-    this process, mirroring XLA's shape-keyed jit cache.  ``extra``
-    merges lane-specific labels into the profile record and
+    flight unless the caller synchronized.  Compiles are the ledger's to
+    count (``devledger.totals.compiles``, from ``jax.monitoring``).
+    ``extra`` merges lane-specific labels into the profile record and
     ``extra_tags`` onto the dispatch counter (bounded cardinality is the
     caller's responsibility)."""
-    key = (kernel, lane, _shape_sig(args))
-    with _dispatch_lock:
-        miss = key not in _seen_programs
-        if miss and len(_seen_programs) < _MAX_SEEN_PROGRAMS:
-            _seen_programs.add(key)
     if lane != "host":
         # Ledger booking: the jit call already returned on this thread, so
         # any XLA compiles it triggered sit in the thread stash — claim
         # them under this site, and book the launch + identity.
         site = dl_site or _DL_KERNELS
+        key = (kernel, lane, _shape_sig(args))
         site.track_key(key)
         site.claim(sig=f"{kernel}/{lane}:{key[2]}")
         site.record_launch(wall or 0.0)
@@ -202,9 +193,6 @@ def _note_dispatch(
         f"kernel:{kernel}", f"lane:{lane}", *extra_tags
     )
     tagged.count("kernel_dispatch")
-    kernel_stats.count(
-        "kernel_compile_misses" if miss else "kernel_compile_hits"
-    )
     if demoted:
         tagged.count("kernel_demotions")
     if padded_bytes:
@@ -212,11 +200,7 @@ def _note_dispatch(
         tagged.count("kernel_useful_bytes", int(useful_bytes))
     if wall is not None:
         tagged.timing("kernel_dispatch", wall)
-    rec: dict = {
-        "kernel": kernel,
-        "lane": lane,
-        "jit_cache": "miss" if miss else "hit",
-    }
+    rec: dict = {"kernel": kernel, "lane": lane}
     if wall is not None:
         rec["wall_ms"] = round(wall * 1e3, 3)
     if demoted:
@@ -337,12 +321,10 @@ def record_host_op(kernel: str) -> None:
 
 def telemetry_snapshot() -> dict:
     """JSON-safe kernel-telemetry rollup for /debug/vars and tests:
-    dispatch-lane counts, compile-cache proxy, transfer
-    bytes, pallas gate states."""
+    dispatch-lane counts, transfer bytes, pallas gate states."""
     snap = kernel_stats.snapshot()
     lanes: dict[str, int] = {}
     transfers: dict[str, int] = {}
-    compile_cache = {"hits": 0, "misses": 0}
     for label, v in snap["counters"].items():
         name, _, tagstr = label.partition("{")
         tags = dict(
@@ -354,10 +336,6 @@ def telemetry_snapshot() -> dict:
         elif name == "kernel_transfer_bytes":
             d = tags.get("direction", "?")
             transfers[d] = transfers.get(d, 0) + int(v)
-        elif name == "kernel_compile_hits":
-            compile_cache["hits"] += int(v)
-        elif name == "kernel_compile_misses":
-            compile_cache["misses"] += int(v)
     return {
         "pallas_fallbacks": pallas_fallback_count(),
         "gram_gates": {
@@ -371,7 +349,6 @@ def telemetry_snapshot() -> dict:
             },
         },
         "dispatch_lanes": lanes,
-        "compile_cache": compile_cache,
         "transfer_bytes": transfers,
         "counters": snap["counters"],
     }

@@ -320,10 +320,15 @@ class Handler(BaseHTTPRequestHandler):
         none), one after the response is written and booked."""
         t0 = time.thread_time()
         self.raw_requestline = b""
+        self._root_span = None
         super().handle_one_request()
         if self.raw_requestline:
-            self._cpu.seconds += time.thread_time() - t0
+            cpu = time.thread_time() - t0
+            self._cpu.seconds += cpu
             self._cpu.requests += 1
+            if self._root_span is not None:
+                # the route's span reads no CPU clock of its own
+                tracing.book_cpu(self._root_span, int(cpu * 1e9))
 
     def parse_request(self) -> bool:
         """The request's line as the stdlib splits it, then the fields by
@@ -536,6 +541,7 @@ class Handler(BaseHTTPRequestHandler):
                 parent = tracing.get_tracer().extract_headers(self.headers)
                 span = tracing.start_span(f"http.{name}", child_of=parent)
                 span.set_tag("method", method).set_tag("path", path)
+                self._root_span = span
                 # Error budget: server-attributed failures only.  504s
                 # (deadline/batcher expiry) and 500s burn budget; 4xx
                 # client mistakes don't.

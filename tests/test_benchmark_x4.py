@@ -16,6 +16,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
 
 import manifest as mf  # noqa: E402
+from test_benchmark_ingest import FLIGHT_MS, flight_ms_of_the_log  # noqa: E402
 
 CELL = "taxi-x4.dashboard-c32"
 MANIFEST = mf.load()
@@ -46,7 +47,7 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert mf.validate_line(MANIFEST, CELL, bool(trace), line) == []
     want = [m["name"] for m in mf.metrics_for(MANIFEST, CELL, bool(trace))]
-    assert list(line["metrics"]) == want and len(want) == (23 if trace else 3)
+    assert list(line["metrics"]) == want and len(want) == (28 if trace else 3)
     assert line["correct"] is False
     over = {k: v for k, (v, limit) in line["compared"].items() if v > limit}
     assert over == {"rehearsal": 1}, err
@@ -54,4 +55,8 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     if trace:
         # counts, not times: the window's launches ran over the mesh
         assert line["metrics"]["mesh.sharded_launch_pct"]["value"] > 75, err
+        # a flight's wall time in three: interpreter, device wait, and the rest
+        value = {k: v["value"] for k, v in line["metrics"].items()}
+        assert value[FLIGHT_MS[0]] > 0 and value[FLIGHT_MS[1]] > 0, err
+        assert sum(value[n] for n in FLIGHT_MS) == flight_ms_of_the_log(p.stderr)
     assert os.listdir(tmp_path) == [], "the run left its work directory"

@@ -8,7 +8,8 @@ mesh: among them the three that say which route a stack's refresh took
 device, every block is gathered on the chip that holds the shard).  The judge
 is the benchmark's own (``benchmark/reference.py``, every sampled read held to
 "an acknowledged import is visible").  A rehearsal is never a pass: exit 3,
-``correct`` false, and ``rehearsal`` the one number over its limit."""
+``correct`` false, and ``rehearsal`` over its limit: alone, or with
+``stream_slabs_short``, which the clock of a loaded CPU decides."""
 
 import json
 import os
@@ -21,6 +22,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
 
 import manifest as mf  # noqa: E402
+from test_benchmark_ingest import FLIGHT_MS, flight_ms_of_the_log, over_limit  # noqa: E402
 
 CELL = "taxi-x4.ingest-serve"
 MANIFEST = mf.load()
@@ -36,6 +38,8 @@ MESHED = [
     "device.busy_spread_pct",
 ]
 OWN = "stacks.refresh_peer_mb_per_import"
+# PR 42: a counter both stream cells had and no run logged
+OUT_OF_PLACE = "stacks.refresh_out_of_place_per_import"
 
 
 def test_the_configuration_is_taxi_x4s_record_half_loaded_at_six_shards_a_chip():
@@ -101,7 +105,7 @@ def test_the_cell_is_the_new_configuration_under_the_stream_cells_traffic_on_fou
 
 def test_the_cells_metrics_are_the_streams_the_meshs_and_its_own():
     listed = [m["name"] for m in MANIFEST["per_layer"] if CELL in m.get("workloads", ())]
-    assert listed == MESHED + STREAMED + [OWN]
+    assert listed == MESHED + STREAMED + [OWN, OUT_OF_PLACE]
     own = next(m for m in MANIFEST["per_layer"] if m["name"] == OWN)
     assert own["workloads"] == [CELL]  # the entry exists and lists the cell alone
     assert (own["layer"], own["moves"], own["source"], own["better"], own["unit"]) == (
@@ -157,10 +161,10 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert mf.validate_line(MANIFEST, CELL, bool(trace), line) == []
     want = [m["name"] for m in mf.metrics_for(MANIFEST, CELL, bool(trace))]
-    assert list(line["metrics"]) == want and len(want) == (31 if trace else 3)
+    assert list(line["metrics"]) == want and len(want) == (37 if trace else 3)
     assert line["correct"] is False
     compared = {k: v for k, (v, _) in line["compared"].items()}
-    assert {k: v for k, (v, limit) in line["compared"].items() if v > limit} == {"rehearsal": 1}, err
+    assert over_limit(line) == {"rehearsal": 1}, err
     assert compared["read_mismatches"] == compared["readback_mismatches"] == 0
     assert {"imports_failed", "stream_slabs_short", "classes_unjudged", "window_compiles",
             "failed_requests"} <= set(compared)
@@ -175,7 +179,10 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
         assert value["stacks.rebuild_share_pct"] == 0
         assert value["stacks.refresh_ms_per_import"] > 0
         assert value["stacks.refresh_host_mb_per_import"] == 0
-        assert value[OWN] == 0
+        assert value[OWN] == 0 and value[OUT_OF_PLACE] >= 0
+        # a flight's wall time in three: interpreter, device wait, and the rest
+        assert value[FLIGHT_MS[0]] > 0 and value[FLIGHT_MS[1]] > 0, err
+        assert sum(value[n] for n in FLIGHT_MS) == flight_ms_of_the_log(p.stderr)
         # the reads ran over the mesh; a refresh's launch is one chip's
         assert 50 < value["mesh.sharded_launch_pct"] <= 100, err
     assert os.listdir(tmp_path) == [], "the run left its work directory"

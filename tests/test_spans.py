@@ -10,6 +10,10 @@
     them, in the test's own process.
 (d) Cost as a count: a span with no store, no profile and no session takes
     no lock and mints one id per span and one more per trace.
+(e) The thread's CPU clock beside the wall clock: ``cpu_seconds``,
+    ``self_cpu_seconds`` and ``device_wait_seconds`` of a row, read by every
+    span under the dispatcher's two and by ``kernels.pull``, and booked into a
+    handler's root from the pair the handler reads; ``cpuMs`` in a kept trace.
 
 Every test that starts a server, a batcher thread or a profiler session
 has a time limit of its own (``time_limit``).
@@ -18,6 +22,7 @@ has a time limit of its own (``time_limit``).
 from __future__ import annotations
 
 import ast
+import contextvars
 import functools
 import glob
 import json
@@ -250,9 +255,13 @@ def test_flight_spans_reach_the_members_store_without_http():
 # -- (b) the table --------------------------------------------------------------
 
 
+_ROW_KEYS = {"count", "seconds", "self_seconds", "items", "cpu_seconds", "self_cpu_seconds",
+             "device_wait_seconds"}
+
+
 def _leaves(block, path=()):
     for k, v in block.items():
-        if isinstance(v, dict) and set(v) != {"count", "seconds", "self_seconds", "items"}:
+        if isinstance(v, dict) and set(v) != _ROW_KEYS:
             yield from _leaves(v, path + (k,))
         else:
             yield path + (k,), v
@@ -292,7 +301,8 @@ def test_every_registered_name_is_served_at_zero_before_any_request():
     # the request that read the table is the only one that ran
     busy = {n for n, row in served.items() if row["count"]}
     assert busy <= {"http.debug_vars"} and all(
-        row == {"count": 0, "seconds": 0.0, "self_seconds": 0.0, "items": 0}
+        row == {"count": 0, "seconds": 0.0, "self_seconds": 0.0, "items": 0, "cpu_seconds": 0.0,
+                "self_cpu_seconds": 0.0, "device_wait_seconds": 0.0}
         for n, row in served.items() if n not in busy)
     # device bytes by owner, and the backend's own figure beside them
     dev = out["device"]
@@ -508,9 +518,154 @@ def test_a_span_nobody_reads_takes_no_lock_and_mints_one_id(monkeypatch, depth, 
             if k > 1:
                 nest(k - 1)
 
+    cpu_reads = []
+    real_cpu = time.thread_time_ns
+    monkeypatch.setattr(tracing.time, "thread_time_ns", lambda: cpu_reads.append(1) or real_cpu())
+
     nest(depth)
     assert rng.calls == want  # one trace id for the root, one span id a span
     assert len(clock_reads) == 2 * depth  # one clock read at each end
+    assert cpu_reads == []  # the thread's CPU clock is a system call: only where the table says
     assert locks == [] and profile_calls == []
     assert all(s.duration is not None and s.tags == {} for s in spans)
     assert len({s.context.trace_id for s in spans}) == 1
+
+
+# -- (e) the thread's CPU clock ------------------------------------------------------
+
+
+def _burn(ms: float) -> None:
+    """Run this thread until its CPU clock has moved by ``ms`` (a clock that
+    ticks moves by a whole tick)."""
+    t0 = time.thread_time_ns()
+    while time.thread_time_ns() - t0 < ms * 1e6:
+        pass
+
+
+def _on_another_thread(fn) -> None:
+    """Under a copy of this context, as the fan-out pools run their legs."""
+    t = threading.Thread(target=contextvars.copy_context().run, args=(fn,))
+    t.start()
+    t.join(20)
+
+
+def _table_row(name):
+    block, what = name.split(".")
+    return tracing.spans_snapshot()[block][what]
+
+
+@time_limit(60)
+def test_cpu_and_device_wait_on_a_hand_built_tree_under_the_dispatchers_root():
+    names = ("batcher.flight", "test.parent", "test.child", "test.other", "test.op", "kernels.pull")
+    before = {n: _table_row(n) for n in names}
+    legs = []
+
+    def leg():  # opened and finished on a worker, under the parent: a fan-out leg
+        with tracing.start_span("test.child") as s:
+            _burn(40)
+        legs.append(s)
+
+    with tracing.start_span("batcher.flight") as flight:  # every span below reads the CPU clock
+        with tracing.start_span("test.parent") as parent:
+            _burn(30)
+            with tracing.start_span("kernels.pull") as pull:  # the row that waits for the device
+                time.sleep(0.03)
+            _on_another_thread(leg)
+            stray = tracing.start_span("test.other")  # opened here, finished by another thread
+            _burn(5)
+            _on_another_thread(stray.finish)
+            rec = tracing.record_span("test.op", parent.start_ns, parent.start_ns + 2_000_000)
+    (leg_span,) = legs
+    ns = lambda span: round(span.duration * 1e9)  # noqa: E731
+    # the pull's thread ran next to nothing of its 30 ms: the rest is its device wait
+    assert pull.wait_ns == ns(pull) - pull.cpu_ns >= 15_000_000
+    # the parent ran its own 35 ms and the pull's; the worker's 40 ms are not in its clock
+    assert 35_000_000 <= parent.cpu_ns < 65_000_000 and leg_span.cpu_ns >= 40_000_000
+    assert parent.child_cpu_ns == pull.cpu_ns and parent.child_ns >= ns(pull) + ns(leg_span)
+    # waits by construction book no CPU, and carry nothing up
+    assert stray.duration is not None and stray.cpu_ns == 0 and rec.cpu_ns == 0
+    assert parent.wait_ns == flight.wait_ns == pull.wait_ns and leg_span.wait_ns == 0
+    assert flight.child_cpu_ns == parent.cpu_ns <= flight.cpu_ns
+    assert flight.cpu_ns + flight.wait_ns <= ns(flight)  # what is left is neither
+
+    def moved(name, key):
+        return _table_row(name)[key] - before[name][key]
+
+    for name, span in (("batcher.flight", flight), ("test.parent", parent), ("test.child", leg_span),
+                       ("kernels.pull", pull)):
+        assert moved(name, "cpu_seconds") == pytest.approx(span.cpu_ns * 1e-9, abs=1e-9), name
+        assert moved(name, "self_cpu_seconds") == pytest.approx(
+            (span.cpu_ns - span.child_cpu_ns) * 1e-9, abs=1e-9), name
+    for name in ("batcher.flight", "test.parent", "kernels.pull"):
+        assert moved(name, "device_wait_seconds") == pytest.approx(pull.wait_ns * 1e-9, abs=1e-9), name
+    for name in ("test.other", "test.op"):
+        assert moved(name, "count") == 1 and moved(name, "seconds") > 0
+    for name in ("test.child", "test.other", "test.op"):
+        assert moved(name, "device_wait_seconds") == 0, name
+    for name in ("test.other", "test.op"):
+        assert moved(name, "cpu_seconds") == moved(name, "self_cpu_seconds") == 0, name
+    # seconds = cpu + device wait + what is left, for any row
+    for name in names:
+        assert moved(name, "cpu_seconds") + moved(name, "device_wait_seconds") <= moved(name, "seconds") + 1e-9
+
+
+@time_limit(60)
+def test_elsewhere_only_the_pull_reads_the_cpu_clock_and_a_handler_books_its_own():
+    """Where the clock is a system call of microseconds a span cannot read it by
+    default: outside the dispatcher's trees only a pull does, its device wait is
+    carried up to the root, and the root is given the CPU its handler read."""
+    before = {n: _table_row(n) for n in ("test.root", "test.child")}
+    with tracing.start_span("test.root") as root:
+        with tracing.start_span("test.child") as child:
+            _burn(10)
+            with tracing.start_span("kernels.pull") as pull:
+                time.sleep(0.02)
+        with tracing.start_span("batcher.collect") as tree:  # the mark is the row's, wherever it opens
+            with tracing.start_span("test.op") as below:
+                _burn(10)
+    assert root.cpu_ns == 0 and child.cpu_ns == 0 and child.child_cpu_ns == pull.cpu_ns
+    assert below.cpu_ns >= 10_000_000 and tree.child_cpu_ns == below.cpu_ns
+    assert pull.wait_ns >= 10_000_000 and child.wait_ns == root.wait_ns == pull.wait_ns
+    assert root.child_cpu_ns == tree.cpu_ns  # what the children that read the clock ran
+    # server/http.py reads the thread's clock around a request anyway, and books it
+    tracing.book_cpu(root, 25_000_000)
+    assert root.cpu_ns == 25_000_000
+    moved = {k: _table_row("test.root")[k] - before["test.root"][k]
+             for k in ("cpu_seconds", "self_cpu_seconds", "device_wait_seconds")}
+    assert moved["cpu_seconds"] == pytest.approx(0.025, abs=1e-9)
+    assert moved["self_cpu_seconds"] == pytest.approx((25_000_000 - tree.cpu_ns) * 1e-9, abs=1e-9)
+    assert moved["device_wait_seconds"] == pytest.approx(pull.wait_ns * 1e-9, abs=1e-9)
+    assert _table_row("test.child")["cpu_seconds"] == before["test.child"]["cpu_seconds"]
+    marks = {name: metric for name, _, metric in tracing.registered()}
+    assert marks["kernels.pull"].endswith("; " + tracing.DEVICE_WAIT)
+    assert marks["batcher.flight"].endswith("; " + tracing.CPU_TREE)
+    assert marks["batcher.collect"].endswith("; " + tracing.CPU_TREE)
+    leaves = {n for n, m in marks.items() if m.endswith("; " + tracing.CPU_LEAF)}
+    assert leaves == {"executor.demux", "kernels.h2d", "kernels.enqueue"}
+    assert sum(";" in m for m in marks.values()) == 6
+
+
+@time_limit(90)
+def test_a_kept_trace_renders_cpu_ms_beside_duration_ms_over_http(node):
+    _post(node.uri, "/index/sp/query", "Count(Intersect(Row(f=1), Row(f=3)))")
+    summaries = _wait_for(
+        lambda: [t for t in _get(node.uri, "/debug/traces")["traces"] if t["root"] == "http.query"])
+    # the handler books the request's CPU into its root once the response is out
+    spans = _wait_for(lambda: [
+        d for d in [_get(node.uri, f"/debug/traces?id={summaries[0]['traceId']}")["spans"]]
+        if next(s for s in d if s["name"] == "http.query")["cpuMs"] > 0][:1])[0]
+    assert all(isinstance(s["cpuMs"], float) and s["cpuMs"] >= 0 for s in spans)
+    by_name = {s["name"]: s for s in spans}
+    # the two waits of a member were built after the fact: no CPU
+    assert by_name["batcher.queueWait"]["cpuMs"] == by_name["batcher.dispatch"]["cpuMs"] == 0
+    # the handler ran, and waited for its flight: less CPU than wall, and (the clock
+    # may tick) not none over both trees
+    root, flight = by_name["http.query"], by_name["batcher.flight"]
+    assert root["cpuMs"] <= root["durationMs"] + 10 and flight["cpuMs"] <= flight["durationMs"] + 10
+    # a handler's children read no CPU clock of their own; the flight's tree does
+    assert by_name["http.decode"]["cpuMs"] == 0
+    table = _get(node.uri, "/debug/vars")["spans"]
+    assert table["http"]["query"]["cpu_seconds"] > 0
+    assert table["batcher"]["flight"]["cpu_seconds"] > 0
+    assert table["kernels"]["pull"]["device_wait_seconds"] > 0
+    assert table["batcher"]["flight"]["device_wait_seconds"] > 0
