@@ -24,6 +24,11 @@ program per query shape, cached"):
   executor.go:1515-1531; the reference treats time views as ordinary
   fragments, view.go:33-38) — so time-quantum queries ride the same
   compiled one-launch programs, with one cached stack per (field, view).
+* Under a ``Sum`` (:func:`match_sum_filter`) a pure BSI condition is a
+  leaf too: it names its int field's raw stack and carries its encoded
+  bounds as per-query input beside the row slots, and the tree compiles
+  into the Sum's own program (:func:`run_sum_batch`): the filter words
+  never exist on the host.
 
 Launches are counted in :data:`launches` so tests can assert O(1)
 dispatch per query batch regardless of shard count or tree width.
@@ -43,6 +48,7 @@ from pilosa_tpu.core.field import FIELD_TYPE_INT
 from pilosa_tpu.core.view import VIEW_STANDARD
 from pilosa_tpu.exec import planner as planner_mod
 from pilosa_tpu.obs import devledger
+from pilosa_tpu.ops import bsi
 from pilosa_tpu.pql.ast import Call, Condition
 
 # Device cost ledger site for compiled-plan launches: every run_* call
@@ -68,13 +74,15 @@ _OPS = {
 # cover thousands of views).
 MAX_TIME_COVER = 16
 
-# sig nodes: ("row", stack_ordinal) | (op, *child_sigs).  Leaves refer
+# sig nodes: ("row", stack_ordinal) | ("range", stack_ordinal, depth) |
+# (op, *child_sigs).  Leaves refer
 # to (field, view) stacks by first-appearance ORDINAL, not by name: the
 # compiled program depends only on the tree shape and stack positions,
 # so a rolling time window (same cover shape, different view names)
 # reuses one program instead of tracing a fresh one per period.  The
 # actual (field, view) pairs ride alongside in ``pairs`` and join the
-# executor's launch-group key.
+# executor's launch-group key.  A "range" leaf (a Sum's filter only)
+# reads the raw BSI stack of an int field, ``depth`` planes deep.
 
 
 def _stackable_field(idx, fname: str):
@@ -98,17 +106,37 @@ def _ordinal(pairs: list[tuple[str, str]], fname: str, vname: str) -> int:
         return len(pairs) - 1
 
 
+def _shape(call: Call) -> str:
+    """A sort key that tells a tree's shape and fields and none of its
+    drawn values, so both orders of a commutative node's children sign
+    into one program."""
+    if call.children:
+        return f"{call.name}({','.join(sorted(map(_shape, call.children)))})"
+    fname = call.field_arg() or ""
+    ranged = isinstance(call.args.get(fname), Condition)
+    timed = "from" in call.args or "to" in call.args
+    return f"{call.name}:{fname}:{ranged:d}{timed:d}"
+
+
 def match_tree(
     idx,
     call: Call,
     leaves: list[tuple[str, str, int]],
     pairs: list[tuple[str, str]],
+    ranges: list | None = None,
 ):
     """``sig`` for a batchable bitmap tree, appending its
     (field, view, row) leaves in traversal order and the distinct
     (field, view) stack pairs to ``pairs`` (the compiled program's
     argument order); None when any node falls outside the compilable set
-    (BSI conditions, Shift, keyed rows...)."""
+    (BSI conditions, Shift, keyed rows...).
+
+    ``ranges`` is a Sum's filter (:func:`match_sum_filter`) and no other
+    caller's: a pure BSI condition then signs as a "range" leaf, its
+    ``(field, condition)`` appended to ``ranges`` in traversal order and
+    its int field's BSI view to ``pairs``, and the children of a
+    commutative node sign in the order of their shapes.  Without it a
+    tree signs as it always did."""
     name = call.name
     if name == planner_mod.SHARED:
         # flight-planner graft (exec/planner.py): the subtree is already
@@ -117,6 +145,15 @@ def match_tree(
         # host segment algebra instead of re-launching the whole tree.
         # (Any unknown name declines anyway; this spells the contract.)
         return None
+    if ranges is not None:
+        m = _bsi_condition(idx, call)
+        if m is not None:
+            field = m[0]
+            ranges.append(m)
+            return (
+                "range", _ordinal(pairs, field.name, field.bsi_view_name()),
+                field.bit_depth,
+            )
     if name == "Row":
         fname = call.field_arg()
         field = _stackable_field(idx, fname)
@@ -162,7 +199,7 @@ def match_tree(
             return None
         leaves.append((ef.name, VIEW_STANDARD, 0))
         esig = ("row", _ordinal(pairs, ef.name, VIEW_STANDARD))
-        child = match_tree(idx, call.children[0], leaves, pairs)
+        child = match_tree(idx, call.children[0], leaves, pairs, ranges)
         if child is None:
             return None
         return ("difference", esig, child)
@@ -170,9 +207,12 @@ def match_tree(
     if op is not None:
         if not call.children or call.args:
             return None
+        children = call.children
+        if ranges is not None and name in planner_mod.COMMUTATIVE:
+            children = sorted(children, key=_shape)
         subs = []
-        for c in call.children:
-            s = match_tree(idx, c, leaves, pairs)
+        for c in children:
+            s = match_tree(idx, c, leaves, pairs, ranges)
             if s is None:
                 return None
             subs.append(s)
@@ -197,14 +237,17 @@ def match_count(
 
 
 def _build(sig, ctr: list[int]):
-    """Recursively build the tree evaluator: (stacks, slots) -> [S, W]
-    words.  Leaf order mirrors match_tree's traversal order."""
+    """Recursively build the tree evaluator: (stacks, slots[, bounds]) ->
+    [S, W] words.  Leaf order mirrors match_tree's traversal order;
+    ``ctr`` counts the row leaves and the range leaves met so far.
+    ``bounds`` holds, for each range leaf in that order, one query's
+    row of ``bsi.pack_bounds``; a tree without one reads none."""
     if sig[0] == "row":
         li = ctr[0]
         ctr[0] += 1
         fi = sig[1]
 
-        def leaf(stacks, slots, li=li, fi=fi):
+        def leaf(stacks, slots, bounds=(), li=li, fi=fi):
             s = slots[li]
             row = stacks[fi][:, jnp.maximum(s, 0)]  # [S, W]
             return row & jnp.where(
@@ -212,6 +255,15 @@ def _build(sig, ctr: list[int]):
             )
 
         return leaf
+    if sig[0] == "range":
+        ri = ctr[1]
+        ctr[1] += 1
+        _, fi, depth = sig
+
+        def span(stacks, slots, bounds):
+            return bsi.range_words(stacks[fi], bounds[ri], depth=depth)
+
+        return span
     op = sig[0]
     kids = [_build(k, ctr) for k in sig[1:]]
 
@@ -220,21 +272,21 @@ def _build(sig, ctr: list[int]):
             return kids[0]
 
         # left fold a\b\c == a & ~(b | c) (reference row.go Difference)
-        def node(stacks, slots):
-            rest = kids[1](stacks, slots)
+        def node(stacks, slots, bounds=()):
+            rest = kids[1](stacks, slots, bounds)
             for k in kids[2:]:
-                rest = rest | k(stacks, slots)
-            return kids[0](stacks, slots) & ~rest
+                rest = rest | k(stacks, slots, bounds)
+            return kids[0](stacks, slots, bounds) & ~rest
 
         return node
 
     fold = {"intersect": lambda a, b: a & b, "union": lambda a, b: a | b,
             "xor": lambda a, b: a ^ b}[op]
 
-    def node(stacks, slots):
-        out = kids[0](stacks, slots)
+    def node(stacks, slots, bounds=()):
+        out = kids[0](stacks, slots, bounds)
         for k in kids[1:]:
-            out = fold(out, k(stacks, slots))
+            out = fold(out, k(stacks, slots, bounds))
         return out
 
     return node
@@ -262,7 +314,7 @@ def compiled(sig, count_mode: bool):
     counts (scan over the batch — no [B, S, W] materialization); bitmap
     programs take ``(stacks, slots[L])`` and return the uint32 ``[S, W]``
     result words."""
-    ctr = [0]
+    ctr = [0, 0]
     root = _build(sig, ctr)
     n_leaves = ctr[0]
 
@@ -295,7 +347,7 @@ def _compiled_spanning(sig, mesh, axis, chunk, n_stacks):
 
     from pilosa_tpu.ops import kernels as _k
 
-    ctr = [0]
+    ctr = [0, 0]
     root = _build(sig, ctr)
     n_leaves = ctr[0]
 
@@ -387,6 +439,126 @@ def run_bitmap(sig, stacks: tuple, slots_np: np.ndarray):
     ):
         w.mesh = _k._multi_device(stacks[0])
         return fn(stacks, slots)
+
+
+# ------------------------------------------------- a filtered Sum's program
+#
+# ``Sum(<tree>, field=v)``: the tree compiles into the Sum's own program.
+# One launch takes the summed field's RAW stack, the stacks the filter's
+# leaves read, a chunk of queries' row slots and encoded range bounds;
+# it builds each query's filter words where the stacks lie and feeds
+# them to the fused popcount matmul (ops/bsi.py).  Nothing but slots and
+# bounds leaves the host, and only the accumulator comes back.
+
+# queries a launch: a flight's same-shape Sums go in chunks of this many,
+# the last padded with queries that select nothing (slot -1, zero
+# bounds), so a shape compiles ONE program whatever a flight holds (a
+# bucket a size would be a program a bucket the warm-up has to meet).
+# 4, not 8: ssb-flat's flights hold 2.3 Sums a (field, shape) group, and
+# at the same seed the device spent 21.6 ms a read against 22.0 (PERF.md
+# section 6, PR 43); a larger group takes one more launch a four
+SUM_CHUNK = 4
+
+
+def match_sum_filter(idx, call: Call):
+    """``(sig, pairs, leaves, ranges)`` of a ``Sum``'s filter tree as its
+    own program compiles it (:func:`match_tree` with range leaves), or
+    None: the Sum is then the per-call path's."""
+    leaves: list[tuple[str, str, int]] = []
+    pairs: list[tuple[str, str]] = []
+    ranges: list = []
+    sig = match_tree(idx, call, leaves, pairs, ranges)
+    if sig is None:
+        return None
+    return sig, tuple(pairs), leaves, ranges
+
+
+def _range_depths(sig) -> list[int]:
+    """The depths of a sig's range leaves, in traversal order."""
+    if sig[0] == "range":
+        return [sig[2]]
+    if sig[0] == "row":
+        return []
+    return [d for k in sig[1:] for d in _range_depths(k)]
+
+
+@lru_cache(maxsize=256)
+def compiled_sum(sig, layout=None):
+    """(jitted_fn, row leaves) of a filtered Sum whose filter signs as
+    ``sig``: ``(bits, stacks, queries)`` -> the accumulator
+    ``int32[depth+1, 2P]`` of ``bsi.sum_filters_acc``.  ``queries`` is
+    ``uint32[P, ...]``, a row a query: its row slots (int32 bits), then
+    its ``bsi.pack_bounds`` row of each range leaf.  A scan over the
+    queries builds the filter words ``[P, S, W]``.  ``layout`` = (mesh,
+    axis) of stacks sharded over a serving mesh: each device then runs
+    the same on its own shards, no collective, and the accumulators come
+    back ``[devices, depth+1, 2P]`` along the mesh axis; the queries are
+    replicated."""
+    ctr = [0, 0]
+    root = _build(sig, ctr)
+    n_leaves, depths = ctr[0], _range_depths(sig)
+
+    def local(bits, stacks, queries):
+        def one(_, q):
+            slots = lax.bitcast_convert_type(q[:n_leaves], jnp.int32)
+            bounds, at = [], n_leaves
+            for d in depths:
+                bounds.append(q[at:at + bsi.bounds_width(d)])
+                at += bsi.bounds_width(d)
+            return None, root(stacks, slots, bounds)
+
+        _, words = lax.scan(one, None, queries)
+        return bsi.sum_filters_acc(bits, jnp.swapaxes(words, 0, 1))
+
+    if layout is None:
+        return jax.jit(local), n_leaves
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    mesh, axis = layout
+    stack_spec = P(axis, None, None)
+    fn = jax.jit(
+        shard_map(
+            lambda *a: local(*a)[None],
+            mesh=mesh,
+            in_specs=(stack_spec, stack_spec, P(None)),
+            out_specs=stack_spec,
+            check_vma=False,
+        )
+    )
+    return fn, n_leaves
+
+
+def run_sum_batch(
+    sig, bits, stacks: tuple, slots_np, bounds_np: tuple, n: int
+):
+    """One launch, NOT awaited: the device accumulator of a chunk of
+    same-shape filtered Sums, the first ``n`` of ``SUM_CHUNK`` queries,
+    over the summed field's raw stack ``bits`` (``bsi.sum_pairs`` reads
+    it once pulled).  ``slots_np`` is int32 ``[SUM_CHUNK, row leaves]``
+    and ``bounds_np`` one ``bsi.pack_bounds`` array a range leaf, in
+    traversal order: they go up as ONE array.  ``stacks`` in ``pairs``
+    order, all laid out as ``bits`` is."""
+    global launches
+    from pilosa_tpu.ops import kernels as _k
+
+    fn, n_leaves = compiled_sum(sig, _k.shards_axis_of(bits))
+    assert slots_np.shape[1] == n_leaves
+    launches += 1
+    queries = _k.h2d(
+        np.concatenate([slots_np.view(np.uint32), *bounds_np], axis=1)
+    )
+    with _k.enqueue("bsi_sum_filtered") as sp:
+        acc = fn(bits, stacks, queries)
+    _k.note_bsi_dispatch(
+        "bsi_sum_filtered",
+        wall=sp.duration,
+        args=(bits, queries, *stacks),
+        depth=int(bits.shape[1]) - 2,
+        q_bucket=int(slots_np.shape[0]),
+        q_useful=n,
+    )
+    return acc
 
 
 # ------------------------------------------------------------- BSI signing

@@ -60,6 +60,8 @@ DEFAULT_MIN_THRESHOLD = 1
 
 # Sentinel for "not yet computed" result slots in the batch fast path.
 _UNSET = object()
+# a compiled tree's leaf over a view the field does not have (_tree_stacks)
+_ABSENT_VIEW = object()
 
 # the executor's own launches book beside the stack uploads
 _DL_STACK = stacks_mod.DL_STACK
@@ -199,6 +201,13 @@ class Executor:
         self.groupby_lane = dict.fromkeys(
             ("calls", "pulls", "inflight_sum", "budget_waits"), 0
         )
+        # the Sum lane (_batch_bsi_sums): filtered Sums whose filter was
+        # built on the device, in the Sum's own program; filtered Sums it
+        # left to the per-call path, which builds theirs on the host; and
+        # its launches
+        self.sum_lane = dict.fromkeys(
+            ("device_filters", "host_filters", "launches"), 0
+        )
 
     # ------------------------------------------------------------------ API
 
@@ -245,8 +254,12 @@ class Executor:
                     results[i] = res
             self._batch_pair_counts(idx, calls[:first_write], shards, results)
             self._batch_general(idx, calls[:first_write], shards, results)
-            with self._groupby_lane(idx, calls[:first_write], shards, results):
-                self._batch_bsi(idx, calls[:first_write], shards, results)
+            with self._flight_lanes(
+                idx, calls[:first_write], shards, results
+            ) as late:
+                self._batch_bsi(
+                    idx, calls[:first_write], shards, results, late
+                )
             for i, call in enumerate(calls):
                 if results[i] is _UNSET:
                     with tracing.start_span(execute_span(call.name)):
@@ -335,8 +348,12 @@ class Executor:
                     )
                 self._batch_pair_counts(idx, flat_calls, shards, flat_results)
                 self._batch_general(idx, flat_calls, shards, flat_results)
-                with self._groupby_lane(idx, flat_calls, shards, flat_results):
-                    self._batch_bsi(idx, flat_calls, shards, flat_results)
+                with self._flight_lanes(
+                    idx, flat_calls, shards, flat_results
+                ) as late:
+                    self._batch_bsi(
+                        idx, flat_calls, shards, flat_results, late
+                    )
                 pos = 0
                 for qi in qis:
                     calls = cloned[qi]
@@ -725,17 +742,80 @@ class Executor:
         self, field: Field, shard_list: list[int], view_name: str,
         demand: int,
     ):
-        """The view's stack for a batch lane's leaf, or None when it
-        declines.  A live stack serves for free; a cold one is built
-        only when >= 2 calls of the flight read it (stack builds are
-        full-field uploads), a heuristic that stands until the ledger
-        prices the batch-vs-solo lanes."""
-        if self.stacks.cached(
-            field, shard_list, view_name
-        ) or self.planner.choose_lane("tree_count", demand >= 2):
-            return self.stacks.get(field, shard_list, view_name)
+        """The view's stack for a batch lane's leaf (an int field's BSI
+        view: its raw BSI stack), or None when it declines.  A live
+        stack serves for free; a cold one is built only when >= 2 calls
+        of the flight read it (stack builds are full-field uploads), a
+        heuristic that stands until the ledger prices the batch-vs-solo
+        lanes."""
+        raw = field.is_bsi()
+        live = (
+            self.stacks.bsi_cached(field, shard_list) if raw
+            else self.stacks.cached(field, shard_list, view_name)
+        )
+        if live or self.planner.choose_lane("tree_count", demand >= 2):
+            return (
+                self.stacks.bsi(field, shard_list) if raw
+                else self.stacks.get(field, shard_list, view_name)
+            )
         self.stacks.refusals["demand"] += 1
         return None
+
+    def _tree_stacks(
+        self, idx: Index, pairs, shard_list: list[int],
+        demand: dict[tuple[str, str], int], memo: dict,
+        allow_spanning: bool,
+    ):
+        """(stacks tuple, slot_of per (field, view)) for the ``pairs`` a
+        compiled tree reads, or the reason (of _DECLINE_REASONS) when any
+        leaf declines (cold + under-demanded, or over budget).  ``memo``
+        is the flight's: (field, view) -> (slot_of, bits) of its stack |
+        the reason it declined | _ABSENT_VIEW (no such view: an all-zero
+        leaf, e.g. an empty period of a time-range cover).
+        ``allow_spanning``: count programs reduce in-program on a
+        process-spanning mesh (astbatch._compiled_spanning), but what
+        comes back per shard or per device (a bitmap program's [S, W]
+        words, a Sum's accumulators) is not addressable across
+        processes, so those decline."""
+        from pilosa_tpu.ops import kernels
+
+        out: list[Any] = []
+        slot_maps = {}
+        for pair in pairs:
+            fname, vname = pair
+            if pair not in memo:
+                field = idx.field(fname)  # includes _exists
+                if field is None:
+                    memo[pair] = "shape"
+                elif field.view(vname) is None:
+                    # a range leaf reads its stack's own rows: no stand-in
+                    memo[pair] = "shape" if field.is_bsi() else _ABSENT_VIEW
+                else:
+                    stack = self._stack_on_demand(
+                        field, shard_list, vname, demand.get(pair, 0)
+                    )
+                    memo[pair] = (
+                        "budget" if stack is None
+                        else (stack.slot_of, stack.bits)
+                    )
+            entry = memo[pair]
+            if isinstance(entry, str):
+                return entry
+            if entry is _ABSENT_VIEW:
+                slot_maps[pair] = {}
+                out.append(None)  # placeholder filled below
+            else:
+                slot_maps[pair] = entry[0]
+                out.append(entry[1])
+        # absent views still need a stack-shaped input for their
+        # argument position: reuse any real stack — every such
+        # leaf's slot is -1, which masks the gather to zero words
+        real = next((a for a in out if a is not None), None)
+        if real is None:
+            return "shape"  # every leaf view absent
+        if not allow_spanning and kernels.stack_spans_processes(real):
+            return "mesh"
+        return tuple(a if a is not None else real for a in out), slot_maps
 
     @stacks_mod.reading()
     def _batch_general(
@@ -786,59 +866,8 @@ class Executor:
             return
         shard_list = self._shards_for(idx, shards)
 
-        # (field, view) -> (slot_of, bits) of its stack | the reason it
-        # declined | _ABSENT (no such view: an all-zero leaf, e.g. an
-        # empty period of a time-range cover)
-        _ABSENT = object()
+        # the flight's stacks by (field, view): _tree_stacks' memo
         stacks_by_view: dict[tuple[str, str], Any] = {}
-
-        def _stacks_for(pairs, allow_spanning):
-            """(stacks tuple, slot_of per (field, view)), or the reason
-            (of _DECLINE_REASONS) when any leaf declines (cold +
-            under-demanded, or over budget).
-            ``allow_spanning``: count programs reduce in-program on a
-            process-spanning mesh (astbatch._compiled_spanning), but
-            bitmap programs materialize [S, W] result words for
-            host-side Row segments — not addressable across processes,
-            so those decline."""
-            out: list[Any] = []
-            slot_maps = {}
-            for pair in pairs:
-                fname, vname = pair
-                if pair not in stacks_by_view:
-                    field = idx.field(fname)  # includes _exists
-                    if field is None:
-                        stacks_by_view[pair] = "shape"
-                    elif field.view(vname) is None:
-                        stacks_by_view[pair] = _ABSENT
-                    else:
-                        stack = self._stack_on_demand(
-                            field, shard_list, vname, demand.get(pair, 0)
-                        )
-                        stacks_by_view[pair] = (
-                            "budget" if stack is None
-                            else (stack.slot_of, stack.bits)
-                        )
-                entry = stacks_by_view[pair]
-                if isinstance(entry, str):
-                    return entry
-                if entry is _ABSENT:
-                    slot_maps[pair] = {}
-                    out.append(None)  # placeholder filled below
-                else:
-                    slot_maps[pair] = entry[0]
-                    out.append(entry[1])
-            # absent views still need a stack-shaped input for their
-            # argument position: reuse any real stack — every such
-            # leaf's slot is -1, which masks the gather to zero words
-            real = next((a for a in out if a is not None), None)
-            if real is None:
-                return "shape"  # every leaf view absent
-            from pilosa_tpu.ops import kernels
-
-            if not allow_spanning and kernels.stack_spans_processes(real):
-                return "mesh"
-            return tuple(a if a is not None else real for a in out), slot_maps
 
         def _slots_of(leaves, slot_maps) -> np.ndarray:
             # absent rows -> slot -1 (masked to zero words in the leaf)
@@ -848,7 +877,10 @@ class Executor:
             )
 
         for (sig, pairs), items in count_groups.items():
-            st = _stacks_for(pairs, allow_spanning=True)
+            st = self._tree_stacks(
+                idx, pairs, shard_list, demand, stacks_by_view,
+                allow_spanning=True,
+            )
             if isinstance(st, str):
                 self._lane_decline("general", st, len(items))
                 continue
@@ -878,7 +910,10 @@ class Executor:
                         self._count_stat(idx)
 
         for i, sig, pairs, leaves in bitmap_items:
-            st = _stacks_for(pairs, allow_spanning=False)
+            st = self._tree_stacks(
+                idx, pairs, shard_list, demand, stacks_by_view,
+                allow_spanning=False,
+            )
             if isinstance(st, str):
                 self._lane_decline("general", st)
                 continue
@@ -1131,6 +1166,27 @@ class Executor:
     # ------------------------------------------------- batched GroupBy lane
 
     @contextlib.contextmanager
+    def _flight_lanes(
+        self, idx: Index, calls: list[Call], shards: list[int] | None,
+        results: list[Any],
+    ):
+        """The scope ``execute`` and ``execute_batch`` run the BSI lane
+        in: the GroupBy lane around it (:meth:`_groupby_lane`), and ONE
+        lease over both and over what comes after.  It yields ``late``,
+        the list the BSI lane leaves its filtered Sums' launches in,
+        enqueued and not awaited (:meth:`_batch_bsi_sums`): they are
+        pulled (:meth:`_pull_sums`) once the GroupBy lane's calls in
+        flight have ended, so a Sum's program queues behind the first
+        levels on the device's one stream and nobody waits for it there.
+        A flight without a lane ``GroupBy`` pulls them at the same
+        place."""
+        late: list = []
+        with stacks_mod.reading():
+            with self._groupby_lane(idx, calls, shards, results):
+                yield late
+            self._pull_sums(late, results)
+
+    @contextlib.contextmanager
     def _groupby_lane(
         self, idx: Index, calls: list[Call], shards: list[int] | None,
         results: list[Any],
@@ -1288,15 +1344,16 @@ class Executor:
     @stacks_mod.reading()
     def _batch_bsi(
         self, idx: Index, calls: list[Call], shards: list[int] | None,
-        results: list[Any],
+        results: list[Any], late: list,
     ) -> None:
         """Answer every BSI call astbatch signs as batchable with shared
         slice-plane launches: flight-mates group by (field, depth,
         op-class), so Q concurrent range predicates cost ONE
-        range_batch/range_count_batch dispatch and Q filtered Sums ONE
-        fused popcount matmul (ops/bsi.py batched kernels); range counts
+        range_batch/range_count_batch dispatch; range counts
         intersected with set rows group by their filter stacks too, ONE
-        launch a group (_batch_bsi_filtered_counts).  Per-item
+        launch a group (_batch_bsi_filtered_counts), and so do filtered
+        Sums by their filter's shape (_batch_bsi_sums: enqueued here,
+        left in ``late`` for :meth:`_flight_lanes` to pull).  Per-item
         trouble leaves the slot _UNSET for the per-call path, which
         re-raises inside the owning query's demux scope — one bad query
         never fails its flight-mates.
@@ -1318,15 +1375,24 @@ class Executor:
             if m is None:
                 continue
             op_class, field, cond, leaves = m
+            read = {leaf[:2] for leaf in leaves}
+            if op_class == astbatch.BSI_SUM and len(call.children) == 1:
+                # a Sum carries its filter's signature where a range
+                # class carries its condition (None: answered per call)
+                cond = astbatch.match_sum_filter(idx, call.children[0])
+                if cond is not None:
+                    read = set(cond[1])
             by_field.setdefault(field.name, []).append(
                 (i, op_class, cond, leaves)
             )
             fields[field.name] = field
-            for pair in {leaf[:2] for leaf in leaves}:
+            for pair in read:
                 demand[pair] = demand.get(pair, 0) + 1
         if not by_field:
             return
 
+        # the flight's filter stacks by (field, view): _tree_stacks' memo
+        memo: dict = {}
         shard_list: list[int] | None = None
         for fname, items in by_field.items():
             field = fields[fname]
@@ -1357,7 +1423,7 @@ class Executor:
             ).set_tag("n", len(items)):
                 self._batch_bsi_field(
                     idx, field, stack, bits, groups, shard_list, calls,
-                    results,
+                    results, demand, memo, late,
                 )
                 for pairs, fitems in filtered.items():
                     self._batch_bsi_filtered_counts(
@@ -1367,7 +1433,7 @@ class Executor:
 
     def _batch_bsi_field(
         self, idx: Index, field: Field, stack, bits, groups, shard_list,
-        calls: list[Call], results: list[Any],
+        calls: list[Call], results: list[Any], demand, memo, late: list,
     ) -> None:
         """One field's grouped BSI launches against its live stack."""
         from pilosa_tpu.exec import astbatch
@@ -1486,13 +1552,14 @@ class Executor:
                             self._count_stat(idx)
 
         # -- Sum: unfiltered repeats collapse onto the cached stacked
-        # aggregate; filtered Sums share one fused popcount matmul when
-        # the int32 accumulator and the filter tensor stay in budget
+        # aggregate; filtered Sums share one fused popcount matmul a
+        # filter shape when the int32 accumulator and the filter tensor
+        # stay in budget
         sum_items = groups.get(astbatch.BSI_SUM, [])
         if sum_items:
             self._batch_bsi_sums(
                 idx, field, stack, bits, sum_items, shard_list, calls,
-                results,
+                results, demand, memo, late,
             )
 
         # -- Min/Max: one cached scalar per (field, kind); grouped here
@@ -1579,25 +1646,25 @@ class Executor:
 
     def _batch_bsi_sums(
         self, idx: Index, field: Field, stack, bits, sum_items, shard_list,
-        calls: list[Call], results: list[Any],
+        calls: list[Call], results: list[Any], demand, memo, late: list,
     ) -> None:
+        """One int field's ``Sum`` calls.  The unfiltered ones are one
+        cached scalar.  A filtered one whose filter astbatch signed
+        (``sum_items`` carries the signature) never passes through the
+        host: the flight's Sums group by (filter sig, stack pairs), and a
+        group is launched in chunks of ``astbatch.SUM_CHUNK`` queries,
+        each ONE program over the raw BSI stack and the filter leaves'
+        stacks that builds the filter words where they lie.  A lone one
+        rides too.  What that path declines (an unsigned tree; a stack
+        cold and under-demanded or over budget; stacks under two layouts;
+        the int32 gate; the filter tensor's budget) is counted by reason
+        and left _UNSET for the per-call path (:meth:`_execute_sum`).
+        Nothing is awaited here: every launch goes into ``late``."""
+        from pilosa_tpu.exec import astbatch
         from pilosa_tpu.ops import kernels
 
         depth = field.bit_depth
-        S_stack, W = int(bits.shape[0]), field.n_words
-        unfiltered: list[int] = []
-        filtered: list[tuple[int, Row]] = []
-        for i, _ in sum_items:
-            try:
-                filt = self._sum_filter(idx, calls[i], shard_list)
-            except Exception:
-                # malformed: per-call path raises per query
-                self._lane_decline("bsi_sums", "error")
-                continue
-            if filt is None:
-                unfiltered.append(i)
-            else:
-                filtered.append((i, filt))
+        unfiltered = [i for i, _ in sum_items if not calls[i].children]
         if unfiltered:
             # every unfiltered Sum in the flight is the SAME scalar:
             # one cached stacked compute answers them all
@@ -1613,42 +1680,114 @@ class Executor:
             except Exception:
                 # per-call path re-raises per query
                 self._lane_decline("bsi_sums", "error", len(unfiltered))
-        if not filtered:
-            return
-        Q = len(filtered)
-        P = _pow2(Q)
-        # a mesh-sharded stack takes the filter tensor in its own layout:
-        # each device accumulates, and holds, its own shards' share
-        sh = bits.sharding if kernels.shards_axis_of(bits) else None
-        S_dev = S_stack // len(sh.device_set) if sh is not None else S_stack
-        declined = (
-            "demand" if Q < 2
-            else "shape" if not bsi.sum_batch_supported(S_dev, W)
+        # (sig, pairs) -> [(slot, leaves, ranges)]
+        groups: dict[tuple, list[tuple]] = {}
+        for i, signed in sum_items:
+            if not calls[i].children:
+                continue
+            if signed is None:
+                self._sum_decline("shape")
+            else:
+                sig, pairs, leaves, ranges = signed
+                groups.setdefault((sig, pairs), []).append(
+                    (i, leaves, ranges)
+                )
+        # a mesh-sharded stack: each device accumulates, and holds the
+        # filter words of, its own shards' share
+        layout = kernels.shards_axis_of(bits)
+        S_dev = int(bits.shape[0]) // (
+            len(bits.sharding.device_set) if layout else 1
+        )
+        P = astbatch.SUM_CHUNK
+        # the int32 accumulator and a chunk's filter words, whatever the tree
+        gate = (
+            "shape" if not bsi.sum_batch_supported(S_dev, field.n_words)
             else "budget"
-            if S_dev * P * W * 4 > self._BSI_SUM_FILTER_BUDGET_BYTES
+            if S_dev * P * field.n_words * 4
+            > self._BSI_SUM_FILTER_BUDGET_BYTES
             else None
         )
-        if declined is not None:
-            # per-query host lane (existing sum path) answers
-            self._lane_decline("bsi_sums", declined, Q)
+        for (sig, pairs), items in groups.items():
+            st = self._tree_stacks(
+                idx, pairs, shard_list, demand, memo, allow_spanning=False
+            )
+            declined = (
+                st if isinstance(st, str)
+                # stacks built under two serving meshes share no program
+                else "mesh"
+                if any(kernels.shards_axis_of(a) != layout for a in st[0])
+                else gate
+            )
+            if declined is not None:
+                self._sum_decline(declined, len(items))
+                continue
+            stacks, slot_maps = st
+            ready = []  # (slot, row slots, a bound list a range leaf)
+            for i, leaves, ranges in items:
+                try:
+                    ready.append((
+                        i,
+                        # absent rows -> slot -1 (zero words in the leaf)
+                        [slot_maps[f, vn].get(r, -1) for f, vn, r in leaves],
+                        [self._bsi_stored_bounds(f, c) for f, c in ranges],
+                    ))
+                except (ValueError, TypeError):
+                    # the per-call path raises per query
+                    self._sum_decline("error")
+            depths = [f.bit_depth for f, _ in items[0][2]]
+            for at in range(0, len(ready), P):
+                chunk = ready[at:at + P]
+                slots = np.full((P, len(items[0][1])), -1, np.int32)
+                slots[:len(chunk)] = [sl for _, sl, _ in chunk]
+                bounds = tuple(
+                    bsi.pack_bounds([qb[k] for _, _, qb in chunk], d, P)
+                    for k, d in enumerate(depths)
+                )
+                self.bsi_stack_launches += 1
+                self.sum_lane["launches"] += 1
+                self.sum_lane["device_filters"] += len(chunk)
+                with tracing.start_span("executor.bsiSumBatch").set_tag(
+                    "n", len(chunk)
+                ):
+                    acc = astbatch.run_sum_batch(
+                        sig, bits, stacks, slots, bounds, len(chunk)
+                    )
+                late.append((acc, field, [c[0] for c in chunk]))
+
+    def _sum_decline(self, reason: str, n: int = 1) -> None:
+        """``n`` filtered Sums the device-filter path hands to the
+        per-call path, which builds their filters on the host."""
+        self._lane_decline("bsi_sums", reason, n)
+        self.sum_lane["host_filters"] += n
+
+    def _pull_sums(self, late: list, results: list[Any]) -> None:
+        """The Sum lane's launches of a flight, pulled, combined and
+        demuxed: ``late`` holds (accumulator, summed field, slots) a
+        launch.  The device runs its stream in order, so behind the
+        GroupBy lane's last pull these are ready.  Booked under
+        ``executor.batchBSI`` like the lane that launched them.  Trouble
+        with one launch leaves its slots _UNSET for the per-call path."""
+        if not late:
             return
-        fw = np.zeros((S_stack, P, W), np.uint32)
-        for qi, (_, filt) in enumerate(filtered):
-            fw[:, qi, :] = self._row_to_shard_matrix(filt, shard_list, bits)
-        if P > Q:
-            kernels.note_pad(
-                "bsi_sum_batch", S_stack * P * W * 4, S_stack * Q * W * 4
-            )
-        exists, sign, planes = self._bsi_split(bits)
-        self.bsi_stack_launches += 1
-        with tracing.start_span("executor.bsiSumBatch").set_tag("n", Q):
-            filters = kernels.h2d(fw, sh)
-            pairs = bsi.sum_batch_host(
-                planes, exists, sign, filters, depth=depth
-            )
-            with tracing.start_span("executor.demux").set_tag("n", Q):
-                for (i, _), tc in zip(filtered, pairs):
-                    results[i] = self._sum_valcount(field, tc)
+        with tracing.start_span("executor.batchBSI").set_tag(
+            "n", sum(len(slots) for *_, slots in late)
+        ), tracing.start_span("executor.bsiSumPull").set_tag(
+            "launches", len(late)
+        ):
+            for acc, field, slots in late:
+                try:
+                    pairs = bsi.sum_pairs(
+                        acc, depth=field.bit_depth, n=len(slots)
+                    )
+                except Exception:
+                    # per-call path re-raises per query
+                    self._lane_decline("bsi_sums", "error", len(slots))
+                    continue
+                with tracing.start_span("executor.demux").set_tag(
+                    "n", len(slots)
+                ):
+                    for i, tc in zip(slots, pairs):
+                        results[i] = self._sum_valcount(field, tc)
 
     def _bitmap_call(self, idx: Index, call: Call, shards: list[int]) -> Row:
         name = call.name

@@ -70,7 +70,7 @@ SHARED = "__shared__"
 _CSE_OPS = {"Intersect", "Union", "Difference", "Xor", "Not"}
 
 # Fully-commutative operators; Difference commutes only past its head.
-_COMMUTATIVE = {"Intersect", "Union", "Xor"}
+COMMUTATIVE = {"Intersect", "Union", "Xor"}
 
 # Unpriceable subtrees sort last (stable), never first.
 _UNKNOWN_COST = float("inf")
@@ -296,7 +296,7 @@ class FlightPlanner:
         for c in node.children:
             changed += self._reorder_tree(idx, c, shard_list, cache)
         kids = node.children
-        if node.name in _COMMUTATIVE and len(kids) > 1:
+        if node.name in COMMUTATIVE and len(kids) > 1:
             order = self._cost_order(idx, kids, shard_list, cache)
             if order != list(range(len(kids))):
                 node.children = [kids[j] for j in order]
@@ -340,7 +340,7 @@ class FlightPlanner:
         if name in ("Not", "All"):
             bits, _ = self._field_mass(idx, "_exists", shard_list, cache)
             return float(bits)
-        if name in _COMMUTATIVE or name == "Difference":
+        if name in COMMUTATIVE or name == "Difference":
             kid_costs = [
                 self._subtree_cost(idx, c, shard_list, cache)
                 for c in call.children

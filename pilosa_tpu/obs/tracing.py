@@ -155,6 +155,7 @@ SPAN_TABLE = (
     ("executor.bsiRangeCountBatch", _LANES, _HOST_MS),
     ("executor.bsiFilteredCountBatch", _LANES, _HOST_MS),
     ("executor.bsiSumBatch", _LANES, _HOST_MS),
+    ("executor.bsiSumPull", _LANES, _HOST_MS),
     ("executor.batchGroupBy", _LANES, _HOST_MS),
     ("executor.groupByBatch", _LANES, _HOST_MS),
     ("executor.groupByKLevel", _LANES, _HOST_MS),

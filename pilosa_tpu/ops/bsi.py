@@ -480,6 +480,37 @@ def _query_eval(planes, neg_cols, nonneg_cols, mB, iB, tB, depth: int, need):
     return r
 
 
+def bounds_width(depth: int) -> int:
+    """Words a query's two bounds take in :func:`pack_bounds`' row."""
+    return 2 * (depth + _M_CH)
+
+
+def pack_bounds(queries, depth: int, q_pad: int) -> np.ndarray:
+    """One row a query for the encoded bounds of ``queries`` where a
+    program takes a range predicate as a LEAF of a compiled tree
+    (exec/astbatch.py): ``uint32[q_pad, bounds_width(depth)]``, each of
+    the two bounds its magnitude masks and then its meta row.  Always two
+    bounds a query (a missing one is the neutral ``any``) and read with
+    ``need = (True, True)``, so no drawn value, a band whose low end is 0
+    or a bound out of range among them, is a compile key."""
+    two = [list(b) + [("any", 0)] * (2 - len(b)) for b in queries]
+    qmask, _, qmeta, _ = encode_query_bounds(two, depth, q_pad=q_pad)
+    return np.concatenate([qmask, qmeta], axis=-1).reshape(q_pad, -1)
+
+
+def range_words(bits, packed, *, depth: int):
+    """Traced: the ``[S, W]`` words of one query's range predicate
+    (``packed``: its row of :func:`pack_bounds`) over the raw BSI stack
+    ``bits[S, depth+2, W]``, sliced in here."""
+    exists, sign, planes = bits[:, 0], bits[:, 1], bits[:, 2:]
+    packed = packed.reshape(2, depth + _M_CH)
+    mB, tB = packed[:, :depth], packed[:, depth:]
+    return _query_eval(
+        planes, exists & sign, exists & ~sign, mB, ~mB, tB, depth,
+        (True, True),
+    )
+
+
 @partial(jax.jit, static_argnames=("depth", "need"))
 def _range_batch_kernel(planes, exists, sign, qmask, qinv, qmeta, *, depth: int, need):
     """[Q, ..., W] result masks for Q encoded range predicates in ONE
@@ -766,60 +797,26 @@ def _sum_batch_kernel(planes, exists, sign, filters):
     return acc
 
 
-@lru_cache(maxsize=8)
-def _sum_batch_mesh_fn(mesh, axis):
-    """jit(shard_map) of the fused Sum over a shards-sharded BSI stack
-    and filter tensor: each device scans its own shards and the
-    per-device accumulators ``int32[devices, depth+1, 2Q]`` come back
-    along the mesh axis for the host's int64 sum."""
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    def local(planes, exists, sign, filters):
-        return _sum_batch_kernel(planes, exists, sign, filters)[None]
-
-    return jax.jit(
-        shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(
-                P(axis, None, None), P(axis, None), P(axis, None),
-                P(axis, None, None),
-            ),
-            out_specs=P(axis, None, None),
-            check_vma=False,
-        )
-    )
+def sum_filters_acc(bits, filters):
+    """Traced: :func:`_sum_batch_kernel` over the RAW stack
+    ``bits[S, depth+2, W]``, sliced in here, for filter words a program
+    built itself (exec/astbatch.py ``compiled_sum``)."""
+    return _sum_batch_kernel(bits[:, 2:], bits[:, 0], bits[:, 1], filters)
 
 
-def sum_batch_host(planes, exists, sign, filters, *, depth: int):
-    """Batched Sum host wrapper: ``[(sum, count), ...]`` per filter row.
-    ``filters`` is ``uint32[S, Q, W]`` (pass ``exists`` slices for
-    unfiltered queries); place-value combine in python ints so totals
-    past 2^63 stay exact.  Filters laid out over a serving mesh (the
-    stack's own sharding) choose the SPMD form."""
+def sum_pairs(acc, *, depth: int, n: int):
+    """ONE pull of a fused Sum's accumulator (``int32[depth+1, 2Q]``, or
+    one such a device along a leading mesh axis) and the place-value
+    combine: ``[(sum, count), ...]`` of its first ``n`` queries, in
+    python ints so totals past 2^63 stay exact."""
     from pilosa_tpu.ops import kernels
 
-    Q = int(filters.shape[1])
-    m = kernels.shards_axis_of(filters)
-    with kernels.enqueue("bsi_sum_batch") as sp:
-        if m is not None:
-            acc = _sum_batch_mesh_fn(*m)(planes, exists, sign, filters)
-        else:
-            acc = _sum_batch_kernel(planes, exists, sign, filters)
-    kernels.note_bsi_dispatch(
-        "bsi_sum_batch",
-        wall=sp.duration,
-        args=(planes, filters),
-        depth=depth,
-        q_bucket=Q,
-        q_useful=Q,
-    )
-    acc = kernels.pull(acc, "bsi_sum_batch").astype(np.int64)  # [depth+1, 2Q]
-    if m is not None:
+    acc = kernels.pull(acc, "bsi_sum_filtered").astype(np.int64)
+    if acc.ndim == 3:
         acc = acc.sum(axis=0)  # one accumulator a device
+    Q = acc.shape[1] // 2
     out = []
-    for q in range(Q):
+    for q in range(n):
         pos, neg = acc[:, q], acc[:, Q + q]
         total = sum(int(pos[k]) << k for k in range(depth)) - sum(
             int(neg[k]) << k for k in range(depth)

@@ -745,6 +745,10 @@ class Handler(BaseHTTPRequestHandler):
                     f"groupby_lane_{k}": v
                     for k, v in ex.groupby_lane.items()
                 },
+                # the Sum lane: filtered Sums whose filter was built on
+                # the device, those it left to the per-call path, and its
+                # launches
+                **{f"sum_lane_{k}": v for k, v in ex.sum_lane.items()},
                 # stacks not built, by reason (exec/stacks.py), and
                 # flight items a batch lane handed back to the per-call
                 # path, by lane and reason (exec/executor.py)

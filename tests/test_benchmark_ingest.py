@@ -36,6 +36,8 @@ STREAMED = [
 # PR 42: a flight's wall time in three, from the span table's CPU clock
 FLIGHT_MS = ["batcher.cpu_ms_per_flight", "batcher.device_wait_ms_per_flight",
              "batcher.stalled_ms_per_flight"]
+# PR 43: the share of filtered Sums whose filter was built on the device
+SUM_DEVICE = "executor.sum_filter_device_pct"
 
 
 def over_limit(line: dict) -> dict:
@@ -157,9 +159,28 @@ def test_the_three_of_a_flight_add_up_and_the_counters_readers_are_data():
     assert run.read_layer_metric(waits, ctx) == pytest.approx(0.125)
     ctx["vars"]["serving_cache"]["groupby_lane_pulls"] = 0  # a window in which the lane pulled nothing
     assert run.read_layer_metric(waits, ctx) == 0.0
-    # the last six entries are this PR's, in the order they were appended
-    assert [m["name"] for m in MANIFEST["per_layer"][-6:]] == FLIGHT_MS + [
-        "listener.stalled_ms_per_read", out_of_place, waits]
+    # PR 42's six entries in the order they were appended, then PR 43's one
+    assert [m["name"] for m in MANIFEST["per_layer"][-7:]] == FLIGHT_MS + [
+        "listener.stalled_ms_per_read", out_of_place, waits, SUM_DEVICE]
+
+
+def test_the_sum_lanes_share_reads_its_two_counters_and_zero_where_there_are_none():
+    import run
+
+    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == SUM_DEVICE)
+    assert entry == {"name": SUM_DEVICE, "unit": "%", "better": "higher", "source": "program_counter",
+                     "layer": "executor lanes", "moves": "read_qps"}  # no list: every cell sends filtered Sums
+    assert os.path.exists(os.path.join(REPO, "benchmark", "layer_metrics", SUM_DEVICE + ".py"))
+
+    def read(cache):
+        return run.read_layer_metric(SUM_DEVICE, {"vars": {"serving_cache": cache}})
+
+    assert read({"sum_lane_device_filters": 57, "sum_lane_host_filters": 3}) == pytest.approx(95.0)
+    assert read({"sum_lane_device_filters": 8, "sum_lane_host_filters": 0}) == 100.0
+    assert read({"sum_lane_device_filters": 0, "sum_lane_host_filters": 4}) == 0.0
+    assert read({"sum_lane_device_filters": 0, "sum_lane_host_filters": 0}) == 0.0  # no filtered Sum
+    assert read({"groupby_lane_pulls": 9}) == 0.0  # a program without the counter
+    assert run.read_layer_metric(SUM_DEVICE, {"vars": {}}) == 0.0
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -176,7 +197,7 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert mf.validate_line(MANIFEST, CELL, bool(trace), line) == []
     want = [m["name"] for m in mf.metrics_for(MANIFEST, CELL, bool(trace))]
-    assert list(line["metrics"]) == want and len(want) == (33 if trace else 3)
+    assert list(line["metrics"]) == want and len(want) == (34 if trace else 3)
     assert line["correct"] is False
     compared = {k: v for k, (v, _) in line["compared"].items()}
     assert over_limit(line) == {"rehearsal": 1}, err
@@ -188,12 +209,14 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
         value = {k: v["value"] for k, v in line["metrics"].items()}
         # the stream's own, then what later PRs gave every cell
         assert [n for n in want if n in STREAMED] == STREAMED
-        assert want[-8:-6] == ["listener.cpu_ms_per_read", "executor.groupby_inflight_per_pull"]
-        assert value[want[-8]] > 0 and value[want[-7]] >= 1, err  # groupby3 rode the lane
-        assert want[-6:-2] == FLIGHT_MS + ["listener.stalled_ms_per_read"]
-        assert want[-2:] == ["stacks.refresh_out_of_place_per_import",
-                             "executor.groupby_budget_waits_per_pull"]
-        assert value[want[-2]] >= 0 and value[want[-1]] == 0  # the tiny shape is far under the lane's bound
+        assert want[-9:-7] == ["listener.cpu_ms_per_read", "executor.groupby_inflight_per_pull"]
+        assert value[want[-9]] > 0 and value[want[-8]] >= 1, err  # groupby3 rode the lane
+        assert want[-7:-3] == FLIGHT_MS + ["listener.stalled_ms_per_read"]
+        assert want[-3:] == ["stacks.refresh_out_of_place_per_import",
+                             "executor.groupby_budget_waits_per_pull", SUM_DEVICE]
+        assert value[want[-3]] >= 0 and value[want[-2]] == 0  # the tiny shape is far under the lane's bound
+        # every filtered Sum of the window had its filter built on the device (PR 43)
+        assert value[SUM_DEVICE] == 100, err
         # a flight's wall time in three: interpreter, device wait, and the rest
         assert value[FLIGHT_MS[0]] > 0 and value[FLIGHT_MS[1]] > 0, err
         assert sum(value[n] for n in FLIGHT_MS) == flight_ms_of_the_log(p.stderr)

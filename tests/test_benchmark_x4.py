@@ -16,7 +16,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
 
 import manifest as mf  # noqa: E402
-from test_benchmark_ingest import FLIGHT_MS, flight_ms_of_the_log  # noqa: E402
+from test_benchmark_ingest import FLIGHT_MS, SUM_DEVICE, flight_ms_of_the_log  # noqa: E402
 
 CELL = "taxi-x4.dashboard-c32"
 MANIFEST = mf.load()
@@ -47,7 +47,7 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert mf.validate_line(MANIFEST, CELL, bool(trace), line) == []
     want = [m["name"] for m in mf.metrics_for(MANIFEST, CELL, bool(trace))]
-    assert list(line["metrics"]) == want and len(want) == (28 if trace else 3)
+    assert list(line["metrics"]) == want and len(want) == (29 if trace else 3)
     assert line["correct"] is False
     over = {k: v for k, (v, limit) in line["compared"].items() if v > limit}
     assert over == {"rehearsal": 1}, err
@@ -59,4 +59,6 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
         value = {k: v["value"] for k, v in line["metrics"].items()}
         assert value[FLIGHT_MS[0]] > 0 and value[FLIGHT_MS[1]] > 0, err
         assert sum(value[n] for n in FLIGHT_MS) == flight_ms_of_the_log(p.stderr)
+        # the mesh form of a filtered Sum's program took every one of the window's (PR 43)
+        assert want[-1] == SUM_DEVICE and value[SUM_DEVICE] == 100, err
     assert os.listdir(tmp_path) == [], "the run left its work directory"

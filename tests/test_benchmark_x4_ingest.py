@@ -22,7 +22,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmark"))
 
 import manifest as mf  # noqa: E402
-from test_benchmark_ingest import FLIGHT_MS, flight_ms_of_the_log, over_limit  # noqa: E402
+from test_benchmark_ingest import FLIGHT_MS, SUM_DEVICE, flight_ms_of_the_log, over_limit  # noqa: E402
 
 CELL = "taxi-x4.ingest-serve"
 MANIFEST = mf.load()
@@ -161,7 +161,7 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
     line = json.loads(p.stdout.strip().splitlines()[-1])
     assert mf.validate_line(MANIFEST, CELL, bool(trace), line) == []
     want = [m["name"] for m in mf.metrics_for(MANIFEST, CELL, bool(trace))]
-    assert list(line["metrics"]) == want and len(want) == (37 if trace else 3)
+    assert list(line["metrics"]) == want and len(want) == (38 if trace else 3)
     assert line["correct"] is False
     compared = {k: v for k, (v, _) in line["compared"].items()}
     assert over_limit(line) == {"rehearsal": 1}, err
@@ -183,6 +183,8 @@ def test_rehearsal_line_is_the_manifests(tmp_path, trace):
         # a flight's wall time in three: interpreter, device wait, and the rest
         assert value[FLIGHT_MS[0]] > 0 and value[FLIGHT_MS[1]] > 0, err
         assert sum(value[n] for n in FLIGHT_MS) == flight_ms_of_the_log(p.stderr)
+        # beside the stream and over the mesh, every filtered Sum's filter was built on the device (PR 43)
+        assert want[-1] == SUM_DEVICE and value[SUM_DEVICE] == 100, err
         # the reads ran over the mesh; a refresh's launch is one chip's
         assert 50 < value["mesh.sharded_launch_pct"] <= 100, err
     assert os.listdir(tmp_path) == [], "the run left its work directory"

@@ -281,7 +281,8 @@ def test_sum_batch_matches_per_query(stacked):
         ],
         axis=1,
     )
-    got = bsi.sum_batch_host(planes, exists, sign, filters, depth=DEPTH)
+    acc = bsi._sum_batch_kernel(planes, exists, sign, filters)
+    got = bsi.sum_pairs(acc, depth=DEPTH, n=3)
     assert len(got) == 3
     for q in range(3):
         total, count = 0, 0
