@@ -54,13 +54,15 @@ def to_json(call_name: str, raw):
         order = np.argsort(-counts, kind="stable")
         pairs = [{"id": int(i), "count": int(counts[i])} for i in order if counts[i] > 0]
         return pairs[:n] if n else pairs
-    if call_name == "GroupBy":
-        names, counts = raw
-        return [
-            {"group": [{"field": f, "rowID": int(r)} for f, r in zip(names, idx)],
-             "count": int(counts[idx])}
-            for idx in zip(*np.nonzero(counts))
-        ]
+    if call_name == "GroupBy":  # Pilosa 1.4's GroupCount: group, count and, under aggregate=Sum(field=), sum
+        names, counts, *sums = raw
+        listed = []
+        for idx in zip(*np.nonzero(counts)):
+            listed.append({"group": [{"field": f, "rowID": int(r)} for f, r in zip(names, idx)],
+                           "count": int(counts[idx])})
+            if sums:
+                listed[-1]["sum"] = int(sums[0][idx])
+        return listed
     return {"attrs": {}, "columns": [int(c) for c in raw]}
 
 
@@ -92,7 +94,7 @@ def check_answer(call_name: str, got, want) -> str | None:
                 return "topn repeats an id"
             return None
         if call_name == "GroupBy":
-            names, counts = want
+            names, counts, *sums = want  # sums: asked for with aggregate=Sum(field=), else a served one is ignored
             seen = np.zeros(counts.shape, np.int64)
             for g in got:
                 if [x["field"] for x in g["group"]] != names:
@@ -101,9 +103,17 @@ def check_answer(call_name: str, got, want) -> str | None:
                 if g["count"] <= 0 or seen[idx]:
                     return f"group {idx} count {g['count']} (empty or repeated)"
                 seen[idx] = g["count"]
+                if sums:
+                    want_sum = int(sums[0][idx])  # a Python integer: a served one may be past int64
+                    if "sum" not in g:
+                        return f"group {idx} has no sum, want {want_sum}"
+                    if isinstance(g["sum"], bool) or not isinstance(g["sum"], int):
+                        return f"group {idx} sum {g['sum']!r} is no integer, want {want_sum}"
+                    if g["sum"] != want_sum:
+                        return f"group {idx} sum {g['sum']}, want {want_sum}"
             bad = np.argwhere(seen != counts)
             if len(bad):
-                idx = tuple(bad[0])
+                idx = tuple(int(i) for i in bad[0])
                 return f"group {idx} count {seen[idx]}, want {counts[idx]}"
             return None
         cols = np.asarray(got["columns"], np.int64)

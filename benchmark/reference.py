@@ -233,8 +233,10 @@ class Reference:
     def evaluate(self, c: Call):
         """The call's answer in a plain form ``compare`` understands:
         Count -> int; Sum -> (value, count); TopN -> (per-row counts, n);
-        GroupBy -> (field names, counts by combined row ids); a bitmap
-        call -> sorted column ids."""
+        GroupBy -> (field names, counts by combined row ids), and with
+        ``aggregate=Sum(field=<int field>)`` a third member, the field's
+        values added up by the same ids (int64, exact); a bitmap call ->
+        sorted column ids."""
         if c.name == "Count":
             return int(np.count_nonzero(self.bitmap(c.pos[0])))
         if c.name == "Sum":
@@ -247,6 +249,7 @@ class Reference:
             filt = self.bitmap(c.pos[1]) if len(c.pos) > 1 else None
             return self.row_counts(c.pos[0], filt), c.kw.get("n", 0)
         if c.name == "GroupBy":
+            summed = _summed_field(c, self.fields)  # an aggregate it does not know is refused before any work
             names = [r.pos[0] for r in c.pos]
             sizes = [int(self.fields[n]["rows"]) for n in names]
             valid = self.bitmap(c.kw["filter"]) if "filter" in c.kw else None
@@ -257,8 +260,14 @@ class Reference:
             code = np.zeros(len(at), np.int64)
             for n, size in zip(names, sizes):
                 code = code * size + self.one[n].ravel()[at]
-            counts = np.bincount(code, minlength=int(np.prod(sizes)))
-            return names, counts.reshape(sizes)
+            groups = int(np.prod(sizes))
+            counts = np.bincount(code, minlength=groups).reshape(sizes)
+            if summed is None:
+                return names, counts
+            # a group's count is its columns under the filter, valued or not; its sum is over the valued
+            v = self.one[summed].ravel()[at].astype(np.int64)
+            held = v >= 0
+            return names, counts, _group_sums(code[held], v[held], groups).reshape(sizes)
         cols = np.flatnonzero(self.bitmap(c).ravel())
         # [shards, columns] -> global column ids
         return (cols // self.hi) * self.width + cols % self.hi
@@ -266,8 +275,9 @@ class Reference:
 
 def _names(c: Call) -> set:
     """Every identifier of a call that can name a field: the keys of a
-    ``Row``, the field of a condition, of ``Sum(field=)``, of ``TopN`` and
-    ``Rows``; the caller keeps those that are fields."""
+    ``Row``, the field of a condition, of ``Sum(field=)`` (also where it is
+    a ``GroupBy``'s ``aggregate``), of ``TopN`` and ``Rows``; the caller
+    keeps those that are fields."""
     out = set(c.kw) | {v for v in c.kw.values() if isinstance(v, str)}
     out |= {p for p in c.pos if isinstance(p, str)}
     if c.cond is not None:
@@ -276,6 +286,33 @@ def _names(c: Call) -> set:
         if isinstance(p, Call):
             out |= _names(p)
     return out
+
+
+def _summed_field(c: Call, fields: dict) -> str | None:
+    """The int field a ``GroupBy`` adds up beside each group's count:
+    ``aggregate=Sum(field=<int field of the index>)`` and nothing else in
+    the ``Sum``; None where the call has no ``aggregate``."""
+    agg = c.kw.get("aggregate")
+    if agg is None:
+        return None
+    if (not isinstance(agg, Call) or agg.name != "Sum" or agg.pos or agg.cond is not None
+            or set(agg.kw) != {"field"} or not isinstance(agg.kw["field"], str)
+            or fields.get(agg.kw["field"], {}).get("kind") != "int"):
+        raise ValueError(f"no reference for GroupBy(aggregate={agg!r})")
+    return agg.kw["field"]
+
+
+def _group_sums(code: np.ndarray, values: np.ndarray, groups: int) -> np.ndarray:
+    """``values`` (int64) added up by ``code`` -> int64 [groups].  Integer
+    adds over the sorted codes: ``np.bincount(weights=)`` adds in float64,
+    which is exact only under 2^53."""
+    sums = np.zeros(groups, np.int64)
+    if len(code):
+        order = np.argsort(code, kind="stable")
+        code, values = code[order], values[order]
+        starts = np.flatnonzero(np.concatenate(([True], code[1:] != code[:-1])))
+        sums[code[starts]] = np.add.reduceat(values, starts)
+    return sums
 
 
 def _compare(v: np.ndarray, op: str, x) -> np.ndarray:
